@@ -14,10 +14,6 @@ import timeit
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import jax.numpy as jnp
 import numpy as np
 import optax
@@ -25,6 +21,7 @@ import optax
 import horovod_tpu as hvd
 import horovod_tpu.jax as hvd_jax
 from horovod_tpu import models
+from horovod_tpu.utils import compile_cache
 
 
 def main():
@@ -43,6 +40,7 @@ def main():
     parser.add_argument("--use-adasum", action="store_true")
     args = parser.parse_args()
 
+    compile_cache.enable()
     hvd.init()
     n = hvd.size()
     global_batch = n * args.batch_size

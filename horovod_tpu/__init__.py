@@ -22,7 +22,7 @@ from .basics import (  # noqa: F401
 from .exceptions import (  # noqa: F401
     HorovodInternalError, HostsUpdatedInterrupt, NotInitializedError,
     DuplicateNameError, StalledTensorError, SubmissionOrderError,
-    CollectiveLintError,
+    CollectiveLintError, TpuHostSharedError,
 )
 from .ops.reduce_ops import (  # noqa: F401
     Average, Sum, Adasum, Min, Max, Product,

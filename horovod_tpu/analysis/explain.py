@@ -11,7 +11,7 @@ slot where the cohort diverged, and maps it back to the exact source
 line (f-string collective names like ``f"step{epoch}"`` are matched
 through the patterns the schedule extractor records).
 
-Divergence taxonomy (mirrors the simulator's rule family):
+Divergence classes (mirrors the simulator's rule family):
 
 - ``missing_submission`` → **HVD501**: some rank(s) never submitted a
   slot the others are waiting in — the runtime incarnation of a proven

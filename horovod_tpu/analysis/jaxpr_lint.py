@@ -352,18 +352,8 @@ def check_fn(fn, *args, axis_sizes=None, **kwargs):
 
     axis_sizes = dict(axis_sizes or {})
     try:
-        core = jax.core
-        extend = core.extend_axis_env_nd
-    except AttributeError:  # pragma: no cover - jax version drift
-        from jax._src import core as _core
-        extend = _core.extend_axis_env_nd
-
-    try:
-        if axis_sizes:
-            with extend(list(axis_sizes.items())):
-                closed = jax.make_jaxpr(fn)(*args, **kwargs)
-        else:
-            closed = jax.make_jaxpr(fn)(*args, **kwargs)
+        closed = jax.make_jaxpr(
+            fn, axis_env=list(axis_sizes.items()))(*args, **kwargs)
     except NameError as exc:
         # "unbound axis name: X" — the trace itself proves HVD101.
         return [Diagnostic.make(

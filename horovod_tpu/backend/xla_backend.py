@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax import shard_map as _shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import Backend
@@ -35,7 +36,6 @@ from ..ops import reduce_ops
 from ..telemetry import core as telemetry
 from ..telemetry import span as tele_span
 from ..utils import envparse
-from ..utils.jax_compat import shard_map as _shard_map
 
 AXIS = "hvd"
 # Bound on cached compiled programs, the analog of the reference's

@@ -21,12 +21,12 @@ owns the remaining chips. Select with ``HVDTPU_CPU_OPERATIONS=xla``.
 """
 
 import numpy as np
+from jax import shard_map as _shard_map
 
 from .tcp_backend import TcpBackend
 from .. import native
 from ..exceptions import HorovodInternalError
 from ..utils import envparse
-from ..utils.jax_compat import shard_map as _shard_map
 from ..utils.logging_util import get_logger
 
 # Native wire enums (csrc/common.h).
@@ -78,16 +78,13 @@ def init_jax_distributed(topology):
     (rank 0 publishes; the analog of the NCCL unique-id broadcast through
     the controller, nccl_operations.cc:102-119)."""
     import jax
-    try:
-        if jax.distributed.is_initialized():
-            # Fresh world pre-initialized by user code: reuse it. (A
-            # stale post-reset world cannot reach here: elastic resets
-            # on this plane happen across a process boundary —
-            # elastic.py exit-restart — so a live process never holds a
-            # previous cohort's jax.distributed world.)
-            return
-    except AttributeError:  # older jax
-        pass
+    if jax.distributed.is_initialized():
+        # Fresh world pre-initialized by user code: reuse it. (A stale
+        # post-reset world cannot reach here: elastic resets on this
+        # plane happen across a process boundary — elastic.py
+        # exit-restart — so a live process never holds a previous
+        # cohort's jax.distributed world.)
+        return
     log = get_logger()
     coord = envparse.get_str(envparse.XLA_COORD, "")
     if coord:
@@ -369,8 +366,7 @@ class XlaGlobalBackend(TcpBackend):
             out_specs = P()
 
         # Replication-check off: all_gather-then-index outputs ARE
-        # replicated over 'hvd' but the inference can't prove it (the
-        # compat shim maps check_vma onto check_rep on older jax).
+        # replicated over 'hvd' but the inference can't prove it.
         fn = jax.jit(_shard_map(body, mesh=mesh, in_specs=P("hvd"),
                                 out_specs=out_specs, check_vma=False))
         self._fn_cache[key] = fn

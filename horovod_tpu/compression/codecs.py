@@ -244,8 +244,7 @@ def quantized_allreduce_axis(x, axis_name, codec="int8",
     if not c.wire:
         raise ValueError(
             f"quantized_allreduce_axis needs a wire codec, got {c.name!r}")
-    from ..utils.jax_compat import axis_size
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     orig_shape = x.shape
     orig_dtype = x.dtype
     flat = x.reshape(-1).astype(jnp.float32)

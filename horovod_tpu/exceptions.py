@@ -1,6 +1,6 @@
 """Framework exceptions.
 
-Mirrors the exception taxonomy of the reference framework
+Mirrors the exception hierarchy of the reference framework
 (reference: horovod/common/exceptions.py) so elastic training loops can be
 written the same way: a recoverable collective failure raises
 ``HorovodInternalError`` and a membership change raises
@@ -44,6 +44,13 @@ class HostsUpdatedInterrupt(Exception):
 
 class HorovodVersionMismatchError(ImportError):
     """Library/extension version mismatch (reference: horovod/common/exceptions.py)."""
+
+
+class TpuHostSharedError(RuntimeError):
+    """Several launcher-spawned processes on one host were about to open
+    the TPU. A chip belongs to one process and the launcher pins no chip
+    to a worker, so all but one of them would die on libtpu's lockfile
+    or hang in client creation."""
 
 
 class NotInitializedError(RuntimeError):

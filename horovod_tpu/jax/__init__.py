@@ -25,6 +25,7 @@ TPU:
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map as _shard_map
 
 from .. import basics
 from ..functions import (broadcast_object, broadcast_optimizer_state,
@@ -34,13 +35,9 @@ from ..ops import reduce_ops
 from ..ops.adasum import adasum_axis
 from ..ops.compression import Compression
 from ..process_sets import global_process_set
+from ..utils.jax_compat import pvary as _pvary
 
 HVD_AXIS = "hvd"
-
-
-from ..utils.jax_compat import axis_size as _axis_size  # noqa: E402
-from ..utils.jax_compat import pvary as _pvary  # noqa: E402
-from ..utils.jax_compat import shard_map as _shard_map  # noqa: E402
 
 
 def _reduce_in_axis(grads, op, axis_name, prescale=None, postscale=None):
@@ -56,7 +53,7 @@ def _reduce_in_axis(grads, op, axis_name, prescale=None, postscale=None):
             # All ranks hold the identical tree-reduction, but the ppermute
             # schedule leaves the value typed device-varying; a psum of g/n
             # is a semantic no-op that re-establishes replica invariance.
-            n = _axis_size(axis_name)
+            n = lax.axis_size(axis_name)
             g = lax.psum(g / n, axis_name)
         else:
             raise ValueError(
@@ -486,9 +483,7 @@ class DistributedOptimizer:
 
         # Merge the stepped and held states with a select rather than
         # lax.cond: the optimizer update is a few elementwise ops per
-        # parameter (noise next to the backward pass), and cond branches
-        # break the shard_map replication checker on pre-vma jax
-        # ("branches produced mismatched replication types").
+        # parameter (noise next to the backward pass).
         def pick(a, b):
             return jnp.where(do_step, a, b)
 

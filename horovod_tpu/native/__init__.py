@@ -24,7 +24,7 @@ _LIB_PATH = os.path.join(_PKG_DIR, "libhvdcore.so")
 _CSRC_DIR = os.path.join(os.path.dirname(os.path.dirname(_PKG_DIR)), "csrc")
 
 _lib = None
-_lib_lock = threading.Lock()
+_lib_lock = threading.RLock()
 
 # Enum values must match csrc/common.h.
 REQ_ALLREDUCE, REQ_ALLGATHER, REQ_BROADCAST, REQ_ALLTOALL = 0, 1, 2, 3
@@ -78,14 +78,23 @@ def _stale():
     return False
 
 
+def ensure_built():
+    """Build the library if it is missing or older than csrc/. The
+    launcher calls this once before it spawns workers: the lock below
+    is per process, so N workers starting from a clean tree would
+    otherwise each run ``make`` in csrc/ at once."""
+    with _lib_lock:
+        if _stale():
+            _build_library()
+
+
 def load_library():
     """Load (building if needed) the native core library."""
     global _lib
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if _stale():
-            _build_library()
+        ensure_built()
         lib = ctypes.CDLL(_LIB_PATH)
 
         lib.hvd_core_create.restype = ctypes.c_void_p

@@ -121,8 +121,7 @@ def adasum_axis(x, axis_name):
     compiled-data-plane analog of the reference's AdasumMPI recursive
     halving (reference: horovod/common/ops/adasum/adasum_mpi.cc).
     """
-    from ..utils.jax_compat import axis_size
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if not _is_pow2(n):
         raise ValueError(f"Adasum requires power-of-2 axis size, got {n}")
     idx = lax.axis_index(axis_name)

@@ -34,7 +34,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..utils import envparse
-from ..utils import jax_compat
 
 _bridge_fallback_noted = set()
 
@@ -80,11 +79,8 @@ def _interpret():
 def _struct(shape, dtype, *like):
     """ShapeDtypeStruct carrying the union of the inputs' varying-mesh-axes
     type so pallas_call type-checks inside shard_map (check_vma)."""
-    try:
-        vma = frozenset().union(*(jax.typeof(x).vma for x in like))
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except (AttributeError, TypeError):
-        return jax.ShapeDtypeStruct(shape, dtype)
+    vma = frozenset().union(*(jax.typeof(x).vma for x in like))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _pad_to(x, multiple, axis):
@@ -290,8 +286,8 @@ def _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
         _struct((bh, sq, d), q.dtype, q, k, v, lens),
         _struct((bh, 1, sq), jnp.float32, q, k, v, lens),
     ]
-    compiler_params = jax_compat.tpu_compiler_params(
-        ("parallel", "parallel", "arbitrary"))
+    compiler_params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
     o, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -492,8 +488,8 @@ def _bwd_call(q, k, v, o, do, lse, lens, sm_scale, causal, block_q, block_k,
     lse3 = lse[:, None, :]
     delta3 = delta[:, None, :]
 
-    compiler_params = jax_compat.tpu_compiler_params(
-        ("parallel", "parallel", "arbitrary"))
+    compiler_params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     dkv_in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, j, i, lens: (b, i, 0)),
@@ -701,16 +697,7 @@ def _prepare(q, k, v, block_q, block_k):
 
 def _varying(*xs):
     """True when any input is device-varying under shard_map (vma)."""
-    try:
-        return bool(frozenset().union(
-            *(jax.typeof(x).vma for x in xs if hasattr(x, "dtype")
-              or not np.isscalar(x))))
-    except (AttributeError, TypeError):
-        # Pre-varying-types jax: no vma on avals. Any named axis in the
-        # tracing env means we are inside a shard_map/pmap body, where
-        # interpret-mode pallas_call has no replication rule — treat it
-        # as varying so the caller takes the einsum fallback.
-        return jax_compat.inside_named_axis()
+    return any(jax.typeof(x).vma for x in xs)
 
 
 def flash_attention(q, k, v, *, causal=False, sm_scale=None,

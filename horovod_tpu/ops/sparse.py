@@ -627,10 +627,9 @@ def sparse_allreduce_axis(sg, axis_name, op=reduce_ops.Average,
     psums exactly like a dense gradient."""
     import jax.numpy as jnp
     from jax import lax
-    from ..utils.jax_compat import axis_size as _axis_size
 
     _validate_op(op, name or "<axis>")
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     plane = _plane()
     path = "dense"
     if plane is not None:

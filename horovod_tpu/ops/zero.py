@@ -52,11 +52,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map as _shard_map
 
 from . import reduce_ops
 from .bucketing import DEFAULT_BUCKET_BYTES, plan_buckets, _pack, _unpack
 from ..utils import envparse
-from ..utils.jax_compat import shard_map as _shard_map
 from ..utils.logging_util import get_logger
 
 #: ``HVDTPU_ZERO_BUCKET_BYTES`` default mirrors the overlap plane's

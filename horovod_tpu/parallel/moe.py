@@ -24,7 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..utils import jax_compat
 
 
 def _top_k_dispatch(gate_logits, k, capacity):
@@ -86,7 +85,7 @@ def moe_apply(x, w_gate, w_in, w_out, *, axis_name=None, k=2,
     """
     tokens, d = x.shape
     e_global = w_gate.shape[1]
-    n = jax_compat.axis_size(axis_name) if axis_name is not None else 1
+    n = lax.axis_size(axis_name) if axis_name is not None else 1
     e_local = w_in.shape[0]
     if e_local * n != e_global:
         raise ValueError(

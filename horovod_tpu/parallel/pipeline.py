@@ -41,8 +41,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..utils import jax_compat
-
 from ..utils.jax_compat import pvary as _pvary
 
 
@@ -55,7 +53,7 @@ def _circuit(stage_fn, params_ro, queue, axis_name, *, first=None,
     Returns (M, ...) per-tick outputs ys[n-1:] (meaningful on the last
     rank; caller masks/replicates).
     """
-    n = jax_compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     per = queue.shape[0]
     m = per * n
@@ -94,7 +92,7 @@ def _circuit(stage_fn, params_ro, queue, axis_name, *, first=None,
 
 
 def _replicate_from_last(outputs, axis_name):
-    n = jax_compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     return lax.psum(
         jnp.where(idx == n - 1, outputs, jnp.zeros_like(outputs)),
@@ -133,7 +131,7 @@ def pipeline_apply(stage_fn, stage_params, microbatches, axis_name="pp", *,
         further dp reduction); if False, only the last rank's values are
         meaningful.
     """
-    n = jax_compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     per = microbatches.shape[0]
 

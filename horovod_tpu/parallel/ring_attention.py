@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..utils import jax_compat
 
 from ..ops.flash_attention import (
     _NEG_INF, flash_attention, reference_attention)
@@ -54,7 +53,7 @@ def ring_attention(q, k, v, axis_name="sp", *, causal=True, sm_scale=None,
         shapes).
     Returns the local output chunk (batch, heads, seq_local, head_dim).
     """
-    n = jax_compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     s_local = q.shape[2]
     q_off = idx * s_local

@@ -29,13 +29,13 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops import reduce_ops
 from ..ops.bucketing import _unpack
 from ..ops.zero import (DEFAULT_ZERO_BUCKET_BYTES, _pack_padded,
                         _validate_elementwise_state, plan_zero)
-from ..utils.jax_compat import shard_map as _shard_map
 from ..utils.logging_util import get_logger
 from .sharding import make_param_specs, transformer_param_rules
 

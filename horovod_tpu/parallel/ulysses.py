@@ -11,7 +11,6 @@ extreme sequence lengths. Both compose with tp/dp via the mesh (mesh.py).
 
 from jax import lax
 
-from ..utils import jax_compat
 
 from ..ops.flash_attention import flash_attention, reference_attention
 
@@ -25,7 +24,7 @@ def ulysses_attention(q, k, v, axis_name="sp", *, causal=True, sm_scale=None,
         be divisible by the axis size.
     Returns the local output chunk (batch, heads, seq_local, head_dim).
     """
-    n = jax_compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     heads = q.shape[1]
     if heads % n != 0:
         raise ValueError(

@@ -250,7 +250,6 @@ def make_jit_executor(program, mesh, axis_name):
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import PartitionSpec as P
-    from ..utils.jax_compat import shard_map as _shard_map
 
     n = int(mesh.shape[axis_name])
     for side, name in ((program.src, "src"), (program.dst, "dst")):
@@ -338,7 +337,7 @@ def make_jit_executor(program, mesh, axis_name):
 
     in_specs = tuple(P(axis_name) for _ in src_keys)
     out_specs = tuple(P(axis_name) for _ in dst_keys)
-    mapped = jax.jit(_shard_map(
+    mapped = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False))
 

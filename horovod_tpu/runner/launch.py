@@ -293,6 +293,8 @@ def run_commandline(argv=None):
         return 0
     if args.check_build:
         return check_build()
+    from .. import native
+    native.ensure_built()
     settings = Settings(
         num_proc=args.num_proc, hosts=args.hosts, hostfile=args.hostfile,
         start_timeout=args.start_timeout, verbose=args.verbose,

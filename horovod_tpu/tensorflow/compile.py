@@ -474,8 +474,7 @@ def _classify_static_mask(mval, kind, n_q, n_k):
     q_offset = n_k - n_q — else None (fall back to einsum)."""
     # mval is concrete (the caller filtered tracers) — concretize with
     # numpy directly: jnp.asarray would re-lift it into the ambient
-    # trace (JVP/grad) where even ensure_compile_time_eval cannot
-    # concretize it back on older jax.
+    # trace (JVP/grad).
     m = np.asarray(mval)
     if kind == "select":
         if m.dtype != np.bool_:
@@ -502,8 +501,8 @@ def _classify_static_mask(mval, kind, n_q, n_k):
 
 
 def _concrete_or_none(x):
-    from ..utils.jax_compat import concrete_or_none
-    return concrete_or_none(x)
+    import jax
+    return None if isinstance(x, jax.core.Tracer) else x
 
 
 def _try_flash_attention(env, plan, opr):

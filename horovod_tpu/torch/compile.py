@@ -119,15 +119,12 @@ def _resolve_static_mask(attn_mask, jnp):
         return None
     import jax
 
-    from ..utils.jax_compat import concrete_or_none
-    concrete = concrete_or_none(attn_mask)
-    if concrete is None:
+    if isinstance(attn_mask, jax.core.Tracer):
         return attn_mask
-    # The mask is concrete (const-folded, possibly behind a check_rep
-    # RewriteTracer under shard_map), but any op on it inside the jit
-    # trace would be staged — inspect it at compile time instead.
+    # The mask is concrete (const-folded), but any op on it inside the
+    # jit trace would be staged — inspect it at compile time instead.
     import numpy as _np
-    m = _np.asarray(concrete)
+    m = _np.asarray(attn_mask)
     if m.dtype == _np.bool_:
         if bool(m.all()):
             return None

@@ -1,142 +1,17 @@
-"""JAX API compatibility shims shared across modules."""
-
-import functools
+"""Varying-axes helper shared by the shard_map train steps."""
 
 import jax
 from jax import lax
 
 
-def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-              check_vma=True):
-    """``jax.shard_map`` across the API transition: newer releases
-    export it at top level with ``check_vma=``; older ones live in
-    ``jax.experimental.shard_map`` and spell the flag ``check_rep=``."""
-    import jax
-    top = getattr(jax, "shard_map", None)
-    if top is not None:
-        try:
-            return top(f, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=check_vma)
-        except TypeError:
-            return top(f, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
-def tpu_compiler_params(dimension_semantics):
-    """Mosaic compiler params across the ``pltpu.TPUCompilerParams`` →
-    ``pltpu.CompilerParams`` rename; None when neither spelling exists
-    (pallas_call accepts compiler_params=None)."""
-    from jax.experimental.pallas import tpu as pltpu
-    for name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            try:
-                return cls(dimension_semantics=dimension_semantics)
-            except TypeError:
-                continue
-    return None
-
-
-def axis_size(axis_name):
-    """Static size of a named mesh axis from inside shard_map/pmap.
-    ``lax.axis_size`` only exists on newer jax; older releases expose
-    the same number through the core axis-env frame."""
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    import jax.core
-    frame = jax.core.axis_frame(axis_name)
-    # Older cores return the frame object, newer ones the bare size.
-    return getattr(frame, "size", frame)
-
-
-def _vary_ladder(x, axis_name, pre_vma):
-    """The pcast → pvary API ladder shared by :func:`pvary` and
-    :func:`vary_replicated`; ``pre_vma`` supplies the behavior on
-    releases that predate varying types entirely."""
-    try:
-        return lax.pcast(x, axis_name, to="varying")
-    except ValueError:
-        return x  # already device-varying along axis_name
-    except (AttributeError, TypeError):
-        pass
-    try:
-        return lax.pvary(x, axis_name)
-    except ValueError:
-        return x
-    except AttributeError:
-        return pre_vma(x, axis_name)
-
-
-def vary_replicated(x, axis_name):
-    """Declare a replicated shard_map input before differentiating a
-    loss that uses it, so its cotangent is correctly reduced across
-    ``axis_name``.
-
-    On varying-types jax this is exactly ``pvary`` (the op the type
-    system would auto-insert; transpose = psum). Pre-vma jax inserts
-    nothing — ``jax.grad`` inside a shard_map body silently returns one
-    shard's partial gradient for replicated inputs — so there this is a
-    custom-vjp identity whose backward is ``lax.pmean``: on those
-    releases psum/pmean themselves transpose to a psum of the
-    replicated cotangent (an extra factor of the axis size), and the
-    mean here cancels it, making the end-to-end gradient exact for any
-    loss that crosses the reduction once (verified against dense
-    oracles in tests/test_parallel.py and tests/test_long_context.py)."""
-    return _vary_ladder(x, axis_name, _pre_vma_vary)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-def _pre_vma_vary(x, axis_name):
-    return x
-
-
-def _pre_vma_vary_fwd(x, axis_name):
-    return x, None
-
-
-def _pre_vma_vary_bwd(axis_name, _, g):
-    return (lax.pmean(g, axis_name),)
-
-
-_pre_vma_vary.defvjp(_pre_vma_vary_fwd, _pre_vma_vary_bwd)
-
-
-def concrete_or_none(x):
-    """The concrete value behind ``x``, or None when it is genuinely
-    abstract. Unwraps bookkeeping tracers that carry their payload in
-    ``.val`` — notably the check_rep RewriteTracer of older shard_map,
-    which wraps even constants evaluated under
-    ``jax.ensure_compile_time_eval()`` inside a shard_map body."""
-    for _ in range(8):
-        if not isinstance(x, jax.core.Tracer):
-            return x
-        x = getattr(x, "val", None)
-        if x is None:
-            return None
-    return None
-
-
-def inside_named_axis():
-    """True when tracing under any named mesh axis (shard_map/pmap
-    body). Newer jax exposes this through value types (``jax.typeof(x)
-    .vma``); pre-varying-types releases only record it in the core axis
-    env, which this reads."""
-    try:
-        from jax._src import core as _core
-        return bool(_core.unsafe_get_axis_names())
-    except (ImportError, AttributeError):
-        return False
-
-
 def pvary(x, axis_name):
-    """Mark a value device-varying along ``axis_name`` (no-op if it
-    already is). Papers over the lax.pcast / lax.pvary API transition.
-    On pre-varying-types jax (<= 0.4.x) there is no vma tracking to
-    appease, so identity is exactly right — callers who need the
-    gradient contract of the auto-inserted pvary (psum'd cotangents for
-    replicated inputs) use :func:`vary_replicated` instead."""
-    return _vary_ladder(x, axis_name, lambda v, _axis: v)
+    """Mark ``x`` device-varying along ``axis_name`` (no-op if it
+    already is; ``lax.pcast`` rejects a varying input).
+
+    The cast's transpose is a psum. Differentiating with respect to the
+    cast value gives the per-replica gradient (``make_train_step``
+    reduces it explicitly); differentiating with respect to the
+    replicated input gives the gradient already summed over the axis."""
+    if axis_name in jax.typeof(x).vma:
+        return x
+    return lax.pcast(x, axis_name, to="varying")

@@ -1,6 +1,6 @@
 """Ablation: quantify BN cost in the ResNet-50 train step on the chip."""
-import sys, timeit
-sys.path.insert(0, "/root/repo")
+import os, sys, timeit
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax, optax
 import jax.numpy as jnp
 import numpy as np

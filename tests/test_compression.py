@@ -469,7 +469,7 @@ def test_quantized_allreduce_axis_numerics(hvd, n_devices):
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from horovod_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     mesh = basics.runtime().mesh
     x = rand(n_devices, 1000, seed=60)
 

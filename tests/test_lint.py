@@ -49,9 +49,8 @@ class TestJaxprRules:
         assert rules_of(diags) == ["HVD101"]
 
     def test_unbound_axis_structural(self):
-        core = jax.core
-        with core.extend_axis_env_nd([("hvd", 8), ("tp", 2)]):
-            closed = jax.make_jaxpr(lambda x: lax.psum(x, "tp"))(1.0)
+        closed = jax.make_jaxpr(lambda x: lax.psum(x, "tp"),
+                                axis_env=[("hvd", 8), ("tp", 2)])(1.0)
         assert rules_of(analysis.check_jaxpr(
             closed, bound_axes={"hvd"})) == ["HVD101"]
         # negative: the axis IS declared bound
@@ -59,11 +58,10 @@ class TestJaxprRules:
                                     bound_axes={"hvd", "tp"}) == []
 
     def test_shard_map_binds_its_axis(self):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         mesh = Mesh(np.array(jax.devices()), ("hvd",))
-        fn = shard_map(lambda x: lax.psum(x, "hvd"), mesh=mesh,
-                       in_specs=P("hvd"), out_specs=P())
+        fn = jax.shard_map(lambda x: lax.psum(x, "hvd"), mesh=mesh,
+                           in_specs=P("hvd"), out_specs=P())
         assert analysis.check_fn(fn, jnp.ones(8)) == []
 
     def test_declared_axis_is_clean(self):

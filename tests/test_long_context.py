@@ -7,13 +7,14 @@ on TPU the compiled kernel engages) — the ring schedule, collectives and
 autodiff path are identical either way."""
 
 import jax
-from horovod_tpu.utils.jax_compat import shard_map, vary_replicated
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from horovod_tpu.ops.flash_attention import reference_attention
+from horovod_tpu.utils.jax_compat import pvary
 from horovod_tpu.parallel import ring_attention
 
 B, H, S, DH, DM = 1, 2, 4096, 32, 64
@@ -57,7 +58,7 @@ def test_ring_flash_seq4k_gradient_parity():
     def ring_loss(p, x, y):
         # p is a replicated shard_map input: declare it varying so its
         # cotangent reduces across 'sp' (vma-jax auto-inserts this).
-        p = jax.tree.map(lambda w: vary_replicated(w, "sp"), p)
+        p = jax.tree.map(lambda w: pvary(w, "sp"), p)
         out = _model(p, x, lambda q, k, v: ring_attention(
             q, k, v, "sp", causal=True, impl="flash"))
         return jax.lax.pmean(jnp.mean((out - y) ** 2), "sp")
@@ -88,7 +89,7 @@ def test_ring_flash_seq4k_training_descends():
 
     def step(p, x, y):
         def loss_fn(p):
-            p = jax.tree.map(lambda w: vary_replicated(w, "sp"), p)
+            p = jax.tree.map(lambda w: pvary(w, "sp"), p)
             out = _model(p, x, lambda q, k, v: ring_attention(
                 q, k, v, "sp", causal=True, impl="flash"))
             return jax.lax.pmean(jnp.mean((out - y) ** 2), "sp")

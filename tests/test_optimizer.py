@@ -3,11 +3,11 @@ DistributedOptimizer tests in test/parallel/test_torch.py and the MNIST
 example smoke runs in CI, .buildkite/gen-pipeline.sh:155-279)."""
 
 import jax
-from horovod_tpu.utils.jax_compat import shard_map
 import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax import shard_map
 
 import horovod_tpu as hvd_mod
 import horovod_tpu.jax as hvd_jax

@@ -13,10 +13,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from horovod_tpu.ops import bucketing, reduce_ops
-from horovod_tpu.utils.jax_compat import shard_map
 
 
 # ==========================================================================

@@ -7,13 +7,14 @@ replicated execution.
 """
 
 import jax
-from horovod_tpu.utils.jax_compat import shard_map, vary_replicated
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from horovod_tpu.ops.flash_attention import reference_attention
+from horovod_tpu.utils.jax_compat import pvary
 from horovod_tpu.parallel import (
     MeshConfig, make_mesh, moe_apply, pipeline_apply, ring_attention,
     ulysses_attention)
@@ -331,7 +332,7 @@ def test_moe_gate_gradient_matches_replicated_oracle():
         from jax import lax
         # wg is the replicated gate: declare it varying so its cotangent
         # is the cross-rank reduction (vma-jax auto-inserts this).
-        wg = vary_replicated(wg, "ep")
+        wg = pvary(wg, "ep")
         y, _ = moe_apply(x, wg, wi, wo, axis_name="ep", k=2,
                          capacity_factor=8.0)
         return lax.psum(jnp.sum(y ** 2), "ep")

@@ -335,6 +335,23 @@ def test_version_prints_and_exits(capsys):
     assert horovod_tpu.__version__ in capsys.readouterr().out
 
 
+def test_launcher_builds_native_core_before_spawning(monkeypatch):
+    """From a clean tree N workers would each run make in csrc/ at once
+    (the loader's lock is per process): the launcher parent builds."""
+    import pytest
+    from horovod_tpu import native
+    from horovod_tpu.runner import launch
+    order = []
+    monkeypatch.setattr(native, "ensure_built",
+                        lambda: order.append("build"))
+    monkeypatch.setattr(launch, "launch_job",
+                        lambda settings, command: order.append("spawn") or 0)
+    with pytest.raises(SystemExit) as exit_info:
+        launch.run_commandline(["-np", "2", "true"])
+    assert exit_info.value.code == 0
+    assert order == ["build", "spawn"]
+
+
 def test_timeline_mark_cycles_emits_markers(tmp_path):
     """start_timeline(mark_cycles=True) drops CYCLE_START instants when
     host-plane cycles move tensors (previously a dead parameter)."""

@@ -25,6 +25,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
 import horovod_tpu as hvd_mod
 from horovod_tpu import basics, guardian
@@ -32,7 +33,6 @@ from horovod_tpu.coordinator import Coordinator, TensorEntry
 from horovod_tpu.ops import reduce_ops, sparse
 from horovod_tpu.process_sets import global_process_set
 from horovod_tpu.utils import envparse
-from horovod_tpu.utils.jax_compat import shard_map
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 

@@ -16,11 +16,11 @@ import jax.numpy as jnp
 import optax
 import pytest
 from jax import lax
+from jax import shard_map as _shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from horovod_tpu import resharding
 from horovod_tpu.parallel import twod
-from horovod_tpu.utils.jax_compat import shard_map as _shard_map
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs 8 (virtual) devices")
