@@ -142,6 +142,9 @@ def init(comm=None, process_sets=None):
             ps_mod._setup(_runtime, process_sets or [])
             return _runtime
 
+        from .utils import compile_cache
+        compile_cache.listen()
+
         # Fresh runtime: auto-name counters restart with it so ranks
         # that re-init (elastic restart) agree on generated names.
         from .ops.collectives import reset_auto_name_counters

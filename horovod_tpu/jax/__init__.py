@@ -39,6 +39,20 @@ from ..utils.jax_compat import pvary as _pvary
 
 HVD_AXIS = "hvd"
 
+# The compiled step's tracing contract (docs/tracing.md "The compiled
+# step in a device trace"): the jitted step is named ``STEP_NAME``, so
+# the profiler's "XLA Modules" line and the HLO module read
+# ``jit_hvd_train_step``, and every instruction's ``op_name`` carries
+# one of three scopes: ``SCOPE_GRAD`` (forward under ``jvp(...)``,
+# backward under ``transpose(jvp(...))``), ``SCOPE_EXCHANGE`` (every
+# cross-replica reduction, packing and unpacking included) and
+# ``SCOPE_OPTIMIZER`` (the inner update and its application). Readers
+# of a device trace (benchmark/scope_reduce.py) match these literals.
+STEP_NAME = "hvd_train_step"
+SCOPE_GRAD = "hvd_grad"
+SCOPE_EXCHANGE = "hvd_exchange"
+SCOPE_OPTIMIZER = "hvd_optimizer"
+
 
 def _reduce_in_axis(grads, op, axis_name, prescale=None, postscale=None):
     def red(g):
@@ -452,9 +466,11 @@ class DistributedOptimizer:
             return self._zero_rt.update_in_axis(grads, state, params)
         inner_state, acc, count = state
         if self.k == 1:
-            reduced = self._reduce(grads)
-            updates, new_inner = self.inner.update(reduced, inner_state,
-                                                   params)
+            with jax.named_scope(SCOPE_EXCHANGE):
+                reduced = self._reduce(grads)
+            with jax.named_scope(SCOPE_OPTIMIZER):
+                updates, new_inner = self.inner.update(
+                    reduced, inner_state, params)
             return updates, (new_inner, None, count + 1)
         if self.axis_name is not None or _is_traced(grads):
             return self._update_aggregated_traced(grads, state, params)
@@ -470,8 +486,15 @@ class DistributedOptimizer:
         (reduction is linear) and XLA overlaps the extra collectives with
         compute; the comm-sparing accumulate-then-reduce variant lives on
         the eager SPMD path below."""
+        with jax.named_scope(SCOPE_EXCHANGE):
+            g = self._reduce(grads)
+        with jax.named_scope(SCOPE_OPTIMIZER):
+            return self._step_aggregated(g, state, params)
+
+    def _step_aggregated(self, g, state, params):
+        """The accumulate / step / hold half of
+        ``_update_aggregated_traced``, on the reduced gradient."""
         inner_state, acc, count = state
-        g = self._reduce(grads)
         acc = jax.tree.map(jnp.add, acc, g)
         count = count + 1
         do_step = (count % self.k) == 0
@@ -529,6 +552,43 @@ def DistributedAdasumOptimizer(optimizer, axis_name=None, **kwargs):
     _DistributedAdasumOptimizer)."""
     return DistributedOptimizer(optimizer, op=reduce_ops.Adasum,
                                 axis_name=axis_name, **kwargs)
+
+
+def _step_body(loss_fn, axis_name, has_aux, apply):
+    """The per-replica body every compiled train step shares (plain,
+    ``has_aux`` and ZeRO), so that the tracing contract above holds for
+    all three: ``apply(grads, opt_state, params) -> (new_params,
+    new_opt_state)`` owns the gradient exchange and the update, and
+    scopes them itself."""
+
+    def mean(tree):
+        with jax.named_scope(SCOPE_EXCHANGE):
+            return jax.tree.map(lambda x: lax.pmean(x, axis_name), tree)
+
+    def grads_of(params, *rest):
+        # Mark params device-varying before differentiating: otherwise the
+        # shard_map varying-axes type system auto-psums the gradient of
+        # replicated inputs, which would double-count with the explicit
+        # reduction below (and would break Adasum, which needs the
+        # un-reduced per-replica gradients).
+        params_v = jax.tree.map(lambda p: _pvary(p, axis_name), params)
+        with jax.named_scope(SCOPE_GRAD):
+            return jax.value_and_grad(loss_fn, has_aux=has_aux)(
+                params_v, *rest)
+
+    if has_aux:
+        def body(params, aux, opt_state, batch):
+            (loss, new_aux), grads = grads_of(params, aux, batch)
+            new_aux = mean(new_aux)
+            new_params, new_opt_state = apply(grads, opt_state, params)
+            return new_params, new_aux, new_opt_state, mean(loss)
+    else:
+        def body(params, opt_state, batch):
+            loss, grads = grads_of(params, batch)
+            new_params, new_opt_state = apply(grads, opt_state, params)
+            return new_params, new_opt_state, mean(loss)
+    body.__name__ = body.__qualname__ = STEP_NAME
+    return body
 
 
 def make_train_step(loss_fn, dist_opt, mesh=None, axis_name=HVD_AXIS,
@@ -597,52 +657,23 @@ def make_train_step(loss_fn, dist_opt, mesh=None, axis_name=HVD_AXIS,
             f"DistributedOptimizer was built for axis "
             f"{dist_opt.axis_name!r} but the train step uses {axis_name!r}")
 
-    def _grads(params, batch, aux=None):
-        # Mark params device-varying before differentiating: otherwise the
-        # shard_map varying-axes type system auto-psums the gradient of
-        # replicated inputs, which would double-count with the explicit
-        # reduction below (and would break Adasum, which needs the
-        # un-reduced per-replica gradients).
-        params_v = jax.tree.map(lambda p: _pvary(p, axis_name), params)
-        if has_aux:
-            (loss, new_aux), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params_v, aux, batch)
-            new_aux = jax.tree.map(lambda a: lax.pmean(a, axis_name),
-                                   new_aux)
-            return loss, grads, new_aux
-        loss, grads = jax.value_and_grad(loss_fn)(params_v, batch)
-        return loss, grads, None
-
-    def body_plain(params, opt_state, batch):
-        loss, grads, _ = _grads(params, batch)
+    def apply(grads, opt_state, params):
         updates, new_opt_state = dist_opt.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
-        return new_params, new_opt_state, lax.pmean(loss, axis_name)
+        with jax.named_scope(SCOPE_OPTIMIZER):
+            return optax.apply_updates(params, updates), new_opt_state
 
-    def body_aux(params, aux, opt_state, batch):
-        loss, grads, new_aux = _grads(params, batch, aux)
-        updates, new_opt_state = dist_opt.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
-        return (new_params, new_aux, new_opt_state,
-                lax.pmean(loss, axis_name))
+    body = _step_body(loss_fn, axis_name, has_aux, apply)
 
     # Wire-codec compression ends in an all_gather whose output IS
     # replicated by construction (every rank receives every requantized
     # shard) but the replication checker cannot prove it — same
     # exception as make_zero_train_step's gathered params.
     check = getattr(dist_opt, "_wire_codec", None) is None
-    if has_aux:
-        sharded = _shard_map(
-            body_aux, mesh=mesh,
-            in_specs=(P(), P(), P(), P(axis_name)),
-            out_specs=(P(), P(), P(), P()), check_vma=check)
-        donate_argnums = (0, 1, 2) if donate else ()
-    else:
-        sharded = _shard_map(
-            body_plain, mesh=mesh,
-            in_specs=(P(), P(), P(axis_name)),
-            out_specs=(P(), P(), P()), check_vma=check)
-        donate_argnums = (0, 1) if donate else ()
+    replicated = (P(),) * (3 if has_aux else 2)     # params, [aux,] state
+    sharded = _shard_map(
+        body, mesh=mesh, in_specs=replicated + (P(axis_name),),
+        out_specs=replicated + (P(),), check_vma=check)
+    donate_argnums = tuple(range(len(replicated))) if donate else ()
     return jax.jit(sharded, donate_argnums=donate_argnums)
 
 
@@ -719,51 +750,22 @@ def _make_zero_step(loss_fn, dist_opt, mesh, axis_name, donate, has_aux):
     def build(zrt):
         state_spec = zrt.state_specs()
 
-        def _grads(params, batch, aux=None):
-            params_v = jax.tree.map(lambda p: _pvary(p, axis_name),
-                                    params)
-            if has_aux:
-                (loss, new_aux), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(params_v, aux, batch)
-                new_aux = jax.tree.map(
-                    lambda a: lax.pmean(a, axis_name), new_aux)
-                return loss, grads, new_aux
-            loss, grads = jax.value_and_grad(loss_fn)(params_v, batch)
-            return loss, grads, None
-
         # apply_in_axis (not update + optax.apply_updates): the update
         # is applied to the parameter shard BEFORE the allgather, so
         # the optimizer multiply and parameter add compile to the same
         # fused form as the replicated step — bit-identical fp32
         # (ops/zero.py _run docstring).
-        def body_plain(params, opt_state, batch):
-            loss, grads, _ = _grads(params, batch)
-            new_params, new_state = zrt.apply_in_axis(
-                grads, opt_state, params)
-            return new_params, new_state, lax.pmean(loss, axis_name)
-
-        def body_aux(params, aux, opt_state, batch):
-            loss, grads, new_aux = _grads(params, batch, aux)
-            new_params, new_state = zrt.apply_in_axis(
-                grads, opt_state, params)
-            return (new_params, new_aux, new_state,
-                    lax.pmean(loss, axis_name))
+        body = _step_body(loss_fn, axis_name, has_aux, zrt.apply_in_axis)
 
         # check_vma off: the allgather'd updates are replicated by
         # construction (every rank contributes its shard and receives
         # all others) but the varying-axes type system cannot prove it.
-        if has_aux:
-            sharded = _shard_map(
-                body_aux, mesh=zrt.mesh,
-                in_specs=(P(), P(), state_spec, P(axis_name)),
-                out_specs=(P(), P(), state_spec, P()), check_vma=False)
-            dn = (0, 1, 2) if donate else ()
-        else:
-            sharded = _shard_map(
-                body_plain, mesh=zrt.mesh,
-                in_specs=(P(), state_spec, P(axis_name)),
-                out_specs=(P(), state_spec, P()), check_vma=False)
-            dn = (0, 1) if donate else ()
+        lead = (P(),) * (2 if has_aux else 1)           # params, [aux]
+        sharded = _shard_map(
+            body, mesh=zrt.mesh,
+            in_specs=lead + (state_spec, P(axis_name)),
+            out_specs=lead + (state_spec, P()), check_vma=False)
+        dn = tuple(range(len(lead) + 1)) if donate else ()
         return jax.jit(sharded, donate_argnums=dn)
 
     def step(*args):

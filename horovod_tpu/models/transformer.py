@@ -45,6 +45,7 @@ def BertConfig(**overrides):
     return TransformerConfig(**base)
 
 
+@jax.named_scope("rope")
 def _rope(q, k):
     """Rotary position embeddings (applied over the head dim)."""
     *_, seq, head_dim = q.shape

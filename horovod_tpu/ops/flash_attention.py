@@ -71,6 +71,16 @@ DEFAULT_BLOCK_K = 128
 _LANE = 128          # TPU lane width: scratch vectors are (block, _LANE)
 _NEG_INF = -1e30
 
+# Names in a device trace (docs/tracing.md): each Mosaic call carries
+# its kernel's name (``pallas_call(name=)`` names the HLO instruction and
+# pushes a scope of the same name), inside ``SCOPE`` together with the
+# XLA work the kernel drags along (pads, ``delta``, layout copies).
+# Readers of a trace match these literals.
+SCOPE = "hvd_flash"
+KERNEL_FWD = "hvd_flash_fwd"
+KERNEL_BWD_DKDV = "hvd_flash_bwd_dkdv"
+KERNEL_BWD_DQ = "hvd_flash_bwd_dq"
+
 
 def _interpret():
     return jax.default_backend() != "tpu"
@@ -294,6 +304,7 @@ def _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
         out_shape=out_shapes,
         compiler_params=compiler_params,
         interpret=_interpret(),
+        name=KERNEL_FWD,
     )(lens, *operands)
     return o, lse[:, 0, :]
 
@@ -472,6 +483,7 @@ def _bwd_dq_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
+@jax.named_scope(SCOPE)
 def _bwd_call(q, k, v, o, do, lse, lens, sm_scale, causal, block_q, block_k,
               g_lse=None, dm=None, dropout_rate=0.0, seeded=False):
     bh, sq, d = q.shape
@@ -528,6 +540,7 @@ def _bwd_call(q, k, v, o, do, lse, lens, sm_scale, causal, block_q, block_k,
         ],
         compiler_params=compiler_params,
         interpret=_interpret(),
+        name=KERNEL_BWD_DKDV,
     )(lens, *dkv_operands)
 
     dq_in_specs = [
@@ -562,6 +575,7 @@ def _bwd_call(q, k, v, o, do, lse, lens, sm_scale, causal, block_q, block_k,
         out_shape=[_struct((bh, sq, d), q.dtype, q, k, v, do, lens)],
         compiler_params=compiler_params,
         interpret=_interpret(),
+        name=KERNEL_BWD_DQ,
     )(lens, *dq_operands)
     return dq, dk, dv
 
@@ -700,6 +714,7 @@ def _varying(*xs):
     return any(jax.typeof(x).vma for x in xs)
 
 
+@jax.named_scope(SCOPE)
 def flash_attention(q, k, v, *, causal=False, sm_scale=None,
                     q_offset=0, k_offset=0, kv_len=None,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,

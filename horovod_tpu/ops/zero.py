@@ -527,6 +527,10 @@ class ZeroRuntime:
         than raw parameter values), and every rank — owner included —
         applies the dequantized payload, so params stay replica-
         identical."""
+        # The step's tracing contract (horovod_tpu/jax: both legs and
+        # their packing are the exchange, the sharded step between them
+        # the optimizer).
+        from ..jax import SCOPE_EXCHANGE, SCOPE_OPTIMIZER
         plan = self.ensure_plan(params)
         n, axis = self.n, self.axis_name
         bucket_states, res_s, res_g = state
@@ -535,38 +539,45 @@ class ZeroRuntime:
         out = [None] * len(g_leaves)
         new_states, new_res_s, new_res_g = [], [], []
         for k, (b, s) in enumerate(zip(plan.buckets, plan.shards)):
-            g_shard = self._bucket_grad_shard(
-                g_leaves, k, b, s, res_s, new_res_s)
+            with jax.named_scope(SCOPE_EXCHANGE):
+                g_shard = self._bucket_grad_shard(
+                    g_leaves, k, b, s, res_s, new_res_s)
             # -- sharded optimizer step (1/n of the state) -----------------
-            p = _pack_padded(p_leaves, b, s.padded)
-            p_shard = p.reshape(n, s.shard_len)[lax.axis_index(axis)]
-            u_shard, new_state_k = self.inner.update(
-                g_shard, bucket_states[k], p_shard)
+            with jax.named_scope(SCOPE_OPTIMIZER):
+                p = _pack_padded(p_leaves, b, s.padded)
+                p_shard = p.reshape(n, s.shard_len)[lax.axis_index(axis)]
+                u_shard, new_state_k = self.inner.update(
+                    g_shard, bucket_states[k], p_shard)
+                # What the gather carries: the new parameter shard where
+                # the update can be applied first (see the docstring).
+                send = u_shard
+                if gather_params and self.codec is None:
+                    send = p_shard + u_shard.astype(p_shard.dtype)
             new_states.append(new_state_k)
             # -- allgather leg ---------------------------------------------
-            if self.codec is not None and self.codec.wire:
-                res = res_g[k] if self.error_feedback else None
-                u_full, new_res = _wire_all_gather(
-                    u_shard.astype(jnp.float32), axis, self.codec,
-                    self.block, res)
-                u_full = u_full.astype(b.dtype)
-                if self.error_feedback:
-                    new_res_g.append(new_res)
-                full = (p + u_full) if gather_params else u_full
-            elif self.codec is not None:
-                payload, _ = self.codec.encode(u_shard, 0)
-                u_full = self.codec.decode(
-                    lax.all_gather(payload, axis, tiled=True),
-                    None, 0, dtype=b.dtype)
-                full = (p + u_full) if gather_params else u_full
-            elif gather_params:
-                new_p_shard = p_shard + u_shard.astype(p_shard.dtype)
-                full = lax.all_gather(new_p_shard, axis, tiled=True)
-            else:
-                full = lax.all_gather(u_shard, axis, tiled=True)
-            if s.padded != s.size:
-                full = lax.slice(full, (0,), (s.size,))
-            _unpack(full, g_leaves, b, out)
+            with jax.named_scope(SCOPE_EXCHANGE):
+                if self.codec is not None and self.codec.wire:
+                    res = res_g[k] if self.error_feedback else None
+                    full, new_res = _wire_all_gather(
+                        send.astype(jnp.float32), axis, self.codec,
+                        self.block, res)
+                    full = full.astype(b.dtype)
+                    if self.error_feedback:
+                        new_res_g.append(new_res)
+                elif self.codec is not None:
+                    payload, _ = self.codec.encode(send, 0)
+                    full = self.codec.decode(
+                        lax.all_gather(payload, axis, tiled=True),
+                        None, 0, dtype=b.dtype)
+                else:
+                    full = lax.all_gather(send, axis, tiled=True)
+            if gather_params and self.codec is not None:
+                with jax.named_scope(SCOPE_OPTIMIZER):
+                    full = p + full
+            with jax.named_scope(SCOPE_EXCHANGE):
+                if s.padded != s.size:
+                    full = lax.slice(full, (0,), (s.size,))
+                _unpack(full, g_leaves, b, out)
         tree = jax.tree.unflatten(jax.tree.structure(grads), out)
         new_state = (tuple(new_states),
                      tuple(new_res_s) if self.error_feedback else (),

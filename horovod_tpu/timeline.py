@@ -37,6 +37,12 @@ class Timeline:
         self._jax_profiler_dir = jax_profiler_dir
         self._jax_profiling = False
 
+    @property
+    def profiling(self):
+        """True while this timeline's ``jax.profiler`` trace runs
+        (telemetry/spans.py then writes its spans into it too)."""
+        return self._jax_profiling
+
     # -- producer side (coordinator) --------------------------------------
     def begin(self, names, activity):
         if self._running:

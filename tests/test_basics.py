@@ -92,6 +92,14 @@ def test_timeline_with_jax_profiler(hvd, tmp_path):
     # The profiler wrote its plugin directory structure.
     found = any("plugins" in dirs for _, dirs, _f in os.walk(profdir))
     assert found, list(os.walk(profdir))
+    # The coordinator's spans are in the profiler's trace too, on its
+    # clock, under the "hvd:" prefix.
+    import glob
+    from jax.profiler import ProfileData
+    (pb,) = glob.glob(str(profdir / "plugins/profile/*/*.xplane.pb"))
+    names = {e.name for plane in ProfileData.from_file(pb).planes
+             for line in plane.lines for e in line.events}
+    assert any(n.startswith("hvd:") for n in names), sorted(names)[:50]
 
 
 def test_checkpoint_save_restore(hvd, tmp_path):

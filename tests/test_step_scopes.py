@@ -1,0 +1,168 @@
+"""The compiled step's tracing contract (docs/tracing.md "The compiled
+step in a device trace"): the module name and the four scopes of
+``make_train_step`` (plain, ``has_aux``, ZeRO), the three named flash
+kernels, and the compile-event log of ``utils/compile_cache.py``."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import Mesh
+
+import horovod_tpu.jax as hvd_jax
+from horovod_tpu import telemetry
+from horovod_tpu.ops import flash_attention as fa
+from horovod_tpu.utils import compile_cache
+
+CHIPS = 4
+
+
+def _step_and_args(kind):
+    mesh = Mesh(np.array(jax.devices()[:CHIPS]), ("hvd",))
+    params = {"w": jnp.ones((8, 3)), "b": jnp.zeros((3,))}
+    batch = (jnp.ones((2 * CHIPS, 8)), jnp.ones((2 * CHIPS, 3)))
+
+    def loss_fn(p, b):
+        return jnp.mean((b[0] @ p["w"] + p["b"] - b[1]) ** 2)
+
+    def loss_aux(p, aux, b):
+        return loss_fn(p, b), {"seen": aux["seen"] + jnp.mean(b[0])}
+
+    opt = hvd_jax.DistributedOptimizer(optax.adam(1e-2),
+                                       zero=(kind == "zero"))
+    if kind == "has_aux":
+        step = hvd_jax.make_train_step(loss_aux, opt, mesh=mesh,
+                                       has_aux=True, donate=False)
+        return step, (params, {"seen": jnp.zeros(())}, opt.init(params),
+                      batch)
+    step = hvd_jax.make_train_step(loss_fn, opt, mesh=mesh, donate=False)
+    return step, (params, opt.init(params), batch)
+
+
+@pytest.mark.parametrize("kind", ["plain", "has_aux", "zero"])
+def test_compiled_step_names_its_parts(kind):
+    step, args = _step_and_args(kind)
+    # The ZeRO step is a Python wrapper that builds its jitted step on
+    # the first call; seen through an outer jit it is one more level.
+    jitted = step if hasattr(step, "lower") else jax.jit(step)
+    text = jitted.lower(*args).compile().as_text()
+    if jitted is step:
+        assert text.startswith("HloModule jit_hvd_train_step")
+    op_names = re.findall(r'op_name="([^"]+)"', text)
+    for scope in ("jit(hvd_train_step)/", "hvd_grad/jvp(",
+                  "hvd_grad/transpose(jvp(", "hvd_exchange/",
+                  "hvd_optimizer/"):
+        assert any(scope in n for n in op_names), scope
+    # The step's collectives run under the exchange.
+    collective = "reduce-scatter" if kind == "zero" else "all-reduce"
+    lines = [x for x in text.splitlines()
+             if re.search(rf" {collective}(-start)?\(", x)]
+    assert lines and all("hvd_exchange/" in x for x in lines)
+    if kind == "zero":
+        gathers = [x for x in text.splitlines()
+                   if re.search(r" all-gather(-start)?\(", x)]
+        assert gathers and all("hvd_exchange/" in x for x in gathers)
+
+
+def test_scope_names_are_the_documented_constants():
+    assert (hvd_jax.STEP_NAME, hvd_jax.SCOPE_GRAD, hvd_jax.SCOPE_EXCHANGE,
+            hvd_jax.SCOPE_OPTIMIZER) == (
+        "hvd_train_step", "hvd_grad", "hvd_exchange", "hvd_optimizer")
+    assert (fa.SCOPE, fa.KERNEL_FWD, fa.KERNEL_BWD_DKDV,
+            fa.KERNEL_BWD_DQ) == (
+        "hvd_flash", "hvd_flash_fwd", "hvd_flash_bwd_dkdv",
+        "hvd_flash_bwd_dq")
+
+
+def _pallas_calls(jaxpr, out):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append((eqn.params["name"],
+                        str(eqn.source_info.name_stack)))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _pallas_calls(sub, out)
+    return out
+
+
+@pytest.mark.parametrize("variant", ["plain", "with_lse", "dropout"])
+def test_flash_kernels_are_named(variant):
+    x = jnp.ones((1, 2, 128, 64), jnp.bfloat16)
+    kwargs = {"plain": {}, "with_lse": {"with_lse": True},
+              "dropout": {"dropout_mask": jnp.ones((1, 2, 128, 128), bool),
+                          "dropout_rate": 0.1}}[variant]
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True, **kwargs)
+        return sum(jnp.sum(o.astype(jnp.float32))
+                   for o in jax.tree.leaves(out))
+
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
+        x, x, x)
+    calls = _pallas_calls(jaxpr.jaxpr, [])
+    assert [name for name, _ in calls] == [
+        "hvd_flash_fwd", "hvd_flash_bwd_dkdv", "hvd_flash_bwd_dq"]
+    # Each call lies under its own name, and the backward kernels under
+    # a plain ``hvd_flash`` as well as the transposed stack.
+    for name, stack in calls:
+        assert stack.endswith(name) and "hvd_flash" in stack
+    assert all("transpose(" in stack and "/hvd_flash/" in stack
+               for _, stack in calls[1:])
+
+
+def _phases_since(n):
+    return [phase for phase, _, _ in compile_cache.events()[n:]]
+
+
+def test_compile_log_grows_when_something_compiles(monkeypatch, tmp_path):
+    # With the variable set, enable() leaves JAX's configuration alone
+    # and still registers the listeners, once.
+    monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+    assert compile_cache.enable() == str(tmp_path)
+    assert compile_cache.enable() == str(tmp_path)
+    x = jnp.arange(7.0)
+    fn = jax.jit(lambda v: jnp.tanh(v) * 3.0 + 1.5)
+    n = len(compile_cache.events())
+    fn(x).block_until_ready()
+    # One executable, so each listener is there once; the functions of
+    # jax.numpy inside it are traced on their own.
+    phases = _phases_since(n)
+    assert phases.count("backend_compile") == 1
+    assert phases.count("lower") == 1 and "trace" in phases
+    stamps = [at for _, _, at in compile_cache.events()[n:]]
+    assert stamps == sorted(stamps) and all(
+        seconds >= 0 for _, seconds, _ in compile_cache.events()[n:])
+    n = len(compile_cache.events())
+    fn(x).block_until_ready()
+    assert _phases_since(n) == []
+    # A copy: the caller cannot edit the log.
+    compile_cache.events().clear()
+    assert len(compile_cache.events()) == n
+
+
+def test_compile_log_feeds_the_metrics_registry(monkeypatch):
+    monkeypatch.setenv("HOROVOD_TPU_METRICS", "1")
+    telemetry.reset()
+    try:
+        compile_cache.listen()
+        n = len(compile_cache.events())
+        jax.jit(lambda v: jnp.sin(v) - 0.25)(
+            jnp.arange(5.0)).block_until_ready()
+        logged = _phases_since(n)
+        families = telemetry.snapshot()["families"]
+        seen = {s["labels"]["phase"]: s["count"]
+                for s in families["hvd_compile_seconds"]["samples"]}
+        assert seen == {p: logged.count(p) for p in set(logged)}
+        assert seen["backend_compile"] >= 1
+    finally:
+        monkeypatch.delenv("HOROVOD_TPU_METRICS")
+        telemetry.reset()
+
+
+def test_compile_log_is_silent_with_metrics_off():
+    telemetry.reset()
+    compile_cache.listen()
+    jax.jit(lambda v: jnp.cos(v) + 0.125)(jnp.arange(3.0))
+    assert telemetry.registry().families() == {}
