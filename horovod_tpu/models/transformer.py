@@ -2,10 +2,16 @@
 
 Counterpart of the reference's BERT-large pretraining benchmark config
 (BASELINE.json: "BERT-large pretraining (examples/pytorch, torch-xla
-backend)"). TPU-first choices: bfloat16 activations with fp32 params,
-einsum-formulated attention (MXU-friendly), optional jax.checkpoint
-rematerialization per block, and head/hidden dimensions kept in multiples
-of 128 for MXU tiling. Sequence/tensor sharding is applied externally via
+backend)"). TPU-first choices: bfloat16 activations with fp32 params;
+attention either einsum-formulated (``attention_impl="einsum"``, the
+default and the one that takes padding masks) or the Pallas flash kernel
+of ``ops/flash_attention.py`` (``"flash"``, what the benchmark's cells
+run, at 1024 tiles); rotary positions as one rotation with its own
+backward (``_rope``); optional jax.checkpoint rematerialization per
+block. Hidden sizes are multiples of 128 for MXU tiling; the head
+dimension is ``hidden // heads``, 64 at BERT-large's widths (half of the
+128 lanes, which the kernel and XLA's layouts pay for), and has to be
+even for rope. Sequence/tensor sharding is applied externally via
 horovod_tpu.parallel (logical axis annotations would over-couple the model
 to one partitioning).
 """
@@ -45,22 +51,45 @@ def BertConfig(**overrides):
     return TransformerConfig(**base)
 
 
+def _rotate(x, cos, sin):
+    """``x * cos + rotate_half(x) * sin`` in float32, cast to ``x.dtype``
+    last: ``x`` (``[..., seq, heads, head_dim]``) rotated by the angles
+    whose ``cos`` and ``sin`` (``[seq, 1, head_dim]``) these are.
+
+    ``rotate_half(x) = [-x2, x1]`` is ``x`` times a constant signed
+    permutation, not a slice and concatenate of the head dimension: those
+    the TPU compiler answers with layout copies of float32 half heads,
+    while the product shuffles the lanes on the MXU and takes the
+    multiply-add as its output fusion, one pass over ``x``. Each entry of
+    the product is one ``+-x`` element, so it is exact."""
+    swap = np.kron([[0.0, 1.0], [-1.0, 0.0]], np.eye(x.shape[-1] // 2))
+    swapped = jnp.matmul(x, jnp.asarray(swap, x.dtype),
+                         precision=jax.lax.Precision.HIGHEST,
+                         preferred_element_type=jnp.float32)
+    return (x * cos + swapped * sin).astype(x.dtype)
+
+
+_rotary = jax.custom_vjp(_rotate)
+# The transpose of a rotation is the rotation by the negative angle: one
+# pass over the cotangent, as forward (autodiff's transpose of the
+# product and of the casts is not), with nothing saved but the tables.
+_rotary.defvjp(lambda x, cos, sin: (_rotate(x, cos, sin), (cos, sin)),
+               lambda tables, g: (_rotate(g, tables[0], -tables[1]),
+                                  None, None))
+
+
 @jax.named_scope("rope")
 def _rope(q, k):
-    """Rotary position embeddings (applied over the head dim)."""
-    *_, seq, head_dim = q.shape
-    half = head_dim // 2
-    freqs = 1.0 / (10000.0 ** (np.arange(0, half) / half))
-    t = np.arange(seq)
-    angles = jnp.asarray(np.einsum("s,d->sd", t, freqs))
-    sin, cos = jnp.sin(angles), jnp.cos(angles)
-
-    def rot(x):
-        x1, x2 = x[..., :half], x[..., half:]
-        return jnp.concatenate(
-            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
-        ).astype(x.dtype)
-    return rot(q), rot(k)
+    """Rotary position embeddings over the head dimension of ``q`` and
+    ``k`` (``[..., seq, heads, head_dim]``, head_dim even): base 10000,
+    lane ``i`` paired with lane ``i + head_dim // 2``."""
+    seq, half = q.shape[-3], q.shape[-1] // 2
+    freqs = 1.0 / (10000.0 ** (np.arange(half) / half))
+    angles = jnp.asarray(np.einsum("s,d->sd", np.arange(seq), freqs),
+                         jnp.float32)
+    cos, sin = (jnp.concatenate([t, t], axis=-1)[:, None]
+                for t in (jnp.cos(angles), jnp.sin(angles)))
+    return _rotary(q, cos, sin), _rotary(k, cos, sin)
 
 
 class Attention(nn.Module):
@@ -75,11 +104,7 @@ class Attention(nn.Module):
         q, k, v = qkv[..., 0, :, :], qkv[..., 1, :, :], qkv[..., 2, :, :]
         # (batch, seq, heads, head_dim) -> attention in einsum form.
         if cfg.use_rope:
-            q = q.swapaxes(1, 2)
-            k = k.swapaxes(1, 2)
             q, k = _rope(q, k)
-            q = q.swapaxes(1, 2)
-            k = k.swapaxes(1, 2)
         if cfg.attention_impl == "flash":
             # Pallas kernel path (ops/flash_attention.py): BHSD layout,
             # causal handled in-kernel. Per-sample padding masks need the

@@ -67,6 +67,41 @@ def test_compiled_step_names_its_parts(kind):
         assert gathers and all("hvd_exchange/" in x for x in gathers)
 
 
+def test_compiled_step_names_rope_forward_and_backward():
+    # The rotation has its own backward (a custom_vjp): both directions
+    # keep scope ``rope`` below the model's path, the backward one under
+    # ``transpose(``, which is how the scope reduction tells them apart.
+    from horovod_tpu.models import TransformerConfig, TransformerLM
+    mesh = Mesh(np.array(jax.devices()[:CHIPS]), ("hvd",))
+    model = TransformerLM(TransformerConfig(
+        vocab_size=64, hidden=64, layers=1, heads=2, max_len=16))
+    tokens = jnp.zeros((CHIPS, 16), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens)
+
+    def loss_fn(p, batch):
+        return optax.softmax_cross_entropy_with_integer_labels(
+            model.apply(p, batch[0]), batch[1]).mean()
+
+    opt = hvd_jax.DistributedOptimizer(optax.adam(1e-2))
+    step = hvd_jax.make_train_step(loss_fn, opt, mesh=mesh, donate=False)
+    text = step.lower(params, opt.init(params),
+                      (tokens, tokens)).compile().as_text()
+    roped = [n for n in re.findall(r'op_name="([^"]+)"', text)
+             if "/attn/rope/" in n]
+    forward = [n for n in roped if "transpose(" not in n]
+    backward = [n for n in roped if "transpose(" in n]
+    # The lane shuffle is a product, each way.
+    assert any(n.endswith("/rope/dot_general") for n in forward)
+    assert any(n.endswith("/rope/dot_general") for n in backward)
+    # And the benchmark's reduction by scope files them as it did.
+    from benchmark import scope_reduce
+    path = "TransformerLM/backbone/block_0/attn/rope"
+    assert {scope_reduce.classify(n) for n in forward} == {
+        ("fwd", None, path)}
+    assert {scope_reduce.classify(n) for n in backward} == {
+        ("bwd", None, path)}
+
+
 def test_scope_names_are_the_documented_constants():
     assert (hvd_jax.STEP_NAME, hvd_jax.SCOPE_GRAD, hvd_jax.SCOPE_EXCHANGE,
             hvd_jax.SCOPE_OPTIMIZER) == (
