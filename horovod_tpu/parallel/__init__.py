@@ -12,7 +12,9 @@ mesh axis, every data exchange is an XLA collective over ICI.
 - ulysses:         sequence parallelism via head-scatter all_to_all
 - sharding:        parameter/activation PartitionSpec rules (tp + fsdp)
 - pipeline:        pipeline parallelism via shard_map + microbatch streaming
-- moe:             expert parallelism — top-k gating + all_to_all dispatch
+- moe:             sparse experts — a dropless, sigmoid-routed expert layer
+                   told which experts it holds (one chip's share of an
+                   expert-parallel layer; grouped products, shared expert)
 """
 
 from .mesh import MeshConfig, make_mesh  # noqa: F401

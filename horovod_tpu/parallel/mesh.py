@@ -14,8 +14,9 @@ Axis convention (outer → inner, slowest → fastest wire):
   tp   — tensor parallelism (innermost: highest-bandwidth ICI neighbors)
 
 ``ep`` (expert parallelism) does not get its own wires: experts shard over
-the ('dp','fsdp') axes (the standard mapping — expert dispatch all_to_all
-rides the data-parallel axis), see moe.py.
+the ('dp','fsdp') axes (the standard mapping — the token exchange of an
+expert-parallel layer, not built yet, would ride the data-parallel axis),
+see moe.py and ROADMAP B3.
 """
 
 import dataclasses

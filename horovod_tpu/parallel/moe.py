@@ -1,145 +1,258 @@
-"""Expert parallelism: top-k gated mixture-of-experts with all_to_all
-dispatch.
+"""Sparse experts: a dropless, sigmoid-routed expert layer that is told
+which experts it holds.
 
-Experts shard over the data axes (the standard mapping: the dispatch
-all_to_all rides the same wires the gradient allreduce uses, and dp ranks
-already hold distinct tokens). Dispatch/combine use the dense one-hot
-formulation — (tokens, experts, capacity) einsums — which XLA lowers to MXU
-matmuls, avoiding gather/scatter (slow on TPU). Over-capacity tokens are
-dropped (their combine weight is zero), standard Switch/GShard semantics.
+The layer routes every token over all the experts the model has (the
+router keeps its published width and its experts per token) and computes
+the part of the result that the experts held here give, plus the shared
+expert, which every chip computes alike. That is one chip's share of an
+expert-parallel layer (``first_held`` says where its experts start; with
+all of them held it is the whole layer). The shares of all the chips,
+the shared expert counted once, add up to the whole layer's output
+(``tests/test_glm4_moe_lite.py``). Exchanging tokens between chips
+(the all-to-all of a layout in which each chip sees its own tokens only)
+is not here: ROADMAP B3.
 
-Two entry points:
-- ``moe_apply``: functional, callable inside shard_map with a named 'ep'
-  axis (manual collectives), or with axis_name=None under plain jit where
-  GSPMD partitions the expert dimension via the sharding rules
-  (parallel/sharding.py: moe/w_in over ('dp','fsdp')).
-- ``MoELayer``: flax module for the model zoo (GSPMD route).
+How it computes, and why (PERF.md section 3, "expert layer"):
+
+- Routing (scope ``hvd_moe/route``, which also holds the sort, the
+  gathers and the weighted sum below): scores ``sigmoid(x W_r)`` in
+  float32 at ``highest`` precision, the chosen set the top-k of
+  ``scores + bias`` (the bias selects and takes no part in the weights
+  nor any gradient), weights ``scale * s_i / (sum of the chosen s +
+  1e-20)`` over all k chosen experts, held here or not.
+- Dispatch is dropless: the ``(token, choice)`` pairs are sorted by
+  expert, pairs of experts held elsewhere last; no capacity, no token
+  dropped. The buffer has a row for every pair (a static shape must
+  cover every token choosing experts held here); rows past the held
+  pairs are zero and the grouped product skips their tiles.
+- The experts' products (scope ``hvd_moe/experts``) are
+  ``jax.lax.ragged_dot`` over the groups: XLA's own grouped Mosaic
+  kernel on the TPU, with both gradients. The buffers are worst-case
+  sized and seven eighths empty at 8 of 64 experts, so they are not
+  kept for the backward pass: the routed part is recomputed there
+  (``jax.checkpoint``; 1.3% of the step's required FLOPs).
+- Gather and un-gather are a permutation and its inverse, each the
+  other's transpose, so neither direction scatters.
 """
 
+import dataclasses
 import functools
+from typing import Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
+from ..utils.jax_compat import pvary
+
+# Names in a device trace (docs/tracing.md); readers match the literals.
+SCOPE = "hvd_moe"
+SCOPE_ROUTE = "route"
+SCOPE_EXPERTS = "experts"
+STATE = "moe_state"     # flax collection: selection bias, tokens drawn
 
 
-def _top_k_dispatch(gate_logits, k, capacity):
-    """Build dispatch/combine tensors from gate logits.
+@dataclasses.dataclass(frozen=True)
+class MoEConfig:
+    experts: int                    # routed experts of the model
+    per_token: int                  # chosen per token
+    width: int                      # an expert's hidden width
+    held: Tuple[int, int] = None    # [first, end) held here; None: all
+    shared: int = 1                 # shared experts (one SwiGLU, wider)
+    scale: float = 1.0              # routed_scaling_factor
+    first_dense: int = 1            # leading layers with a dense FFN
 
-    Returns (dispatch (T,E,C) bool-ish float, combine (T,E,C) float,
-    aux_loss scalar).
-    """
-    t, e = gate_logits.shape
-    gates = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
-
-    dispatch = jnp.zeros((t, e, capacity), jnp.float32)
-    combine = jnp.zeros((t, e, capacity), jnp.float32)
-    masked = gate_logits.astype(jnp.float32)
-    # Tokens already routed in earlier slots occupy expert capacity first.
-    fill = jnp.zeros((e,), jnp.float32)
-    density_sum = jnp.zeros((e,), jnp.float32)
-    for _ in range(k):
-        choice = jnp.argmax(masked, axis=-1)                  # (T,)
-        onehot = jax.nn.one_hot(choice, e, dtype=jnp.float32)  # (T,E)
-        density_sum = density_sum + onehot.mean(axis=0)
-        # Position of each token within its chosen expert's buffer.
-        pos = (jnp.cumsum(onehot, axis=0) - 1.0) + fill[None, :]
-        pos = jnp.sum(pos * onehot, axis=-1)                   # (T,)
-        keep = pos < capacity
-        pos = jnp.clip(pos, 0, capacity - 1).astype(jnp.int32)
-        slot = jax.nn.one_hot(pos, capacity, dtype=jnp.float32)  # (T,C)
-        d = onehot[:, :, None] * slot[:, None, :]
-        d = d * keep[:, None, None]
-        dispatch = dispatch + d
-        prob = jnp.sum(gates * onehot, axis=-1)                # (T,)
-        combine = combine + d * prob[:, None, None]
-        fill = fill + jnp.sum(onehot * keep[:, None], axis=0)
-        masked = jnp.where(onehot > 0, -1e30, masked)
-
-    # Renormalize the kept top-k probabilities.
-    denom = jnp.sum(combine, axis=(1, 2), keepdims=True)
-    combine = combine / jnp.where(denom == 0, 1.0, denom)
-    # GShard load-balancing auxiliary loss.
-    density = density_sum / k
-    mean_gate = gates.mean(axis=0)
-    aux = e * jnp.sum(density * mean_gate)
-    return dispatch, combine, aux
+    @property
+    def span(self):
+        return self.held or (0, self.experts)
 
 
-def moe_apply(x, w_gate, w_in, w_out, *, axis_name=None, k=2,
-              capacity_factor=1.25, activation=jax.nn.gelu):
-    """Apply the MoE FFN to tokens.
+def swiglu(x, w_gate, w_up, w_down):
+    """``W_down(silu(W_gate x) * W_up x)``: a dense gated FFN, the shared
+    expert's form and every routed expert's."""
+    h = nn.silu(jnp.dot(x, w_gate.astype(x.dtype))) * jnp.dot(
+        x, w_up.astype(x.dtype))
+    return jnp.dot(h, w_down.astype(x.dtype))
 
-    Args:
-      x: (tokens, d_model) local tokens.
-      w_gate: (d_model, n_experts_global).
-      w_in: (experts_local, d_model, d_ff) — local experts when ``axis_name``
-        is set, all experts otherwise.
-      w_out: (experts_local, d_ff, d_model).
-      axis_name: 'ep' mesh axis for expert parallelism (inside shard_map);
-        None = single-program (GSPMD or single device).
-    Returns (y (tokens, d_model), aux_loss scalar).
-    """
-    tokens, d = x.shape
-    e_global = w_gate.shape[1]
-    n = lax.axis_size(axis_name) if axis_name is not None else 1
-    e_local = w_in.shape[0]
-    if e_local * n != e_global:
-        raise ValueError(
-            f"w_in holds {e_local} experts x {n} ranks != gate's {e_global}")
-    capacity = int(np.ceil(k * tokens * capacity_factor / e_global))
-    capacity = max(capacity, 1)
 
-    logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
-                        w_gate.astype(jnp.float32))
-    dispatch, combine, aux = _top_k_dispatch(logits, k, capacity)
+def route(x, w_router, bias, *, k, scale):
+    """(chosen (T, k) int32, weights (T, k) float32, drawn (E,) float32:
+    the tokens each expert drew)."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    _, chosen = lax.top_k(scores + lax.stop_gradient(bias), k)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    weights = scale * picked / (jnp.sum(picked, -1, keepdims=True) + 1e-20)
+    drawn = jnp.sum(jax.nn.one_hot(chosen, scores.shape[-1],
+                                   dtype=jnp.float32), axis=(0, 1))
+    return chosen, weights, drawn
 
-    expert_in = jnp.einsum("td,tec->ecd", x.astype(jnp.float32),
-                           dispatch).astype(x.dtype)      # (E, C, d)
-    if axis_name is not None:
-        # Exchange: each rank keeps its local experts' buffers from every
-        # rank: (E, C, d) -> (E_local, n*C, d).
-        expert_in = lax.all_to_all(expert_in, axis_name, split_axis=0,
-                                   concat_axis=1, tiled=True)
-    h = jnp.einsum("ecd,edf->ecf", expert_in, w_in.astype(expert_in.dtype))
-    h = activation(h)
-    out = jnp.einsum("ecf,efd->ecd", h, w_out.astype(h.dtype))
-    if axis_name is not None:
-        # (E_local, n*C, d) -> (E, C, d): route results back to the ranks
-        # whose tokens they are.
-        out = lax.all_to_all(out, axis_name, split_axis=1, concat_axis=0,
-                             tiled=True)
-    y = jnp.einsum("ecd,tec->td", out.astype(jnp.float32), combine)
-    if axis_name is not None:
-        # Load statistics are per-rank; average the aux loss across ranks.
-        aux = lax.pmean(aux, axis_name)
-    return y.astype(x.dtype), aux
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gather(x, order, inverse, k):
+    """Rows of ``x`` (T, d) in pair order: row ``i`` is token
+    ``order[i] // k``. Its transpose un-permutes and sums each token's
+    k rows: a gather too."""
+    return x[order // k]
+
+
+def _gather_bwd(k, res, g):
+    order, inverse = res
+    return (g[inverse].reshape(-1, k, g.shape[-1]).sum(1), None, None)
+
+
+_gather.defvjp(lambda x, order, inverse, k: (x[order // k],
+                                             (order, inverse)),
+               _gather_bwd)
+
+
+@jax.custom_vjp
+def _ungather(y, order, inverse):
+    """Rows of ``y`` (T*k, d) back in (token, choice) order."""
+    return y[inverse]
+
+
+_ungather.defvjp(lambda y, order, inverse: (y[inverse], (order, inverse)),
+                 lambda res, g: (g[res[0]], None, None))
+
+
+def _vary_like(x, like):
+    """``x`` marked varying over the mesh axes ``like`` varies over
+    (inside ``shard_map``): the cast's transpose is the psum that a
+    hand-written transpose such as ``_gather``'s cannot add itself."""
+    for axis in jax.typeof(like).vma - jax.typeof(x).vma:
+        x = pvary(x, axis)
+    return x
+
+
+def _routed(x, w_gate, w_up, w_down, chosen, weights, drawn, first_held):
+    """The held experts' part of the layer's output for tokens ``x``
+    (T, d): sort, grouped products, un-sort, weigh."""
+    tokens, k = chosen.shape
+    held = w_gate.shape[0]
+    with jax.named_scope(SCOPE_ROUTE):
+        local = chosen.reshape(-1) - first_held
+        mine = (local >= 0) & (local < held)
+        key = jnp.where(mine, local, held)
+        # argsort sorts (key, iota) and types the sorted iota as its
+        # input was: mark it as varying as the key it was sorted by.
+        order = _vary_like(jnp.argsort(key, stable=True), key)
+        inverse = _vary_like(jnp.argsort(order), key)
+        sizes = lax.dynamic_slice(drawn, (first_held,), (held,)).astype(
+            jnp.int32)
+        # Zero the rows of pairs held elsewhere, and with them their
+        # cotangents: what a grouped product leaves in rows past its
+        # groups is not specified.
+        rows = (jnp.arange(tokens * k) < jnp.sum(sizes))[:, None]
+        xs = jnp.where(
+            rows, _gather(_vary_like(x, order), order, inverse, k), 0)
+    with jax.named_scope(SCOPE_EXPERTS):
+        def product(a, w):
+            return lax.ragged_dot(a, w.astype(a.dtype), sizes)
+        ys = product(nn.silu(product(xs, w_gate)) * product(xs, w_up),
+                     w_down)
+    with jax.named_scope(SCOPE_ROUTE):
+        ys = _ungather(jnp.where(rows, ys, 0), order, inverse)
+        weights = jnp.where(mine.reshape(tokens, k), weights, 0.0)
+        return jnp.einsum("tkd,tk->td", ys.reshape(tokens, k, -1),
+                          weights.astype(ys.dtype))
+
+
+def moe_apply(x, params, bias, *, k, scale=1.0, first_held=0):
+    """The expert layer on tokens ``x`` (T, d). Returns ``(y, drawn)``:
+    this share of the layer's output and the tokens each of the model's
+    experts drew (float32, (E,)).
+
+    ``params``: ``router`` (d, E) over all E experts; ``w_gate``,
+    ``w_up`` (held, d, f) and ``w_down`` (held, f, d) of the experts
+    held, which are experts ``first_held`` and on (``first_held`` may be
+    traced, e.g. from ``lax.axis_index``); optionally ``shared_gate``,
+    ``shared_up`` (d, fs), ``shared_down`` (fs, d). ``bias`` (E,): the
+    selection bias, a buffer."""
+    with jax.named_scope(SCOPE):
+        with jax.named_scope(SCOPE_ROUTE):
+            chosen, weights, drawn = route(x, params["router"], bias,
+                                           k=k, scale=scale)
+        y = jax.checkpoint(_routed)(
+            x, params["w_gate"], params["w_up"], params["w_down"], chosen,
+            weights, drawn, first_held)
+        if "shared_gate" in params:
+            with jax.named_scope(SCOPE_EXPERTS):
+                y = y + swiglu(x, params["shared_gate"],
+                               params["shared_up"], params["shared_down"])
+        return y, drawn
 
 
 class MoELayer(nn.Module):
-    """Flax MoE FFN block (GSPMD route; param names match
-    parallel/sharding.py rules under the 'moe' scope)."""
+    """The expert FFN of a transformer block. Parameter names match the
+    ``moe/`` rules of ``parallel/sharding.py``; the selection bias and
+    the tokens each expert drew live in collection ``moe_state``."""
 
-    n_experts: int
-    d_ff: int
-    k: int = 2
-    capacity_factor: float = 1.25
+    cfg: MoEConfig
     dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x):
-        # x: (batch, seq, d); flatten tokens for dispatch.
-        b, s, d = x.shape
-        w_gate = self.param("w_gate", nn.initializers.lecun_normal(),
-                            (d, self.n_experts))
-        w_in = self.param("w_in", nn.initializers.lecun_normal(),
-                          (self.n_experts, d, self.d_ff))
-        w_out = self.param("w_out", nn.initializers.lecun_normal(),
-                           (self.n_experts, self.d_ff, d))
-        y, aux = moe_apply(x.reshape(b * s, d), w_gate, w_in, w_out,
-                           k=self.k, capacity_factor=self.capacity_factor)
-        self.sow("losses", "moe_aux_loss", aux)
-        return y.reshape(b, s, d).astype(self.dtype)
+        cfg = self.cfg
+        d = x.shape[-1]
+        first, end = cfg.span
+        init = nn.initializers.lecun_normal()
+        batched = nn.initializers.lecun_normal(batch_axis=(0,))
+        params = {
+            "router": self.param("router", init, (d, cfg.experts)),
+            "w_gate": self.param("w_gate", batched,
+                                 (end - first, d, cfg.width)),
+            "w_up": self.param("w_up", batched,
+                               (end - first, d, cfg.width)),
+            "w_down": self.param("w_down", batched,
+                                 (end - first, cfg.width, d)),
+        }
+        if cfg.shared:
+            wide = cfg.shared * cfg.width
+            params.update(
+                shared_gate=self.param("shared_gate", init, (d, wide)),
+                shared_up=self.param("shared_up", init, (d, wide)),
+                shared_down=self.param("shared_down", init, (wide, d)))
+        bias = self.variable(STATE, "bias", jnp.zeros, (cfg.experts,))
+        tokens = self.variable(STATE, "expert_tokens", jnp.zeros,
+                               (cfg.experts,))
+        y, drawn = moe_apply(
+            x.reshape(-1, d).astype(self.dtype), params, bias.value,
+            k=cfg.per_token, scale=cfg.scale, first_held=first)
+        if self.is_mutable_collection(STATE):
+            tokens.value = drawn
+        return y.reshape(x.shape)
+
+
+def publish_expert_tokens(state, held=None):
+    """Set ``hvd_moe_expert_tokens{layer,expert}`` and
+    ``hvd_moe_held_share`` from the ``moe_state`` collection a train
+    step returned. Call it outside the step; it fetches the arrays. A
+    no-op when ``HOROVOD_TPU_METRICS`` is off."""
+    from ..telemetry import core as telemetry
+    from .sharding import _path_str
+    if not telemetry.enabled():
+        return
+    tokens = telemetry.gauge(
+        "hvd_moe_expert_tokens",
+        "Tokens the expert drew in the last step (mean over replicas)",
+        ("layer", "expert"))
+    share = telemetry.gauge(
+        "hvd_moe_held_share",
+        "Share of the last step's (token, choice) pairs that went to "
+        "experts held on this chip")
+    mine = total = 0.0
+    for path, drawn in jax.tree_util.tree_leaves_with_path(state):
+        if getattr(path[-1], "key", None) != "expert_tokens":
+            continue
+        layer = _path_str(path[:-1])
+        drawn = jax.device_get(drawn)
+        for expert, n in enumerate(drawn):
+            tokens.labels(layer=layer, expert=expert).set(float(n))
+        first, end = held or (0, len(drawn))
+        mine += float(drawn[first:end].sum())
+        total += float(drawn.sum())
+    if total:
+        share.set(mine / total)

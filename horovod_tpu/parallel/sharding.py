@@ -40,13 +40,17 @@ def transformer_param_rules(tp_axis="tp", fsdp_axis=None):
         (r".*mlp_in/bias", P(tp_axis)),
         (r".*mlp_out/kernel", P(tp_axis, f)),
         (r".*mlp_out/bias", P()),
-        # MoE expert weights: (experts, d, f) — experts over the data axes
-        # (expert parallelism), features over tp.
-        (r".*moe/w_in", P(("dp",) if f is None else ("dp", f), None,
-                          tp_axis)),
-        (r".*moe/w_out", P(("dp",) if f is None else ("dp", f), tp_axis,
-                           None)),
-        (r".*moe/w_gate", P()),
+        (r".*mlp_gate/kernel", P(f, tp_axis)),
+        # Expert layer (parallel/moe.py): routed weights (experts, d, f)
+        # and (experts, f, d), experts over the data axes (expert
+        # parallelism), features over tp; the shared expert as an MLP.
+        (r".*moe/w_(gate|up)", P(("dp",) if f is None else ("dp", f), None,
+                                 tp_axis)),
+        (r".*moe/w_down", P(("dp",) if f is None else ("dp", f), tp_axis,
+                            None)),
+        (r".*moe/shared_(gate|up)", P(f, tp_axis)),
+        (r".*moe/shared_down", P(tp_axis, f)),
+        (r".*moe/router", P()),
         (r".*embed/embedding", P(tp_axis, f)),
         (r".*lm_head/kernel", P(f, tp_axis)),
         (r".*mlm_head/kernel", P(f, tp_axis)),

@@ -116,6 +116,34 @@ def test_lse_cotangent_flows_through_kernel_vjp():
                                    atol=5e-4, rtol=5e-4)
 
 
+@pytest.mark.parametrize("tile", [128, 256])
+def test_head_dimension_256_matches_reference(tile):
+    """Latent attention's shape: q.k and v 256 wide (two lane tiles a
+    head), value and all three gradients, at a tile the sequence spans
+    twice and at one it fills."""
+    q, k, v = (_rand((1, 2, 256, 256), i) for i in range(3))
+    weights = jnp.cos(jnp.arange(q.size, dtype=jnp.float32)).reshape(q.shape)
+
+    def loss(attend, q, k, v):
+        return jnp.sum(attend(q, k, v) * weights)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=tile,
+                               block_k=tile)
+
+    def plain(q, k, v):
+        return reference_attention(q, k, v, causal=True)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(plain(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+    g1 = jax.grad(loss, argnums=(1, 2, 3))(flash, q, k, v)
+    g2 = jax.grad(loss, argnums=(1, 2, 3))(plain, q, k, v)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-4, rtol=5e-4)
+
+
 def test_transformer_attention_impl_parity():
     """TransformerLM(attention_impl='flash') matches the einsum path."""
     import jax

@@ -2,7 +2,7 @@
 
 Each strategy is validated against a single-device oracle: ring/Ulysses
 attention vs full flash/einsum attention, pipeline vs sequential stage
-application, MoE expert-parallel vs single-program MoE, GSPMD sharding vs
+application, the dropless expert layer vs a dense-mask oracle, GSPMD sharding vs
 replicated execution.
 """
 
@@ -14,7 +14,6 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from horovod_tpu.ops.flash_attention import reference_attention
-from horovod_tpu.utils.jax_compat import pvary
 from horovod_tpu.parallel import (
     MeshConfig, make_mesh, moe_apply, pipeline_apply, ring_attention,
     ulysses_attention)
@@ -281,78 +280,103 @@ def test_pipeline_rounds_interleaved_placement():
 
 # -- MoE -------------------------------------------------------------------
 
-def test_moe_expert_parallel_matches_single():
+def _moe_layer(tokens=64, d=16, f=32, e=8):
+    params = {"router": _rand((d, e), 1) / 4.0,
+              "w_gate": _rand((e, d, f), 2) / 4.0,
+              "w_up": _rand((e, d, f), 3) / 4.0,
+              "w_down": _rand((e, f, d), 4) / 4.0}
+    bias = 0.05 * _rand((e,), 5)
+    return _rand((tokens, d), 0), params, bias
+
+
+def _moe_dense_oracle(x, params, bias, k, scale):
+    """Every expert on every token, weighted by the token's weight for
+    it or by 0: no sort, no grouped product."""
+    scores = jax.nn.sigmoid(x @ params["router"])
+    _, chosen = jax.lax.top_k(scores + bias, k)
+    picked = scores * jax.nn.one_hot(chosen, scores.shape[-1]).sum(-2)
+    weights = scale * picked / picked.sum(-1, keepdims=True)
+    h = jax.nn.silu(jnp.einsum("td,edf->etf", x, params["w_gate"]))
+    h = h * jnp.einsum("td,edf->etf", x, params["w_up"])
+    return jnp.einsum("etf,efd,te->td", h, params["w_down"], weights)
+
+
+@pytest.mark.parametrize("k", [1, 2, 4])
+def test_moe_dropless_matches_dense_oracle(k):
+    x, params, bias = _moe_layer()
+    y, drawn = moe_apply(x, params, bias, k=k, scale=1.8)
+    assert float(drawn.sum()) == k * x.shape[0]
+    np.testing.assert_allclose(
+        np.asarray(y), np.asarray(_moe_dense_oracle(x, params, bias, k, 1.8)),
+        atol=2e-5, rtol=2e-4)
+
+
+def test_moe_gradients_match_dense_oracle():
+    """Tokens, router and all three expert matrices: the sort, the
+    permutations' transposes and the grouped products' both gradients
+    against plain einsums."""
+    x, params, bias = _moe_layer()
+
+    def loss(fn, x, params):
+        return jnp.sum(fn(x, params) ** 2)
+
+    got = jax.grad(lambda x, p: loss(
+        lambda x, p: moe_apply(x, p, bias, k=2, scale=1.8)[0], x, p),
+        argnums=(0, 1))(x, params)
+    want = jax.grad(lambda x, p: loss(
+        lambda x, p: _moe_dense_oracle(x, p, bias, 2, 1.8), x, p),
+        argnums=(0, 1))(x, params)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-4, rtol=2e-3)
+
+
+def test_moe_shares_over_a_mesh_add_up():
+    """Four chips hold two experts each and see the same tokens: every
+    chip routes over all eight, computes its own experts' part, and the
+    parts sum (psum) to the single-program layer, gradients included.
+    No token exchange: that layout (each chip its own tokens, an
+    all-to-all both ways) is ROADMAP B3."""
     n = 4
     mesh = Mesh(np.array(jax.devices()[:n]), ("ep",))
-    tokens, d, f, e = 64, 16, 32, 8
-    x = _rand((tokens, d), 0)
-    w_gate = _rand((d, e), 1)
-    w_in = _rand((e, d, f), 2)
-    w_out = _rand((e, f, d), 3)
+    x, params, bias = _moe_layer()
 
-    y_ref, aux_ref = moe_apply(x, w_gate, w_in, w_out, k=2,
-                               capacity_factor=8.0)  # no drops
+    def share(x, params):
+        first = jax.lax.axis_index("ep") * params["w_gate"].shape[0]
+        y, _ = moe_apply(x, params, bias, k=2, scale=1.8, first_held=first)
+        return jax.lax.psum(y, "ep")
 
-    def body(x, w_gate, w_in, w_out):
-        y, aux = moe_apply(x, w_gate, w_in, w_out, axis_name="ep", k=2,
-                           capacity_factor=8.0)
-        return y, jnp.array([aux])
+    specs = {"router": P(), "w_gate": P("ep"), "w_up": P("ep"),
+             "w_down": P("ep")}
+    sharded = shard_map(share, mesh=mesh, in_specs=(P(), specs),
+                        out_specs=P())
 
-    # Tokens replicated (every rank dispatches the same tokens would double
-    # count — instead shard tokens over ep like dp ranks do).
-    y, aux = jax.jit(shard_map(
-        body, mesh=mesh,
-        in_specs=(P("ep"), P(), P("ep"), P("ep")),
-        out_specs=(P("ep"), P("ep"))))(x, w_gate, w_in, w_out)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
-                               atol=2e-4, rtol=2e-4)
+    def whole(x, params):
+        return moe_apply(x, params, bias, k=2, scale=1.8)[0]
 
-
-def test_moe_gate_gradient_matches_replicated_oracle():
-    """Gate gradient under expert parallelism: the replicated w_gate's
-    cotangent needs the transpose-time psum (each rank sees only its
-    token shard). check_vma=True makes shard_map insert it; the oracle is
-    the single-program gradient over all tokens. This is the hole the
-    round-3 dryrun left open (gate excluded from argnums under vma-off)."""
-    n = 4
-    mesh = Mesh(np.array(jax.devices()[:n]), ("ep",))
-    tokens, d, f, e = 64, 16, 32, 8
-    x = _rand((tokens, d), 0)
-    w_gate = _rand((d, e), 1)
-    w_in = _rand((e, d, f), 2)
-    w_out = _rand((e, f, d), 3)
-
-    def loss_single(wg):
-        y, _ = moe_apply(x, wg, w_in, w_out, k=2, capacity_factor=8.0)
-        return jnp.sum(y ** 2)
-
-    g_ref = jax.grad(loss_single)(w_gate)
-
-    def loss_ep(x, wg, wi, wo):
-        from jax import lax
-        # wg is the replicated gate: declare it varying so its cotangent
-        # is the cross-rank reduction (vma-jax auto-inserts this).
-        wg = pvary(wg, "ep")
-        y, _ = moe_apply(x, wg, wi, wo, axis_name="ep", k=2,
-                         capacity_factor=8.0)
-        return lax.psum(jnp.sum(y ** 2), "ep")
-
-    g_ep = jax.jit(shard_map(
-        jax.grad(loss_ep, argnums=1), mesh=mesh,
-        in_specs=(P("ep"), P(), P("ep"), P("ep")),
-        out_specs=P()))(x, w_gate, w_in, w_out)
-    np.testing.assert_allclose(np.asarray(g_ep), np.asarray(g_ref),
-                               atol=2e-3, rtol=2e-3)
+    np.testing.assert_allclose(
+        np.asarray(jax.jit(sharded)(x, params)),
+        np.asarray(whole(x, params)), atol=2e-5, rtol=2e-4)
+    got = jax.jit(jax.grad(lambda x, p: jnp.sum(sharded(x, p) ** 2),
+                           argnums=(0, 1)))(x, params)
+    want = jax.grad(lambda x, p: jnp.sum(whole(x, p) ** 2),
+                    argnums=(0, 1))(x, params)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-4, rtol=2e-3)
 
 
-def test_moe_capacity_drops_tokens():
-    # With capacity_factor tiny, most tokens drop: output mostly zero rows.
-    tokens, d, f, e = 32, 8, 16, 4
-    x = _rand((tokens, d), 0)
-    y, _ = moe_apply(x, _rand((d, e), 1), _rand((e, d, f), 2),
-                     _rand((e, f, d), 3), k=1, capacity_factor=0.124)
-    zero_rows = np.sum(np.all(np.asarray(y) == 0.0, axis=-1))
-    assert zero_rows > 0
+def test_moe_uneven_routing_drops_nothing():
+    # A bias that sends every token to experts 0 and 1: two groups of
+    # 64 rows and six empty ones.
+    x, params, bias = _moe_layer()
+    bias = bias.at[:2].add(10.0)
+    y, drawn = moe_apply(x, params, bias, k=2, scale=1.0)
+    np.testing.assert_array_equal(np.asarray(drawn),
+                                  [64, 64, 0, 0, 0, 0, 0, 0])
+    np.testing.assert_allclose(
+        np.asarray(y), np.asarray(_moe_dense_oracle(x, params, bias, 2, 1.0)),
+        atol=2e-5, rtol=2e-4)
 
 
 # -- GSPMD sharding rules --------------------------------------------------
@@ -378,9 +402,9 @@ def test_param_specs_shard_qkv_and_tolerate_missing_axes():
 
     # A mesh without the axes named in the moe rules must not crash.
     small = Mesh(np.array(jax.devices()[:2]), ("fsdp", ))
-    specs2 = make_param_specs({"moe": {"w_in": jnp.zeros((8, 16, 32))}},
+    specs2 = make_param_specs({"moe": {"w_up": jnp.zeros((8, 16, 32))}},
                               small)
-    assert specs2["moe"]["w_in"] == P()
+    assert specs2["moe"]["w_up"] == P()
 
 
 def test_gspmd_sharded_matmul_matches_replicated():

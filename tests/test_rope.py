@@ -136,7 +136,7 @@ def test_transformer_lm_equals_the_parents_formula(monkeypatch, dtype,
     ours = logits_and_grads()
     # The parent's rope: autodiff through slices of the head dimension.
     monkeypatch.setattr(transformer, "_rope",
-                        lambda q, k: (_theirs(q), _theirs(k)))
+                        lambda q, k, theta: (_theirs(q), _theirs(k)))
     theirs = logits_and_grads()
     for a, b in zip(jax.tree.leaves(ours), jax.tree.leaves(theirs)):
         scale = float(jnp.max(jnp.abs(b)))

@@ -102,7 +102,56 @@ def test_compiled_step_names_rope_forward_and_backward():
         ("bwd", None, path)}
 
 
+def test_compiled_step_names_latent_attention_experts_and_mtp():
+    # The scopes the expert layer, latent attention and the MTP module
+    # add (docs/tracing.md), forward and backward, as the benchmark's
+    # readers look for them: anywhere in the op_name, in this order.
+    from benchmark import scope_reduce, scope_sum
+    from horovod_tpu.models import TransformerConfig, TransformerLM
+    from horovod_tpu.models.transformer import MLAConfig
+    from horovod_tpu.parallel.moe import MoEConfig
+    mesh = Mesh(np.array(jax.devices()[:CHIPS]), ("hvd",))
+    model = TransformerLM(TransformerConfig(
+        vocab_size=64, hidden=64, layers=2, heads=2, max_len=16,
+        norm="rmsnorm", bias=False, mlp="swiglu", mlp_width=128,
+        mla=MLAConfig(24, 16, 24, 8, 32), mtp_layers=1,
+        moe=MoEConfig(experts=8, per_token=2, width=48, held=(0, 2))))
+    tokens = jnp.zeros((CHIPS, 16), jnp.int32)
+    variables = model.init(jax.random.PRNGKey(0), tokens,
+                           next_tokens=tokens)
+    params = {"params": variables["params"]}
+    aux = {"moe_state": variables["moe_state"]}
+
+    def loss_fn(p, aux, batch):
+        (main, mtp), aux = model.apply({**p, **aux}, batch[0],
+                                       next_tokens=batch[1],
+                                       mutable=list(aux))
+        return main.mean() + mtp.mean(), aux
+
+    opt = hvd_jax.DistributedOptimizer(optax.adam(1e-2))
+    step = hvd_jax.make_train_step(loss_fn, opt, mesh=mesh, has_aux=True,
+                                   donate=False)
+    text = step.lower(params, aux, opt.init(params),
+                      (tokens, tokens)).compile().as_text()
+    parts = [scope_reduce._parts(n)
+             for n in re.findall(r'op_name="([^"]+)"', text)]
+    for scopes in (("hvd_mla",), ("hvd_mla", "rope"), ("hvd_moe", "route"),
+                   ("hvd_moe", "experts"), ("hvd_mtp", "hvd_mla"),
+                   ("hvd_mtp", "hvd_moe", "experts")):
+        found = [p for p in parts if scope_sum._within(scopes, p)]
+        assert found, scopes
+    names = re.findall(r'op_name="([^"]+)"', text)
+    for scope in ("hvd_mla", "hvd_moe", "hvd_mtp"):
+        assert any(scope in n and "transpose(" in n for n in names), scope
+        assert any(scope in n and "transpose(" not in n for n in names)
+
+
 def test_scope_names_are_the_documented_constants():
+    from horovod_tpu.models import transformer
+    from horovod_tpu.parallel import moe
+    assert (transformer.SCOPE_MLA, transformer.SCOPE_MTP, moe.SCOPE,
+            moe.SCOPE_ROUTE, moe.SCOPE_EXPERTS) == (
+        "hvd_mla", "hvd_mtp", "hvd_moe", "route", "experts")
     assert (hvd_jax.STEP_NAME, hvd_jax.SCOPE_GRAD, hvd_jax.SCOPE_EXCHANGE,
             hvd_jax.SCOPE_OPTIMIZER) == (
         "hvd_train_step", "hvd_grad", "hvd_exchange", "hvd_optimizer")
