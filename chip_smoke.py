@@ -37,7 +37,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 VOCAB, HIDDEN, LAYERS, HEADS = 30522, 1024, 24, 16
 SEQ, SEQS_PER_CHIP, STEPS = 2048, 6, 5
 TILE = 1024              # models/transformer.py asks for 1024-token tiles
-KERNELS_PER_LAYER = 3    # forward, dk/dv backward, dq backward
+KERNELS_PER_LAYER = 2    # forward, and one backward for dq, dk and dv
 # max|kernel - reference| / max|reference| on bf16 inputs, the reference
 # in fp32 at highest matmul precision. bf16 carries 8 bits of mantissa;
 # a masking or block-skip bug shows as an error of order 1.

@@ -17,8 +17,14 @@ Design (MXU/VMEM-first):
   every step of a ring schedule (offsets are device-varying under shard_map).
 - Returns (out, lse); lse makes partial results mergeable (ring attention)
   and feeds the backward pass.
-- Custom VJP with two backward kernels (dk/dv by key block, dq by query
-  block), the standard flash-attention backward split.
+- Custom VJP with one backward kernel. Two Mosaic calls a layer:
+  ``hvd_flash_fwd`` writes the output and the log-sum-exp;
+  ``hvd_flash_bwd_dkdv`` writes dq, dk and dv from one pass over the
+  tiles (s, one exp, dp and ds once a tile: five matrix products). Its
+  grid is (batch*heads, k_blocks, q_blocks): dk and dv accumulate in
+  per-key-block scratch, dq in a float32 VMEM accumulator over the whole
+  query range of the (batch, head). It keeps the name of the dk/dv
+  kernel it grew from, which the readers of a trace match.
 
 On non-TPU backends the kernels run in Pallas interpret mode, so the full
 test suite exercises the exact kernel logic on the CPU mesh.
@@ -79,7 +85,6 @@ _NEG_INF = -1e30
 SCOPE = "hvd_flash"
 KERNEL_FWD = "hvd_flash_fwd"
 KERNEL_BWD_DKDV = "hvd_flash_bwd_dkdv"
-KERNEL_BWD_DQ = "hvd_flash_bwd_dq"
 
 
 def _interpret():
@@ -139,15 +144,15 @@ def _tile_interior(causal, q_start, k_start, kv_len, qb, kb, block_q,
 
 def _keep_scale(dm_ref, dropout_rate):
     """fp32 dropout multiplier for the current tile: keep-mask rescaled
-    by 1/(1-rate). One definition keeps the four fwd/bwd use sites in
+    by 1/(1-rate). One definition keeps the fwd and bwd use sites in
     exact sync (a fwd/bwd mismatch would be a silent gradient bug)."""
     return dm_ref[0].astype(jnp.float32) * (1.0 / (1.0 - dropout_rate))
 
 
 def _seeded_keep_scale(lens_ref, qb, kb, block_q, block_k, dropout_rate):
     """fp32 dropout multiplier drawn from the ON-CHIP prng (TPU only):
-    seeded per (batch·head, q-tile, k-tile), so the forward and both
-    backward kernels regenerate the exact same keep pattern without a
+    seeded per (batch·head, q-tile, k-tile), so the forward and the
+    backward kernel regenerate the exact same keep pattern without a
     single byte of mask leaving VMEM — no bernoulli host program, no
     O(S²) mask residual. The threshold compare gives keep probability
     exact to 2^-32.
@@ -310,87 +315,101 @@ def _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
 
 
 # ---------------------------------------------------------------------------
-# Backward kernels
+# Backward kernel
 # ---------------------------------------------------------------------------
 
-def _bwd_dkv_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                    delta_ref, *rest, sm_scale, causal, block_q,
-                    block_k, n_q, dropout_rate=0.0, seeded=False):
-    # rest = [dm_ref?], dk_ref, dv_ref, dk_scr, dv_scr
+def _bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                *rest, sm_scale, causal, block_q, block_k, qb0,
+                dropout_rate=0.0, seeded=False):
+    # rest = [dm_ref?], dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr
     if dropout_rate > 0.0 and not seeded:
-        dm_ref, dk_ref, dv_ref, dk_scr, dv_scr = rest
+        dm_ref, *rest = rest
     else:
         dm_ref = None
-        dk_ref, dv_ref, dk_scr, dv_scr = rest
-    qb = pl.program_id(2)
-    kb = pl.program_id(1)
+    dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr = rest
+    kb, n_k = pl.program_id(1), pl.num_programs(1)
+    qb, n_q = pl.program_id(2), pl.num_programs(2)
+    # This call's query rows are a chunk of the sequence (see _bwd_call):
+    # tile ``qb`` here is tile ``qg`` of the whole, which is what the mask
+    # and the dropout seed are drawn from.
+    qg = qb0 + qb
     q_start = lens_ref[0]
     k_start = lens_ref[1]
     kv_len = lens_ref[2]
+    rows = pl.ds(pl.multiple_of(qb * block_q, block_q), block_q)
 
     @pl.when(qb == 0)
     def _():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    skip = _block_skip(causal, q_start, k_start, kv_len, qb, kb,
+    @pl.when(kb == 0)
+    def _():
+        dq_scr[rows, :] = jnp.zeros((block_q, dq_scr.shape[1]), jnp.float32)
+
+    skip = _block_skip(causal, q_start, k_start, kv_len, qg, kb,
                        block_q, block_k)
-    interior = _tile_interior(causal, q_start, k_start, kv_len, qb, kb,
+    interior = _tile_interior(causal, q_start, k_start, kv_len, qg, kb,
                               block_q, block_k)
 
     def tile_update(masked):
+        # Key-major: every (block_k, block_q) tile below is the transpose
+        # of the forward's. p^T and ds^T then enter dv and dk as plain
+        # left operands and lse, delta broadcast along sublanes as they
+        # are stored; only dq contracts over the left operand's rows.
         q = q_ref[0]                  # (block_q, d)
         k = k_ref[0]                  # (block_k, d)
         v = v_ref[0]
         do = do_ref[0]
-        lse = lse_ref[0, 0]           # (block_q,)
-        delta = delta_ref[0, 0]       # (block_q,)
+        lse = lse_ref[0]              # (1, block_q)
+        delta = delta_ref[0]
 
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # (bq, bk)
+        st = jax.lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale  # (bk, bq)
         if masked:
-            rows = qb * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
             cols = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
+                jnp.int32, (block_k, block_q), 0)
+            pos = qg * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 1)
             mask = cols < kv_len
             if causal:
                 mask = jnp.logical_and(
-                    mask, (q_start + rows) >= (k_start + cols))
-            p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+                    mask, (q_start + pos) >= (k_start + cols))
+            pt = jnp.where(mask, jnp.exp(st - lse), 0.0)
         else:
             # Interior tile: no element masked (see _tile_interior).
-            p = jnp.exp(s - lse[:, None])        # (bq, bk) fp32
+            pt = jnp.exp(st - lse)                # (bk, bq) fp32
 
         # Dropout backward: o = (P∘M̃)V with M̃ = mask/(1-rate), so
         # dV = (P∘M̃)ᵀdO and dP = (dO Vᵀ)∘M̃; the delta trick survives
         # because Σₖ Pᵢₖ dPᵢₖ = rowsum(dO∘O) = delta exactly as without
         # dropout (O already carries M̃).
-        pv = p
         keep = None
         if dropout_rate > 0.0 and seeded:
-            keep = _seeded_keep_scale(lens_ref, qb, kb, block_q,
-                                      block_k, dropout_rate)
-            pv = p * keep
+            keep = _seeded_keep_scale(lens_ref, qg, kb, block_q,
+                                      block_k, dropout_rate).T
         elif dm_ref is not None:
-            keep = _keep_scale(dm_ref, dropout_rate)
-            pv = p * keep
+            keep = _keep_scale(dm_ref, dropout_rate).T
+        pvt = pt if keep is None else pt * keep
         # MXU operands in the input dtype (bf16 in training; identity for
         # fp32 inputs), fp32 accumulation. fp32 operands would run the
         # matmuls at a fraction of MXU rate — the softmax weights and ds
         # are the canonical safe-to-round tensors of the flash backward.
         dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            pv.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            pvt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)              # (bq, bk)
+        dpt = jax.lax.dot_general(
+            v, do, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)              # (bk, bq)
         if keep is not None:
-            dp = dp * keep
-        ds = p * (dp - delta[:, None]) * sm_scale
+            dpt = dpt * keep
+        dst = (pt * (dpt - delta) * sm_scale).astype(q.dtype)
         dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            dst, q, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dq_scr[rows, :] = dq_scr[rows, :] + jax.lax.dot_general(
+            dst, k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     @pl.when(jnp.logical_and(jnp.logical_not(skip), interior))
@@ -407,89 +426,43 @@ def _bwd_dkv_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
-
-def _bwd_dq_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                   delta_ref, *rest, sm_scale, causal, block_q,
-                   block_k, n_k, dropout_rate=0.0, seeded=False):
-    # rest = [dm_ref?], dq_ref, dq_scr
-    if dropout_rate > 0.0 and not seeded:
-        dm_ref, dq_ref, dq_scr = rest
-    else:
-        dm_ref = None
-        dq_ref, dq_scr = rest
-    kb = pl.program_id(2)
-    qb = pl.program_id(1)
-    q_start = lens_ref[0]
-    k_start = lens_ref[1]
-    kv_len = lens_ref[2]
-
-    @pl.when(kb == 0)
-    def _():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
-
-    skip = _block_skip(causal, q_start, k_start, kv_len, qb, kb,
-                       block_q, block_k)
-    interior = _tile_interior(causal, q_start, k_start, kv_len, qb, kb,
-                              block_q, block_k)
-
-    def tile_update(masked):
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if masked:
-            rows = qb * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            mask = cols < kv_len
-            if causal:
-                mask = jnp.logical_and(
-                    mask, (q_start + rows) >= (k_start + cols))
-            p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
-        else:
-            p = jnp.exp(s - lse[:, None])  # interior: nothing masked
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if dropout_rate > 0.0 and seeded:
-            dp = dp * _seeded_keep_scale(lens_ref, qb, kb, block_q,
-                                         block_k, dropout_rate)
-        elif dm_ref is not None:
-            dp = dp * _keep_scale(dm_ref, dropout_rate)
-        ds = p * (dp - delta[:, None]) * sm_scale
-        # input-dtype operand, fp32 accumulation (see _bwd_dkv_kernel).
-        dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(jnp.logical_and(jnp.logical_not(skip), interior))
-    def _():
-        tile_update(False)
-
-    @pl.when(jnp.logical_and(jnp.logical_not(skip),
-                             jnp.logical_not(interior)))
-    def _():
-        tile_update(True)
-
     @pl.when(kb == n_k - 1)
     def _():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+        dq_ref[0, rows, :] = dq_scr[rows, :].astype(dq_ref.dtype)
+
+
+# Scoped VMEM of the backward kernel. Its tiles and their temporaries get
+# a Mosaic kernel's default on a v5e (16 MiB of 128) for every lane tile
+# of the head's width. Compiled alone, 1024 tiles need 12 MiB at a head
+# of 64 and 19 at 256; inside a whole step XLA's prefetches of the
+# kernel's operands count against the same limit (the GLM step does not
+# compile at 19). Not more than that either: XLA keeps buffers of its
+# own in the VMEM the kernels leave, and 32 MiB at a head of 64 put 11 to
+# 18 MB of them back into HBM. dq's resident bytes come on top.
+_TILE_VMEM_BYTES = 16 * 2 ** 20
+# Most that dq's float32 accumulator and its double-buffered output block
+# may hold; a longer query range goes through in chunks.
+_DQ_RESIDENT_BYTES = 32 * 2 ** 20
+
+
+def _lane_tiles(d):
+    return -(-d // _LANE)
+
+
+def _dq_resident_bytes(rows, d, dtype):
+    return rows * _lane_tiles(d) * _LANE * (4 + 2 * jnp.dtype(dtype).itemsize)
 
 
 @jax.named_scope(SCOPE)
 def _bwd_call(q, k, v, o, do, lse, lens, sm_scale, causal, block_q, block_k,
               g_lse=None, dm=None, dropout_rate=0.0, seeded=False):
+    """dq, dk and dv from one kernel: one pass over the (key, query)
+    tiles computes s, exp, dp and ds once and feeds all three gradients.
+    Grid (batch*heads, k_blocks, q_blocks), query innermost: dk and dv
+    accumulate in per-key-block scratch, dq in a float32 VMEM accumulator
+    over the whole query range of the (batch, head), its output block
+    resident until the last key block has been added."""
     bh, sq, d = q.shape
-    sk = k.shape[1]
-    n_q = sq // block_q
-    n_k = sk // block_k
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)                        # (bh, sq)
     if g_lse is not None:
@@ -499,84 +472,97 @@ def _bwd_call(q, k, v, o, do, lse, lens, sm_scale, causal, block_q, block_k,
     # 3-D (bh, 1, sq) layout for TPU block-shape rules (see _fwd_kernel).
     lse3 = lse[:, None, :]
     delta3 = delta[:, None, :]
+    # Where the accumulator would not fit, the query range goes through
+    # the same kernel in chunks and dk, dv are summed in float32.
+    n_q = sq // block_q
+    per_chunk = max(1, _DQ_RESIDENT_BYTES
+                    // _dq_resident_bytes(block_q, d, q.dtype))
+    chunk = functools.partial(
+        _bwd_chunk, k=k, v=v, lens=lens, sm_scale=sm_scale, causal=causal,
+        block_q=block_q, block_k=block_k, dropout_rate=dropout_rate,
+        seeded=seeded)
+    if n_q <= per_chunk:
+        return chunk(q, do, lse3, delta3, dm, qb0=0)
+    dqs, dk, dv = [], 0.0, 0.0
+    for qb0 in range(0, n_q, per_chunk):
+        rows = slice(qb0 * block_q, (qb0 + per_chunk) * block_q)
+        dq_c, dk_c, dv_c = chunk(
+            q[:, rows], do[:, rows], lse3[:, :, rows], delta3[:, :, rows],
+            None if dm is None else dm[:, rows], qb0=qb0,
+            kv_dtype=jnp.float32)
+        dqs.append(dq_c)
+        dk, dv = dk + dk_c, dv + dv_c
+    return (jnp.concatenate(dqs, axis=1), dk.astype(k.dtype),
+            dv.astype(v.dtype))
 
-    compiler_params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
-    dkv_in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, j, i, lens: (b, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, j, i, lens: (b, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, j, i, lens: (b, j, 0)),
-        pl.BlockSpec((1, block_q, d), lambda b, j, i, lens: (b, i, 0)),
-        pl.BlockSpec((1, 1, block_q), lambda b, j, i, lens: (b, 0, i)),
-        pl.BlockSpec((1, 1, block_q), lambda b, j, i, lens: (b, 0, i)),
-    ]
-    dkv_operands = [q, k, v, do, lse3, delta3]
-    if dropout_rate > 0.0 and not seeded:
-        dkv_in_specs.append(pl.BlockSpec(
-            (1, block_q, block_k), lambda b, j, i, lens: (b, i, j)))
-        dkv_operands.append(dm)
-    dkv_spec = pltpu.PrefetchScalarGridSpec(
+def _bwd_chunk(q, do, lse3, delta3, dm, *, k, v, lens, qb0, sm_scale,
+               causal, block_q, block_k, dropout_rate, seeded,
+               kv_dtype=None):
+    """The backward kernel over the query rows it is given: tiles ``qb0``
+    onward of the sequence. dk and dv are this chunk's share, in
+    ``kv_dtype`` (k's and v's own unless the caller sums shares)."""
+    bh, sq, d = q.shape
+    sk = k.shape[1]
+    n_q = sq // block_q
+    n_k = sk // block_k
+
+    def qi(j, i, lens):
+        """The query block grid step (j, i) holds. Under a causal mask the
+        steps before a key block's first visible query block are skipped
+        (_block_skip): they name that first block, so they fetch nothing
+        and the block is there when its step comes."""
+        if not causal or n_q == 1:
+            return i
+        # Truncating division: where it differs from the floor the first
+        # block is negative and ``i`` wins either way.
+        first = lax.div(lens[1] + j * block_k - lens[0],
+                        jnp.int32(block_q)) - qb0
+        return lax.max(i, lax.min(first, jnp.int32(n_q - 1)))
+
+    q_tile = pl.BlockSpec((1, block_q, d),
+                          lambda b, j, i, lens: (b, qi(j, i, lens), 0))
+    q_row = pl.BlockSpec((1, 1, block_q),
+                         lambda b, j, i, lens: (b, 0, qi(j, i, lens)))
+    k_tile = pl.BlockSpec((1, block_k, d), lambda b, j, i, lens: (b, j, 0))
+    in_specs = [q_tile, k_tile, k_tile, q_tile, q_row, q_row]
+    operands = [q, k, v, do, lse3, delta3]
+    if dm is not None:
+        in_specs.append(pl.BlockSpec(
+            (1, block_q, block_k),
+            lambda b, j, i, lens: (b, qi(j, i, lens), j)))
+        operands.append(dm)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(bh, n_k, n_q),
-        in_specs=dkv_in_specs,
+        in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, i, lens: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i, lens: (b, j, 0)),
+            pl.BlockSpec((1, sq, d), lambda b, j, i, lens: (b, 0, 0)),
+            k_tile, k_tile,
         ],
         scratch_shapes=[
+            pltpu.VMEM((sq, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
     )
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k, n_q=n_q,
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_kernel, sm_scale=sm_scale, causal=causal,
+                          block_q=block_q, block_k=block_k, qb0=qb0,
                           dropout_rate=dropout_rate, seeded=seeded),
-        grid_spec=dkv_spec,
+        grid_spec=grid_spec,
         out_shape=[
-            _struct((bh, sk, d), k.dtype, q, k, v, do, lens),
-            _struct((bh, sk, d), v.dtype, q, k, v, do, lens),
+            _struct((bh, sq, d), q.dtype, q, k, v, do, lens),
+            _struct((bh, sk, d), kv_dtype or k.dtype, q, k, v, do, lens),
+            _struct((bh, sk, d), kv_dtype or v.dtype, q, k, v, do, lens),
         ],
-        compiler_params=compiler_params,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_TILE_VMEM_BYTES * _lane_tiles(d)
+            + _dq_resident_bytes(sq, d, q.dtype)),
         interpret=_interpret(),
         name=KERNEL_BWD_DKDV,
-    )(lens, *dkv_operands)
-
-    dq_in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, i, j, lens: (b, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j, lens: (b, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j, lens: (b, j, 0)),
-        pl.BlockSpec((1, block_q, d), lambda b, i, j, lens: (b, i, 0)),
-        pl.BlockSpec((1, 1, block_q), lambda b, i, j, lens: (b, 0, i)),
-        pl.BlockSpec((1, 1, block_q), lambda b, i, j, lens: (b, 0, i)),
-    ]
-    dq_operands = [q, k, v, do, lse3, delta3]
-    if dropout_rate > 0.0 and not seeded:
-        dq_in_specs.append(pl.BlockSpec(
-            (1, block_q, block_k), lambda b, i, j, lens: (b, i, j)))
-        dq_operands.append(dm)
-    dq_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(bh, n_q, n_k),
-        in_specs=dq_in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j, lens: (b, i, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-        ],
-    )
-    (dq,) = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k, n_k=n_k,
-                          dropout_rate=dropout_rate, seeded=seeded),
-        grid_spec=dq_spec,
-        out_shape=[_struct((bh, sq, d), q.dtype, q, k, v, do, lens)],
-        compiler_params=compiler_params,
-        interpret=_interpret(),
-        name=KERNEL_BWD_DQ,
-    )(lens, *dq_operands)
+    )(lens, *operands)
     return dq, dk, dv
 
 
@@ -740,8 +726,8 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None,
       dropout_rate: the rate the mask was drawn with (for rescaling).
       dropout_seed: TPU-only alternative to dropout_mask — an int32
         scalar (may be traced) seeding the ON-CHIP prng; the keep
-        pattern is regenerated per tile inside the forward and both
-        backward kernels, so no mask is ever materialized in HBM (no
+        pattern is regenerated per tile inside the forward and the
+        backward kernel, so no mask is ever materialized in HBM (no
         bernoulli program, no O(S²) residual). Unsupported in interpret
         mode (pltpu prng has no CPU lowering) — callers on CPU use
         dropout_mask instead.

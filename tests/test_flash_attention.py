@@ -1,6 +1,8 @@
 """Flash-attention kernel correctness vs the einsum oracle (interpret mode
 on the CPU mesh; same kernel code compiles on TPU)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -208,3 +210,118 @@ def test_dropout_zero_mask_is_identity_path():
     out = flash_attention(q, k, v, dropout_mask=dm, dropout_rate=0.0)
     ref = flash_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref))
+
+
+# ---------------------------------------------------------------------------
+# The one backward kernel: dq, dk and dv from one pass over the tiles
+# ---------------------------------------------------------------------------
+
+def _grads(attend, q, k, v, seed=5, **kwargs):
+    """Gradients of a fixed random projection of every output (the value
+    and, with ``with_lse``, the visible rows' log-sum-exp)."""
+    def loss(q, k, v):
+        outs = jax.tree.leaves(attend(q, k, v, **kwargs))
+        total = jnp.sum(outs[0] * _rand(outs[0].shape, seed))
+        for lse in outs[1:]:
+            total += jnp.sum(jnp.where(lse > -1e29, lse, 0.0)
+                             * _rand(lse.shape, seed + 1))
+        return total
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+def _assert_grads_match(q, k, v, flash_kwargs=None, **kwargs):
+    g1 = _grads(flash_attention, q, k, v, **kwargs, **(flash_kwargs or {}))
+    g2 = _grads(reference_attention, q, k, v, **kwargs)
+    for a, b in zip(g1, g2):
+        assert a.shape == b.shape and np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-4, rtol=5e-4)
+    return g1
+
+
+# (sq, sk, d, block_q, block_k, kwargs): what the two backward kernels
+# covered between them, each case with several tiles on the axis it names.
+FUSED_BACKWARD_CASES = {
+    "sq_lt_sk_kv_len_padding": (
+        64, 160, 32, 32, 32, dict(causal=False, kv_len=150)),
+    "sq_gt_sk_kv_len_padding": (
+        160, 96, 32, 32, 32, dict(causal=False, kv_len=70)),
+    "causal_3x5_tiles_offset": (
+        96, 160, 32, 32, 32, dict(causal=True, q_offset=64, kv_len=150)),
+    "causal_5x2_tiles_wide_keys": (
+        160, 128, 32, 32, 64, dict(causal=True, q_offset=0)),
+    "causal_unaligned_padded": (
+        100, 100, 48, 64, 32, dict(causal=True)),
+    "lse_cotangent_with_offsets": (
+        96, 128, 32, 32, 32,
+        dict(causal=True, q_offset=32, k_offset=0, with_lse=True)),
+    "lse_cotangent_future_keys": (
+        64, 64, 32, 32, 32,
+        dict(causal=True, q_offset=16, k_offset=32, with_lse=True)),
+    "head_dim_256_3x2_tiles": (
+        384, 256, 256, 128, 128, dict(causal=True, q_offset=128)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FUSED_BACKWARD_CASES))
+def test_fused_backward_matches_reference(case):
+    sq, sk, d, block_q, block_k, kwargs = FUSED_BACKWARD_CASES[case]
+    q = _rand((1, 2, sq, d), 0)
+    k, v = _rand((1, 2, sk, d), 1), _rand((1, 2, sk, d), 2)
+    _assert_grads_match(q, k, v, dict(block_q=block_q, block_k=block_k),
+                        **kwargs)
+
+
+# A ring step's kernel call: offsets traced under jit, one compiled
+# kernel for every position of the key chunk.
+RING_POSITIONS = {"all_visible": (128, 0), "diagonal": (128, 128),
+                  "all_skipped": (0, 128)}
+
+
+@pytest.mark.parametrize("with_lse", [False, True], ids=["out", "out_lse"])
+@pytest.mark.parametrize("position", sorted(RING_POSITIONS))
+def test_fused_backward_ring_positions_traced_offsets(position, with_lse):
+    q, k, v = (_rand((1, 2, 128, 32), i) for i in range(3))
+
+    @functools.partial(jax.jit, static_argnums=0)
+    def grads(attend, q_offset, k_offset):
+        return _grads(attend, q, k, v, causal=True, q_offset=q_offset,
+                      k_offset=k_offset, with_lse=with_lse)
+
+    offsets = [jnp.int32(o) for o in RING_POSITIONS[position]]
+    g1 = grads(functools.partial(flash_attention, block_q=32, block_k=64),
+               *offsets)
+    g2 = grads(reference_attention, *offsets)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-4, rtol=5e-4)
+    if position == "all_skipped":
+        # Every tile skipped: the accumulators' zeros, not a rounding.
+        for a in g1:
+            assert not np.asarray(a).any()
+
+
+@pytest.mark.parametrize("variant", ["plain", "lse_offsets", "dropout"])
+def test_fused_backward_query_chunks(monkeypatch, variant):
+    """A query range whose dq accumulator would not fit goes through the
+    same kernel in chunks (here 2 + 2 + 1 tiles), dk and dv summed."""
+    from horovod_tpu.ops import flash_attention as fa
+    monkeypatch.setattr(
+        fa, "_DQ_RESIDENT_BYTES",
+        2 * fa._dq_resident_bytes(32, 32, jnp.float32))
+    q = _rand((1, 2, 160, 32), 0)
+    k, v = _rand((1, 2, 96, 32), 1), _rand((1, 2, 96, 32), 2)
+    kwargs = {
+        "plain": dict(causal=True, kv_len=90),
+        "lse_offsets": dict(causal=True, q_offset=8, k_offset=40,
+                            with_lse=True),
+        "dropout": dict(causal=True, dropout_rate=0.25,
+                        dropout_mask=jax.random.bernoulli(
+                            jax.random.PRNGKey(3), 0.75, (1, 2, 160, 96))),
+    }[variant]
+    calls = []
+    chunk = fa._bwd_chunk
+    monkeypatch.setattr(fa, "_bwd_chunk", lambda q, *a, **kw: (
+        calls.append((kw["qb0"], q.shape[1])), chunk(q, *a, **kw))[1])
+    _assert_grads_match(q, k, v, dict(block_q=32, block_k=32), **kwargs)
+    assert calls == [(0, 64), (2, 64), (4, 32)]

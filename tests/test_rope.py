@@ -205,8 +205,8 @@ def test_attention_layer_compiles_to_one_pass_of_rope_on_v5e(
     with_rope = _compiled_layer_gradient(one_chip, batch, seq, True)
     without = _compiled_layer_gradient(one_chip, batch, seq, False)
     text = with_rope.as_text()
-    # Forward, dk/dv and dq, and no other custom kernel.
-    assert text.count("tpu_custom_call") == 3
+    # Forward and the one backward kernel, and no other custom kernel.
+    assert text.count("tpu_custom_call") == 2
     # Under scope rope, forward and backward, nothing is written out in
     # float32 at q's size: the entry computation's instructions are what
     # goes to memory (a fusion's own body stays in registers).
@@ -222,3 +222,33 @@ def test_attention_layer_compiles_to_one_pass_of_rope_on_v5e(
     above = (with_rope.cost_analysis()["bytes accessed"]
              - without.cost_analysis()["bytes accessed"])
     assert above <= ROPE_BYTES_ABOVE
+
+
+# The flash kernels alone, at a chip's (batch, heads, seq, head_dim) of
+# lm365m-seq8192-1chip, the two lm365m seq-2048 cells and
+# glm47flash-seq4096-1chip (kept in this file: the TPU's library goes to
+# one test process).
+FLASH_SHAPES = [(2, 16, 8192, 64), (6, 16, 2048, 64), (2, 20, 4096, 256)]
+
+
+@pytest.mark.parametrize("shape", FLASH_SHAPES,
+                         ids=["seq8192", "seq2048", "glm47flash"])
+def test_flash_gradient_compiles_to_two_kernels_on_v5e(
+        one_chip, monkeypatch, shape):
+    from horovod_tpu.ops import flash_attention as fa
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True, block_q=1024,
+                                 block_k=1024)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    # The forward, and one backward that makes dq, dk and dv: dq's
+    # accumulator over the whole query range fits the chip's VMEM.
+    assert text.count("tpu_custom_call") == 2
+    for name in (fa.KERNEL_FWD, fa.KERNEL_BWD_DKDV):
+        assert len(re.findall(rf'{name}[.\d]* = [^\n]*custom_call_target='
+                              r'"tpu_custom_call"', text)) == 1
