@@ -32,8 +32,8 @@ import numpy as np  # noqa: E402
 from jax import lax  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
-# The 365M decoder of bench.py's seq-2048 line: BERT-large widths as a
-# causal LM, 6 sequences per chip.
+# The lm365m configuration (benchmark/configs/lm365m.json) at seq 2048:
+# BERT-large widths as a causal LM, 6 sequences per chip.
 VOCAB, HIDDEN, LAYERS, HEADS = 30522, 1024, 24, 16
 SEQ, SEQS_PER_CHIP, STEPS = 2048, 6, 5
 TILE = 1024              # models/transformer.py asks for 1024-token tiles

@@ -29,9 +29,8 @@ What the model deliberately ignores (docs/lint.md "Model
 assumptions"): link congestion from neighbours, DCN vs ICI topology
 splits, per-dtype math throughput, and fusion-buffer padding waste
 beyond the bucket-count term. It is a *ranking and cliff-finding*
-model, not a cycle-accurate one — the ``bench.py --simulate`` lane
-archives its residual against measured n=2/4/8 runs exactly so the
-extrapolated 256/1024-rank numbers stay honest.
+model, not a cycle-accurate one: no run on the chip has checked its
+256/1024-rank extrapolations (ROADMAP.md C3).
 
 On top of the prediction sit the HVD6xx static performance rules
 (docs/lint.md):

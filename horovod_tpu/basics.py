@@ -283,7 +283,7 @@ def shutdown():
 
 def _maybe_dump_metrics():
     """Write a final JSON snapshot to HVDTPU_METRICS_DUMP (if set) —
-    the file `hvd-metrics diff` consumes and bench.py archives."""
+    the file `hvd-metrics diff` consumes."""
     path = envparse.get_str(envparse.METRICS_DUMP, "")
     if not path or not envparse.get_bool(envparse.METRICS):
         return

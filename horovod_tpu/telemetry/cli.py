@@ -7,9 +7,9 @@
 
 ``dump`` prints a snapshot as Prometheus text (default) or JSON; a URL
 source hits the runner HTTP server's token-gated ``/metrics.json``
-route, a file source reads a snapshot written by ``HVDTPU_METRICS_DUMP``
-or ``bench.py``. ``watch`` re-scrapes on an interval and prints per-
-second rates for counters. ``diff`` subtracts two snapshot files —
+route, a file source reads a snapshot written by
+``HVDTPU_METRICS_DUMP``. ``watch`` re-scrapes on an interval and prints
+per-second rates for counters. ``diff`` subtracts two snapshot files —
 counter deltas and histogram count/sum deltas — the evidence format
 perf PRs cite. Exit codes: 0 ok, 2 usage/fetch error.
 """

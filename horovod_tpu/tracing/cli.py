@@ -2,7 +2,7 @@
 
     hvd-trace collect --url http://driver:port --token T --out DIR
     hvd-trace merge DIR [shard...] --out trace.json
-    hvd-trace report DIR [--json] [--metrics BENCH_metrics.json]
+    hvd-trace report DIR [--json] [--metrics snapshot.json]
     hvd-trace postmortem DIR [--out bundle.json]
 
 ``collect`` pulls the shards every rank pushed to the launcher KV store

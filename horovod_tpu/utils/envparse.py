@@ -214,9 +214,6 @@ METRICS = register(
 METRICS_PUSH_INTERVAL = register(
     "METRICS_PUSH_INTERVAL", "5",
     "Seconds between per-rank snapshot pushes to the driver KV store")
-METRICS_SNAPSHOT = register(
-    "METRICS_SNAPSHOT", "BENCH_metrics.json",
-    "Path where bench.py archives the run's telemetry snapshot")
 METRICS_DUMP = register(
     "METRICS_DUMP", "", "Final JSON snapshot path written at shutdown")
 

@@ -7,16 +7,16 @@
 #      HVD6xx) over horovod_tpu/ itself plus the knob-registry and
 #      metric-registry docs cross-checks (HVD306/HVD307), failing on
 #      warnings.
-#   2. dogfood sweep  — hvd-lint verify over examples/ and bench.py,
-#      failing on warnings: the shipped entry points stay clean (the
-#      schedule simulator included — zero HVD5xx).
+#   2. dogfood sweep  — hvd-lint verify over examples/, failing on
+#      warnings: the shipped entry points stay clean (the schedule
+#      simulator included — zero HVD5xx).
 #   3. canary corpus  — the fixture corpus must still TRIP every rule
 #      family (a gate that stopped seeing its fixtures has rotted),
 #      including the simulator's proven HVD501/502 and the new
 #      protocol-order HVD704/705, and its findings are emitted as
 #      lint.sarif for the CI artifact/code-scanning upload.
 #   4. perf canary    — hvd-lint perf stays zero-false-positive over
-#      examples/ + bench.py at fail-on-warning, while the perf fixture
+#      examples/ at fail-on-warning, while the perf fixture
 #      corpus (with its checked-in calibration table) still trips
 #      every HVD6xx rule; findings land in perf.sarif.
 #   5. model check    — hvd-model explores the bounded state space of
@@ -61,9 +61,9 @@ leg_start
 run_lint --self --check-knobs --check-metrics
 leg_done
 
-echo "== hvd-lint verify: examples/ + bench.py (fail on warnings) =="
+echo "== hvd-lint verify: examples/ (fail on warnings) =="
 leg_start
-run_lint verify examples bench.py --fail-on warning
+run_lint verify examples --fail-on warning
 leg_done
 
 echo "== hvd-lint verify: fixture corpus -> ${sarif_out} =="
@@ -87,9 +87,9 @@ check_sarif "${sarif_out}" \
     --require-rule HVD704 --require-rule HVD705 \
     --require-flows HVD501:2 --require-flows HVD502:2
 
-echo "== hvd-lint perf: examples/ + bench.py (zero HVD6xx FPs) =="
+echo "== hvd-lint perf: examples/ (zero HVD6xx FPs) =="
 leg_start
-run_lint perf examples bench.py --fail-on warning
+run_lint perf examples --fail-on warning
 leg_done
 
 echo "== hvd-lint perf: fixture corpus -> ${perf_sarif_out} =="

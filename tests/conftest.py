@@ -30,8 +30,8 @@ import pytest  # noqa: E402
 # keeps the in-process suites — the fast iteration loop.
 _SLOW_MODULES = {
     "test_spmd", "test_examples", "test_cluster", "test_frameworks",
-    "test_elastic", "test_xla_global", "test_weak_scaling",
-    "test_chaos_matrix", "test_fleet_matrix",
+    "test_elastic", "test_xla_global", "test_chaos_matrix",
+    "test_fleet_matrix",
 }
 # Individual subprocess-spawning tests inside otherwise-fast modules
 # (spawned workers may contend for the real chip; the fast lane stays

@@ -497,7 +497,7 @@ def test_wire_bytes_model():
 
 
 def test_gather_beats_dense_wire_at_low_density():
-    """The BENCH_r09 contract in unit form: at <=5% density the gather
+    """The sparse plane's wire contract: at <=5% density the gather
     transport models >=4x fewer wire bytes than the densified ring."""
     rows, width, n = 100_000, 64, 8
     nnz_per_rank = rows // 20  # 5% density

@@ -497,9 +497,8 @@ def test_real_keras_mha_flash_routing_subprocess():
     """The REAL tf.keras MultiHeadAttention graph routes to the flash
     kernel — run in a fresh interpreter because keras binds its backend
     at first import (this test session may already hold the jax
-    backend), mirroring the bench.py isolation. Guards against a keras
-    upgrade changing the emitted attention pattern without the
-    hand-rolled replica tests noticing."""
+    backend). Guards against a keras upgrade changing the emitted
+    attention pattern without the hand-rolled replica tests noticing."""
     import subprocess
     from conftest import clean_spawn_env
 
