@@ -145,11 +145,13 @@ def _attend(cfg, q, k, v, mask=None):
                 "attention_impl='flash' does not support padding "
                 "masks; use 'einsum'")
         from ..ops.flash_attention import flash_attention
-        # 1024-tiles measured fastest at head dimension 64 (round-3
-        # sweep, docs/PERF.md: 2048² exceeds the 16M scoped-VMEM stack)
-        # and at 256 (PERF.md, PR 26: 12.57 ms a layer against
-        # 13.3-17.7 at smaller tiles); _prepare clamps to the sequence
-        # for shorter contexts.
+        # 1024 blocks: what a grid step fetches. Measured fastest at
+        # head dimension 64 (round 3, by the deleted bench.py: 2048²
+        # exceeds the 16M scoped-VMEM stack) and at 256 (PERF.md, PR 26:
+        # 12.57 ms a layer against 13.3-17.7 at smaller blocks);
+        # _clamp_blocks clamps to the sequence for shorter contexts. What
+        # the mask leaves of a block the forward resolves finer, in its
+        # own sub-tiles (ops/flash_attention.py: _sub_tile).
         return flash_attention(
             q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),
             causal=cfg.causal, block_q=1024,

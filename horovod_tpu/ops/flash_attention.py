@@ -10,8 +10,25 @@ Design (MXU/VMEM-first):
 - Online-softmax tiling: grid (batch*heads, q_blocks, k_blocks); the k axis
   is the innermost (sequential) grid dimension, with fp32 running max /
   denominator / accumulator in VMEM scratch that persists across k steps.
+- Two sizes. A **block** (the callers' ``block_q``, ``block_k``; 1024 in
+  the model zoo) is what one grid step's DMA brings: a step's fixed cost
+  and the rescaling of the running statistics are paid once a block, so
+  the largest that VMEM holds is fastest. A **sub-tile** (``_sub_tile``:
+  512 on a side, 256 at a head of two lane tiles) is what the causal mask
+  is resolved at: the forward walks the block the diagonal crosses in
+  sub-blocks of query rows, each against the keys it can see, builds the
+  mask for the one sub-tile on the diagonal and runs nothing for those
+  beyond it. Blocks past a query block's last visible one are neither
+  computed nor fetched (their index maps name a block already held).
+- The forward is key-major, like the backward: s^T = k q^T is (keys,
+  queries), so max and sum over keys add vregs to each other and the
+  running statistics are rows along lanes. Query-major, every 8 rows of
+  every tile paid two reductions across lanes, and those, not the
+  products or the exponential, set the kernel's time (PERF.md section 6,
+  PR 29).
 - Logits and accumulation in fp32 on the MXU (``preferred_element_type``),
-  inputs bf16 or fp32.
+  inputs bf16 or fp32. A power-of-two ``sm_scale`` is folded into q
+  (exact), any other multiplies s.
 - Global-position masking: query/key chunk offsets arrive as dynamic scalars
   (scalar-prefetch), so the same compiled kernel serves local attention and
   every step of a ring schedule (offsets are device-varying under shard_map).
@@ -31,6 +48,7 @@ test suite exercises the exact kernel logic on the CPU mesh.
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -108,6 +126,10 @@ def _pad_to(x, multiple, axis):
     return jnp.pad(x, widths)
 
 
+def _lane_tiles(d):
+    return -(-d // _LANE)
+
+
 # ---------------------------------------------------------------------------
 # Forward kernel
 # ---------------------------------------------------------------------------
@@ -118,12 +140,13 @@ def _block_skip(causal, q_start, k_start, kv_len, qb, kb, block_q,
     padding, or (causal) the whole tile lies above the diagonal. Skipped
     tiles are mathematically identity updates (p==0 everywhere), so
     guarding them with pl.when drops ~half the FLOPs of a causal kernel
-    without changing results."""
+    without changing results. Traced scalars, Python integers (a Python
+    bool out) and numpy arrays alike."""
     skip = kb * block_k >= kv_len
     if causal:
         max_row = q_start + qb * block_q + block_q - 1
         min_col = k_start + kb * block_k
-        skip = jnp.logical_or(skip, max_row < min_col)
+        skip = skip | (max_row < min_col)
     return skip
 
 
@@ -138,15 +161,15 @@ def _tile_interior(causal, q_start, k_start, kv_len, qb, kb, block_q,
     if causal:
         min_row = q_start + qb * block_q
         max_col = k_start + kb * block_k + block_k - 1
-        inside = jnp.logical_and(inside, max_col <= min_row)
+        inside = inside & (max_col <= min_row)
     return inside
 
 
-def _keep_scale(dm_ref, dropout_rate):
-    """fp32 dropout multiplier for the current tile: keep-mask rescaled
-    by 1/(1-rate). One definition keeps the fwd and bwd use sites in
+def _keep_scale(dm, dropout_rate):
+    """fp32 dropout multiplier for a tile of the keep-mask: rescaled by
+    1/(1-rate). One definition keeps the fwd and bwd use sites in
     exact sync (a fwd/bwd mismatch would be a silent gradient bug)."""
-    return dm_ref[0].astype(jnp.float32) * (1.0 / (1.0 - dropout_rate))
+    return dm.astype(jnp.float32) * (1.0 / (1.0 - dropout_rate))
 
 
 def _seeded_keep_scale(lens_ref, qb, kb, block_q, block_k, dropout_rate):
@@ -173,8 +196,78 @@ def _seeded_keep_scale(lens_ref, qb, kb, block_q, block_k, dropout_rate):
         1.0 / (1.0 - dropout_rate))
 
 
+# Side of the forward's sub-tiles at a head of one lane tile.
+_SUB_TILE = 512
+
+
+def _sub_tile(causal, block_q, block_k, d):
+    """Side of the square sub-tiles the forward walks a block on the
+    diagonal in (the block's own where it is smaller: one sub-tile, and
+    still the diagonal's constant mask), or None where it walks none (no
+    causal mask, blocks not square). The blocks are what a
+    grid step's DMA brings (the callers' to choose, as large as VMEM
+    allows: a step's fixed cost and the statistics' rescaling are paid
+    once a block); the sub-tile is what the mask is resolved at.
+    Measured on ``_fwd_call`` alone (PERF.md section 6, PR 29): 512 at a
+    head of one lane tile, 256 at two, where the products are long
+    enough to pay for four sub-blocks of rows; never smaller."""
+    sub = min(block_q, _SUB_TILE // min(2, _lane_tiles(d)))
+    if not causal or block_q != block_k or block_q % sub:
+        return None
+    return sub
+
+
+def _on_diagonal(q_start, k_start, kv_len, qb, kb, block):
+    """True for the block the diagonal crosses from corner to corner
+    with every key valid: its sub-tiles' classes are fixed
+    (_strip_extent)."""
+    return ((q_start + qb * block == k_start + kb * block)
+            & ((kb + 1) * block <= kv_len))
+
+
+def _strip_extent(i, n_j, sub):
+    """(keys seen, keys seen whole) by sub-block ``i`` of query rows in
+    a tile whose corner lies on the diagonal and whose keys are all
+    valid: the sub-tiles' classes, by the functions that class a tile,
+    on each sub-tile's own corners."""
+    at = [(True, 0, 0, n_j * sub, i, j, sub, sub) for j in range(n_j)]
+    seen = sum(not _block_skip(*a) for a in at)
+    whole = sum(bool(_tile_interior(*a)) for a in at)
+    return seen * sub, whole * sub
+
+
+def _last_key_block(i, lens, n_k, block_q, block_k, causal):
+    """The last key block the forward's query block ``i`` sees, inside
+    the grid; None where every step is visible or nothing would be
+    saved. The steps after it are skipped (_block_skip). ``lax.div``,
+    not ``//``, which Mosaic lowers as a nested ``pjit`` a map (1.2 s of
+    set-up in PR 27)."""
+    if not causal or n_k == 1:
+        return None
+    last = lax.div(lens[0] + i * block_q + (block_q - 1) - lens[1],
+                   jnp.int32(block_k))
+    last = lax.min(last, lax.div(lens[2] - 1, jnp.int32(block_k)))
+    # Truncating division: a negative numerator gives 0 or more where
+    # the floor gives -1, and both end at block 0.
+    return lax.max(lax.min(last, jnp.int32(n_k - 1)), jnp.int32(0))
+
+
+def _kv_block(i, j, lens, n_k, block_q, block_k, causal):
+    """The K and V block grid step (i, j) of the forward holds: its own
+    while visible, block 0 on the skipped steps after. One fetch, under
+    the diagonal tile's products, brings what the next query block
+    starts with, and the other skipped steps fetch nothing (measured
+    against naming the last visible block, which fetches at the row's
+    end: 4.25 -> 4.10 ms a layer at 32 x 8192 x 64, PERF.md, PR 29)."""
+    last = _last_key_block(i, lens, n_k, block_q, block_k, causal)
+    if last is None:
+        return j
+    return lax.select(j <= last, j, jnp.zeros_like(j))
+
+
 def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, *rest, sm_scale, causal,
-                block_q, block_k, n_k, dropout_rate=0.0, seeded=False):
+                block_q, block_k, n_k, sub, dropout_rate=0.0,
+                seeded=False):
     # rest = [dm_ref?], o_ref, lse_ref, m_scr, l_scr, acc_scr
     if dropout_rate > 0.0 and not seeded:
         dm_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
@@ -186,6 +279,9 @@ def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, *rest, sm_scale, causal,
     q_start = lens_ref[0]
     k_start = lens_ref[1]
     kv_len = lens_ref[2]
+    # A power of two: q * scale is exact and so is every product of it,
+    # so s needs no multiply of its own.
+    fold = math.frexp(sm_scale)[0] == 0.5
 
     @pl.when(kb == 0)
     def _():
@@ -193,95 +289,161 @@ def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, *rest, sm_scale, causal,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    skip = _block_skip(causal, q_start, k_start, kv_len, qb, kb,
-                       block_q, block_k)
-    interior = _tile_interior(causal, q_start, k_start, kv_len, qb, kb,
-                              block_q, block_k)
+    mask_of = (causal, q_start, k_start, kv_len)
+    skip = _block_skip(*mask_of, qb, kb, block_q, block_k)
+    interior = _tile_interior(*mask_of, qb, kb, block_q, block_k)
 
-    def tile_update(masked):
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # (bq, bk)
+    def update(keep, row0, n_rows, width, mask_from, on_diagonal=False):
+        """Online-softmax update of the tile's query rows ``row0`` to
+        ``row0 + n_rows`` by its first ``width`` keys. The keys from
+        ``mask_from`` on go through the mask; the ones before are known
+        to be visible. Key-major: s^T is (keys, queries), so the
+        statistics are rows along lanes and a reduction over keys adds
+        vregs to each other, nothing across lanes."""
+        rows = pl.ds(row0, n_rows)
+        q = q_ref[0, rows, :]
+        if fold:
+            q = (q.astype(jnp.float32) * sm_scale).astype(q.dtype)
+        st = jax.lax.dot_general(
+            k_ref[0, :width, :], q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)      # (width, n_rows)
+        if not fold:
+            st = st * sm_scale
         mask = None
-        if masked:
-            rows = qb * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            mask = cols < kv_len      # mask key padding
-            if causal:
-                mask = jnp.logical_and(
-                    mask, (q_start + rows) >= (k_start + cols))
-            s = jnp.where(mask, s, _NEG_INF)
+        if mask_from is not None:
+            tail = (width - mask_from, n_rows)
+            c = mask_from + jax.lax.broadcasted_iota(jnp.int32, tail, 0)
+            r = row0 + jax.lax.broadcasted_iota(jnp.int32, tail, 1)
+            if on_diagonal:
+                # The tile's corner is on the diagonal and its keys are
+                # all valid: the mask is the same in every such tile.
+                mask = r >= c
+            else:
+                mask = kb * block_k + c < kv_len      # key padding
+                if causal:
+                    mask = jnp.logical_and(
+                        mask, (q_start + qb * block_q + r)
+                        >= (k_start + kb * block_k + c))
 
-        m_prev = m_scr[:, :1]         # (block_q, 1)
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
+        def masked(x, fill):
+            if mask is None:
+                return x
+            x_tail = jnp.where(mask, x[mask_from:, :], fill)
+            if mask_from == 0:
+                return x_tail
+            return jnp.concatenate([x[:mask_from, :], x_tail], axis=0)
+
+        st = masked(st, _NEG_INF)
+        m_prev = m_scr[:1, rows]                   # (1, n_rows)
+        m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)        # (block_q, block_k) fp32
-        if mask is not None:
+        pt = jnp.exp(st - m_new)                   # (width, n_rows) fp32
+        if not on_diagonal:
             # Fully-masked rows: m_new stays _NEG_INF and p would be
-            # exp(0)=1 — zero those contributions so l stays 0 for them.
-            p = jnp.where(mask, p, 0.0)
-
-        l_prev = l_scr[:, :1]
-        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            # exp(0)=1 — zero those contributions so l stays 0 for
+            # them. (On the diagonal every row sees its own key, so a
+            # masked p is exp(-1e30 - m) = 0 already.)
+            pt = masked(pt, 0.0)
+        l_new = alpha * l_scr[:1, rows] + jnp.sum(pt, axis=0, keepdims=True)
         # Attention dropout (torch semantics: probs are dropped AFTER
         # softmax, so the normalizer l uses the undropped p while the
         # value accumulation uses the dropped/rescaled weights).
-        pv = p
-        if dropout_rate > 0.0 and seeded:
-            pv = p * _seeded_keep_scale(lens_ref, qb, kb, block_q,
-                                        block_k, dropout_rate)
+        pvt = pt
+        if keep is not None:
+            pvt = pt * keep[row0:row0 + n_rows, :width].T
         elif dm_ref is not None:
-            pv = p * _keep_scale(dm_ref, dropout_rate)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            pv.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+            pvt = pt * _keep_scale(dm_ref[0, rows, :width], dropout_rate).T
+        acc_scr[:, rows] = acc_scr[:, rows] * alpha + jax.lax.dot_general(
+            v_ref[0, :width, :], pvt.astype(v_ref.dtype),
+            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_scr[:, rows] = jnp.broadcast_to(m_new, (m_scr.shape[0], n_rows))
+        l_scr[:, rows] = jnp.broadcast_to(l_new, (l_scr.shape[0], n_rows))
 
-    @pl.when(jnp.logical_and(jnp.logical_not(skip), interior))
-    def _():
-        tile_update(False)
+    def draw():
+        if dropout_rate > 0.0 and seeded:
+            return _seeded_keep_scale(lens_ref, qb, kb, block_q, block_k,
+                                      dropout_rate)
+        return None
 
-    @pl.when(jnp.logical_and(jnp.logical_not(skip),
-                             jnp.logical_not(interior)))
+    visible = jnp.logical_not(skip)
+
+    @pl.when(jnp.logical_and(visible, interior))
     def _():
-        tile_update(True)
+        update(draw(), 0, block_q, block_k, None)
+
+    partial = jnp.logical_and(visible, jnp.logical_not(interior))
+    if sub is not None:
+        # A sub-block of query rows sees the sub-tiles left of the
+        # diagonal whole, the one on it through the mask, and nothing of
+        # those right of it.
+        on_diagonal = _on_diagonal(q_start, k_start, kv_len, qb, kb,
+                                   block_q)
+
+        @pl.when(jnp.logical_and(partial, on_diagonal))
+        def _():
+            keep = draw()
+            for i in range(block_q // sub):
+                seen, whole = _strip_extent(i, block_k // sub, sub)
+                update(keep, i * sub, sub, seen, whole, on_diagonal=True)
+
+        partial = jnp.logical_and(partial, jnp.logical_not(on_diagonal))
+
+    @pl.when(partial)
+    def _():
+        update(draw(), 0, block_q, block_k, 0)
 
     @pl.when(kb == n_k - 1)
     def _():
-        l = l_scr[:, :1]
+        l = l_scr[:1, :]                           # (1, block_q)
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
-        m = m_scr[:, 0]
-        lse = jnp.where(l_scr[:, 0] == 0.0, _NEG_INF,
-                        m + jnp.log(l_scr[:, 0]))
+        o_ref[0] = (acc_scr[:] / safe_l).T.astype(o_ref.dtype)
         # lse is laid out (bh, 1, sq): TPU requires the last two block dims
         # to divide (8, 128) or equal the array dims — (1, 1, block_q) does.
-        lse_ref[0, 0] = lse.astype(lse_ref.dtype)
+        lse_ref[0] = jnp.where(l == 0.0, _NEG_INF,
+                               m_scr[:1, :] + jnp.log(safe_l))
 
 
 def _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
               dm=None, dropout_rate=0.0, seeded=False):
+    """One forward kernel call. The layers of a model make the same
+    call, so it goes through ``jax.jit``: the kernel is traced and
+    lowered once a program, not once a layer, and XLA inlines the calls
+    (each keeps its own layer's ``op_name``). What the trace reads of
+    the module's state is an argument, so no cached trace outlives it."""
+    return _fwd_jit(q, k, v, lens, dm, sm_scale, causal, block_q, block_k,
+                    _sub_tile(causal, block_q, block_k, q.shape[2]),
+                    dropout_rate, seeded, _interpret())
+
+
+@functools.partial(jax.jit, static_argnums=tuple(range(5, 13)))
+def _fwd_jit(q, k, v, lens, dm, sm_scale, causal, block_q, block_k, sub,
+             dropout_rate, seeded, interpret):
     bh, sq, d = q.shape
     sk = k.shape[1]
     n_q = sq // block_q
     n_k = sk // block_k
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k, n_k=n_k,
+        block_q=block_q, block_k=block_k, n_k=n_k, sub=sub,
         dropout_rate=dropout_rate, seeded=seeded)
+
+    grid_of = (n_k, block_q, block_k, causal)
+    k_tile = pl.BlockSpec(
+        (1, block_k, d),
+        lambda b, i, j, lens: (b, _kv_block(i, j, lens, *grid_of), 0))
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j, lens: (b, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j, lens: (b, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j, lens: (b, j, 0)),
+        k_tile, k_tile,
     ]
     operands = [q, k, v]
     if dropout_rate > 0.0 and not seeded:
-        in_specs.append(pl.BlockSpec(
-            (1, block_q, block_k), lambda b, i, j, lens: (b, i, j)))
+        def dm_block(b, i, j, lens):
+            # The next query block's first mask block is another one:
+            # the skipped steps keep the last visible one.
+            last = _last_key_block(i, lens, *grid_of)
+            return b, i, j if last is None else lax.min(j, last)
+
+        in_specs.append(pl.BlockSpec((1, block_q, block_k), dm_block))
         operands.append(dm)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -292,9 +454,9 @@ def _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
             pl.BlockSpec((1, 1, block_q), lambda b, i, j, lens: (b, 0, i)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, _LANE), jnp.float32),
-            pltpu.VMEM((block_q, _LANE), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((8, block_q), jnp.float32),
+            pltpu.VMEM((8, block_q), jnp.float32),
+            pltpu.VMEM((d, block_q), jnp.float32),
         ],
     )
     out_shapes = [
@@ -308,7 +470,7 @@ def _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
         grid_spec=grid_spec,
         out_shape=out_shapes,
         compiler_params=compiler_params,
-        interpret=_interpret(),
+        interpret=interpret,
         name=KERNEL_FWD,
     )(lens, *operands)
     return o, lse[:, 0, :]
@@ -390,7 +552,7 @@ def _bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             keep = _seeded_keep_scale(lens_ref, qg, kb, block_q,
                                       block_k, dropout_rate).T
         elif dm_ref is not None:
-            keep = _keep_scale(dm_ref, dropout_rate).T
+            keep = _keep_scale(dm_ref[0], dropout_rate).T
         pvt = pt if keep is None else pt * keep
         # MXU operands in the input dtype (bf16 in training; identity for
         # fp32 inputs), fp32 accumulation. fp32 operands would run the
@@ -443,10 +605,6 @@ _TILE_VMEM_BYTES = 16 * 2 ** 20
 # Most that dq's float32 accumulator and its double-buffered output block
 # may hold; a longer query range goes through in chunks.
 _DQ_RESIDENT_BYTES = 32 * 2 ** 20
-
-
-def _lane_tiles(d):
-    return -(-d // _LANE)
 
 
 def _dq_resident_bytes(rows, d, dtype):
@@ -668,17 +826,86 @@ def _flash_seeded_bwd(sm_scale, causal, block_q, block_k, rate, res, g):
 _flash_seeded.defvjp(_flash_seeded_fwd, _flash_seeded_bwd)
 
 
+def _clamp_blocks(sq, sk, block_q, block_k):
+    """Clamp requested blocks to the (pow2-rounded) sequence lengths. The
+    caller may ask for >128 blocks: a block is what one grid step's DMA
+    brings, and a step's fixed cost and the rescaling of the running
+    statistics are paid once a block, so the largest that VMEM holds is
+    fastest (1024: PERF.md section 7). What the mask leaves of a block
+    is resolved finer, in the forward's sub-tiles (_sub_tile)."""
+    return (min(block_q, max(8, 1 << (sq - 1).bit_length())),
+            min(block_k, max(8, 1 << (sk - 1).bit_length())))
+
+
+def fwd_subtile_counts(sq, sk, block_q, block_k, causal, q_offset=0,
+                       k_offset=0, kv_len=None, head_dim=64):
+    """How the forward kernel visits one (batch, head)'s score matrix:
+    sub-tiles ``interior`` (no mask built), ``masked`` and ``skipped``
+    (no product, no exponential), and ``steps_without_fetch``, the grid
+    steps whose K and V blocks are the ones already held. A function of
+    shapes and offsets alone, by the functions the kernel itself
+    classes tiles and names blocks with. A tile that is not walked in
+    sub-tiles counts as one sub-tile."""
+    block_q, block_k = _clamp_blocks(sq, sk, block_q, block_k)
+    n_q, n_k = -(-sq // block_q), -(-sk // block_k)
+    kv_len = sk if kv_len is None else kv_len
+    qb, kb = np.meshgrid(np.arange(n_q), np.arange(n_k), indexing="ij")
+    tile = (causal, q_offset, k_offset, kv_len, qb, kb, block_q, block_k)
+    skip = _block_skip(*tile) & np.ones_like(qb, bool)
+    interior = _tile_interior(*tile) & ~skip
+    walked = np.zeros_like(skip)
+    # Sub-tiles a block: all, seen and seen whole by a walked block's
+    # rows.
+    per_block, seen, whole = 1, 1, 0
+    sub = _sub_tile(causal, block_q, block_k, head_dim)
+    if sub is not None:
+        n = block_q // sub
+        extents = [_strip_extent(i, n, sub) for i in range(n)]
+        per_block = n * n
+        seen = sum(s for s, _ in extents) // sub
+        whole = sum(w for _, w in extents) // sub
+        walked = ~skip & ~interior & _on_diagonal(
+            q_offset, k_offset, kv_len, qb, kb, block_q)
+    n_walked = int(walked.sum())
+    counts = {
+        "interior": int(interior.sum()) * per_block + n_walked * whole,
+        "masked": int((~skip & ~interior & ~walked).sum()) * per_block
+        + n_walked * (seen - whole),
+        "skipped": int(skip.sum()) * per_block
+        + n_walked * (per_block - seen),
+    }
+    with jax.ensure_compile_time_eval():
+        lens = jnp.asarray([q_offset, k_offset, kv_len], jnp.int32)
+        held = np.asarray(_kv_block(
+            jnp.asarray(qb, jnp.int32), jnp.asarray(kb, jnp.int32), lens,
+            n_k, block_q, block_k, causal)).reshape(-1)
+    counts["steps_without_fetch"] = int((held[1:] == held[:-1]).sum())
+    return counts
+
+
+def _publish_subtiles(*call):
+    """Set ``hvd_flash_fwd_subtiles{kind}`` (docs/metrics.md) from
+    ``fwd_subtile_counts(*call)`` of the call being traced. A no-op when
+    ``HOROVOD_TPU_METRICS`` is off."""
+    from ..telemetry import core as telemetry
+    if not telemetry.enabled():
+        return
+    gauge = telemetry.gauge(
+        "hvd_flash_fwd_subtiles",
+        "Sub-tiles of one (batch, head) the flash forward kernel last "
+        "traced visits, by kind, and its grid steps that fetch no K/V",
+        ("kind",))
+    for kind, n in fwd_subtile_counts(*call).items():
+        gauge.labels(kind=kind).set(float(n))
+
+
 def _prepare(q, k, v, block_q, block_k):
     """Reshape (B,H,S,D)→(BH,S,D), pad D to a lane tile (64 when D<=64,
     else 128) and S to block multiples. Returns padded tensors +
     original dims."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    # Clamp requested blocks to the (pow2-rounded) sequence lengths; the
-    # caller may ask for >128 tiles (bigger s-tiles amortize the online-
-    # softmax bookkeeping at long context — see docs/PERF.md sweep).
-    block_q = min(block_q, max(8, 1 << (sq - 1).bit_length()))
-    block_k = min(block_k, max(8, 1 << (sk - 1).bit_length()))
+    block_q, block_k = _clamp_blocks(sq, sk, block_q, block_k)
 
     def flat(x):
         return x.reshape((b * h,) + x.shape[2:])
@@ -762,6 +989,10 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None,
             k_offset=k_offset, kv_len=kv_len, with_lse=with_lse,
             dropout_mask=dropout_mask, dropout_rate=dropout_rate)
     qp, kp, vp, dims, bq, bk = _prepare(q, k, v, block_q, block_k)
+    if all(isinstance(x, (int, np.integer))
+           for x in (q_offset, k_offset, kv_len)):
+        _publish_subtiles(sq, k.shape[2], bq, bk, bool(causal), q_offset,
+                          k_offset, kv_len, qp.shape[2])
     lens = jnp.asarray([q_offset, k_offset, kv_len], jnp.int32)
     if has_dropout and dropout_seed is not None:
         lens4 = jnp.concatenate(

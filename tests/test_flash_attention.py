@@ -325,3 +325,229 @@ def test_fused_backward_query_chunks(monkeypatch, variant):
         calls.append((kw["qb0"], q.shape[1])), chunk(q, *a, **kw))[1])
     _assert_grads_match(q, k, v, dict(block_q=32, block_k=32), **kwargs)
     assert calls == [(0, 64), (2, 64), (4, 32)]
+
+
+# ---------------------------------------------------------------------------
+# The forward's sub-tiles: a tile on the diagonal is walked at the
+# granularity of the mask, key-major (statistics as rows along lanes)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def sub_tile(monkeypatch):
+    """Small blocks hold 2 x 2 and 4 x 4 sub-tiles where the sub-tile is
+    small too: the rule's constant, set for the test."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    def set_to(sub):
+        monkeypatch.setattr(fa, "_SUB_TILE", sub)
+    return set_to
+
+
+# (sq, sk, d, block, sub-tile, dtype, kwargs of both sides); offsets
+# marked traced go through jit as arguments (a ring step's call).
+SUBTILE_CASES = {
+    "diagonal_2x2": (128, 128, 32, 64, 32, jnp.float32, dict(causal=True)),
+    "diagonal_4x4": (128, 128, 32, 64, 16, jnp.float32, dict(causal=True)),
+    "diagonal_shifted_a_block_4x4": (
+        128, 192, 32, 64, 16, jnp.float32, dict(causal=True, q_offset=64)),
+    "ring_on_the_diagonal_traced": (
+        128, 128, 32, 64, 16, jnp.float32,
+        dict(causal=True, q_offset=256, k_offset=256)),
+    "ring_off_every_boundary_traced": (
+        128, 128, 32, 64, 16, jnp.float32,
+        dict(causal=True, q_offset=7, k_offset=3)),
+    "ring_whole_rows_masked_traced": (
+        128, 128, 32, 64, 16, jnp.float32,
+        dict(causal=True, q_offset=0, k_offset=40)),
+    "kv_len_cuts_a_sub_tile_causal": (
+        128, 128, 32, 64, 16, jnp.float32, dict(causal=True, kv_len=77)),
+    "kv_len_cuts_a_sub_tile": (
+        128, 128, 32, 64, 16, jnp.float32, dict(causal=False, kv_len=77)),
+    "unaligned_seq_and_head_dim": (
+        100, 100, 48, 64, 16, jnp.float32, dict(causal=True)),
+    "head_dim_256_2x2": (       # two lane tiles a head: half the side
+        256, 256, 256, 128, 128, jnp.float32, dict(causal=True)),
+    "bfloat16_4x4": (128, 128, 64, 64, 16, jnp.bfloat16, dict(causal=True)),
+    "scale_not_a_power_of_two": (
+        128, 128, 32, 64, 16, jnp.float32, dict(causal=True, sm_scale=0.3)),
+    "blocks_not_square": (
+        128, 128, 32, 64, 32, jnp.float32, dict(causal=True, block_k=32)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SUBTILE_CASES))
+def test_subtiled_forward_matches_reference(sub_tile, case):
+    """Value, log-sum-exp and the gradients through both (the new
+    forward's lse into the unchanged backward) against the oracle."""
+    sq, sk, d, block, sub, dtype, kwargs = SUBTILE_CASES[case]
+    sub_tile(sub)
+    kwargs = dict(kwargs)
+    blocks = dict(block_q=block, block_k=kwargs.pop("block_k", block))
+    q = _rand((1, 2, sq, d), 0, dtype)
+    k, v = _rand((1, 2, sk, d), 1, dtype), _rand((1, 2, sk, d), 2, dtype)
+    traced = {name: jnp.int32(kwargs.pop(name))
+              for name in ("q_offset", "k_offset")
+              if case.endswith("_traced")}
+
+    @functools.partial(jax.jit, static_argnums=0)
+    def run(attend, offsets):
+        attend = functools.partial(attend, **kwargs, **offsets,
+                                   with_lse=True)
+        return attend(q, k, v), _grads(attend, q, k, v)
+
+    (o, lse), g1 = run(functools.partial(flash_attention, **blocks), traced)
+    (ro, rlse), g2 = run(reference_attention, traced)
+    value, grad = (3e-2, 6e-2) if dtype == jnp.bfloat16 else (2e-5, 5e-4)
+    assert o.dtype == dtype
+    np.testing.assert_allclose(np.asarray(o, np.float32),
+                               np.asarray(ro, np.float32),
+                               atol=value, rtol=value)
+    # Rows that see no key: l == 0, and the log-sum-exp says so.
+    unseen = np.asarray(rlse) < -1e29
+    assert (np.asarray(lse)[unseen] < -1e29).all()
+    assert unseen.any() == (case == "ring_whole_rows_masked_traced")
+    np.testing.assert_allclose(np.asarray(lse)[~unseen],
+                               np.asarray(rlse)[~unseen],
+                               atol=max(value, 1e-4), rtol=value)
+    for a, b in zip(g1, g2):
+        assert np.isfinite(np.asarray(a, np.float32)).all()
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   atol=grad, rtol=grad)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_subtiled_dropout_mask_forward_and_gradient(sub_tile, causal):
+    """The keep-mask's block is sliced a sub-tile's rows and keys at a
+    time; forward and backward use the same pattern."""
+    sub_tile(16)
+    q, k, v = (_rand((1, 2, 128, 32), i) for i in range(3))
+    dm = jax.random.bernoulli(jax.random.PRNGKey(13), 0.8, (1, 2, 128, 128))
+    kwargs = dict(causal=causal, dropout_mask=dm, dropout_rate=0.2)
+    out = flash_attention(q, k, v, block_q=64, block_k=64, **kwargs)
+    ref = reference_attention(q, k, v, **kwargs)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+    _assert_grads_match(q, k, v, dict(block_q=64, block_k=64), **kwargs)
+
+
+# (n_q, n_k, block_q, block_k, q_offset, k_offset, kv_len)
+KV_MAP_CASES = {
+    "square_4x4": (4, 4, 64, 64, 0, 0, 256),
+    "ring_past_chunk": (2, 4, 64, 64, 256, 0, 256),
+    "ring_future_chunk": (2, 2, 64, 64, 0, 128, 128),
+    "off_every_boundary": (3, 5, 32, 64, 7, 3, 320),
+    "negative_numerator": (4, 3, 32, 32, 0, 40, 96),
+    "kv_len_ends_early": (4, 4, 64, 64, 0, 0, 130),
+    "wide_query_blocks": (2, 6, 128, 32, 16, 0, 192),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KV_MAP_CASES))
+def test_forward_kv_index_map_fetches_once_on_skipped_steps(case):
+    """The K/V index map alone, offsets as the traced scalars an index
+    map sees: a visible step names its own block; the skipped steps
+    after it all name one block inside the grid, block 0, which the
+    next visible step (the next query block's first) names too, so of
+    a row's skipped steps at most the first fetches. The dropout
+    mask's map keeps the row's last visible block, and fetches
+    nothing."""
+    from horovod_tpu.ops import flash_attention as fa
+    n_q, n_k, block_q, block_k, q_offset, k_offset, kv_len = \
+        KV_MAP_CASES[case]
+    lens = jnp.asarray([q_offset, k_offset, kv_len], jnp.int32)
+    grid_of = (n_k, block_q, block_k, True)
+    skipped_steps = 0
+    for i in range(n_q):
+        held = 0
+        last = int(fa._last_key_block(jnp.int32(i), lens, *grid_of))
+        assert 0 <= last < n_k
+        for j in range(n_k):
+            named = int(fa._kv_block(jnp.int32(i), jnp.int32(j), lens,
+                                     *grid_of))
+            if fa._block_skip(True, q_offset, k_offset, kv_len, i, j,
+                              block_q, block_k):
+                skipped_steps += 1
+                # (a row that sees nothing holds block 0 throughout)
+                assert named == 0 and (j > last or last == 0), (i, j)
+                assert min(j, last) == held, (i, j)
+            else:
+                assert named == j and j <= last, (i, j)
+                held = j
+    assert (skipped_steps > 0) == (case != "ring_past_chunk")
+    # Without a causal mask, or with one key block, the map is the
+    # identity (a single block is held from step to step anyway).
+    for i in range(n_q):
+        for j in range(n_k):
+            assert fa._kv_block(i, j, lens, n_k, block_q, block_k,
+                                False) == j
+    assert fa._kv_block(3, 0, lens, 1, block_q, block_k, True) == 0
+    assert fa._last_key_block(3, lens, 1, block_q, block_k, True) is None
+
+
+# The five cells that run the kernel: (seq, head_dim) at 1024 blocks,
+# and per (batch, head) the sub-tiles by kind and the steps that fetch
+# nothing (the skipped steps, and the second row's first, which finds
+# block 0 held since the first row's skipped steps).
+CELL_COUNTS = {
+    "lm365m-seq8192-1chip": (8192, 64, 120, 16, 120, 29),
+    "lm365m-seq2048-1chip": (2048, 64, 6, 4, 6, 2),
+    "lm365m-seq2048-4chip": (2048, 64, 6, 4, 6, 2),
+    "lm365m-seq512-1chip": (512, 64, 0, 1, 0, 0),
+    "glm47flash-seq4096-1chip": (4096, 256, 120, 16, 120, 7),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_COUNTS))
+def test_fwd_subtile_counts_at_the_cells_shapes(cell):
+    from horovod_tpu.ops import flash_attention as fa
+    seq, d, interior, masked, skipped, unfetched = CELL_COUNTS[cell]
+    counts = fa.fwd_subtile_counts(seq, seq, 1024, 1024, True, head_dim=d)
+    assert counts == {"interior": interior, "masked": masked,
+                      "skipped": skipped, "steps_without_fetch": unfetched}
+    block = min(seq, 1024)
+    sub = fa._sub_tile(True, block, block, d) or block
+    assert interior + masked + skipped == (seq // sub) ** 2
+    # Every sub-tile is on or under the diagonal, over it, or crossed.
+    n = seq // sub
+    assert (interior, masked, skipped) == (n * (n - 1) // 2, n,
+                                           n * (n - 1) // 2)
+
+
+def test_fwd_subtile_counts_follow_offsets_and_kv_len():
+    from horovod_tpu.ops import flash_attention as fa
+    # Off the sub-tiles' corners nothing is walked in sub-tiles: the
+    # three tiles the diagonal touches go through the mask whole.
+    assert fa.fwd_subtile_counts(2048, 2048, 1024, 1024, True, q_offset=7,
+                                 k_offset=3) == {
+        "interior": 4, "masked": 12, "skipped": 0, "steps_without_fetch": 0}
+    # No mask: tiles are not walked in sub-tiles, and count as one each.
+    assert fa.fwd_subtile_counts(2048, 2048, 1024, 1024, False) == {
+        "interior": 4, "masked": 0, "skipped": 0, "steps_without_fetch": 0}
+    # Key padding: the last key block is cut, the one before is whole.
+    assert fa.fwd_subtile_counts(256, 384, 128, 128, False, kv_len=200) == {
+        "interior": 2, "masked": 2, "skipped": 2, "steps_without_fetch": 0}
+
+
+def test_subtile_gauge_is_set_when_metrics_are_on(monkeypatch):
+    from horovod_tpu import telemetry
+    monkeypatch.setenv("HOROVOD_TPU_METRICS", "1")
+    telemetry.reset()
+    try:
+        q, k, v = (_rand((1, 1, 256, 32), i) for i in range(3))
+        jax.jit(functools.partial(flash_attention, causal=True,
+                                  block_q=128, block_k=128))(q, k, v)
+        family = telemetry.registry().families()["hvd_flash_fwd_subtiles"]
+        values = {s["labels"]["kind"]: s["value"]
+                  for s in family.samples()}
+        assert values == {"interior": 1.0, "masked": 2.0, "skipped": 1.0,
+                          "steps_without_fetch": 2.0}
+        # Traced offsets: the schedule is not known here, nothing is set.
+        telemetry.reset()
+        jax.jit(lambda o: flash_attention(q, k, v, causal=True,
+                                          q_offset=o))(jnp.int32(0))
+        assert "hvd_flash_fwd_subtiles" not in \
+            telemetry.registry().families()
+    finally:
+        monkeypatch.delenv("HOROVOD_TPU_METRICS", raising=False)
+        telemetry.reset()
