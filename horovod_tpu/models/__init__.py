@@ -11,4 +11,5 @@ from .mlp import MLP, MnistCNN  # noqa: F401
 from .resnet import ResNet50, ResNet18, ResNet101  # noqa: F401
 from .transformer import (  # noqa: F401
     TransformerLM, TransformerConfig, BertConfig, BertModel,
+    looped_lm_loss, publish_exit_shares,
 )

@@ -11,8 +11,11 @@ backward (``_rope``); optional jax.checkpoint rematerialization per
 block. One ``Block`` / ``Backbone`` / ``TransformerLM`` skeleton serves
 every configuration; ``TransformerConfig`` chooses the norm, the
 attention (multi-head, or latent: ``mla``), the FFN (biased GELU,
-bias-free SwiGLU, or per layer the expert layer of ``parallel/moe.py``)
-and a multi-token-prediction module (``mtp_layers``). Hidden sizes are multiples of 128 for MXU tiling; the head
+bias-free SwiGLU, or per layer the expert layer of ``parallel/moe.py``),
+a multi-token-prediction module (``mtp_layers``), and a looped stack:
+the blocks run ``passes`` times with the same weights, with norms on the
+sub-layers' outputs too (``sandwich_norm``) and an exit gate whose loss
+is ``looped_lm_loss``. Hidden sizes are multiples of 128 for MXU tiling; the head
 dimension is ``hidden // heads``, 64 at BERT-large's widths (half of the
 128 lanes, which the kernel and XLA's layouts pay for), and has to be
 even for rope. Sequence/tensor sharding is applied externally via
@@ -28,12 +31,15 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+import optax
 
 from ..parallel.moe import MoEConfig, MoELayer
 
 # Names in a device trace (docs/tracing.md); readers match the literals.
 SCOPE_MLA = "hvd_mla"
 SCOPE_MTP = "hvd_mtp"
+SCOPE_LOOP = "hvd_loop"     # the stack of a looped model, all its passes
+SCOPE_EXIT = "hvd_exit"     # its exit gates, heads and the loss's mix
 
 
 @dataclasses.dataclass(frozen=True)
@@ -57,9 +63,11 @@ class TransformerConfig:
     mlp_ratio: int = 4
     max_len: int = 512
     dtype: jnp.dtype = jnp.bfloat16
-    # False | True/"full" (recompute everything) | "dots" (save matmul
-    # outputs, recompute elementwise — near-free recompute, most of the
-    # memory win; the policy that unlocks larger batches on 16G HBM).
+    # What a block keeps for its backward pass. False: everything.
+    # "dots": the matrix products' outputs (element-wise work and the
+    # flash kernel are made again). "flash": the block's input and the
+    # flash kernel's output and log-sum-exp (everything but the kernel
+    # is made again). True/"full": the block's input alone.
     remat: object = False
     causal: bool = True
     use_rope: bool = True          # decoder LM; BERT uses learned positions
@@ -76,6 +84,17 @@ class TransformerConfig:
     # modules that predict the token after next from the last hidden
     # state and the next token's embedding; embedding and head shared.
     mtp_layers: int = 0
+    # A looped stack (Universal Transformer, arXiv:1807.03819; looped
+    # language models, arXiv:2510.25741): the ``layers`` blocks and the
+    # final norm are applied ``passes`` times with the same weights, each
+    # pass's normed state the next one's input and an output of its own.
+    passes: int = 1
+    # A norm on the output of attention and of the FFN before the
+    # residual add (``ln1_out``, ``ln2_out``), besides those on the inputs.
+    sandwich_norm: bool = False
+    # A ``hidden -> 1`` product with bias on each pass's normed state:
+    # the logit of leaving after that pass (``looped_lm_loss``).
+    exit_gate: bool = False
 
 
 # BERT-large hyperparameters (the reference benchmark target).
@@ -131,6 +150,13 @@ def _norm(cfg, name):
     if cfg.norm == "rmsnorm":
         return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype, name=name)
     return nn.LayerNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype, name=name)
+
+
+def _head(cfg):
+    # bf16 matmul on the MXU (fp32 here costs several passes of MXU
+    # time on a 1024x30k projection), fp32 logits for the softmax.
+    return nn.Dense(cfg.vocab_size, dtype=cfg.dtype, use_bias=cfg.bias,
+                    name="lm_head")
 
 
 def _attend(cfg, q, k, v, mask=None):
@@ -222,11 +248,16 @@ class Block(nn.Module):
     def __call__(self, x, mask=None):
         cfg = self.cfg
         attention = LatentAttention if cfg.mla else Attention
+
+        def out(name, y):
+            return _norm(cfg, name)(y) if cfg.sandwich_norm else y
+
         h = _norm(cfg, "ln1")(x)
-        x = x + attention(cfg, name="attn")(h, mask)
+        x = x + out("ln1_out", attention(cfg, name="attn")(h, mask))
         h = _norm(cfg, "ln2")(x)
         if self.expert:
-            return x + MoELayer(cfg.moe, dtype=cfg.dtype, name="moe")(h)
+            return x + out("ln2_out", MoELayer(cfg.moe, dtype=cfg.dtype,
+                                               name="moe")(h))
         width = cfg.mlp_width or cfg.hidden * cfg.mlp_ratio
         dense = functools.partial(nn.Dense, dtype=cfg.dtype,
                                   use_bias=cfg.bias)
@@ -235,15 +266,37 @@ class Block(nn.Module):
                 width, name="mlp_in")(h)
         else:
             h = nn.gelu(dense(width, name="mlp_in")(h))
-        return x + dense(cfg.hidden, name="mlp_out")(h)
+        return x + out("ln2_out", dense(cfg.hidden, name="mlp_out")(h))
 
 
 def _block(cfg):
+    policies = jax.checkpoint_policies
     if cfg.remat == "dots":
-        return nn.remat(
-            Block,
-            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+        return nn.remat(Block,
+                        policy=policies.dots_with_no_batch_dims_saveable)
+    if cfg.remat == "flash":
+        from ..ops.flash_attention import SAVED_NAMES
+        return nn.remat(Block,
+                        policy=policies.save_only_these_names(*SAVED_NAMES))
     return nn.remat(Block) if cfg.remat else Block
+
+
+# ``nn.scan`` over a function of a module that uses the module's own
+# parameters at every iteration: one leaf a weight, however often used.
+_scan_shared = functools.partial(nn.scan, variable_broadcast="params",
+                                 split_rngs={"params": False})
+
+
+def _looped(module, body, x, passes):
+    """``body(module, x)`` applied ``passes`` times, each result the next
+    call's ``x``, with the same parameters (one leaf a weight, whose
+    gradient is the sum over its uses). Rolled: ``body`` is traced once,
+    whatever ``passes`` is. Returns every call's result, stacked."""
+    def step(module, x, _):
+        x = body(module, x)
+        return x, x
+
+    return _scan_shared(step, length=passes)(module, x, None)[1]
 
 
 class MTPModule(nn.Module):
@@ -270,8 +323,14 @@ class Backbone(nn.Module):
     def __call__(self, tokens, mask=None, next_tokens=None):
         """The final hidden states; with ``next_tokens`` (the tokens
         shifted by one) and ``cfg.mtp_layers``, a tuple of them: the
-        main model's, then each MTP module's."""
+        main model's, then each MTP module's. With ``cfg.passes`` over
+        1, every pass's, stacked: ``[passes, batch, seq, hidden]``."""
         cfg = self.cfg
+        if cfg.passes > 1 and (cfg.moe is not None or cfg.mtp_layers):
+            raise ValueError(
+                "a stack that runs several times (passes > 1) takes "
+                "neither the expert layer, whose state is per layer and "
+                "not per pass, nor MTP modules")
         embed = nn.Embed(cfg.vocab_size, cfg.hidden, dtype=cfg.dtype,
                          name="tok_embed")
         x = embed(tokens)
@@ -280,6 +339,14 @@ class Backbone(nn.Module):
                            name="pos_embed")(jnp.arange(tokens.shape[1]))
             x = x + pos[None]
         block = _block(cfg)
+        if cfg.passes > 1:
+            def stack(module, x):
+                for i in range(cfg.layers):
+                    x = block(cfg, name=f"block_{i}")(x, mask)
+                return _norm(cfg, "ln_f")(x)
+
+            with jax.named_scope(SCOPE_LOOP):
+                return _looped(self, stack, x, cfg.passes)
         for i in range(cfg.layers):
             expert = cfg.moe is not None and i >= cfg.moe.first_dense
             x = block(cfg, expert=expert, name=f"block_{i}")(x, mask)
@@ -305,19 +372,97 @@ class TransformerLM(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, tokens, mask=None, next_tokens=None):
+    def __call__(self, tokens, mask=None, next_tokens=None, targets=None):
         """Float32 logits; with ``next_tokens`` and ``cfg.mtp_layers`` a
         tuple: the main model's, then each MTP module's (logits ``i`` of
-        module ``d`` are for token ``i + d + 2``)."""
+        module ``d`` are for token ``i + d + 2``).
+
+        With ``cfg.passes`` over 1, a pair ``(exits, gate_logits)``, each
+        ``[passes, batch, seq, ...]``: ``exits`` the logits of every
+        pass's state under the one head, or with ``targets`` their
+        cross-entropy at every position, in which case one pass's logits
+        live at a time, forward and backward; ``gate_logits`` the exit
+        gate's (float32; None without ``cfg.exit_gate``). What
+        ``looped_lm_loss`` takes."""
         cfg = self.cfg
         x = Backbone(cfg, name="backbone")(tokens, mask, next_tokens)
-        # bf16 matmul on the MXU (fp32 here costs several passes of MXU
-        # time on a 1024x30k projection), fp32 logits for the softmax.
-        head = nn.Dense(cfg.vocab_size, dtype=cfg.dtype, use_bias=cfg.bias,
-                        name="lm_head")
+        if cfg.passes > 1:
+            with jax.named_scope(SCOPE_EXIT):
+                return self._exits(x, targets)
+        head = _head(cfg)
         if isinstance(x, tuple):
             return tuple(head(h).astype(jnp.float32) for h in x)
         return head(x).astype(jnp.float32)
+
+    def _exits(self, states, targets):
+        cfg = self.cfg
+
+        def one(module, _, h, targets):
+            out = _head(cfg)(h).astype(jnp.float32)
+            if targets is not None:
+                out = optax.softmax_cross_entropy_with_integer_labels(
+                    out, targets)
+            gate = None
+            if cfg.exit_gate:
+                gate = nn.Dense(1, dtype=jnp.float32,
+                                name="exit_gate")(h)[..., 0]
+            return None, (out, gate)
+
+        # Rolled and made again on the way back: a pass's logits
+        # ([tokens, vocabulary] in float32) are temporaries of its own
+        # iteration, and what is kept is a number a token a pass.
+        if targets is not None:
+            one = nn.remat(one)
+        return _scan_shared(one, in_axes=(0, nn.broadcast))(
+            self, None, states, targets)[1]
+
+
+def looped_lm_loss(xent, gate_logits, beta):
+    """The loss of a looped language model with an exit gate (Zhu et
+    al., arXiv:2510.25741, the entropy-regularised objective under a
+    uniform prior over exit steps), and the mean share of positions
+    leaving at each pass (``[passes]``; no gradient flows to it).
+
+    ``xent`` and ``gate_logits``: ``[passes, ...]``, the cross-entropy of
+    every pass's logits at every position and the gate's logit there.
+    ``lambda_t = sigmoid(gate_logits[t])`` is the chance of leaving after
+    pass ``t`` having got there, so the exit distribution is ``p_t =
+    lambda_t prod_{j<t} (1 - lambda_j)``, the last pass taking what is
+    left (its own gate is not used). The loss is ``mean_i [sum_t p_t
+    l_t - beta H(p)]``, worked out in log space so that ``p log p`` is
+    finite wherever a gate saturates."""
+    with jax.named_scope(SCOPE_EXIT):
+        gate_logits = gate_logits.astype(jnp.float32)
+        stay = jax.nn.log_sigmoid(-gate_logits)         # log(1 - lambda)
+        reached = jnp.cumsum(stay, axis=0) - stay       # sum over j < t
+        log_p = jnp.concatenate(
+            [reached[:-1] + jax.nn.log_sigmoid(gate_logits[:-1]),
+             reached[-1:]], axis=0)
+        p = jnp.exp(log_p)
+        loss = jnp.mean(jnp.sum(p * (xent + beta * log_p), axis=0))
+        share = jnp.mean(p.reshape(p.shape[0], -1), axis=1)
+        return loss, jax.lax.stop_gradient(share)
+
+
+def publish_exit_shares(shares):
+    """Set ``hvd_loop_exit_share{step}`` and ``hvd_loop_passes`` from the
+    exit shares a train step returned (``looped_lm_loss``'s second
+    result). Call it outside the step; it fetches the array. A no-op
+    when ``HOROVOD_TPU_METRICS`` is off."""
+    from ..telemetry import core as telemetry
+    if not telemetry.enabled():
+        return
+    share = telemetry.gauge(
+        "hvd_loop_exit_share",
+        "Mean share of positions whose exit distribution leaves after "
+        "this pass of the looped stack, in the last step", ("step",))
+    shares = jax.device_get(shares)
+    for step, value in enumerate(shares, start=1):
+        share.labels(step=step).set(float(value))
+    telemetry.gauge(
+        "hvd_loop_passes",
+        "Times the looped stack runs in one forward pass").set(
+            float(len(shares)))
 
 
 class BertModel(nn.Module):

@@ -53,7 +53,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import ad_checkpoint, lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -728,6 +728,12 @@ def _bwd_chunk(q, do, lse3, delta3, dm, *, k, v, lens, qb0, sm_scale,
 # Differentiable public entry points
 # ---------------------------------------------------------------------------
 
+# What the forward kernel hands the backward one, as ``jax.checkpoint``
+# policies may name it (``save_only_these_names(*SAVED_NAMES)``): with
+# both kept, a recomputed forward pass does not run the kernel again.
+SAVED_NAMES = ("hvd_flash_o", "hvd_flash_lse")
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def _flash(q, k, v, lens, sm_scale, causal, block_q, block_k):
     o, _ = _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k)
@@ -735,7 +741,8 @@ def _flash(q, k, v, lens, sm_scale, causal, block_q, block_k):
 
 
 def _flash_fwd(q, k, v, lens, sm_scale, causal, block_q, block_k):
-    o, lse = _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k)
+    o, lse = map(ad_checkpoint.checkpoint_name, _fwd_call(
+        q, k, v, lens, sm_scale, causal, block_q, block_k), SAVED_NAMES)
     return o, (q, k, v, o, lse, lens)
 
 
