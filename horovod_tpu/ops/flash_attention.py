@@ -13,13 +13,13 @@ Design (MXU/VMEM-first):
 - Two sizes. A **block** (the callers' ``block_q``, ``block_k``; 1024 in
   the model zoo) is what one grid step's DMA brings: a step's fixed cost
   and the rescaling of the running statistics are paid once a block, so
-  the largest that VMEM holds is fastest. A **sub-tile** (``_sub_tile``:
-  512 on a side, 256 at a head of two lane tiles) is what the causal mask
-  is resolved at: the forward walks the block the diagonal crosses in
-  sub-blocks of query rows, each against the keys it can see, builds the
-  mask for the one sub-tile on the diagonal and runs nothing for those
-  beyond it. Blocks past a query block's last visible one are neither
-  computed nor fetched (their index maps name a block already held).
+  the largest that VMEM holds is fastest. A **sub-tile** (``_sub_tile``,
+  a side for each kernel) is what the causal mask is resolved at: both
+  kernels walk the block the diagonal crosses in strips of query rows,
+  each against the keys it can see, build the mask for the one sub-tile
+  on the diagonal and run nothing for those beyond it. Blocks past a
+  query block's last visible one are neither computed nor fetched (their
+  index maps name a block already held).
 - The forward is key-major, like the backward: s^T = k q^T is (keys,
   queries), so max and sum over keys add vregs to each other and the
   running statistics are rows along lanes. Query-major, every 8 rows of
@@ -196,12 +196,14 @@ def _seeded_keep_scale(lens_ref, qb, kb, block_q, block_k, dropout_rate):
         1.0 / (1.0 - dropout_rate))
 
 
-# Side of the forward's sub-tiles at a head of one lane tile.
+# Side of the forward's sub-tiles at a head of one lane tile, and of the
+# backward's at every head width.
 _SUB_TILE = 512
+_SUB_TILE_BWD = 128
 
 
-def _sub_tile(causal, block_q, block_k, d):
-    """Side of the square sub-tiles the forward walks a block on the
+def _sub_tile(causal, block_q, block_k, d, backward=False):
+    """Side of the square sub-tiles a kernel walks a block on the
     diagonal in (the block's own where it is smaller: one sub-tile, and
     still the diagonal's constant mask), or None where it walks none (no
     causal mask, blocks not square). The blocks are what a
@@ -210,8 +212,16 @@ def _sub_tile(causal, block_q, block_k, d):
     once a block); the sub-tile is what the mask is resolved at.
     Measured on ``_fwd_call`` alone (PERF.md section 6, PR 29): 512 at a
     head of one lane tile, 256 at two, where the products are long
-    enough to pay for four sub-blocks of rows; never smaller."""
-    sub = min(block_q, _SUB_TILE // min(2, _lane_tiles(d)))
+    enough to pay for four sub-blocks of rows; never smaller. The
+    backward has five products a tile where the forward has two and no
+    running statistics to rescale a strip: measured on ``_bwd_call``
+    alone (PERF.md section 6, PR 31) the finest side the lanes allow is
+    fastest at every head width, or level."""
+    if backward:
+        side = _SUB_TILE_BWD
+    else:
+        side = _SUB_TILE // min(2, _lane_tiles(d))
+    sub = min(block_q, side)
     if not causal or block_q != block_k or block_q % sub:
         return None
     return sub
@@ -234,6 +244,25 @@ def _strip_extent(i, n_j, sub):
     seen = sum(not _block_skip(*a) for a in at)
     whole = sum(bool(_tile_interior(*a)) for a in at)
     return seen * sub, whole * sub
+
+
+def _tail_mask(tail, row0, key0, on_diagonal, causal, q_pos, k_start, k_pos,
+               kv_len):
+    """Which elements of a key-major corner ``tail`` = (keys, queries)
+    of a tile are visible: its keys from ``key0`` on by its query rows
+    from ``row0`` on. The tile's first row stands at ``q_pos`` of the
+    sequence; its first key at ``k_pos`` of the key chunk (what
+    ``kv_len`` counts), which starts at ``k_start``."""
+    c = key0 + jax.lax.broadcasted_iota(jnp.int32, tail, 0)
+    r = row0 + jax.lax.broadcasted_iota(jnp.int32, tail, 1)
+    if on_diagonal:
+        # The tile's corner is on the diagonal and its keys are all
+        # valid: the mask is the same in every such tile.
+        return r >= c
+    mask = k_pos + c < kv_len                         # key padding
+    if causal:
+        mask = jnp.logical_and(mask, q_pos + r >= k_start + k_pos + c)
+    return mask
 
 
 def _last_key_block(i, lens, n_k, block_q, block_k, causal):
@@ -311,19 +340,10 @@ def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, *rest, sm_scale, causal,
             st = st * sm_scale
         mask = None
         if mask_from is not None:
-            tail = (width - mask_from, n_rows)
-            c = mask_from + jax.lax.broadcasted_iota(jnp.int32, tail, 0)
-            r = row0 + jax.lax.broadcasted_iota(jnp.int32, tail, 1)
-            if on_diagonal:
-                # The tile's corner is on the diagonal and its keys are
-                # all valid: the mask is the same in every such tile.
-                mask = r >= c
-            else:
-                mask = kb * block_k + c < kv_len      # key padding
-                if causal:
-                    mask = jnp.logical_and(
-                        mask, (q_start + qb * block_q + r)
-                        >= (k_start + kb * block_k + c))
+            mask = _tail_mask(
+                (width - mask_from, n_rows), row0, mask_from, on_diagonal,
+                causal, q_start + qb * block_q, k_start, kb * block_k,
+                kv_len)
 
         def masked(x, fill):
             if mask is None:
@@ -480,8 +500,23 @@ def _fwd_jit(q, k, v, lens, dm, sm_scale, causal, block_q, block_k, sub,
 # Backward kernel
 # ---------------------------------------------------------------------------
 
+def _q_block(j, i, lens, n_q, block_q, block_k, causal, qb0):
+    """The query block grid step (j, i) of the backward holds, of a call
+    whose tiles are ``qb0`` onward of the sequence. Under a causal mask
+    the steps before a key block's first visible query block are skipped
+    (_block_skip): they name that first block, so they fetch nothing and
+    the block is there when its step comes."""
+    if not causal or n_q == 1:
+        return i
+    # Truncating division: where it differs from the floor the first
+    # block is negative and ``i`` wins either way.
+    first = lax.div(lens[1] + j * block_k - lens[0],
+                    jnp.int32(block_q)) - qb0
+    return lax.max(i, lax.min(first, jnp.int32(n_q - 1)))
+
+
 def _bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                *rest, sm_scale, causal, block_q, block_k, qb0,
+                *rest, sm_scale, causal, block_q, block_k, qb0, sub,
                 dropout_rate=0.0, seeded=False):
     # rest = [dm_ref?], dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr
     if dropout_rate > 0.0 and not seeded:
@@ -498,7 +533,13 @@ def _bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     q_start = lens_ref[0]
     k_start = lens_ref[1]
     kv_len = lens_ref[2]
-    rows = pl.ds(pl.multiple_of(qb * block_q, block_q), block_q)
+    # A power of two: scaling q for s and dk, and dq once as it is
+    # written, is exactly the multiply of s and ds (it commutes with
+    # every rounding), without the two passes over a float32 tile.
+    fold = math.frexp(sm_scale)[0] == 0.5
+
+    def dq_rows(row0, n_rows):
+        return pl.ds(pl.multiple_of(qb * block_q + row0, n_rows), n_rows)
 
     @pl.when(qb == 0)
     def _():
@@ -507,81 +548,115 @@ def _bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(kb == 0)
     def _():
-        dq_scr[rows, :] = jnp.zeros((block_q, dq_scr.shape[1]), jnp.float32)
+        dq_scr[dq_rows(0, block_q), :] = jnp.zeros(
+            (block_q, dq_scr.shape[1]), jnp.float32)
 
-    skip = _block_skip(causal, q_start, k_start, kv_len, qg, kb,
-                       block_q, block_k)
-    interior = _tile_interior(causal, q_start, k_start, kv_len, qg, kb,
-                              block_q, block_k)
+    mask_of = (causal, q_start, k_start, kv_len)
+    skip = _block_skip(*mask_of, qg, kb, block_q, block_k)
+    interior = _tile_interior(*mask_of, qg, kb, block_q, block_k)
 
-    def tile_update(masked):
-        # Key-major: every (block_k, block_q) tile below is the transpose
-        # of the forward's. p^T and ds^T then enter dv and dk as plain
-        # left operands and lse, delta broadcast along sublanes as they
-        # are stored; only dq contracts over the left operand's rows.
-        q = q_ref[0]                  # (block_q, d)
-        k = k_ref[0]                  # (block_k, d)
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0]              # (1, block_q)
-        delta = delta_ref[0]
+    def tile_update(keep, row0, n_rows, width, mask_from,
+                    on_diagonal=False):
+        """Add to dq, dk and dv what the tile's query rows ``row0`` to
+        ``row0 + n_rows`` and its first ``width`` keys give. The keys
+        from ``mask_from`` on go through the mask; the ones before are
+        known to be visible. Key-major: every (keys, queries) tile below
+        is the transpose of the forward's. p^T and ds^T then enter dv
+        and dk as plain left operands and lse, delta broadcast along
+        sublanes as they are stored; only dq contracts over the left
+        operand's rows."""
+        rows = slice(row0, row0 + n_rows)
+        keys = slice(0, width)
+        q = q_ref[0, rows, :]             # (n_rows, d)
+        do = do_ref[0, rows, :]
+        k = k_ref[0, keys, :]             # (width, d)
+        v = v_ref[0, keys, :]
+        lse = lse_ref[0, :, rows]         # (1, n_rows)
+        delta = delta_ref[0, :, rows]
+        if fold:
+            q = (q.astype(jnp.float32) * sm_scale).astype(q.dtype)
 
         st = jax.lax.dot_general(
             k, q, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # (bk, bq)
-        if masked:
-            cols = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 0)
-            pos = qg * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 1)
-            mask = cols < kv_len
-            if causal:
-                mask = jnp.logical_and(
-                    mask, (q_start + pos) >= (k_start + cols))
-            pt = jnp.where(mask, jnp.exp(st - lse), 0.0)
-        else:
-            # Interior tile: no element masked (see _tile_interior).
-            pt = jnp.exp(st - lse)                # (bk, bq) fp32
+            preferred_element_type=jnp.float32)      # (width, n_rows)
+        if not fold:
+            st = st * sm_scale
+        pt = jnp.exp(st - lse)                       # (width, n_rows) fp32
+        if mask_from is not None:
+            mask = _tail_mask(
+                (width - mask_from, n_rows), row0, mask_from, on_diagonal,
+                causal, q_start + qg * block_q, k_start, kb * block_k,
+                kv_len)
+            pt_tail = jnp.where(mask, pt[mask_from:, :], 0.0)
+            pt = pt_tail if mask_from == 0 else jnp.concatenate(
+                [pt[:mask_from, :], pt_tail], axis=0)
 
         # Dropout backward: o = (P∘M̃)V with M̃ = mask/(1-rate), so
         # dV = (P∘M̃)ᵀdO and dP = (dO Vᵀ)∘M̃; the delta trick survives
         # because Σₖ Pᵢₖ dPᵢₖ = rowsum(dO∘O) = delta exactly as without
         # dropout (O already carries M̃).
-        keep = None
-        if dropout_rate > 0.0 and seeded:
-            keep = _seeded_keep_scale(lens_ref, qg, kb, block_q,
-                                      block_k, dropout_rate).T
+        if keep is not None:
+            keep = keep[rows, keys].T
         elif dm_ref is not None:
-            keep = _keep_scale(dm_ref[0], dropout_rate).T
+            keep = _keep_scale(dm_ref[0, rows, keys], dropout_rate).T
         pvt = pt if keep is None else pt * keep
         # MXU operands in the input dtype (bf16 in training; identity for
         # fp32 inputs), fp32 accumulation. fp32 operands would run the
         # matmuls at a fraction of MXU rate — the softmax weights and ds
         # are the canonical safe-to-round tensors of the flash backward.
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
+        dv_scr[keys, :] = dv_scr[keys, :] + jax.lax.dot_general(
             pvt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dpt = jax.lax.dot_general(
             v, do, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)              # (bk, bq)
+            preferred_element_type=jnp.float32)      # (width, n_rows)
         if keep is not None:
             dpt = dpt * keep
-        dst = (pt * (dpt - delta) * sm_scale).astype(q.dtype)
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
+        dst = pt * (dpt - delta)
+        if not fold:
+            dst = dst * sm_scale
+        dst = dst.astype(q.dtype)
+        dk_scr[keys, :] = dk_scr[keys, :] + jax.lax.dot_general(
             dst, q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dq_scr[rows, :] = dq_scr[rows, :] + jax.lax.dot_general(
+        at = dq_rows(row0, n_rows)
+        dq_scr[at, :] = dq_scr[at, :] + jax.lax.dot_general(
             dst, k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(jnp.logical_and(jnp.logical_not(skip), interior))
-    def _():
-        tile_update(False)
+    def draw():
+        if dropout_rate > 0.0 and seeded:
+            return _seeded_keep_scale(lens_ref, qg, kb, block_q, block_k,
+                                      dropout_rate)
+        return None
 
-    @pl.when(jnp.logical_and(jnp.logical_not(skip),
-                             jnp.logical_not(interior)))
+    visible = jnp.logical_not(skip)
+
+    @pl.when(jnp.logical_and(visible, interior))
     def _():
-        tile_update(True)
+        tile_update(draw(), 0, block_q, block_k, None)
+
+    partial = jnp.logical_and(visible, jnp.logical_not(interior))
+    if sub is not None:
+        # As the forward walks it: a strip of query rows sees the
+        # sub-tiles left of the diagonal whole, the one on it through
+        # the mask, and nothing of those right of it, whose p is zero.
+        on_diagonal = _on_diagonal(q_start, k_start, kv_len, qg, kb,
+                                   block_q)
+
+        @pl.when(jnp.logical_and(partial, on_diagonal))
+        def _():
+            keep = draw()
+            for i in range(block_q // sub):
+                seen, whole = _strip_extent(i, block_k // sub, sub)
+                tile_update(keep, i * sub, sub, seen, whole,
+                            on_diagonal=True)
+
+        partial = jnp.logical_and(partial, jnp.logical_not(on_diagonal))
+
+    @pl.when(partial)
+    def _():
+        tile_update(draw(), 0, block_q, block_k, 0)
 
     @pl.when(qb == n_q - 1)
     def _():
@@ -590,7 +665,10 @@ def _bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(kb == n_k - 1)
     def _():
-        dq_ref[0, rows, :] = dq_scr[rows, :].astype(dq_ref.dtype)
+        at = dq_rows(0, block_q)
+        dq = dq_scr[at, :]
+        dq_ref[0, at, :] = (dq * sm_scale if fold else dq).astype(
+            dq_ref.dtype)
 
 
 # Scoped VMEM of the backward kernel. Its tiles and their temporaries get
@@ -635,10 +713,13 @@ def _bwd_call(q, k, v, o, do, lse, lens, sm_scale, causal, block_q, block_k,
     n_q = sq // block_q
     per_chunk = max(1, _DQ_RESIDENT_BYTES
                     // _dq_resident_bytes(block_q, d, q.dtype))
+    # What the trace reads of the module's state is an argument, as in
+    # _fwd_call.
     chunk = functools.partial(
         _bwd_chunk, k=k, v=v, lens=lens, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k, dropout_rate=dropout_rate,
-        seeded=seeded)
+        block_q=block_q, block_k=block_k,
+        sub=_sub_tile(causal, block_q, block_k, d, backward=True),
+        dropout_rate=dropout_rate, seeded=seeded, interpret=_interpret())
     if n_q <= per_chunk:
         return chunk(q, do, lse3, delta3, dm, qb0=0)
     dqs, dk, dv = [], 0.0, 0.0
@@ -654,29 +735,24 @@ def _bwd_call(q, k, v, o, do, lse, lens, sm_scale, causal, block_q, block_k,
             dv.astype(v.dtype))
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "qb0", "sm_scale", "causal", "block_q", "block_k", "sub",
+    "dropout_rate", "seeded", "interpret", "kv_dtype"))
 def _bwd_chunk(q, do, lse3, delta3, dm, *, k, v, lens, qb0, sm_scale,
-               causal, block_q, block_k, dropout_rate, seeded,
-               kv_dtype=None):
+               causal, block_q, block_k, sub, dropout_rate, seeded,
+               interpret, kv_dtype=None):
     """The backward kernel over the query rows it is given: tiles ``qb0``
     onward of the sequence. dk and dv are this chunk's share, in
-    ``kv_dtype`` (k's and v's own unless the caller sums shares)."""
+    ``kv_dtype`` (k's and v's own unless the caller sums shares). The
+    layers of a model make the same call, so it goes through ``jax.jit``
+    like the forward's: traced and lowered once a program."""
     bh, sq, d = q.shape
     sk = k.shape[1]
     n_q = sq // block_q
     n_k = sk // block_k
 
     def qi(j, i, lens):
-        """The query block grid step (j, i) holds. Under a causal mask the
-        steps before a key block's first visible query block are skipped
-        (_block_skip): they name that first block, so they fetch nothing
-        and the block is there when its step comes."""
-        if not causal or n_q == 1:
-            return i
-        # Truncating division: where it differs from the floor the first
-        # block is negative and ``i`` wins either way.
-        first = lax.div(lens[1] + j * block_k - lens[0],
-                        jnp.int32(block_q)) - qb0
-        return lax.max(i, lax.min(first, jnp.int32(n_q - 1)))
+        return _q_block(j, i, lens, n_q, block_q, block_k, causal, qb0)
 
     q_tile = pl.BlockSpec((1, block_q, d),
                           lambda b, j, i, lens: (b, qi(j, i, lens), 0))
@@ -707,7 +783,8 @@ def _bwd_chunk(q, do, lse3, delta3, dm, *, k, v, lens, qb0, sm_scale,
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k, qb0=qb0,
-                          dropout_rate=dropout_rate, seeded=seeded),
+                          sub=sub, dropout_rate=dropout_rate,
+                          seeded=seeded),
         grid_spec=grid_spec,
         out_shape=[
             _struct((bh, sq, d), q.dtype, q, k, v, do, lens),
@@ -718,7 +795,7 @@ def _bwd_chunk(q, do, lse3, delta3, dm, *, k, v, lens, qb0, sm_scale,
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_TILE_VMEM_BYTES * _lane_tiles(d)
             + _dq_resident_bytes(sq, d, q.dtype)),
-        interpret=_interpret(),
+        interpret=interpret,
         name=KERNEL_BWD_DKDV,
     )(lens, *operands)
     return dq, dk, dv
@@ -839,20 +916,22 @@ def _clamp_blocks(sq, sk, block_q, block_k):
     brings, and a step's fixed cost and the rescaling of the running
     statistics are paid once a block, so the largest that VMEM holds is
     fastest (1024: PERF.md section 7). What the mask leaves of a block
-    is resolved finer, in the forward's sub-tiles (_sub_tile)."""
+    is resolved finer, in the kernels' sub-tiles (_sub_tile)."""
     return (min(block_q, max(8, 1 << (sq - 1).bit_length())),
             min(block_k, max(8, 1 << (sk - 1).bit_length())))
 
 
-def fwd_subtile_counts(sq, sk, block_q, block_k, causal, q_offset=0,
-                       k_offset=0, kv_len=None, head_dim=64):
-    """How the forward kernel visits one (batch, head)'s score matrix:
-    sub-tiles ``interior`` (no mask built), ``masked`` and ``skipped``
-    (no product, no exponential), and ``steps_without_fetch``, the grid
-    steps whose K and V blocks are the ones already held. A function of
-    shapes and offsets alone, by the functions the kernel itself
-    classes tiles and names blocks with. A tile that is not walked in
-    sub-tiles counts as one sub-tile."""
+def subtile_counts(kernel, sq, sk, block_q, block_k, causal, q_offset=0,
+                   k_offset=0, kv_len=None, head_dim=64):
+    """How ``kernel`` (``"fwd"`` or ``"bwd"``) visits one (batch, head)'s
+    score matrix: sub-tiles ``interior`` (no mask built), ``masked`` and
+    ``skipped`` (no product, no exponential), and ``steps_without_fetch``,
+    the grid steps whose blocks are the ones already held (K and V in the
+    forward; q and do in the backward, its query range taken as one
+    chunk). A function of shapes and offsets alone, by the functions the
+    kernels themselves class tiles and name blocks with. A tile that is
+    not walked in sub-tiles counts as one sub-tile."""
+    backward = kernel == "bwd"
     block_q, block_k = _clamp_blocks(sq, sk, block_q, block_k)
     n_q, n_k = -(-sq // block_q), -(-sk // block_k)
     kv_len = sk if kv_len is None else kv_len
@@ -864,7 +943,7 @@ def fwd_subtile_counts(sq, sk, block_q, block_k, causal, q_offset=0,
     # Sub-tiles a block: all, seen and seen whole by a walked block's
     # rows.
     per_block, seen, whole = 1, 1, 0
-    sub = _sub_tile(causal, block_q, block_k, head_dim)
+    sub = _sub_tile(causal, block_q, block_k, head_dim, backward)
     if sub is not None:
         n = block_q // sub
         extents = [_strip_extent(i, n, sub) for i in range(n)]
@@ -883,27 +962,39 @@ def fwd_subtile_counts(sq, sk, block_q, block_k, causal, q_offset=0,
     }
     with jax.ensure_compile_time_eval():
         lens = jnp.asarray([q_offset, k_offset, kv_len], jnp.int32)
-        held = np.asarray(_kv_block(
-            jnp.asarray(qb, jnp.int32), jnp.asarray(kb, jnp.int32), lens,
-            n_k, block_q, block_k, causal)).reshape(-1)
+        qb, kb = jnp.asarray(qb, jnp.int32), jnp.asarray(kb, jnp.int32)
+        if backward:        # key blocks outer, query blocks inner
+            held = np.asarray(_q_block(kb, qb, lens, n_q, block_q, block_k,
+                                       causal, 0)).T.reshape(-1)
+        else:
+            held = np.asarray(_kv_block(qb, kb, lens, n_k, block_q,
+                                        block_k, causal)).reshape(-1)
     counts["steps_without_fetch"] = int((held[1:] == held[:-1]).sum())
     return counts
 
 
+fwd_subtile_counts = functools.partial(subtile_counts, "fwd")
+bwd_subtile_counts = functools.partial(subtile_counts, "bwd")
+
+
 def _publish_subtiles(*call):
-    """Set ``hvd_flash_fwd_subtiles{kind}`` (docs/metrics.md) from
-    ``fwd_subtile_counts(*call)`` of the call being traced. A no-op when
+    """Set ``hvd_flash_fwd_subtiles{kind}`` and
+    ``hvd_flash_bwd_subtiles{kind}`` (docs/metrics.md) from
+    ``subtile_counts`` of the call being traced. A no-op when
     ``HOROVOD_TPU_METRICS`` is off."""
     from ..telemetry import core as telemetry
     if not telemetry.enabled():
         return
-    gauge = telemetry.gauge(
-        "hvd_flash_fwd_subtiles",
-        "Sub-tiles of one (batch, head) the flash forward kernel last "
-        "traced visits, by kind, and its grid steps that fetch no K/V",
-        ("kind",))
-    for kind, n in fwd_subtile_counts(*call).items():
-        gauge.labels(kind=kind).set(float(n))
+    for kernel, name, which, held in (
+            ("fwd", "hvd_flash_fwd_subtiles", "forward", "K/V"),
+            ("bwd", "hvd_flash_bwd_subtiles", "backward", "q/do")):
+        gauge = telemetry.gauge(
+            name,
+            f"Sub-tiles of one (batch, head) the flash {which} kernel of "
+            f"the call last traced visits, by kind, and its grid steps "
+            f"that fetch no {held}", ("kind",))
+        for kind, n in subtile_counts(kernel, *call).items():
+            gauge.labels(kind=kind).set(float(n))
 
 
 def _prepare(q, k, v, block_q, block_k):

@@ -431,6 +431,193 @@ def test_subtiled_dropout_mask_forward_and_gradient(sub_tile, causal):
     _assert_grads_match(q, k, v, dict(block_q=64, block_k=64), **kwargs)
 
 
+# ---------------------------------------------------------------------------
+# The backward's strips: the block on the diagonal walked as the forward
+# walks it, at a side of the backward's own
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def bwd_strips(monkeypatch):
+    """Force the backward's strip side so that a block holds ``strips``
+    strips, and see which side each backward kernel call was given."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    def set_to(block, strips):
+        monkeypatch.setattr(fa, "_SUB_TILE_BWD", block // strips)
+        given, chunk = [], fa._bwd_chunk
+        monkeypatch.setattr(fa, "_bwd_chunk", lambda *a, **kw: (
+            given.append((kw["qb0"], kw["sub"])), chunk(*a, **kw))[1])
+        return given
+    return set_to
+
+
+# (sq, sk, d, block, dtype, kwargs of both sides); offsets marked traced
+# go through jit as arguments (a ring step's call). Where the blocks are
+# square and the mask causal the kernel is given the forced side; the
+# diagonal's blocks then walk strips unless the case says what stops them.
+BWD_STRIP_CASES = {
+    "one_block": (64, 64, 32, 64, jnp.float32, dict(causal=True)),
+    "several_blocks_3x3": (192, 192, 32, 64, jnp.float32,
+                           dict(causal=True)),
+    "head_dim_64": (128, 128, 64, 64, jnp.float32, dict(causal=True)),
+    "head_dim_128": (128, 128, 128, 64, jnp.float32, dict(causal=True)),
+    "head_dim_256": (256, 256, 256, 128, jnp.float32, dict(causal=True)),
+    "padded_sequence_and_head": (100, 100, 48, 64, jnp.float32,
+                                 dict(causal=True)),
+    # The second block on the diagonal has padded keys: the general mask.
+    "kv_len_short_of_the_block": (128, 128, 32, 64, jnp.float32,
+                                  dict(causal=True, kv_len=100)),
+    "ring_on_the_diagonal_traced": (
+        128, 128, 32, 64, jnp.float32,
+        dict(causal=True, q_offset=256, k_offset=256)),
+    "ring_a_block_off_the_diagonal_traced": (
+        128, 128, 32, 64, jnp.float32,
+        dict(causal=True, q_offset=192, k_offset=128)),
+    "ring_off_every_boundary_traced": (
+        128, 128, 32, 64, jnp.float32,
+        dict(causal=True, q_offset=7, k_offset=3)),
+    "lse_cotangent": (128, 128, 32, 64, jnp.float32,
+                      dict(causal=True, with_lse=True)),
+    "lse_cotangent_shifted_a_block": (
+        128, 192, 32, 64, jnp.float32,
+        dict(causal=True, q_offset=64, with_lse=True)),
+    "bfloat16": (128, 128, 64, 64, jnp.bfloat16, dict(causal=True)),
+    "scale_not_a_power_of_two": (
+        128, 128, 32, 64, jnp.float32, dict(causal=True, sm_scale=0.3)),
+    "dropout_mask": (128, 128, 32, 64, jnp.float32,
+                     dict(causal=True, dropout_rate=0.25)),
+}
+
+
+@pytest.mark.parametrize("strips", [1, 2, 4])
+@pytest.mark.parametrize("case", sorted(BWD_STRIP_CASES))
+def test_backward_strips_match_reference(bwd_strips, case, strips):
+    sq, sk, d, block, dtype, kwargs = BWD_STRIP_CASES[case]
+    given = bwd_strips(block, strips)
+    kwargs = dict(kwargs)
+    q = _rand((1, 2, sq, d), 0, dtype)
+    k, v = _rand((1, 2, sk, d), 1, dtype), _rand((1, 2, sk, d), 2, dtype)
+    if "dropout_rate" in kwargs:
+        kwargs["dropout_mask"] = jax.random.bernoulli(
+            jax.random.PRNGKey(3), 1 - kwargs["dropout_rate"],
+            (1, 2, sq, sk))
+    traced = {name: jnp.int32(kwargs.pop(name))
+              for name in ("q_offset", "k_offset")
+              if case.endswith("_traced")}
+
+    @functools.partial(jax.jit, static_argnums=0)
+    def grads(attend, offsets):
+        return _grads(attend, q, k, v, **kwargs, **offsets)
+
+    g1 = grads(functools.partial(flash_attention, block_q=block,
+                                 block_k=block), traced)
+    g2 = grads(reference_attention, traced)
+    assert given == [(0, block // strips)]
+    tol = 6e-2 if dtype == jnp.bfloat16 else 5e-4
+    for a, b in zip(g1, g2):
+        assert a.shape == b.shape
+        assert np.isfinite(np.asarray(a, np.float32)).all()
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("strips", [1, 2, 4])
+@pytest.mark.parametrize("variant", ["plain", "dropout"])
+def test_backward_strips_in_query_chunks(monkeypatch, bwd_strips, variant,
+                                         strips):
+    """The chunked query range: the blocks on the diagonal of the second
+    and third chunk (``qb0`` 2 and 4) are found by the tile's index in
+    the whole sequence, and walk strips there too."""
+    from horovod_tpu.ops import flash_attention as fa
+    monkeypatch.setattr(
+        fa, "_DQ_RESIDENT_BYTES",
+        2 * fa._dq_resident_bytes(32, 32, jnp.float32))
+    given = bwd_strips(32, strips)
+    q, k, v = (_rand((1, 2, 160, 32), i) for i in range(3))
+    kwargs = dict(causal=True)
+    if variant == "dropout":
+        kwargs.update(dropout_rate=0.25, dropout_mask=jax.random.bernoulli(
+            jax.random.PRNGKey(3), 0.75, (1, 2, 160, 160)))
+    _assert_grads_match(q, k, v, dict(block_q=32, block_k=32), **kwargs)
+    assert given == [(qb0, 32 // strips) for qb0 in (0, 2, 4)]
+
+
+@pytest.mark.parametrize("strips", [1, 2, 4])
+def test_backward_strips_slice_the_seeded_layouts_pattern(monkeypatch,
+                                                          bwd_strips,
+                                                          strips):
+    """The on-chip dropout variant draws one (queries, keys) pattern a
+    tile in both kernels; the strips slice it as the forward does. The
+    prng has no CPU lowering, so a pattern of the tile's coordinates
+    stands in for the draw, and the oracle gets the same one whole."""
+    from horovod_tpu.ops import flash_attention as fa
+    rate, seq, block = 0.2, 128, 64
+
+    def pattern(rows, cols, seed):
+        return (rows * 7 + cols * 13 + seed) % 5 != 0
+
+    def draw(lens_ref, qb, kb, block_q, block_k, dropout_rate):
+        shape = (block_q, block_k)
+        keep = pattern(
+            qb * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0),
+            kb * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1),
+            lens_ref[3])
+        return keep.astype(jnp.float32) * (1.0 / (1.0 - dropout_rate))
+
+    monkeypatch.setattr(fa, "_seeded_keep_scale", draw)
+    given = bwd_strips(block, strips)
+    q, k, v = (_rand((1, 2, seq, 64), i) for i in range(3))
+    w = _rand((2, seq, 64), 4)
+    lens = jnp.asarray([0, 0, seq, 3], jnp.int32)
+
+    def seeded(q, k, v):
+        flat = (x.reshape(2, seq, 64) for x in (q, k, v))
+        return jnp.sum(w * fa._flash_seeded(*flat, lens, 0.125, True,
+                                            block, block, rate))
+
+    def oracle(q, k, v):
+        at = jnp.arange(seq)
+        mask = pattern(at[:, None], at[None, :], 3)[None, None]
+        return jnp.sum(w * reference_attention(
+            q, k, v, causal=True, dropout_mask=mask,
+            dropout_rate=rate)[0])
+
+    np.testing.assert_allclose(seeded(q, k, v), oracle(q, k, v), rtol=1e-5)
+    g1 = jax.grad(seeded, argnums=(0, 1, 2))(q, k, v)
+    g2 = jax.grad(oracle, argnums=(0, 1, 2))(q, k, v)
+    assert given == [(0, block // strips)]
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-4, rtol=5e-4)
+
+
+def test_lowered_gradient_holds_each_kernels_body_once(monkeypatch):
+    """The layers of a model make the same two kernel calls, and both go
+    through ``jax.jit``: the lowered gradient of four layers holds one
+    forward and one backward kernel body, each called four times."""
+    from horovod_tpu.models import TransformerConfig, TransformerLM
+    from horovod_tpu.ops import flash_attention as fa
+    # The kernel asks the default backend whether to interpret; here
+    # that is the CPU, and the lowering is for the TPU.
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    model = TransformerLM(TransformerConfig(
+        vocab_size=64, hidden=64, layers=4, heads=2, max_len=256,
+        attention_impl="flash"))
+    tokens = jnp.zeros((1, 256), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+
+    def loss(params, tokens):
+        return model.apply(params, tokens).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss)).trace(params, tokens).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("stablehlo.custom_call @tpu_custom_call") == 2
+    for wrapper in ("_fwd_jit", "_bwd_chunk"):
+        assert text.count(f"func.func private @{wrapper}(") == 1
+        assert text.count(f"call @{wrapper}(") == 4
+
+
 # (n_q, n_k, block_q, block_k, q_offset, k_offset, kv_len)
 KV_MAP_CASES = {
     "square_4x4": (4, 4, 64, 64, 0, 0, 256),
@@ -485,16 +672,17 @@ def test_forward_kv_index_map_fetches_once_on_skipped_steps(case):
     assert fa._last_key_block(3, lens, 1, block_q, block_k, True) is None
 
 
-# The five cells that run the kernel: (seq, head_dim) at 1024 blocks,
-# and per (batch, head) the sub-tiles by kind and the steps that fetch
-# nothing (the skipped steps, and the second row's first, which finds
-# block 0 held since the first row's skipped steps).
+# The six cells that run the kernel: (seq, head_dim) at 1024 blocks,
+# and per (batch, head) the forward's sub-tiles by kind and the steps
+# that fetch nothing (the skipped steps, and the second row's first,
+# which finds block 0 held since the first row's skipped steps).
 CELL_COUNTS = {
     "lm365m-seq8192-1chip": (8192, 64, 120, 16, 120, 29),
     "lm365m-seq2048-1chip": (2048, 64, 6, 4, 6, 2),
     "lm365m-seq2048-4chip": (2048, 64, 6, 4, 6, 2),
     "lm365m-seq512-1chip": (512, 64, 0, 1, 0, 0),
     "glm47flash-seq4096-1chip": (4096, 256, 120, 16, 120, 7),
+    "ouro26b-seq4096-1chip": (4096, 128, 28, 8, 28, 7),
 }
 
 
@@ -514,40 +702,92 @@ def test_fwd_subtile_counts_at_the_cells_shapes(cell):
                                            n * (n - 1) // 2)
 
 
-def test_fwd_subtile_counts_follow_offsets_and_kv_len():
+# The backward at its own side, 128 at every head width: sub-tiles by
+# kind. The steps that fetch no q/do block are the blocks' and not the
+# side's: as many as the forward's that fetch no K/V.
+BWD_CELL_SUBTILES = {8192: (2016, 64, 2016), 4096: (496, 32, 496),
+                     2048: (120, 16, 120), 512: (6, 4, 6)}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_COUNTS))
+def test_bwd_subtile_counts_at_the_cells_shapes(cell, monkeypatch):
     from horovod_tpu.ops import flash_attention as fa
+    seq, d, *forwards, unfetched = CELL_COUNTS[cell]
+    kinds = ("interior", "masked", "skipped", "steps_without_fetch")
+    assert fa.bwd_subtile_counts(
+        seq, seq, 1024, 1024, True, head_dim=d) == dict(zip(
+            kinds, (*BWD_CELL_SUBTILES[seq], unfetched)))
+    # At the forward's side it counts what the forward counts (seq 2048
+    # at 512: interior 6, masked 4, skipped 6 a head).
+    block = min(seq, 1024)
+    monkeypatch.setattr(fa, "_SUB_TILE_BWD",
+                        fa._sub_tile(True, block, block, d))
+    assert fa.bwd_subtile_counts(
+        seq, seq, 1024, 1024, True, head_dim=d) == dict(zip(
+            kinds, (*forwards, unfetched)))
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+def test_subtile_counts_follow_offsets_and_kv_len(kernel, monkeypatch):
+    from horovod_tpu.ops import flash_attention as fa
+    monkeypatch.setattr(fa, "_SUB_TILE_BWD", fa._SUB_TILE)
+    counts = functools.partial(fa.subtile_counts, kernel)
     # Off the sub-tiles' corners nothing is walked in sub-tiles: the
     # three tiles the diagonal touches go through the mask whole.
-    assert fa.fwd_subtile_counts(2048, 2048, 1024, 1024, True, q_offset=7,
-                                 k_offset=3) == {
+    assert counts(2048, 2048, 1024, 1024, True, q_offset=7, k_offset=3) == {
         "interior": 4, "masked": 12, "skipped": 0, "steps_without_fetch": 0}
     # No mask: tiles are not walked in sub-tiles, and count as one each.
-    assert fa.fwd_subtile_counts(2048, 2048, 1024, 1024, False) == {
+    assert counts(2048, 2048, 1024, 1024, False) == {
         "interior": 4, "masked": 0, "skipped": 0, "steps_without_fetch": 0}
     # Key padding: the last key block is cut, the one before is whole.
-    assert fa.fwd_subtile_counts(256, 384, 128, 128, False, kv_len=200) == {
+    assert counts(256, 384, 128, 128, False, kv_len=200) == {
         "interior": 2, "masked": 2, "skipped": 2, "steps_without_fetch": 0}
 
 
-def test_subtile_gauge_is_set_when_metrics_are_on(monkeypatch):
+def test_bwd_subtile_counts_name_the_blocks_the_index_map_names():
+    """Two key blocks by two query blocks on the diagonal: the second
+    key block's first step is skipped and names the query block that is
+    held since the first key block's last step and that its second step
+    needs, so neither fetches. With ``kv_len`` short of the second key
+    block, the block on the diagonal there goes through the general
+    mask whole."""
+    from horovod_tpu.ops import flash_attention as fa
+    at = dict(q_offset=128, k_offset=128)
+    assert fa.bwd_subtile_counts(256, 256, 128, 128, True, **at)[
+        "steps_without_fetch"] == 2
+    cut = fa.bwd_subtile_counts(256, 256, 128, 128, True, kv_len=250, **at)
+    n = 128 // fa._sub_tile(True, 128, 128, 64, backward=True)
+    assert cut["masked"] == n * n + n     # block (1, 1) whole, (0, 0) walked
+    assert cut["skipped"] == n * n + n * (n - 1) // 2
+
+
+def test_subtile_gauges_are_set_when_metrics_are_on(monkeypatch):
     from horovod_tpu import telemetry
+    from horovod_tpu.ops import flash_attention as fa
     monkeypatch.setenv("HOROVOD_TPU_METRICS", "1")
+    monkeypatch.setattr(fa, "_SUB_TILE_BWD", 64)
     telemetry.reset()
     try:
         q, k, v = (_rand((1, 1, 256, 32), i) for i in range(3))
         jax.jit(functools.partial(flash_attention, causal=True,
                                   block_q=128, block_k=128))(q, k, v)
-        family = telemetry.registry().families()["hvd_flash_fwd_subtiles"]
-        values = {s["labels"]["kind"]: s["value"]
-                  for s in family.samples()}
-        assert values == {"interior": 1.0, "masked": 2.0, "skipped": 1.0,
-                          "steps_without_fetch": 2.0}
+
+        def values(name):
+            return {s["labels"]["kind"]: s["value"] for s in
+                    telemetry.registry().families()[name].samples()}
+        assert values("hvd_flash_fwd_subtiles") == {
+            "interior": 1.0, "masked": 2.0, "skipped": 1.0,
+            "steps_without_fetch": 2.0}
+        # The backward at a side of 64: 4 x 4 sub-tiles a block.
+        assert values("hvd_flash_bwd_subtiles") == {
+            "interior": 6.0, "masked": 4.0, "skipped": 6.0,
+            "steps_without_fetch": 2.0}
         # Traced offsets: the schedule is not known here, nothing is set.
         telemetry.reset()
         jax.jit(lambda o: flash_attention(q, k, v, causal=True,
                                           q_offset=o))(jnp.int32(0))
-        assert "hvd_flash_fwd_subtiles" not in \
-            telemetry.registry().families()
+        assert not {"hvd_flash_fwd_subtiles", "hvd_flash_bwd_subtiles"} \
+            & set(telemetry.registry().families())
     finally:
         monkeypatch.delenv("HOROVOD_TPU_METRICS", raising=False)
         telemetry.reset()

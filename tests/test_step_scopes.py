@@ -161,13 +161,17 @@ def test_scope_names_are_the_documented_constants():
     assert not hasattr(fa, "KERNEL_BWD_DQ")
 
 
-def _pallas_calls(jaxpr, out):
+def _pallas_calls(jaxpr, out, outer=""):
+    """(kernel name, name stack) of every pallas_call; an equation
+    inside a ``jit`` (both kernels' calls are) carries its own part of
+    the stack, after the enclosing equation's."""
     for eqn in jaxpr.eqns:
+        stack = "/".join(filter(None, (outer,
+                                       str(eqn.source_info.name_stack))))
         if eqn.primitive.name == "pallas_call":
-            out.append((eqn.params["name"],
-                        str(eqn.source_info.name_stack)))
+            out.append((eqn.params["name"], stack))
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            _pallas_calls(sub, out)
+            _pallas_calls(sub, out, stack)
     return out
 
 
