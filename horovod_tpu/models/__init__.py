@@ -13,3 +13,4 @@ from .transformer import (  # noqa: F401
     TransformerLM, TransformerConfig, BertConfig, BertModel,
     looped_lm_loss, publish_exit_shares,
 )
+from .ssm import SSMConfig  # noqa: F401

@@ -1,0 +1,108 @@
+"""State-space mixers of a hybrid decoder: the Mamba layer and the gated
+memory unit that reads a Mamba layer's scan output further up the stack
+(SambaY, arXiv:2507.06607). ``transformer.Block`` chooses them per layer
+(``TransformerConfig.mixers``), as it chooses the expert layer of
+``parallel/moe.py``; the recurrence itself is the kernel pair of
+``ops/selective_scan.py``.
+
+Mamba-1 (Gu & Dao, arXiv:2312.00752), on a normed input ``h``:
+
+    [x, z] = W_in h                      hidden -> 2 x d_inner, no bias
+    x = silu(conv1d_causal(x) + b_c)     depthwise, d_conv taps
+    [r, B_t, C_t] = W_x x                d_inner -> dt_rank + 2 N, no bias
+    dt = softplus(W_dt r + b_dt)
+    s_t = exp(dt_t A) s_{t-1} + (dt_t x_t) (x) B_t,   A = -exp(A_log)
+    y_t = s_t . C_t + D x_t
+    out = W_out (y silu(z))              d_inner -> hidden, no bias
+
+``dt``, ``A``, the state and ``y`` are float32; the products take
+``cfg.dtype`` operands and accumulate in float32. The layer also returns
+``y`` (with the ``D`` skip, before the gate): the memory that gated
+memory units read.
+"""
+
+import dataclasses
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ..ops import selective_scan as scan_ops
+
+# Names in a device trace (docs/tracing.md); readers match the literals.
+SCOPE_SSM = scan_ops.SCOPE      # "hvd_ssm": the Mamba mixer
+SCOPE_GMU = "hvd_gmu"           # the gated memory unit
+
+
+@dataclasses.dataclass(frozen=True)
+class SSMConfig:
+    """The sizes of a Mamba layer: ``d_inner`` channels, each with a
+    state of ``d_state`` numbers, a depthwise convolution of ``d_conv``
+    taps before the scan, the step size made through ``dt_rank``."""
+    d_inner: int
+    dt_rank: int
+    d_state: int = 16
+    d_conv: int = 4
+
+
+def causal_conv(x, kernel, bias):
+    """Depthwise causal convolution over positions: ``x`` ``[batch, seq,
+    channels]``, ``kernel`` ``[taps, channels]``; tap ``taps - 1`` meets
+    the position itself. Shifted multiply-adds: four passes that XLA
+    fuses into one."""
+    taps = kernel.shape[0]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    seq = x.shape[1]
+    return bias + sum(padded[:, i:i + seq] * kernel[i] for i in range(taps))
+
+
+class MambaMixer(nn.Module):
+    """``(out, y)``: the layer's output and its memory."""
+    cfg: object                 # TransformerConfig (cfg.ssm set)
+
+    @nn.compact
+    def __call__(self, h):
+        cfg, m = self.cfg, self.cfg.ssm
+        n = m.d_state
+        dense = dict(use_bias=False, dtype=cfg.dtype)
+        with jax.named_scope(SCOPE_SSM):
+            xz = nn.Dense(2 * m.d_inner, name="in_proj", **dense)(h)
+            x, z = xz[..., :m.d_inner], xz[..., m.d_inner:]
+            conv_w = self.param("conv_kernel", nn.initializers.lecun_normal(),
+                                (m.d_conv, m.d_inner))
+            conv_b = self.param("conv_bias", nn.initializers.zeros,
+                                (m.d_inner,))
+            x = nn.silu(causal_conv(x, conv_w.astype(cfg.dtype),
+                                    conv_b.astype(cfg.dtype)))
+            rbc = nn.Dense(m.dt_rank + 2 * n, name="x_proj", **dense)(x)
+            dt = nn.Dense(m.d_inner, name="dt_proj", dtype=cfg.dtype,
+                          param_dtype=jnp.float32)(rbc[..., :m.dt_rank])
+            dt = jax.nn.softplus(dt.astype(jnp.float32))
+            a_log = self.param("A_log", nn.initializers.zeros,
+                               (m.d_inner, n))
+            skip = self.param("D", nn.initializers.ones, (m.d_inner,))
+            xf = x.astype(jnp.float32)
+            y = scan_ops.selective_scan(
+                xf, dt, -jnp.exp(a_log.astype(jnp.float32)),
+                rbc[..., m.dt_rank:m.dt_rank + n],
+                rbc[..., m.dt_rank + n:]) + skip * xf
+            gated = (y * nn.silu(z.astype(jnp.float32))).astype(cfg.dtype)
+            out = nn.Dense(cfg.hidden, name="out_proj", **dense)(gated)
+            return out, y.astype(cfg.dtype)
+
+
+class GatedMemoryUnit(nn.Module):
+    """``W_2 (memory * silu(W_1 h))``: the layer's own input gates a
+    Mamba layer's scan output, element by element, in place of a token
+    mixer of its own (arXiv:2507.06607, section 2)."""
+    cfg: object
+
+    @nn.compact
+    def __call__(self, h, memory):
+        cfg = self.cfg
+        dense = dict(use_bias=False, dtype=cfg.dtype)
+        with jax.named_scope(SCOPE_GMU):
+            gate = nn.silu(nn.Dense(memory.shape[-1], name="in_proj",
+                                    **dense)(h))
+            return nn.Dense(cfg.hidden, name="out_proj", **dense)(
+                memory.astype(cfg.dtype) * gate)

@@ -15,7 +15,11 @@ bias-free SwiGLU, or per layer the expert layer of ``parallel/moe.py``),
 a multi-token-prediction module (``mtp_layers``), and a looped stack:
 the blocks run ``passes`` times with the same weights, with norms on the
 sub-layers' outputs too (``sandwich_norm``) and an exit gate whose loss
-is ``looped_lm_loss``. Hidden sizes are multiples of 128 for MXU tiling; the head
+is ``looped_lm_loss``; or a stack with a mixer a layer (``mixers``: Mamba and
+gated memory units of ``models/ssm.py``, windowed, full and cross
+differential attention with grouped K/V heads), in which a layer may
+read what an earlier layer made, and a head tied to the embedding.
+Hidden sizes are multiples of 128 for MXU tiling; the head
 dimension is ``hidden // heads``, 64 at BERT-large's widths (half of the
 128 lanes, which the kernel and XLA's layouts pay for), and has to be
 even for rope. Sequence/tensor sharding is applied externally via
@@ -34,9 +38,11 @@ import numpy as np
 import optax
 
 from ..parallel.moe import MoEConfig, MoELayer
+from .ssm import GatedMemoryUnit, MambaMixer, SSMConfig
 
 # Names in a device trace (docs/tracing.md); readers match the literals.
 SCOPE_MLA = "hvd_mla"
+SCOPE_DIFF = "hvd_diff"     # what differential attention adds to the kernels
 SCOPE_MTP = "hvd_mtp"
 SCOPE_LOOP = "hvd_loop"     # the stack of a looped model, all its passes
 SCOPE_EXIT = "hvd_exit"     # its exit gates, heads and the loss's mix
@@ -65,9 +71,11 @@ class TransformerConfig:
     dtype: jnp.dtype = jnp.bfloat16
     # What a block keeps for its backward pass. False: everything.
     # "dots": the matrix products' outputs (element-wise work and the
-    # flash kernel are made again). "flash": the block's input and the
-    # flash kernel's output and log-sum-exp (everything but the kernel
-    # is made again). True/"full": the block's input alone.
+    # flash kernel are made again). "flash": the block's input and what
+    # the Mosaic kernels hand their backward passes: the flash kernel's
+    # output and log-sum-exp, the selective scan's output and chunk
+    # states (everything but the kernels is made again). True/"full":
+    # the block's input alone.
     remat: object = False
     causal: bool = True
     use_rope: bool = True          # decoder LM; BERT uses learned positions
@@ -95,6 +103,27 @@ class TransformerConfig:
     # A ``hidden -> 1`` product with bias on each pass's normed state:
     # the logit of leaving after that pass (``looped_lm_loss``).
     exit_gate: bool = False
+    # A stack whose layers differ in their token mixer (SambaY,
+    # arXiv:2507.06607): one kind a layer, of MIXERS. None: every layer
+    # is plain attention. A layer may read what an earlier layer made: a
+    # "gmu" layer the scan output of the last "mamba" layer before it, a
+    # "cross" layer the K and V of the last "attention" layer before it.
+    # The attention kinds of such a stack are differential attention
+    # (Ye et al., arXiv:2410.05258; ``DiffAttention``).
+    mixers: Optional[tuple] = None
+    # The layers' indices in the published model where the stack is a
+    # cut of it (differential attention's lambda_init depends on depth).
+    layer_indices: Optional[tuple] = None
+    window: Optional[int] = None     # of the "window" layers: keys seen
+    kv_heads: Optional[int] = None   # K/V heads (None: as many as heads)
+    ssm: Optional[SSMConfig] = None  # the "mamba" layers' sizes
+    mlp_bias: Optional[bool] = None  # None: as ``bias``
+    positions: bool = True           # False: neither rope nor a table
+    tie_embeddings: bool = False     # the head is the embedding's transpose
+
+
+# "window": attention that sees ``cfg.window`` keys and hands nothing on.
+MIXERS = ("attention", "window", "mamba", "gmu", "cross")
 
 
 # BERT-large hyperparameters (the reference benchmark target).
@@ -159,12 +188,17 @@ def _head(cfg):
                     name="lm_head")
 
 
-def _attend(cfg, q, k, v, mask=None):
+def _attend(cfg, q, k, v, mask=None, window=None):
     """Softmax attention of ``[batch, seq, heads, dim]`` q, k, v by the
-    configuration's implementation; the scale is that of q's width."""
+    configuration's implementation; the scale is that of q's width.
+    ``k`` and ``v`` may hold fewer heads than ``q`` (query head ``h``
+    reads head ``h // group``) and ``v`` another width; with ``window``
+    a query sees that many keys, itself the last."""
     if cfg.attention_impl == "flash":
-        # Pallas kernel path (ops/flash_attention.py): BHSD layout,
-        # causal handled in-kernel. Per-sample padding masks need the
+        # Pallas kernel path (ops/flash_attention.py): BHSD layout, the
+        # causal mask and the window handled in-kernel (tiles they hide
+        # are neither run nor fetched), grouped K/V heads read through
+        # the kernels' index maps. Per-sample padding masks need the
         # einsum path (the kernel's kv_len is per-call, not per-row).
         if mask is not None:
             raise ValueError(
@@ -181,17 +215,39 @@ def _attend(cfg, q, k, v, mask=None):
         return flash_attention(
             q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),
             causal=cfg.causal, block_q=1024,
-            block_k=1024).swapaxes(1, 2)
+            block_k=1024, window=window).swapaxes(1, 2)
+    if k.shape[2] != q.shape[2]:
+        k, v = (jnp.repeat(x, q.shape[2] // x.shape[2], axis=2)
+                for x in (k, v))
     scale = 1.0 / np.sqrt(q.shape[-1])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     seq = q.shape[1]
     if cfg.causal:
         causal = jnp.tril(jnp.ones((seq, seq), dtype=bool))
+        if window is not None:
+            causal = jnp.logical_and(causal, jnp.triu(
+                jnp.ones((seq, seq), dtype=bool), 1 - window))
         logits = jnp.where(causal[None, None], logits, -1e30)
     if mask is not None:
         logits = jnp.where(mask[:, None, None, :], logits, -1e30)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(cfg.dtype), v)
+
+
+def _qkv(cfg, x, name="qkv"):
+    """q, k, v of ``x`` from one product: ``[.., 3, heads, head_dim]``
+    features, or with fewer K/V heads ``[.., heads + 2 kv_heads,
+    head_dim]`` (q's heads, then k's, then v's)."""
+    head_dim = cfg.hidden // cfg.heads
+    kv = cfg.kv_heads or cfg.heads
+    if kv == cfg.heads:
+        qkv = nn.DenseGeneral((3, cfg.heads, head_dim), dtype=cfg.dtype,
+                              use_bias=cfg.bias, name=name)(x)
+        return qkv[..., 0, :, :], qkv[..., 1, :, :], qkv[..., 2, :, :]
+    qkv = nn.DenseGeneral((cfg.heads + 2 * kv, head_dim), dtype=cfg.dtype,
+                          use_bias=cfg.bias, name=name)(x)
+    return (qkv[..., :cfg.heads, :], qkv[..., cfg.heads:cfg.heads + kv, :],
+            qkv[..., cfg.heads + kv:, :])
 
 
 class Attention(nn.Module):
@@ -200,16 +256,67 @@ class Attention(nn.Module):
     @nn.compact
     def __call__(self, x, mask=None):
         cfg = self.cfg
-        head_dim = cfg.hidden // cfg.heads
-        qkv = nn.DenseGeneral((3, cfg.heads, head_dim), dtype=cfg.dtype,
-                              use_bias=cfg.bias, name="qkv")(x)
-        q, k, v = qkv[..., 0, :, :], qkv[..., 1, :, :], qkv[..., 2, :, :]
+        q, k, v = _qkv(cfg, x)
         # (batch, seq, heads, head_dim) -> attention in einsum form.
         if cfg.use_rope:
             q, k = _rope(q, k, cfg.rope_theta)
         out = _attend(cfg, q, k, v, mask)
         return nn.DenseGeneral(cfg.hidden, axis=(-2, -1), dtype=cfg.dtype,
                                use_bias=cfg.bias, name="proj")(out)
+
+
+def diff_lambda_init(depth):
+    """Differential attention's ``lambda_init`` at layer ``depth`` of the
+    whole model, 0-based (arXiv:2410.05258, section 2.1)."""
+    return 0.8 - 0.6 * float(np.exp(-0.3 * depth))
+
+
+class DiffAttention(nn.Module):
+    """Differential attention with grouped K/V heads (arXiv:2410.05258 as
+    arXiv:2507.06607 runs it). Adjacent heads pair: query pair ``p`` is
+    heads ``(2p, 2p + 1)``, K/V pair ``g = p // group`` the K heads
+    ``(2g, 2g + 1)`` and their two V heads side by side, twice a head
+    wide. ``a1 = softmax(q1 k1^T) v``, ``a2 = softmax(q2 k2^T) v``, and
+    the pair's output is ``RMSNorm(a1 - lambda a2) (1 - lambda_init)``.
+
+    Returns ``(out, (k, v))``. With ``shared`` (an earlier layer's ``(k,
+    v)``) the layer projects q alone: cross-attention over one K/V that
+    several layers read."""
+    cfg: TransformerConfig
+    depth: int = 0
+    window: Optional[int] = None
+    cross: bool = False
+
+    @nn.compact
+    def __call__(self, x, mask=None, shared=None):
+        cfg = self.cfg
+        head_dim = cfg.hidden // cfg.heads
+        if self.cross:
+            q = nn.DenseGeneral((cfg.heads, head_dim), dtype=cfg.dtype,
+                                use_bias=cfg.bias, name="q")(x)
+            k, v = shared
+        else:
+            q, k, v = _qkv(cfg, x)
+        if cfg.use_rope:
+            q, k = _rope(q, k, cfg.rope_theta)
+        pairs = v.shape[:2] + (v.shape[2] // 2, 2 * head_dim)
+        maps = [_attend(cfg, q[..., i::2, :], k[..., i::2, :],
+                        v.reshape(pairs), mask, self.window)
+                for i in (0, 1)]
+        with jax.named_scope(SCOPE_DIFF):
+            vectors = [self.param(f"lambda_{name}",
+                                  nn.initializers.normal(0.1), (head_dim,))
+                       for name in ("q1", "k1", "q2", "k2")]
+            lambda_init = diff_lambda_init(self.depth)
+            lam = (jnp.exp(jnp.sum(vectors[0] * vectors[1]))
+                   - jnp.exp(jnp.sum(vectors[2] * vectors[3])) + lambda_init)
+            mixed = (maps[0].astype(jnp.float32)
+                     - lam * maps[1].astype(jnp.float32))
+            out = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype,
+                             name="subln")(mixed) * (1.0 - lambda_init)
+            out = out.astype(cfg.dtype).reshape(*x.shape[:2], cfg.hidden)
+        return nn.Dense(cfg.hidden, dtype=cfg.dtype, use_bias=cfg.bias,
+                        name="proj")(out), (k, v)
 
 
 class LatentAttention(nn.Module):
@@ -243,30 +350,54 @@ class LatentAttention(nn.Module):
 class Block(nn.Module):
     cfg: TransformerConfig
     expert: bool = False    # the FFN is the expert layer (cfg.moe)
+    mixer: Optional[str] = None     # one of MIXERS (cfg.mixers[layer])
+    depth: int = 0                  # the layer's index in the whole model
 
     @nn.compact
-    def __call__(self, x, mask=None):
+    def __call__(self, x, mask=None, memory=None, shared_kv=None):
+        """The block's output; in a stack of mixed layers
+        (``self.mixer``) a pair: the output, and what the mixer made for
+        the layers after it (a "mamba" layer its scan output, an
+        "attention" layer its ``(k, v)``, the others None). ``memory``
+        and ``shared_kv`` are what earlier layers made: inputs of the
+        block, which recomputation keeps and does not make again."""
         cfg = self.cfg
-        attention = LatentAttention if cfg.mla else Attention
 
         def out(name, y):
             return _norm(cfg, name)(y) if cfg.sandwich_norm else y
 
         h = _norm(cfg, "ln1")(x)
-        x = x + out("ln1_out", attention(cfg, name="attn")(h, mask))
+        made = None
+        if self.mixer == "mamba":
+            a, made = MambaMixer(cfg, name="mamba")(h)
+        elif self.mixer == "gmu":
+            a = GatedMemoryUnit(cfg, name="gmu")(h, memory)
+        elif self.mixer is not None:
+            a, kv = DiffAttention(
+                cfg, depth=self.depth, cross=self.mixer == "cross",
+                window=cfg.window if self.mixer == "window" else None,
+                name="attn")(h, mask, shared_kv)
+            made = kv if self.mixer == "attention" else None
+        else:
+            attention = LatentAttention if cfg.mla else Attention
+            a = attention(cfg, name="attn")(h, mask)
+        x = x + out("ln1_out", a)
         h = _norm(cfg, "ln2")(x)
         if self.expert:
-            return x + out("ln2_out", MoELayer(cfg.moe, dtype=cfg.dtype,
-                                               name="moe")(h))
-        width = cfg.mlp_width or cfg.hidden * cfg.mlp_ratio
-        dense = functools.partial(nn.Dense, dtype=cfg.dtype,
-                                  use_bias=cfg.bias)
-        if cfg.mlp == "swiglu":
-            h = nn.silu(dense(width, name="mlp_gate")(h)) * dense(
-                width, name="mlp_in")(h)
+            ffn = MoELayer(cfg.moe, dtype=cfg.dtype, name="moe")(h)
         else:
-            h = nn.gelu(dense(width, name="mlp_in")(h))
-        return x + out("ln2_out", dense(cfg.hidden, name="mlp_out")(h))
+            width = cfg.mlp_width or cfg.hidden * cfg.mlp_ratio
+            dense = functools.partial(
+                nn.Dense, dtype=cfg.dtype,
+                use_bias=cfg.bias if cfg.mlp_bias is None else cfg.mlp_bias)
+            if cfg.mlp == "swiglu":
+                h = nn.silu(dense(width, name="mlp_gate")(h)) * dense(
+                    width, name="mlp_in")(h)
+            else:
+                h = nn.gelu(dense(width, name="mlp_in")(h))
+            ffn = dense(cfg.hidden, name="mlp_out")(h)
+        x = x + out("ln2_out", ffn)
+        return x if self.mixer is None else (x, made)
 
 
 def _block(cfg):
@@ -275,9 +406,11 @@ def _block(cfg):
         return nn.remat(Block,
                         policy=policies.dots_with_no_batch_dims_saveable)
     if cfg.remat == "flash":
-        from ..ops.flash_attention import SAVED_NAMES
-        return nn.remat(Block,
-                        policy=policies.save_only_these_names(*SAVED_NAMES))
+        # What the Mosaic kernels hand their backward passes: neither
+        # the flash forward nor the selective scan runs again.
+        from ..ops import flash_attention, selective_scan
+        return nn.remat(Block, policy=policies.save_only_these_names(
+            *flash_attention.SAVED_NAMES, *selective_scan.SAVED_NAMES))
     return nn.remat(Block) if cfg.remat else Block
 
 
@@ -331,10 +464,17 @@ class Backbone(nn.Module):
                 "a stack that runs several times (passes > 1) takes "
                 "neither the expert layer, whose state is per layer and "
                 "not per pass, nor MTP modules")
+        if cfg.mixers is not None and (
+                len(cfg.mixers) != cfg.layers or set(cfg.mixers) - set(MIXERS)
+                or cfg.passes > 1 or cfg.mtp_layers or cfg.mla):
+            raise ValueError(
+                f"mixers {cfg.mixers}: one of {MIXERS} a layer "
+                f"({cfg.layers}), in a stack that runs once with "
+                f"neither latent attention nor MTP modules")
         embed = nn.Embed(cfg.vocab_size, cfg.hidden, dtype=cfg.dtype,
                          name="tok_embed")
         x = embed(tokens)
-        if not cfg.use_rope:
+        if not cfg.use_rope and cfg.positions:
             pos = nn.Embed(cfg.max_len, cfg.hidden, dtype=cfg.dtype,
                            name="pos_embed")(jnp.arange(tokens.shape[1]))
             x = x + pos[None]
@@ -347,9 +487,22 @@ class Backbone(nn.Module):
 
             with jax.named_scope(SCOPE_LOOP):
                 return _looped(self, stack, x, cfg.passes)
+        # What a layer made for the layers after it: the last "mamba"
+        # layer's scan output, the last "attention" layer's (k, v). Their
+        # gradients are the sums over their readers.
+        made = {"mamba": None, "attention": None}
         for i in range(cfg.layers):
             expert = cfg.moe is not None and i >= cfg.moe.first_dense
-            x = block(cfg, expert=expert, name=f"block_{i}")(x, mask)
+            if cfg.mixers is None:
+                x = block(cfg, expert=expert, name=f"block_{i}")(x, mask)
+                continue
+            mixer = cfg.mixers[i]
+            depth = cfg.layer_indices[i] if cfg.layer_indices else i
+            x, new = block(cfg, expert=expert, mixer=mixer, depth=depth,
+                           name=f"block_{i}")(x, mask, made["mamba"],
+                                              made["attention"])
+            if mixer in made:
+                made[mixer] = new
         out = _norm(cfg, "ln_f")(x)
         if next_tokens is None or not cfg.mtp_layers:
             return out
@@ -385,11 +538,22 @@ class TransformerLM(nn.Module):
         gate's (float32; None without ``cfg.exit_gate``). What
         ``looped_lm_loss`` takes."""
         cfg = self.cfg
-        x = Backbone(cfg, name="backbone")(tokens, mask, next_tokens)
+        backbone = Backbone(cfg, name="backbone")
+        x = backbone(tokens, mask, next_tokens)
         if cfg.passes > 1:
             with jax.named_scope(SCOPE_EXIT):
                 return self._exits(x, targets)
-        head = _head(cfg)
+        if cfg.tie_embeddings:
+            # One leaf, used as a gather and as a product: its gradient
+            # is the sum of both uses.
+            table = backbone.variables["params"]["tok_embed"]["embedding"]
+
+            def head(h):
+                return jnp.einsum("...h,vh->...v", h,
+                                  table.astype(cfg.dtype),
+                                  preferred_element_type=jnp.float32)
+        else:
+            head = _head(cfg)
         if isinstance(x, tuple):
             return tuple(head(h).astype(jnp.float32) for h in x)
         return head(x).astype(jnp.float32)

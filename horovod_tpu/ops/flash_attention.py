@@ -32,6 +32,15 @@ Design (MXU/VMEM-first):
 - Global-position masking: query/key chunk offsets arrive as dynamic scalars
   (scalar-prefetch), so the same compiled kernel serves local attention and
   every step of a ring schedule (offsets are device-varying under shard_map).
+- A causal ``window`` is a second edge of the same mask: the blocks
+  whose keys lie a window or more before their queries are skipped and
+  not fetched like those past the diagonal, and the one or two block
+  offsets the window's edge passes through are walked in the same
+  sub-tiles, each strip from its first visible key (``_aligned_offsets``,
+  ``_strip_span``). ``k`` and ``v`` may hold fewer heads than ``q`` (the
+  index maps name the shared row; nothing is repeated in HBM) and ``v``
+  may be wider than ``q`` and ``k`` (differential attention's pair of
+  values).
 - Returns (out, lse); lse makes partial results mergeable (ring attention)
   and feeds the backward pass.
 - Custom VJP with one backward kernel. Two Mosaic calls a layer:
@@ -135,9 +144,11 @@ def _lane_tiles(d):
 # ---------------------------------------------------------------------------
 
 def _block_skip(causal, q_start, k_start, kv_len, qb, kb, block_q,
-                block_k):
+                block_k, window=None):
     """True when the (qb, kb) tile contributes nothing: every key col is
-    padding, or (causal) the whole tile lies above the diagonal. Skipped
+    padding, or (causal) the whole tile lies above the diagonal, or
+    (``window``) every key of it lies ``window`` or more positions
+    before every query. Skipped
     tiles are mathematically identity updates (p==0 everywhere), so
     guarding them with pl.when drops ~half the FLOPs of a causal kernel
     without changing results. Traced scalars, Python integers (a Python
@@ -147,14 +158,19 @@ def _block_skip(causal, q_start, k_start, kv_len, qb, kb, block_q,
         max_row = q_start + qb * block_q + block_q - 1
         min_col = k_start + kb * block_k
         skip = skip | (max_row < min_col)
+    if window is not None:
+        min_row = q_start + qb * block_q
+        max_col = k_start + kb * block_k + block_k - 1
+        skip = skip | (min_row - max_col >= window)
     return skip
 
 
 def _tile_interior(causal, q_start, k_start, kv_len, qb, kb, block_q,
-                   block_k):
+                   block_k, window=None):
     """True when NO element of the (qb, kb) tile is masked: every key
-    col is valid and (causal) the whole tile lies on/below the
-    diagonal. Such tiles skip the iota/compare/where mask construction
+    col is valid, (causal) the whole tile lies on/below the
+    diagonal and (``window``) its first key is inside its last query's
+    window. Such tiles skip the iota/compare/where mask construction
     — per-element VPU work comparable to the exp itself, and at long
     context most tiles are interior."""
     inside = (kb + 1) * block_k <= kv_len
@@ -162,6 +178,10 @@ def _tile_interior(causal, q_start, k_start, kv_len, qb, kb, block_q,
         min_row = q_start + qb * block_q
         max_col = k_start + kb * block_k + block_k - 1
         inside = inside & (max_col <= min_row)
+    if window is not None:
+        max_row = q_start + qb * block_q + block_q - 1
+        min_col = k_start + kb * block_k
+        inside = inside & (max_row - min_col < window)
     return inside
 
 
@@ -227,42 +247,106 @@ def _sub_tile(causal, block_q, block_k, d, backward=False):
     return sub
 
 
-def _on_diagonal(q_start, k_start, kv_len, qb, kb, block):
-    """True for the block the diagonal crosses from corner to corner
-    with every key valid: its sub-tiles' classes are fixed
-    (_strip_extent)."""
-    return ((q_start + qb * block == k_start + kb * block)
+def _aligned_offsets(window, block):
+    """The block offsets (query block less key block) at which a tile
+    with its corner on a block boundary is crossed by the mask: 0, the
+    causal diagonal, and the one or two the window's far edge passes
+    through. A tile at such an offset has the same mask wherever it
+    lies, so its sub-tiles' classes are fixed (_strip_span)."""
+    if window is None:
+        return (0,)
+    return tuple(d for d in range((window + block - 1) // block + 1)
+                 if d == 0 or d * block - (block - 1) < window
+                 <= d * block + block - 1)
+
+
+def _aligned(q_start, k_start, kv_len, qb, kb, block, offset=0):
+    """True for the block whose queries start ``offset`` blocks after
+    its keys, corner on corner, with every key valid; at offset 0 the
+    block the diagonal crosses from corner to corner."""
+    kb_q = kb if offset == 0 else kb + offset
+    return ((q_start + qb * block == k_start + kb_q * block)
             & ((kb + 1) * block <= kv_len))
 
 
-def _strip_extent(i, n_j, sub):
-    """(keys seen, keys seen whole) by sub-block ``i`` of query rows in
-    a tile whose corner lies on the diagonal and whose keys are all
-    valid: the sub-tiles' classes, by the functions that class a tile,
-    on each sub-tile's own corners."""
-    at = [(True, 0, 0, n_j * sub, i, j, sub, sub) for j in range(n_j)]
-    seen = sum(not _block_skip(*a) for a in at)
-    whole = sum(bool(_tile_interior(*a)) for a in at)
-    return seen * sub, whole * sub
+def _strip_span(i, n_j, sub, rows_after=0, window=None):
+    """What sub-block ``i`` of query rows sees of an aligned tile whose
+    first query stands ``rows_after`` positions after its first key and
+    whose keys are all valid: ``(key0, width, head, tail)``, the first
+    key seen, how many are seen, and of those the leading ones that go
+    through the mask (the window's edge) and the trailing ones that do
+    (the diagonal); None where it sees none. The sub-tiles' classes
+    are those of the functions that class a tile, on each sub-tile's
+    own corners. Sub-tiles seen that are all masked count as
+    trailing."""
+    at = [(True, rows_after, 0, n_j * sub, i, j, sub, sub, window)
+          for j in range(n_j)]
+    seen = [j for j in range(n_j) if not _block_skip(*at[j])]
+    if not seen:
+        return None
+    whole = [j for j in seen if _tile_interior(*at[j])]
+    head = whole[0] - seen[0] if whole else 0
+    tail = seen[-1] - whole[-1] if whole else len(seen)
+    return seen[0] * sub, len(seen) * sub, head * sub, tail * sub
 
 
-def _tail_mask(tail, row0, key0, on_diagonal, causal, q_pos, k_start, k_pos,
-               kv_len):
-    """Which elements of a key-major corner ``tail`` = (keys, queries)
+def _span_mask(shape, row0, key0, rows_after, causal, q_pos, k_start, k_pos,
+               kv_len, window=None):
+    """Which elements of a key-major part ``shape`` = (keys, queries)
     of a tile are visible: its keys from ``key0`` on by its query rows
     from ``row0`` on. The tile's first row stands at ``q_pos`` of the
     sequence; its first key at ``k_pos`` of the key chunk (what
-    ``kv_len`` counts), which starts at ``k_start``."""
-    c = key0 + jax.lax.broadcasted_iota(jnp.int32, tail, 0)
-    r = row0 + jax.lax.broadcasted_iota(jnp.int32, tail, 1)
-    if on_diagonal:
-        # The tile's corner is on the diagonal and its keys are all
-        # valid: the mask is the same in every such tile.
-        return r >= c
+    ``kv_len`` counts), which starts at ``k_start``. ``rows_after`` is
+    not None for an aligned tile (_strip_span): its mask is the same
+    wherever it lies, and only the edges that can cut this part are
+    built."""
+    c = key0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    r = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    if rows_after is not None:
+        # Distances query less key over the part, at their extremes.
+        nearest = rows_after + row0 - (key0 + shape[0] - 1)
+        farthest = rows_after + row0 + shape[1] - 1 - key0
+        mask = None
+        if nearest < 0 or window is None:
+            mask = r >= c if rows_after == 0 else r + rows_after >= c
+        if window is not None and farthest >= window:
+            inside = r - c < window - rows_after
+            mask = inside if mask is None else jnp.logical_and(mask, inside)
+        return mask
     mask = k_pos + c < kv_len                         # key padding
     if causal:
         mask = jnp.logical_and(mask, q_pos + r >= k_start + k_pos + c)
+    if window is not None:
+        mask = jnp.logical_and(
+            mask, q_pos + r - (k_start + k_pos + c) < window)
     return mask
+
+
+def _masker(width, head, tail, mask_of):
+    """``masked(x, fill)`` for (keys, queries) tiles ``x`` of ``width``
+    keys: the first ``head`` and the last ``tail`` keys are filled where
+    ``mask_of(first key, keys)`` hides them (None: it hides none), the
+    ones between are left as they are."""
+    head_mask = mask_of(0, head) if head else None
+    tail_mask = mask_of(width - tail, tail) if tail else None
+
+    def masked(x, fill):
+        if head_mask is None and tail_mask is None:
+            return x
+        # Made in the order tail, middle, head.
+        parts = [x[width - tail:, :]] if tail else []
+        if tail_mask is not None:
+            parts = [jnp.where(tail_mask, parts[0], fill)]
+        if width - head - tail:
+            parts.insert(0, x[head:width - tail, :])
+        if head:
+            part = x[:head, :]
+            parts.insert(0, part if head_mask is None
+                         else jnp.where(head_mask, part, fill))
+        return parts[0] if len(parts) == 1 else jnp.concatenate(
+            parts, axis=0)
+
+    return masked
 
 
 def _last_key_block(i, lens, n_k, block_q, block_k, causal):
@@ -281,22 +365,39 @@ def _last_key_block(i, lens, n_k, block_q, block_k, causal):
     return lax.max(lax.min(last, jnp.int32(n_k - 1)), jnp.int32(0))
 
 
-def _kv_block(i, j, lens, n_k, block_q, block_k, causal):
+def _first_key_block(i, lens, n_k, block_q, block_k, window):
+    """The first key block the forward's query block ``i`` sees through
+    a window, inside the grid: the steps before it are skipped."""
+    first = lax.div(lens[0] + i * block_q - (window - 1) - lens[1],
+                    jnp.int32(block_k))
+    return lax.max(lax.min(first, jnp.int32(n_k - 1)), jnp.int32(0))
+
+
+def _kv_block(i, j, lens, n_k, block_q, block_k, causal, window=None,
+              n_q=1):
     """The K and V block grid step (i, j) of the forward holds: its own
     while visible, block 0 on the skipped steps after. One fetch, under
     the diagonal tile's products, brings what the next query block
     starts with, and the other skipped steps fetch nothing (measured
     against naming the last visible block, which fetches at the row's
-    end: 4.25 -> 4.10 ms a layer at 32 x 8192 x 64, PERF.md, PR 29)."""
+    end: 4.25 -> 4.10 ms a layer at 32 x 8192 x 64, PERF.md, PR 29).
+    Under a window the steps before the first visible block name that
+    block, and the ones after the last name the first block of the next
+    query block (of ``n_q``), which is often the one held."""
     last = _last_key_block(i, lens, n_k, block_q, block_k, causal)
     if last is None:
         return j
-    return lax.select(j <= last, j, jnp.zeros_like(j))
+    if window is None:
+        return lax.select(j <= last, j, jnp.zeros_like(j))
+    grid_of = (lens, n_k, block_q, block_k, window)
+    first = _first_key_block(i, *grid_of)
+    after = _first_key_block(lax.rem(i + 1, jnp.int32(n_q)), *grid_of)
+    return lax.select(j < first, first, lax.select(j <= last, j, after))
 
 
 def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, *rest, sm_scale, causal,
                 block_q, block_k, n_k, sub, dropout_rate=0.0,
-                seeded=False):
+                seeded=False, window=None):
     # rest = [dm_ref?], o_ref, lse_ref, m_scr, l_scr, acc_scr
     if dropout_rate > 0.0 and not seeded:
         dm_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
@@ -319,46 +420,42 @@ def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, *rest, sm_scale, causal,
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     mask_of = (causal, q_start, k_start, kv_len)
-    skip = _block_skip(*mask_of, qb, kb, block_q, block_k)
-    interior = _tile_interior(*mask_of, qb, kb, block_q, block_k)
+    skip = _block_skip(*mask_of, qb, kb, block_q, block_k, window)
+    interior = _tile_interior(*mask_of, qb, kb, block_q, block_k, window)
 
-    def update(keep, row0, n_rows, width, mask_from, on_diagonal=False):
+    def update(keep, row0, n_rows, key0, width, head, tail,
+               rows_after=None):
         """Online-softmax update of the tile's query rows ``row0`` to
-        ``row0 + n_rows`` by its first ``width`` keys. The keys from
-        ``mask_from`` on go through the mask; the ones before are known
-        to be visible. Key-major: s^T is (keys, queries), so the
-        statistics are rows along lanes and a reduction over keys adds
-        vregs to each other, nothing across lanes."""
+        ``row0 + n_rows`` by its ``width`` keys from ``key0`` on. The
+        first ``head`` and the last ``tail`` of them go through the
+        mask; the ones between are known to be visible. ``rows_after``
+        is given for an aligned tile (_strip_span). Key-major: s^T is
+        (keys, queries), so the statistics are rows along lanes and a
+        reduction over keys adds vregs to each other, nothing across
+        lanes."""
         rows = pl.ds(row0, n_rows)
+        keys = slice(key0, key0 + width)
         q = q_ref[0, rows, :]
         if fold:
             q = (q.astype(jnp.float32) * sm_scale).astype(q.dtype)
         st = jax.lax.dot_general(
-            k_ref[0, :width, :], q, (((1,), (1,)), ((), ())),
+            k_ref[0, keys, :], q, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)      # (width, n_rows)
         if not fold:
             st = st * sm_scale
-        mask = None
-        if mask_from is not None:
-            mask = _tail_mask(
-                (width - mask_from, n_rows), row0, mask_from, on_diagonal,
-                causal, q_start + qb * block_q, k_start, kb * block_k,
-                kv_len)
 
-        def masked(x, fill):
-            if mask is None:
-                return x
-            x_tail = jnp.where(mask, x[mask_from:, :], fill)
-            if mask_from == 0:
-                return x_tail
-            return jnp.concatenate([x[:mask_from, :], x_tail], axis=0)
+        masked = _masker(
+            width, head, tail, lambda first, n: _span_mask(
+                (n, n_rows), row0, key0 + first, rows_after, causal,
+                q_start + qb * block_q, k_start, kb * block_k, kv_len,
+                window))
 
         st = masked(st, _NEG_INF)
         m_prev = m_scr[:1, rows]                   # (1, n_rows)
         m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         pt = jnp.exp(st - m_new)                   # (width, n_rows) fp32
-        if not on_diagonal:
+        if rows_after != 0:
             # Fully-masked rows: m_new stays _NEG_INF and p would be
             # exp(0)=1 — zero those contributions so l stays 0 for
             # them. (On the diagonal every row sees its own key, so a
@@ -370,11 +467,11 @@ def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, *rest, sm_scale, causal,
         # value accumulation uses the dropped/rescaled weights).
         pvt = pt
         if keep is not None:
-            pvt = pt * keep[row0:row0 + n_rows, :width].T
+            pvt = pt * keep[row0:row0 + n_rows, keys].T
         elif dm_ref is not None:
-            pvt = pt * _keep_scale(dm_ref[0, rows, :width], dropout_rate).T
+            pvt = pt * _keep_scale(dm_ref[0, rows, keys], dropout_rate).T
         acc_scr[:, rows] = acc_scr[:, rows] * alpha + jax.lax.dot_general(
-            v_ref[0, :width, :], pvt.astype(v_ref.dtype),
+            v_ref[0, keys, :], pvt.astype(v_ref.dtype),
             (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         m_scr[:, rows] = jnp.broadcast_to(m_new, (m_scr.shape[0], n_rows))
         l_scr[:, rows] = jnp.broadcast_to(l_new, (l_scr.shape[0], n_rows))
@@ -389,28 +486,31 @@ def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, *rest, sm_scale, causal,
 
     @pl.when(jnp.logical_and(visible, interior))
     def _():
-        update(draw(), 0, block_q, block_k, None)
+        update(draw(), 0, block_q, 0, block_k, 0, 0)
 
     partial = jnp.logical_and(visible, jnp.logical_not(interior))
-    if sub is not None:
-        # A sub-block of query rows sees the sub-tiles left of the
-        # diagonal whole, the one on it through the mask, and nothing of
-        # those right of it.
-        on_diagonal = _on_diagonal(q_start, k_start, kv_len, qb, kb,
-                                   block_q)
+    for offset in _aligned_offsets(window, block_q) if sub else ():
+        # A sub-block of query rows sees the sub-tiles between the
+        # window's edge and the diagonal whole, the ones on either
+        # through the mask, and nothing of those beyond.
+        aligned = _aligned(q_start, k_start, kv_len, qb, kb, block_q,
+                           offset)
 
-        @pl.when(jnp.logical_and(partial, on_diagonal))
-        def _():
+        @pl.when(jnp.logical_and(partial, aligned))
+        def _(offset=offset):
             keep = draw()
             for i in range(block_q // sub):
-                seen, whole = _strip_extent(i, block_k // sub, sub)
-                update(keep, i * sub, sub, seen, whole, on_diagonal=True)
+                span = _strip_span(i, block_k // sub, sub,
+                                   offset * block_q, window)
+                if span is not None:
+                    update(keep, i * sub, sub, *span,
+                           rows_after=offset * block_q)
 
-        partial = jnp.logical_and(partial, jnp.logical_not(on_diagonal))
+        partial = jnp.logical_and(partial, jnp.logical_not(aligned))
 
     @pl.when(partial)
     def _():
-        update(draw(), 0, block_q, block_k, 0)
+        update(draw(), 0, block_q, 0, block_k, 0, block_k)
 
     @pl.when(kb == n_k - 1)
     def _():
@@ -424,36 +524,49 @@ def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, *rest, sm_scale, causal,
 
 
 def _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
-              dm=None, dropout_rate=0.0, seeded=False):
+              dm=None, dropout_rate=0.0, seeded=False, window=None):
     """One forward kernel call. The layers of a model make the same
     call, so it goes through ``jax.jit``: the kernel is traced and
     lowered once a program, not once a layer, and XLA inlines the calls
     (each keeps its own layer's ``op_name``). What the trace reads of
-    the module's state is an argument, so no cached trace outlives it."""
+    the module's state is an argument, so no cached trace outlives it.
+
+    ``k`` and ``v`` may hold fewer heads than ``q`` (``q``'s rows are
+    ``group`` to a row of theirs, adjacent), and ``v`` another width
+    than ``q`` and ``k``."""
     return _fwd_jit(q, k, v, lens, dm, sm_scale, causal, block_q, block_k,
                     _sub_tile(causal, block_q, block_k, q.shape[2]),
-                    dropout_rate, seeded, _interpret())
+                    dropout_rate, seeded, _interpret(), window)
 
 
-@functools.partial(jax.jit, static_argnums=tuple(range(5, 13)))
+def _kv_row(b, group):
+    """The K/V row of query row ``b`` of the flattened (batch, head)s."""
+    return b if group == 1 else lax.div(b, jnp.int32(group))
+
+
+@functools.partial(jax.jit, static_argnums=tuple(range(5, 14)))
 def _fwd_jit(q, k, v, lens, dm, sm_scale, causal, block_q, block_k, sub,
-             dropout_rate, seeded, interpret):
+             dropout_rate, seeded, interpret, window):
     bh, sq, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[2]
+    group = bh // k.shape[0]
     n_q = sq // block_q
     n_k = sk // block_k
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, n_k=n_k, sub=sub,
-        dropout_rate=dropout_rate, seeded=seeded)
+        dropout_rate=dropout_rate, seeded=seeded, window=window)
 
     grid_of = (n_k, block_q, block_k, causal)
-    k_tile = pl.BlockSpec(
-        (1, block_k, d),
-        lambda b, i, j, lens: (b, _kv_block(i, j, lens, *grid_of), 0))
+
+    def kv_at(b, i, j, lens):
+        return (_kv_row(b, group),
+                _kv_block(i, j, lens, *grid_of, window, n_q), 0)
+
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j, lens: (b, i, 0)),
-        k_tile, k_tile,
+        pl.BlockSpec((1, block_k, d), kv_at),
+        pl.BlockSpec((1, block_k, dv), kv_at),
     ]
     operands = [q, k, v]
     if dropout_rate > 0.0 and not seeded:
@@ -470,17 +583,17 @@ def _fwd_jit(q, k, v, lens, dm, sm_scale, causal, block_q, block_k, sub,
         grid=(bh, n_q, n_k),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j, lens: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j, lens: (b, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b, i, j, lens: (b, 0, i)),
         ],
         scratch_shapes=[
             pltpu.VMEM((8, block_q), jnp.float32),
             pltpu.VMEM((8, block_q), jnp.float32),
-            pltpu.VMEM((d, block_q), jnp.float32),
+            pltpu.VMEM((dv, block_q), jnp.float32),
         ],
     )
     out_shapes = [
-        _struct((bh, sq, d), q.dtype, q, k, v, lens),
+        _struct((bh, sq, dv), q.dtype, q, k, v, lens),
         _struct((bh, 1, sq), jnp.float32, q, k, v, lens),
     ]
     compiler_params = pltpu.CompilerParams(
@@ -500,24 +613,39 @@ def _fwd_jit(q, k, v, lens, dm, sm_scale, causal, block_q, block_k, sub,
 # Backward kernel
 # ---------------------------------------------------------------------------
 
-def _q_block(j, i, lens, n_q, block_q, block_k, causal, qb0):
-    """The query block grid step (j, i) of the backward holds, of a call
-    whose tiles are ``qb0`` onward of the sequence. Under a causal mask
-    the steps before a key block's first visible query block are skipped
-    (_block_skip): they name that first block, so they fetch nothing and
-    the block is there when its step comes."""
-    if not causal or n_q == 1:
-        return i
+def _first_query_block(j, lens, n_q, block_q, block_k, qb0):
     # Truncating division: where it differs from the floor the first
     # block is negative and ``i`` wins either way.
     first = lax.div(lens[1] + j * block_k - lens[0],
                     jnp.int32(block_q)) - qb0
-    return lax.max(i, lax.min(first, jnp.int32(n_q - 1)))
+    return lax.min(first, jnp.int32(n_q - 1))
+
+
+def _q_block(j, i, lens, n_q, block_q, block_k, causal, qb0, window=None,
+             n_k=1):
+    """The query block grid step (j, i) of the backward holds, of a call
+    whose tiles are ``qb0`` onward of the sequence. Under a causal mask
+    the steps before a key block's first visible query block are skipped
+    (_block_skip): they name that first block, so they fetch nothing and
+    the block is there when its step comes. Under a window the steps
+    after its last visible query block name the first of the next key
+    block (of ``n_k``)."""
+    if not causal or n_q == 1:
+        return i
+    grid_of = (lens, n_q, block_q, block_k, qb0)
+    first = _first_query_block(j, *grid_of)
+    if window is None:
+        return lax.max(i, first)
+    last = lax.div(lens[1] + j * block_k + (block_k - 1) + (window - 1)
+                   - lens[0], jnp.int32(block_q)) - qb0
+    after = lax.max(_first_query_block(lax.rem(j + 1, jnp.int32(n_k)),
+                                       *grid_of), jnp.int32(0))
+    return lax.select(i <= last, lax.max(i, first), after)
 
 
 def _bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 *rest, sm_scale, causal, block_q, block_k, qb0, sub,
-                dropout_rate=0.0, seeded=False):
+                dropout_rate=0.0, seeded=False, window=None):
     # rest = [dm_ref?], dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr
     if dropout_rate > 0.0 and not seeded:
         dm_ref, *rest = rest
@@ -552,21 +680,23 @@ def _bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             (block_q, dq_scr.shape[1]), jnp.float32)
 
     mask_of = (causal, q_start, k_start, kv_len)
-    skip = _block_skip(*mask_of, qg, kb, block_q, block_k)
-    interior = _tile_interior(*mask_of, qg, kb, block_q, block_k)
+    skip = _block_skip(*mask_of, qg, kb, block_q, block_k, window)
+    interior = _tile_interior(*mask_of, qg, kb, block_q, block_k, window)
 
-    def tile_update(keep, row0, n_rows, width, mask_from,
-                    on_diagonal=False):
+    def tile_update(keep, row0, n_rows, key0, width, head, tail,
+                    rows_after=None):
         """Add to dq, dk and dv what the tile's query rows ``row0`` to
-        ``row0 + n_rows`` and its first ``width`` keys give. The keys
-        from ``mask_from`` on go through the mask; the ones before are
-        known to be visible. Key-major: every (keys, queries) tile below
+        ``row0 + n_rows`` and its ``width`` keys from ``key0`` on give.
+        The first ``head`` and the last ``tail`` of them go through the
+        mask; the ones between are known to be visible. ``rows_after``
+        is given for an aligned tile (_strip_span). Key-major: every
+        (keys, queries) tile below
         is the transpose of the forward's. p^T and ds^T then enter dv
         and dk as plain left operands and lse, delta broadcast along
         sublanes as they are stored; only dq contracts over the left
         operand's rows."""
         rows = slice(row0, row0 + n_rows)
-        keys = slice(0, width)
+        keys = slice(key0, key0 + width)
         q = q_ref[0, rows, :]             # (n_rows, d)
         do = do_ref[0, rows, :]
         k = k_ref[0, keys, :]             # (width, d)
@@ -582,14 +712,11 @@ def _bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if not fold:
             st = st * sm_scale
         pt = jnp.exp(st - lse)                       # (width, n_rows) fp32
-        if mask_from is not None:
-            mask = _tail_mask(
-                (width - mask_from, n_rows), row0, mask_from, on_diagonal,
-                causal, q_start + qg * block_q, k_start, kb * block_k,
-                kv_len)
-            pt_tail = jnp.where(mask, pt[mask_from:, :], 0.0)
-            pt = pt_tail if mask_from == 0 else jnp.concatenate(
-                [pt[:mask_from, :], pt_tail], axis=0)
+        pt = _masker(
+            width, head, tail, lambda first, n: _span_mask(
+                (n, n_rows), row0, key0 + first, rows_after, causal,
+                q_start + qg * block_q, k_start, kb * block_k, kv_len,
+                window))(pt, 0.0)
 
         # Dropout backward: o = (P∘M̃)V with M̃ = mask/(1-rate), so
         # dV = (P∘M̃)ᵀdO and dP = (dO Vᵀ)∘M̃; the delta trick survives
@@ -634,29 +761,32 @@ def _bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(jnp.logical_and(visible, interior))
     def _():
-        tile_update(draw(), 0, block_q, block_k, None)
+        tile_update(draw(), 0, block_q, 0, block_k, 0, 0)
 
     partial = jnp.logical_and(visible, jnp.logical_not(interior))
-    if sub is not None:
+    for offset in _aligned_offsets(window, block_q) if sub else ():
         # As the forward walks it: a strip of query rows sees the
-        # sub-tiles left of the diagonal whole, the one on it through
-        # the mask, and nothing of those right of it, whose p is zero.
-        on_diagonal = _on_diagonal(q_start, k_start, kv_len, qg, kb,
-                                   block_q)
+        # sub-tiles between the window's edge and the diagonal whole,
+        # the ones on either through the mask, and nothing of those
+        # beyond, whose p is zero.
+        aligned = _aligned(q_start, k_start, kv_len, qg, kb, block_q,
+                           offset)
 
-        @pl.when(jnp.logical_and(partial, on_diagonal))
-        def _():
+        @pl.when(jnp.logical_and(partial, aligned))
+        def _(offset=offset):
             keep = draw()
             for i in range(block_q // sub):
-                seen, whole = _strip_extent(i, block_k // sub, sub)
-                tile_update(keep, i * sub, sub, seen, whole,
-                            on_diagonal=True)
+                span = _strip_span(i, block_k // sub, sub,
+                                   offset * block_q, window)
+                if span is not None:
+                    tile_update(keep, i * sub, sub, *span,
+                                rows_after=offset * block_q)
 
-        partial = jnp.logical_and(partial, jnp.logical_not(on_diagonal))
+        partial = jnp.logical_and(partial, jnp.logical_not(aligned))
 
     @pl.when(partial)
     def _():
-        tile_update(draw(), 0, block_q, block_k, 0)
+        tile_update(draw(), 0, block_q, 0, block_k, 0, block_k)
 
     @pl.when(qb == n_q - 1)
     def _():
@@ -691,14 +821,19 @@ def _dq_resident_bytes(rows, d, dtype):
 
 @jax.named_scope(SCOPE)
 def _bwd_call(q, k, v, o, do, lse, lens, sm_scale, causal, block_q, block_k,
-              g_lse=None, dm=None, dropout_rate=0.0, seeded=False):
+              g_lse=None, dm=None, dropout_rate=0.0, seeded=False,
+              window=None):
     """dq, dk and dv from one kernel: one pass over the (key, query)
     tiles computes s, exp, dp and ds once and feeds all three gradients.
     Grid (batch*heads, k_blocks, q_blocks), query innermost: dk and dv
     accumulate in per-key-block scratch, dq in a float32 VMEM accumulator
     over the whole query range of the (batch, head), its output block
-    resident until the last key block has been added."""
+    resident until the last key block has been added. Where ``k`` and
+    ``v`` hold fewer heads than ``q``, the kernel writes every query
+    head's share of dk and dv in float32 and the group's are summed
+    here."""
     bh, sq, d = q.shape
+    group = bh // k.shape[0]
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)                        # (bh, sq)
     if g_lse is not None:
@@ -719,8 +854,9 @@ def _bwd_call(q, k, v, o, do, lse, lens, sm_scale, causal, block_q, block_k,
         _bwd_chunk, k=k, v=v, lens=lens, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k,
         sub=_sub_tile(causal, block_q, block_k, d, backward=True),
-        dropout_rate=dropout_rate, seeded=seeded, interpret=_interpret())
-    if n_q <= per_chunk:
+        dropout_rate=dropout_rate, seeded=seeded, interpret=_interpret(),
+        window=window)
+    if n_q <= per_chunk and group == 1:
         return chunk(q, do, lse3, delta3, dm, qb0=0)
     dqs, dk, dv = [], 0.0, 0.0
     for qb0 in range(0, n_q, per_chunk):
@@ -731,35 +867,49 @@ def _bwd_call(q, k, v, o, do, lse, lens, sm_scale, causal, block_q, block_k,
             kv_dtype=jnp.float32)
         dqs.append(dq_c)
         dk, dv = dk + dk_c, dv + dv_c
+    if group > 1:
+        dk, dv = (x.reshape(-1, group, *x.shape[1:]).sum(axis=1)
+                  for x in (dk, dv))
     return (jnp.concatenate(dqs, axis=1), dk.astype(k.dtype),
             dv.astype(v.dtype))
 
 
 @functools.partial(jax.jit, static_argnames=(
     "qb0", "sm_scale", "causal", "block_q", "block_k", "sub",
-    "dropout_rate", "seeded", "interpret", "kv_dtype"))
+    "dropout_rate", "seeded", "interpret", "kv_dtype", "window"))
 def _bwd_chunk(q, do, lse3, delta3, dm, *, k, v, lens, qb0, sm_scale,
                causal, block_q, block_k, sub, dropout_rate, seeded,
-               interpret, kv_dtype=None):
+               interpret, kv_dtype=None, window=None):
     """The backward kernel over the query rows it is given: tiles ``qb0``
-    onward of the sequence. dk and dv are this chunk's share, in
+    onward of the sequence. dk and dv are this chunk's share, a row to
+    each of ``q``'s, in
     ``kv_dtype`` (k's and v's own unless the caller sums shares). The
     layers of a model make the same call, so it goes through ``jax.jit``
     like the forward's: traced and lowered once a program."""
     bh, sq, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[2]
+    group = bh // k.shape[0]
     n_q = sq // block_q
     n_k = sk // block_k
-
     def qi(j, i, lens):
-        return _q_block(j, i, lens, n_q, block_q, block_k, causal, qb0)
+        return _q_block(j, i, lens, n_q, block_q, block_k, causal, qb0,
+                        window, n_k)
 
-    q_tile = pl.BlockSpec((1, block_q, d),
-                          lambda b, j, i, lens: (b, qi(j, i, lens), 0))
+    def q_tile(width):
+        return pl.BlockSpec((1, block_q, width),
+                            lambda b, j, i, lens: (b, qi(j, i, lens), 0))
+
+    def k_tile(width, row=lambda b: b):
+        return pl.BlockSpec((1, block_k, width),
+                            lambda b, j, i, lens: (row(b), j, 0))
+
+    def kv_row(b):
+        return _kv_row(b, group)
+
     q_row = pl.BlockSpec((1, 1, block_q),
                          lambda b, j, i, lens: (b, 0, qi(j, i, lens)))
-    k_tile = pl.BlockSpec((1, block_k, d), lambda b, j, i, lens: (b, j, 0))
-    in_specs = [q_tile, k_tile, k_tile, q_tile, q_row, q_row]
+    in_specs = [q_tile(d), k_tile(d, kv_row), k_tile(dv, kv_row),
+                q_tile(dv), q_row, q_row]
     operands = [q, k, v, do, lse3, delta3]
     if dm is not None:
         in_specs.append(pl.BlockSpec(
@@ -772,28 +922,32 @@ def _bwd_chunk(q, do, lse3, delta3, dm, *, k, v, lens, qb0, sm_scale,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, sq, d), lambda b, j, i, lens: (b, 0, 0)),
-            k_tile, k_tile,
+            k_tile(d), k_tile(dv),
         ],
         scratch_shapes=[
             pltpu.VMEM((sq, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
     )
     dq, dk, dv = pl.pallas_call(
-        functools.partial(_bwd_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k, qb0=qb0,
-                          sub=sub, dropout_rate=dropout_rate,
-                          seeded=seeded),
+        functools.partial(
+            _bwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
+            block_k=block_k, qb0=qb0, sub=sub, dropout_rate=dropout_rate,
+            seeded=seeded, window=window),
         grid_spec=grid_spec,
         out_shape=[
             _struct((bh, sq, d), q.dtype, q, k, v, do, lens),
             _struct((bh, sk, d), kv_dtype or k.dtype, q, k, v, do, lens),
-            _struct((bh, sk, d), kv_dtype or v.dtype, q, k, v, do, lens),
+            _struct((bh, sk, dv), kv_dtype or v.dtype, q, k, v, do, lens),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-            vmem_limit_bytes=_TILE_VMEM_BYTES * _lane_tiles(d)
+            # A value wider than the key, and a group's shares of dk
+            # and dv written in float32, make the tiles' blocks as large
+            # as a head of twice the width does.
+            vmem_limit_bytes=_TILE_VMEM_BYTES * _lane_tiles(d) * (
+                2 if dv > d or group > 1 else 1)
             + _dq_resident_bytes(sq, d, q.dtype)),
         interpret=interpret,
         name=KERNEL_BWD_DKDV,
@@ -811,22 +965,24 @@ def _bwd_chunk(q, do, lse3, delta3, dm, *, k, v, lens, qb0, sm_scale,
 SAVED_NAMES = ("hvd_flash_o", "hvd_flash_lse")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash(q, k, v, lens, sm_scale, causal, block_q, block_k):
-    o, _ = _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash(q, k, v, lens, sm_scale, causal, block_q, block_k, window=None):
+    o, _ = _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
+                     window=window)
     return o
 
 
-def _flash_fwd(q, k, v, lens, sm_scale, causal, block_q, block_k):
+def _flash_fwd(q, k, v, lens, sm_scale, causal, block_q, block_k, window):
     o, lse = map(ad_checkpoint.checkpoint_name, _fwd_call(
-        q, k, v, lens, sm_scale, causal, block_q, block_k), SAVED_NAMES)
+        q, k, v, lens, sm_scale, causal, block_q, block_k, window=window),
+        SAVED_NAMES)
     return o, (q, k, v, o, lse, lens)
 
 
-def _flash_bwd(sm_scale, causal, block_q, block_k, res, g):
+def _flash_bwd(sm_scale, causal, block_q, block_k, window, res, g):
     q, k, v, o, lse, lens = res
     dq, dk, dv = _bwd_call(q, k, v, o, g, lse, lens, sm_scale, causal,
-                           block_q, block_k)
+                           block_q, block_k, window=window)
     dlens = np.zeros((3,), jax.dtypes.float0)
     return dq, dk, dv, dlens
 
@@ -921,54 +1077,80 @@ def _clamp_blocks(sq, sk, block_q, block_k):
             min(block_k, max(8, 1 << (sk - 1).bit_length())))
 
 
+def _effective_window(window, sq, q_offset, k_offset):
+    """``window``, or None where it is at least the distance from the
+    call's last query to its first key and so hides nothing (offsets
+    known at trace time)."""
+    if window is not None and all(
+            isinstance(x, (int, np.integer)) for x in (q_offset, k_offset)
+    ) and window >= q_offset + sq - k_offset:
+        return None
+    return window
+
+
 def subtile_counts(kernel, sq, sk, block_q, block_k, causal, q_offset=0,
-                   k_offset=0, kv_len=None, head_dim=64):
+                   k_offset=0, kv_len=None, head_dim=64, window=None):
     """How ``kernel`` (``"fwd"`` or ``"bwd"``) visits one (batch, head)'s
     score matrix: sub-tiles ``interior`` (no mask built), ``masked`` and
     ``skipped`` (no product, no exponential), and ``steps_without_fetch``,
     the grid steps whose blocks are the ones already held (K and V in the
     forward; q and do in the backward, its query range taken as one
-    chunk). A function of shapes and offsets alone, by the functions the
-    kernels themselves class tiles and name blocks with. A tile that is
-    not walked in sub-tiles counts as one sub-tile."""
+    chunk). Under a ``window`` that hides something, ``skipped`` are the
+    sub-tiles the causal mask and the padding hide and ``window`` those
+    that only the window does. A function of shapes and offsets alone,
+    by the functions the kernels themselves class tiles and name blocks
+    with. A tile that is not walked in sub-tiles counts as one
+    sub-tile a side of the kernel's (``head_dim`` is the keys')."""
     backward = kernel == "bwd"
+    window = _effective_window(window, sq, q_offset, k_offset)
     block_q, block_k = _clamp_blocks(sq, sk, block_q, block_k)
     n_q, n_k = -(-sq // block_q), -(-sk // block_k)
     kv_len = sk if kv_len is None else kv_len
     qb, kb = np.meshgrid(np.arange(n_q), np.arange(n_k), indexing="ij")
-    tile = (causal, q_offset, k_offset, kv_len, qb, kb, block_q, block_k)
+    tile = (causal, q_offset, k_offset, kv_len, qb, kb, block_q, block_k,
+            window)
     skip = _block_skip(*tile) & np.ones_like(qb, bool)
     interior = _tile_interior(*tile) & ~skip
-    walked = np.zeros_like(skip)
-    # Sub-tiles a block: all, seen and seen whole by a walked block's
-    # rows.
-    per_block, seen, whole = 1, 1, 0
+    plain = ~skip & ~interior       # through the mask whole, unless walked
+    counts = dict.fromkeys(("interior", "masked", "skipped"), 0)
+    # Sub-tiles a block: all, and per walked block those its rows see
+    # and see whole.
+    per_block = 1
     sub = _sub_tile(causal, block_q, block_k, head_dim, backward)
     if sub is not None:
         n = block_q // sub
-        extents = [_strip_extent(i, n, sub) for i in range(n)]
         per_block = n * n
-        seen = sum(s for s, _ in extents) // sub
-        whole = sum(w for _, w in extents) // sub
-        walked = ~skip & ~interior & _on_diagonal(
-            q_offset, k_offset, kv_len, qb, kb, block_q)
-    n_walked = int(walked.sum())
-    counts = {
-        "interior": int(interior.sum()) * per_block + n_walked * whole,
-        "masked": int((~skip & ~interior & ~walked).sum()) * per_block
-        + n_walked * (seen - whole),
-        "skipped": int(skip.sum()) * per_block
-        + n_walked * (per_block - seen),
-    }
+        for offset in _aligned_offsets(window, block_q):
+            spans = [_strip_span(i, n, sub, offset * block_q, window)
+                     for i in range(n)]
+            seen = sum(s[1] for s in spans if s) // sub
+            through = sum(s[2] + s[3] for s in spans if s) // sub
+            walked = plain & _aligned(q_offset, k_offset, kv_len, qb, kb,
+                                      block_q, offset)
+            n_walked = int(walked.sum())
+            counts["interior"] += n_walked * (seen - through)
+            counts["masked"] += n_walked * through
+            counts["skipped"] += n_walked * (per_block - seen)
+            plain = plain & ~walked
+    counts["interior"] += int(interior.sum()) * per_block
+    counts["masked"] += int(plain.sum()) * per_block
+    counts["skipped"] += int(skip.sum()) * per_block
+    if window is not None:
+        hidden = subtile_counts(kernel, sq, sk, block_q, block_k, causal,
+                                q_offset, k_offset, kv_len, head_dim)
+        counts["window"] = counts["skipped"] - hidden["skipped"]
+        counts["skipped"] = hidden["skipped"]
     with jax.ensure_compile_time_eval():
         lens = jnp.asarray([q_offset, k_offset, kv_len], jnp.int32)
         qb, kb = jnp.asarray(qb, jnp.int32), jnp.asarray(kb, jnp.int32)
         if backward:        # key blocks outer, query blocks inner
-            held = np.asarray(_q_block(kb, qb, lens, n_q, block_q, block_k,
-                                       causal, 0)).T.reshape(-1)
+            held = np.asarray(_q_block(
+                kb, qb, lens, n_q, block_q, block_k, causal, 0, window,
+                n_k)).T.reshape(-1)
         else:
-            held = np.asarray(_kv_block(qb, kb, lens, n_k, block_q,
-                                        block_k, causal)).reshape(-1)
+            held = np.asarray(_kv_block(
+                qb, kb, lens, n_k, block_q, block_k, causal, window,
+                n_q)).reshape(-1)
     counts["steps_without_fetch"] = int((held[1:] == held[:-1]).sum())
     return counts
 
@@ -980,7 +1162,8 @@ bwd_subtile_counts = functools.partial(subtile_counts, "bwd")
 def _publish_subtiles(*call):
     """Set ``hvd_flash_fwd_subtiles{kind}`` and
     ``hvd_flash_bwd_subtiles{kind}`` (docs/metrics.md) from
-    ``subtile_counts`` of the call being traced. A no-op when
+    ``subtile_counts`` of the call being traced (kind ``window`` too
+    where the call has a window that hides something). A no-op when
     ``HOROVOD_TPU_METRICS`` is off."""
     from ..telemetry import core as telemetry
     if not telemetry.enabled():
@@ -999,25 +1182,24 @@ def _publish_subtiles(*call):
 
 def _prepare(q, k, v, block_q, block_k):
     """Reshape (B,H,S,D)→(BH,S,D), pad D to a lane tile (64 when D<=64,
-    else 128) and S to block multiples. Returns padded tensors +
+    else 128) and S to block multiples; ``k`` and ``v`` by their own
+    heads and ``v`` by its own width. Returns padded tensors +
     original dims."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
     block_q, block_k = _clamp_blocks(sq, sk, block_q, block_k)
 
-    def flat(x):
-        return x.reshape((b * h,) + x.shape[2:])
+    def flat(x, block):
+        # Head dims <=64 stay at 64 lanes: Mosaic supports 64-wide last
+        # dims, and padding d=64 heads to 128 would double both the
+        # matmul work and the HBM traffic of every block (~10% kernel
+        # time at seq 512, docs/PERF.md round-3 sweep).
+        x = x.reshape((-1,) + x.shape[2:])
+        return _pad_to(_pad_to(x, 64 if x.shape[2] <= 64 else _LANE, 2),
+                       block, 1)
 
-    # Head dims <=64 stay at 64 lanes: Mosaic supports 64-wide last dims,
-    # and padding d=64 heads to 128 would double both the matmul work and
-    # the HBM traffic of every block (~10% kernel time at seq 512,
-    # docs/PERF.md round-3 sweep).
-    d_pad = 64 if d <= 64 else _LANE
-    q, k, v = flat(q), flat(k), flat(v)
-    q = _pad_to(_pad_to(q, d_pad, 2), block_q, 1)
-    k = _pad_to(_pad_to(k, d_pad, 2), block_k, 1)
-    v = _pad_to(_pad_to(v, d_pad, 2), block_k, 1)
-    return q, k, v, (b, h, sq, sk, d), block_q, block_k
+    return (flat(q, block_q), flat(k, block_k), flat(v, block_k),
+            (b, h, sq, sk, d), block_q, block_k)
 
 
 def _varying(*xs):
@@ -1030,10 +1212,23 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None,
                     q_offset=0, k_offset=0, kv_len=None,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
                     with_lse=False, dropout_mask=None, dropout_rate=0.0,
-                    dropout_seed=None):
+                    dropout_seed=None, window=None):
     """Flash attention over (batch, heads, seq, head_dim) tensors.
 
+    ``k`` and ``v`` may hold fewer heads than ``q``, a whole number of
+    query heads to each (grouped K/V heads: query head ``h`` reads head
+    ``h // group``; neither is repeated in HBM, the kernels' index maps
+    name the shared block, and dk, dv are summed over a group in
+    float32). ``v`` may be of another width than ``q`` and ``k``, and
+    the output is of ``v``'s.
+
     Args:
+      window: with ``causal``, a query at position ``t`` sees the keys at
+        positions ``t - window < s <= t`` only (a static integer). The
+        kernels run no sub-tile and fetch no block that the window
+        hides, as for the causal mask; a window that reaches the call's
+        first key from its last query is no window, and traces the
+        program that ``None`` traces. Not with dropout or ``with_lse``.
       causal: apply a causal mask in *global* coordinates:
         position(q) = q_offset + row, position(k) = k_offset + col. Offsets
         may be traced scalars (device-varying under shard_map) — this is what
@@ -1059,10 +1254,18 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None,
     """
     orig_dtype = q.dtype
     b, h, sq, d = q.shape
+    dv = v.shape[3]
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(d)
     if kv_len is None:
         kv_len = k.shape[2]
+    if window is not None and not causal:
+        raise ValueError("flash_attention: a window needs causal=True")
+    window = _effective_window(window, sq, q_offset, k_offset)
+    if h % k.shape[1] or k.shape[:3] != v.shape[:3]:
+        raise ValueError(
+            f"flash_attention: {h} query heads over K/V of shapes "
+            f"{k.shape}, {v.shape}")
     if dropout_seed is not None and dropout_mask is not None:
         raise ValueError(
             "flash_attention: pass dropout_mask OR dropout_seed, not both")
@@ -1072,6 +1275,11 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None,
         raise NotImplementedError(
             "flash_attention: dropout with with_lse is unsupported "
             "(ring/merged attention never uses attention dropout)")
+    if (has_dropout or with_lse) and (
+            window is not None or k.shape[1] != h or dv != d):
+        raise NotImplementedError(
+            "flash_attention: a window, grouped K/V heads and a value "
+            "of another width go with neither dropout nor with_lse")
     if dropout_seed is not None and dropout_rate > 0.0 and _interpret():
         raise NotImplementedError(
             "flash_attention: dropout_seed needs the on-chip prng "
@@ -1085,12 +1293,13 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None,
         return reference_attention(
             q, k, v, causal=causal, sm_scale=sm_scale, q_offset=q_offset,
             k_offset=k_offset, kv_len=kv_len, with_lse=with_lse,
-            dropout_mask=dropout_mask, dropout_rate=dropout_rate)
+            dropout_mask=dropout_mask, dropout_rate=dropout_rate,
+            window=window)
     qp, kp, vp, dims, bq, bk = _prepare(q, k, v, block_q, block_k)
     if all(isinstance(x, (int, np.integer))
            for x in (q_offset, k_offset, kv_len)):
         _publish_subtiles(sq, k.shape[2], bq, bk, bool(causal), q_offset,
-                          k_offset, kv_len, qp.shape[2])
+                          k_offset, kv_len, qp.shape[2], window)
     lens = jnp.asarray([q_offset, k_offset, kv_len], jnp.int32)
     if has_dropout and dropout_seed is not None:
         lens4 = jnp.concatenate(
@@ -1111,19 +1320,23 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None,
         o = o[:, :sq, :d].reshape(b, h, sq, d).astype(orig_dtype)
         lse = lse[:, :sq].reshape(b, h, sq)
         return o, lse
-    o = _flash(qp, kp, vp, lens, float(sm_scale), bool(causal), bq, bk)
-    return o[:, :sq, :d].reshape(b, h, sq, d).astype(orig_dtype)
+    o = _flash(qp, kp, vp, lens, float(sm_scale), bool(causal), bq, bk,
+               window)
+    return o[:, :sq, :dv].reshape(b, h, sq, dv).astype(orig_dtype)
 
 
 def reference_attention(q, k, v, *, causal=False, sm_scale=None,
                         q_offset=0, k_offset=0, kv_len=None,
                         with_lse=False, dropout_mask=None,
-                        dropout_rate=0.0):
+                        dropout_rate=0.0, window=None):
     """Plain einsum attention with the same masking semantics — the
     correctness oracle for the kernel tests and the shard_map-on-CPU
-    fallback. Offsets may be traced scalars."""
+    fallback. Offsets may be traced scalars. Grouped K/V heads are
+    repeated, the window is a mask."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
+    if k.shape[1] != h:
+        k, v = (jnp.repeat(x, h // x.shape[1], axis=1) for x in (k, v))
     if sm_scale is None:
         sm_scale = 1.0 / np.sqrt(d)
     if kv_len is None:
@@ -1135,6 +1348,9 @@ def reference_attention(q, k, v, *, causal=False, sm_scale=None,
     if causal:
         rows = q_offset + jnp.arange(sq)
         cmask = rows[:, None] >= (k_offset + cols)[None, :]
+        if window is not None:
+            cmask = jnp.logical_and(
+                cmask, rows[:, None] - (k_offset + cols)[None, :] < window)
         mask = jnp.logical_and(mask, cmask[None, None])
     mask = jnp.broadcast_to(mask, s.shape)
     s = jnp.where(mask, s, _NEG_INF)
