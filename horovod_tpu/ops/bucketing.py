@@ -21,6 +21,16 @@ for exactly that reason: leaf trees flatten roughly first-layer-first,
 so reversing approximates gradient-availability order and the first
 bucket issued is the first one ready.
 
+Read from the compiled schedule for a TPU v5e 2x2 (PERF.md section 6,
+PR 35): by itself this hides nothing there. XLA's all-reduce combiner
+merges the buckets again into tuple all-reduces, synchronous and after
+the last backward kernel. What keeps all-reduces apart, and
+asynchronous, is ``horovod_tpu.jax._OVERLAP_OPTIONS``, which
+``make_train_step`` passes on a TPU mesh of more than one chip for the
+per-leaf exchange, which then overlaps without this module's two
+copies; with ``HVDTPU_OVERLAP`` on, the step keeps the program it had
+(docs/performance.md).
+
 Numerics: splitting an elementwise collective (psum/pmean) into
 per-bucket concatenated calls performs the identical per-element
 cross-replica reduction, so the bucketed path is bit-identical to the
