@@ -10,6 +10,14 @@ Public API shape follows the reference's per-framework modules
 (reference: horovod/torch/mpi_ops.py, horovod/common/basics.py).
 """
 
+import time as _time
+_T0 = _time.perf_counter()      # first line: what ran before is not ours
+
+# The start-up log (docs/tracing.md "From the process's start to the
+# first step"): this package's own import is its first span.
+from .utils import compile_cache as _startup
+_startup.before_program(_T0)
+
 from .version import __version__  # noqa: F401
 
 from .basics import (  # noqa: F401
@@ -99,3 +107,6 @@ def stop_timeline():
     if rt.timeline is not None:
         rt.timeline.stop()
         rt.timeline = None
+
+
+_startup.imported(__name__, _T0)

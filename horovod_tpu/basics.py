@@ -26,6 +26,7 @@ execution modes:
 
 import atexit
 import threading
+import time
 
 import jax
 import numpy as np
@@ -142,6 +143,7 @@ def init(comm=None, process_sets=None):
             ps_mod._setup(_runtime, process_sets or [])
             return _runtime
 
+        started = time.perf_counter()
         from .utils import compile_cache
         compile_cache.listen()
 
@@ -237,6 +239,16 @@ def init(comm=None, process_sets=None):
                     addr, port, token, topology.rank,
                     interval_s=envparse.get_float(
                         envparse.METRICS_PUSH_INTERVAL, 5.0)).start()
+
+        # The start-up log (docs/tracing.md): this init is one span, and
+        # the first of a process says where the time before it went.
+        first = not compile_cache.startup_seconds()["init"]
+        compile_cache.record("init", compile_cache.PACKAGE, started,
+                             time.perf_counter())
+        if first:
+            log.info("init: start-up so far: %s", ", ".join(
+                f"{stage} {seconds:.2f} s" for stage, seconds
+                in compile_cache.startup_seconds().items() if seconds))
 
         _runtime = runtime
         return _runtime

@@ -22,6 +22,9 @@ TPU:
    backend).
 """
 
+import time as _time
+_T0 = _time.perf_counter()      # first line: the start-up log's span
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -35,6 +38,7 @@ from ..ops import reduce_ops
 from ..ops.adasum import adasum_axis
 from ..ops.compression import Compression
 from ..process_sets import global_process_set
+from ..utils import compile_cache as _startup
 from ..utils.jax_compat import pvary as _pvary
 from .schedule import (exchange_schedule,  # noqa: F401  (re-exported)
                        publish_exchange_schedule)
@@ -50,7 +54,7 @@ HVD_AXIS = "hvd"
 # cross-replica reduction, packing and unpacking included) and
 # ``SCOPE_OPTIMIZER`` (the inner update and its application). Readers
 # of a device trace (benchmark/scope_reduce.py) match these literals.
-STEP_NAME = "hvd_train_step"
+STEP_NAME = _startup.STEP_NAME      # the owner of its compile spans
 SCOPE_GRAD = "hvd_grad"
 SCOPE_EXCHANGE = "hvd_exchange"
 SCOPE_OPTIMIZER = "hvd_optimizer"
@@ -941,3 +945,6 @@ def make_zero_train_step(loss_fn, dist_opt, mesh=None,
             mesh=mesh, axis_name=axis_name).init_state(params)
 
     return step, init_state
+
+
+_startup.imported(__name__, _T0)

@@ -7,6 +7,9 @@ All models are flax.linen modules designed TPU-first: channels-last,
 bfloat16-friendly, static shapes.
 """
 
+import time as _time
+_T0 = _time.perf_counter()      # first line: the start-up log's span
+
 from .mlp import MLP, MnistCNN  # noqa: F401
 from .resnet import ResNet50, ResNet18, ResNet101  # noqa: F401
 from .transformer import (  # noqa: F401
@@ -14,3 +17,6 @@ from .transformer import (  # noqa: F401
     looped_lm_loss, publish_exit_shares,
 )
 from .ssm import SSMConfig  # noqa: F401
+
+from ..utils import compile_cache as _startup
+_startup.imported(__name__, _T0)

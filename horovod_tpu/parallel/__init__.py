@@ -17,6 +17,9 @@ mesh axis, every data exchange is an XLA collective over ICI.
                    expert-parallel layer; grouped products, shared expert)
 """
 
+import time as _time
+_T0 = _time.perf_counter()      # first line: the start-up log's span
+
 from .mesh import MeshConfig, make_mesh  # noqa: F401
 from .ring_attention import ring_attention  # noqa: F401
 from .ulysses import ulysses_attention  # noqa: F401
@@ -26,3 +29,6 @@ from .sharding import (  # noqa: F401
 )
 from .pipeline import pipeline_apply  # noqa: F401
 from .moe import MoELayer, moe_apply  # noqa: F401
+
+from ..utils import compile_cache as _startup
+_startup.imported(__name__, _T0)

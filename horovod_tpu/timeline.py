@@ -14,7 +14,7 @@ import queue
 import threading
 import time
 
-from .utils import envparse
+from .utils import compile_cache, envparse
 
 
 class Timeline:
@@ -63,6 +63,13 @@ class Timeline:
                              ts_us if ts_us is not None
                              else time.perf_counter_ns() // 1000))
 
+    def span(self, name, owner, start, end):
+        """A span that has ended, in seconds on this file's clock: the
+        start-up log's (``compile_cache.follow``), one row an owner."""
+        if self._running:
+            self._queue.put(("X", (owner,), name, int(start * 1e6),
+                             int((end - start) * 1e6)))
+
     def _shard_path(self):
         """Elastic runs restart the timeline after every reset with the
         SAME configured path (basics.init reads one env knob), which
@@ -94,6 +101,9 @@ class Timeline:
                                         args=(self._file, self._queue),
                                         name="hvd-tpu-timeline", daemon=True)
         self._thread.start()
+        # The spans already in the start-up log, and later ones as they
+        # arrive: the trace begins at the process's start.
+        compile_cache.follow(self.span)
         if self._jax_profiler_dir:
             try:
                 import jax
@@ -105,6 +115,7 @@ class Timeline:
     def stop(self):
         if not self._running:
             return
+        compile_cache.unfollow(self.span)
         self._running = False
         if self._jax_profiling:
             try:
@@ -134,10 +145,15 @@ class Timeline:
         file.write(json.dumps(event))
 
     def _emit_item(self, file, item, first, pids):
-        phase, names, activity, ts_us = item
+        phase, names, activity, ts_us, *dur_us = item
         for name in names:
             tid = pids.setdefault(name, len(pids) + 1)
-            if phase == "I":
+            if phase == "X":
+                self._emit(file, {"name": activity, "cat": "hvd_startup",
+                                  "ph": "X", "ts": ts_us, "dur": dur_us[0],
+                                  "pid": 0, "tid": tid,
+                                  "args": {"owner": name}}, first)
+            elif phase == "I":
                 self._emit(file, {"name": activity, "ph": "i",
                                   "ts": ts_us, "pid": 0, "tid": tid,
                                   "s": "g"}, first)

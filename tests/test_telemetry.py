@@ -406,6 +406,14 @@ def test_cli_usage_errors():
 # ==========================================================================
 # Timeline satellites: flush once per drain, race-free stop
 # ==========================================================================
+def _own_events(path):
+    """The events the test wrote: the start-up log's spans, which every
+    timeline begins with (category ``hvd_startup``, docs/tracing.md),
+    are ``tests/test_startup_log.py``'s."""
+    return [e for e in json.loads(path.read_text())
+            if e.get("cat") != "hvd_startup"]
+
+
 def test_timeline_flushes_once_per_drain(tmp_path):
     from horovod_tpu.timeline import Timeline
     path = tmp_path / "trace.json"
@@ -434,7 +442,7 @@ def test_timeline_flushes_once_per_drain(tmp_path):
         tl.marker(f"m{i}")
     hold.set()
     tl.stop()
-    events = json.loads(path.read_text())
+    events = _own_events(path)
     assert len(events) == 100
     # One drain (plus at most a straggler) — not one flush per event.
     assert flushes[0] <= 3, flushes[0]
@@ -465,7 +473,7 @@ def test_timeline_stop_race_free_when_join_times_out(tmp_path):
     hold.set()
     real_thread.join(5)
     assert tl._file.closed
-    events = json.loads(path.read_text())
+    events = _own_events(path)
     assert [e["name"] for e in events] == ["m0"]
 
 
@@ -500,7 +508,7 @@ def test_timeline_restart_while_old_writer_straggles(tmp_path):
     hold.set()           # let the straggler finish its own session
     old_thread.join(5)
     tl.stop()
-    old_events = json.loads(old_path.read_text())
+    old_events = _own_events(old_path)
     assert [e["name"] for e in old_events] == ["old0"]
-    new_events = json.loads((tmp_path / "new.json").read_text())
+    new_events = _own_events(tmp_path / "new.json")
     assert [e["name"] for e in new_events] == ["new0", "new1", "new2"]
