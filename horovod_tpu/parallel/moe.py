@@ -15,24 +15,41 @@ is not here: ROADMAP B3.
 How it computes, and why (PERF.md section 3, "expert layer"):
 
 - Routing (scope ``hvd_moe/route``, which also holds the sort, the
-  gathers and the weighted sum below): scores ``sigmoid(x W_r)`` in
-  float32 at ``highest`` precision, the chosen set the top-k of
+  gathers, the weighing and the way back into token order below):
+  scores ``sigmoid(x W_r)`` in float32 at ``highest`` precision, the
+  chosen set the top-k of
   ``scores + bias`` (the bias selects and takes no part in the weights
   nor any gradient), weights ``scale * s_i / (sum of the chosen s +
   1e-20)`` over all k chosen experts, held here or not.
 - Dispatch is dropless: the ``(token, choice)`` pairs are sorted by
   expert, pairs of experts held elsewhere last; no capacity, no token
-  dropped. The buffer has a row for every pair (a static shape must
-  cover every token choosing experts held here); rows past the held
-  pairs are zero and the grouped product skips their tiles.
+  dropped. A chip that holds ``held`` of ``experts`` experts draws about
+  ``held / experts`` of the pairs, so the routed part works on buffers
+  of ``sized_rows`` rows, twice that expectation (a static shape, from
+  shapes alone), whenever the step's own draw fits in them: only the
+  first rows of the sorted order are gathered, multiplied, weighed and
+  added back into their tokens (``_sized``). A draw that does not fit
+  takes ``_routed``, the same computation on a row for every pair,
+  chosen on the device by ``lax.cond`` on the count the router already
+  makes; with every expert held there is one path and no conditional.
+  Rows past the held pairs are zero and the grouped product skips their
+  tiles.
 - The experts' products (scope ``hvd_moe/experts``) are
   ``jax.lax.ragged_dot`` over the groups: XLA's own grouped Mosaic
-  kernel on the TPU, with both gradients. The buffers are worst-case
-  sized and seven eighths empty at 8 of 64 experts, so they are not
-  kept for the backward pass: the routed part is recomputed there
-  (``jax.checkpoint``; 1.3% of the step's required FLOPs).
-- Gather and un-gather are a permutation and its inverse, each the
-  other's transpose, so neither direction scatters.
+  kernel on the TPU, with both gradients. The routed part keeps
+  nothing for the way back and is made again there: where there are
+  two sizes, by a backward pass of its own (``_sized_or_routed``) that
+  chooses again and differentiates only the branch taken, since the
+  derivative of one conditional, or one ``jax.checkpoint`` round it,
+  keeps the union of its branches' residuals, the full-size buffers
+  among them. Forward and backward each go through ``jax.jit``, so a
+  model's layers share one trace and one lowering of the two sizes.
+- ``_sized`` adds its rows into the tokens by a scatter-add of
+  ``sized_rows`` rows, whose transpose is a gather (and the gather's a
+  scatter-add): on the chip faster than un-gathering by token through a
+  ``[T, k, d]`` buffer (PERF.md section 6, PR 37). In ``_routed`` gather
+  and un-gather are a permutation and its inverse, each the other's
+  transpose, so neither direction scatters a row for every pair.
 """
 
 import dataclasses
@@ -160,6 +177,139 @@ def _routed(x, w_gate, w_up, w_down, chosen, weights, drawn, first_held):
                           weights.astype(ys.dtype))
 
 
+# Rows of the routed part's buffers, in expected draws of the experts
+# held. One layer's draw swings widely from step to step: 0.43 to 1.90
+# of the expectation over 760 readings in ``glm47flash-seq4096-1chip``
+# (PERF.md section 7). A draw over the rows costs that layer the
+# full-size program for that step, never a token; 1.5 would send one
+# step in five there, and every row costs time whether it is drawn or
+# not.
+_ROWS_PER_EXPECTED = 2
+_ROW_TILE = 512     # rows are a multiple of the grouped kernel's tile
+
+
+def sized_rows(pairs, held, experts):
+    """Rows the routed part's buffers get for ``pairs`` (token, choice)
+    pairs where ``held`` of the router's ``experts`` are held: from
+    shapes alone. ``pairs`` itself when that is no fewer."""
+    rows = -(-_ROWS_PER_EXPECTED * pairs * held // experts)
+    return min(pairs, -(-rows // _ROW_TILE) * _ROW_TILE)
+
+
+def took_sized_path(drawn, first, end):
+    """Whether a layer whose experts drew ``drawn`` tokens (E,), of
+    which ``[first, end)`` are held, ran on ``sized_rows`` rows: the
+    test ``moe_apply`` makes on the device, from the counts a step
+    returns."""
+    pairs = round(float(drawn.sum()))       # every token draws k experts
+    rows = sized_rows(pairs, end - first, len(drawn))
+    return rows < pairs and float(drawn[first:end].sum()) <= rows
+
+
+def _sized(rows, x, w_gate, w_up, w_down, chosen, weights, drawn,
+           first_held):
+    """``_routed`` for a draw of at most ``rows`` pairs: the first
+    ``rows`` pairs of the sorted order hold every pair of the experts
+    held, so only they are gathered, multiplied and added back."""
+    k = chosen.shape[1]
+    held = w_gate.shape[0]
+    with jax.named_scope(SCOPE_ROUTE):
+        local = chosen.reshape(-1) - first_held
+        key = jnp.where((local >= 0) & (local < held), local, held)
+        order = _vary_like(jnp.argsort(key, stable=True), key)[:rows]
+        sizes = lax.dynamic_slice(drawn, (first_held,), (held,)).astype(
+            jnp.int32)
+        live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+        token = order // k
+        xs = jnp.where(live, x[token], 0)
+    with jax.named_scope(SCOPE_EXPERTS):
+        def product(a, w):
+            return lax.ragged_dot(a, w.astype(a.dtype), sizes)
+        ys = product(nn.silu(product(xs, w_gate)) * product(xs, w_up),
+                     w_down)
+    with jax.named_scope(SCOPE_ROUTE):
+        # Every live row is a pair of an expert held here: weigh it in
+        # sorted order and add it into its token's row.
+        weight = weights.reshape(-1)[order].astype(ys.dtype)[:, None]
+        return jnp.zeros_like(x).at[token].add(
+            jnp.where(live, ys, 0) * weight)
+
+
+def _fits(rows, routed):
+    _, w_gate, _, _, _, _, drawn, first_held = routed
+    return jnp.sum(lax.dynamic_slice(
+        drawn, (first_held,), (w_gate.shape[0],))) <= rows
+
+
+_TRAINED = (0, 1, 2, 3, 5)      # of ``routed``: x, the three w, weights
+
+
+def _pull(path):
+    """``path``'s backward pass with nothing kept: ``path`` made again
+    and ``g`` pulled back to its trained arguments."""
+    def pull(g, *routed):
+        def of(*trained):
+            args = list(routed)
+            for i, a in zip(_TRAINED, trained):
+                args[i] = a
+            return path(*args)
+        return jax.vjp(of, *(routed[i] for i in _TRAINED))[1](g)
+    return pull
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _either(rows, *routed):
+    with jax.named_scope(SCOPE):
+        return lax.cond(_fits(rows, routed),
+                        functools.partial(_sized, rows), _routed, *routed)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _either_back(rows, g, *routed):
+    with jax.named_scope(SCOPE):
+        return lax.cond(_fits(rows, routed),
+                        _pull(functools.partial(_sized, rows)),
+                        _pull(_routed), g, *routed)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _sized_or_routed(rows, x, w_gate, w_up, w_down, chosen, weights, drawn,
+                     first_held):
+    """``_sized`` where the held experts' draw fits in ``rows`` rows,
+    else ``_routed``, and nothing kept for the way back: the branch
+    taken is made again there and pulled back inside a conditional of
+    its own (differentiating one conditional would keep the union of
+    its branches' residuals, the full-size buffers among them). Both
+    ways go through ``jax.jit``, so the layers of a model share one
+    trace and one lowering of each, under each layer's own names.
+    They open ``hvd_moe`` themselves and are called outside it: XLA
+    names the grouped kernels of a jitted function for its call site
+    alone, and a reader of a trace (``benchmark/scope_sum.py``) files a
+    grouped kernel under ``hvd_moe/experts`` when its name holds no
+    scope of this module, as it did before there was a ``jax.jit``."""
+    return _either(rows, x, w_gate, w_up, w_down, chosen, weights, drawn,
+                   first_held)
+
+
+def _sized_or_routed_bwd(rows, routed, g):
+    pulled = dict(zip(_TRAINED, _either_back(rows, g, *routed)))
+    return tuple(pulled.get(i) for i in range(len(routed)))
+
+
+_sized_or_routed.defvjp(
+    lambda rows, *routed: (_either(rows, *routed), routed),
+    _sized_or_routed_bwd)
+
+
+def _vary_together(*xs):
+    """``xs``, each marked varying over every mesh axis that any of
+    them varies over (inside ``shard_map``): a hand-written backward
+    pass hands each its cotangent as varying as the result."""
+    axes = frozenset().union(*(jax.typeof(x).vma for x in xs))
+    return [functools.reduce(pvary, sorted(axes - jax.typeof(x).vma), x)
+            for x in xs]
+
+
 def moe_apply(x, params, bias, *, k, scale=1.0, first_held=0):
     """The expert layer on tokens ``x`` (T, d). Returns ``(y, drawn)``:
     this share of the layer's output and the tokens each of the model's
@@ -171,18 +321,24 @@ def moe_apply(x, params, bias, *, k, scale=1.0, first_held=0):
     traced, e.g. from ``lax.axis_index``); optionally ``shared_gate``,
     ``shared_up`` (d, fs), ``shared_down`` (fs, d). ``bias`` (E,): the
     selection bias, a buffer."""
-    with jax.named_scope(SCOPE):
-        with jax.named_scope(SCOPE_ROUTE):
-            chosen, weights, drawn = route(x, params["router"], bias,
-                                           k=k, scale=scale)
-        y = jax.checkpoint(_routed)(
-            x, params["w_gate"], params["w_up"], params["w_down"], chosen,
-            weights, drawn, first_held)
-        if "shared_gate" in params:
-            with jax.named_scope(SCOPE_EXPERTS):
-                y = y + swiglu(x, params["shared_gate"],
-                               params["shared_up"], params["shared_down"])
-        return y, drawn
+    pairs = x.shape[0] * k
+    rows = sized_rows(pairs, params["w_gate"].shape[0],
+                      params["router"].shape[1])
+    with jax.named_scope(SCOPE), jax.named_scope(SCOPE_ROUTE):
+        chosen, weights, drawn = route(x, params["router"], bias, k=k,
+                                       scale=scale)
+    routed = (x, params["w_gate"], params["w_up"], params["w_down"],
+              chosen, weights, drawn, first_held)
+    if rows == pairs:
+        with jax.named_scope(SCOPE):
+            y = jax.checkpoint(_routed)(*routed)
+    else:
+        y = _sized_or_routed(rows, *_vary_together(*routed))
+    if "shared_gate" in params:
+        with jax.named_scope(SCOPE), jax.named_scope(SCOPE_EXPERTS):
+            y = y + swiglu(x, params["shared_gate"], params["shared_up"],
+                           params["shared_down"])
+    return y, drawn
 
 
 class MoELayer(nn.Module):
@@ -227,8 +383,9 @@ class MoELayer(nn.Module):
 
 
 def publish_expert_tokens(state, held=None):
-    """Set ``hvd_moe_expert_tokens{layer,expert}`` and
-    ``hvd_moe_held_share`` from the ``moe_state`` collection a train
+    """Set ``hvd_moe_expert_tokens{layer,expert}``,
+    ``hvd_moe_held_share``, ``hvd_moe_buffer_rows{layer}`` and
+    ``hvd_moe_sized_layers`` from the ``moe_state`` collection a train
     step returned. Call it outside the step; it fetches the arrays. A
     no-op when ``HOROVOD_TPU_METRICS`` is off."""
     from ..telemetry import core as telemetry
@@ -243,7 +400,17 @@ def publish_expert_tokens(state, held=None):
         "hvd_moe_held_share",
         "Share of the last step's (token, choice) pairs that went to "
         "experts held on this chip")
+    buffer_rows = telemetry.gauge(
+        "hvd_moe_buffer_rows",
+        "Rows of the routed part's buffers when the held draw fits in "
+        "them (sized_rows: from shapes alone)", ("layer",))
+    sized = telemetry.gauge(
+        "hvd_moe_sized_layers",
+        "Expert layers whose held draw of the last step fitted in "
+        "hvd_moe_buffer_rows rows, fewer than a row for every pair: "
+        "they ran on buffers of that size")
     mine = total = 0.0
+    fitted = 0
     for path, drawn in jax.tree_util.tree_leaves_with_path(state):
         if getattr(path[-1], "key", None) != "expert_tokens":
             continue
@@ -252,7 +419,11 @@ def publish_expert_tokens(state, held=None):
         for expert, n in enumerate(drawn):
             tokens.labels(layer=layer, expert=expert).set(float(n))
         first, end = held or (0, len(drawn))
+        buffer_rows.labels(layer=layer).set(sized_rows(
+            round(float(drawn.sum())), end - first, len(drawn)))
+        fitted += took_sized_path(drawn, first, end)
         mine += float(drawn[first:end].sum())
         total += float(drawn.sum())
     if total:
         share.set(mine / total)
+        sized.set(fitted)

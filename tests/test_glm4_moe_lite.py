@@ -261,6 +261,32 @@ def test_expert_tokens_reach_the_telemetry_plane(monkeypatch):
     assert families["hvd_moe_held_share"]["samples"][0]["value"] == 0.5
 
 
+def test_buffer_rows_and_sized_layers_reach_the_telemetry_plane(monkeypatch):
+    """1024 pairs over 8 experts of which 2 are held: 512 rows. The
+    first layer's held draw (400) fits in them, the second's (700) does
+    not and ran on a row for every pair."""
+    from horovod_tpu.telemetry import core as telemetry
+    monkeypatch.setattr(telemetry, "_ENABLED", True)
+    fits = jnp.asarray([150.0, 250, 104, 104, 104, 104, 104, 104])
+    over = jnp.asarray([300.0, 400, 54, 54, 54, 54, 54, 54])
+    state = {"moe_state": {
+        "block_1": {"moe": {"bias": jnp.zeros((8,)), "expert_tokens": fits}},
+        "block_2": {"moe": {"bias": jnp.zeros((8,)),
+                            "expert_tokens": over}}}}
+    moe.publish_expert_tokens(state, held=(0, 2))
+    families = telemetry.snapshot()["families"]
+    assert {s["labels"]["layer"]: s["value"]
+            for s in families["hvd_moe_buffer_rows"]["samples"]} == {
+        "moe_state/block_1/moe": 512.0, "moe_state/block_2/moe": 512.0}
+    assert families["hvd_moe_sized_layers"]["samples"][0]["value"] == 1.0
+    # Every expert held: one path, buffers of a row for every pair.
+    moe.publish_expert_tokens(state)
+    families = telemetry.snapshot()["families"]
+    assert {s["value"] for s in
+            families["hvd_moe_buffer_rows"]["samples"]} == {1024.0}
+    assert families["hvd_moe_sized_layers"]["samples"][0]["value"] == 0.0
+
+
 def test_publishing_is_a_no_op_with_metrics_off(monkeypatch):
     from horovod_tpu.telemetry import core as telemetry
     monkeypatch.setattr(telemetry, "_ENABLED", False)

@@ -17,6 +17,7 @@ from horovod_tpu.ops.flash_attention import reference_attention
 from horovod_tpu.parallel import (
     MeshConfig, make_mesh, moe_apply, pipeline_apply, ring_attention,
     ulysses_attention)
+from horovod_tpu.parallel import moe as moe_lib
 from horovod_tpu.parallel.pipeline import stack_stage_params
 
 
@@ -289,13 +290,14 @@ def _moe_layer(tokens=64, d=16, f=32, e=8):
     return _rand((tokens, d), 0), params, bias
 
 
-def _moe_dense_oracle(x, params, bias, k, scale):
-    """Every expert on every token, weighted by the token's weight for
-    it or by 0: no sort, no grouped product."""
+def _moe_dense_oracle(x, params, bias, k, scale, first_held=0):
+    """Every expert held on every token, weighted by the token's weight
+    for it or by 0: no sort, no grouped product."""
     scores = jax.nn.sigmoid(x @ params["router"])
     _, chosen = jax.lax.top_k(scores + bias, k)
     picked = scores * jax.nn.one_hot(chosen, scores.shape[-1]).sum(-2)
     weights = scale * picked / picked.sum(-1, keepdims=True)
+    weights = weights[:, first_held:first_held + params["w_gate"].shape[0]]
     h = jax.nn.silu(jnp.einsum("td,edf->etf", x, params["w_gate"]))
     h = h * jnp.einsum("td,edf->etf", x, params["w_up"])
     return jnp.einsum("etf,efd,te->td", h, params["w_down"], weights)
@@ -331,15 +333,20 @@ def test_moe_gradients_match_dense_oracle():
                                    atol=2e-4, rtol=2e-3)
 
 
-def test_moe_shares_over_a_mesh_add_up():
+@pytest.mark.parametrize("tokens", [64, 512], ids=["full", "sized"])
+def test_moe_shares_over_a_mesh_add_up(tokens):
     """Four chips hold two experts each and see the same tokens: every
     chip routes over all eight, computes its own experts' part, and the
     parts sum (psum) to the single-program layer, gradients included.
+    At 512 tokens each chip's buffers are sized to its draw and the
+    choice of the path varies with the chip (``first_held`` is traced).
     No token exchange: that layout (each chip its own tokens, an
     all-to-all both ways) is ROADMAP B3."""
     n = 4
     mesh = Mesh(np.array(jax.devices()[:n]), ("ep",))
-    x, params, bias = _moe_layer()
+    x, params, bias = _moe_layer(tokens)
+    assert (moe_lib.sized_rows(tokens * 2, 2, 8) < tokens * 2) == (
+        tokens == 512)
 
     def share(x, params):
         first = jax.lax.axis_index("ep") * params["w_gate"].shape[0]
@@ -377,6 +384,162 @@ def test_moe_uneven_routing_drops_nothing():
     np.testing.assert_allclose(
         np.asarray(y), np.asarray(_moe_dense_oracle(x, params, bias, 2, 1.0)),
         atol=2e-5, rtol=2e-4)
+
+
+# The routed part on buffers sized to the draw (held experts 2 and 3 of
+# 8, 512 tokens, k = 2: 512 rows for 1024 pairs) and its fallback.
+
+_HELD = 2       # first_held; two experts held
+
+
+def _moe_share(tokens=512):
+    x, params, bias = _moe_layer(tokens)
+    share = {name: w if name == "router" else w[_HELD:_HELD + 2]
+             for name, w in params.items()}
+    return x, share, bias
+
+
+@pytest.fixture
+def poison(monkeypatch):
+    """``poison(path)`` makes ``path`` (``_sized`` or ``_routed``)
+    return NaN: a result that is finite did not come through it. The
+    two are traced under ``jax.jit``, whose traces are dropped before
+    and after."""
+    def make(path):
+        moe_lib._either.clear_cache()
+        monkeypatch.setattr(moe_lib, path,
+                            lambda *args: jnp.full_like(args[-8], jnp.nan))
+    yield make
+    moe_lib._either.clear_cache()
+
+
+def _share_apply(x, share, bias):
+    return moe_apply(x, share, bias, k=2, scale=1.8, first_held=_HELD)
+
+
+def test_moe_sized_path_matches_dense_oracle(poison):
+    x, share, bias = _moe_share()
+    poison("_routed")
+    y, drawn = _share_apply(x, share, bias)
+    assert moe_lib.sized_rows(1024, 2, 8) == 512
+    assert moe_lib.took_sized_path(np.asarray(drawn), _HELD, _HELD + 2)
+    np.testing.assert_allclose(
+        np.asarray(y),
+        np.asarray(_moe_dense_oracle(x, share, bias, 2, 1.8, _HELD)),
+        atol=2e-5, rtol=2e-4)
+
+
+@pytest.mark.parametrize("against", ["oracle", "routed"])
+def test_moe_sized_path_gradients(against):
+    """Tokens, router and the three expert matrices through the sized
+    path against the dense oracle and against ``_routed`` on a row for
+    every pair."""
+    x, share, bias = _moe_share()
+
+    def full(x, p):
+        chosen, weights, drawn = moe_lib.route(x, p["router"], bias, k=2,
+                                               scale=1.8)
+        return moe_lib._routed(x, p["w_gate"], p["w_up"], p["w_down"],
+                               chosen, weights, drawn, _HELD)
+
+    other = full if against == "routed" else (
+        lambda x, p: _moe_dense_oracle(x, p, bias, 2, 1.8, _HELD))
+    np.testing.assert_allclose(
+        np.asarray(_share_apply(x, share, bias)[0]),
+        np.asarray(other(x, share)), atol=2e-5, rtol=2e-4)
+    got = jax.grad(lambda x, p: jnp.sum(_share_apply(x, p, bias)[0] ** 2),
+                   argnums=(0, 1))(x, share)
+    want = jax.grad(lambda x, p: jnp.sum(other(x, p) ** 2),
+                    argnums=(0, 1))(x, share)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-4, rtol=2e-3)
+
+
+def test_moe_draw_over_the_sized_rows_takes_the_fallback(poison):
+    # A bias that sends every token to the two held experts: 1024 pairs
+    # for 512 rows. The full-size program runs and drops nothing.
+    x, share, bias = _moe_share()
+    bias = bias.at[_HELD:_HELD + 2].add(10.0)
+    poison("_sized")
+    y, drawn = _share_apply(x, share, bias)
+    np.testing.assert_array_equal(np.asarray(drawn),
+                                  [0, 0, 512, 512, 0, 0, 0, 0])
+    assert not moe_lib.took_sized_path(np.asarray(drawn), _HELD, _HELD + 2)
+    np.testing.assert_allclose(
+        np.asarray(y),
+        np.asarray(_moe_dense_oracle(x, share, bias, 2, 1.8, _HELD)),
+        atol=2e-5, rtol=2e-4)
+    assert not np.any(np.all(np.asarray(y) == 0.0, axis=-1))
+
+
+@pytest.mark.parametrize("extra,path", [(0, "_sized"), (1, "_routed")],
+                         ids=["draw_equals_rows", "one_pair_over"])
+def test_moe_sized_path_boundary(poison, extra, path):
+    """Every token picks expert 2 (held) and expert 0 (held elsewhere):
+    a draw of exactly the 512 rows, which fits; with one token that
+    picks expert 3 in place of 0 the draw is 513 and does not."""
+    x, share, bias = _moe_share()
+    x = x.at[:, 0].set(1.0).at[:, 1].set(0.0).at[0, 1].set(float(extra))
+    router = share["router"].at[:2].set(0.0)
+    router = router.at[0, 2].set(10.0).at[0, 0].set(5.0)
+    router = router.at[0, 3].set(-5.0).at[1, 3].set(20.0)
+    share = {**share, "router": router}
+    bias = jnp.zeros_like(bias)
+    poison({"_sized": "_routed", "_routed": "_sized"}[path])
+    y, drawn = _share_apply(x, share, bias)
+    assert float(drawn[_HELD:_HELD + 2].sum()) == 512 + extra
+    assert moe_lib.took_sized_path(np.asarray(drawn), _HELD,
+                                   _HELD + 2) == (path == "_sized")
+    np.testing.assert_allclose(
+        np.asarray(y),
+        np.asarray(_moe_dense_oracle(x, share, bias, 2, 1.8, _HELD)),
+        atol=2e-5, rtol=2e-4)
+
+
+def _all_shapes(jaxpr):
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            yield tuple(getattr(var.aval, "shape", ()))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _all_shapes(sub)
+
+
+def test_moe_every_expert_held_traces_no_conditional():
+    x, params, bias = _moe_layer(512)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda x, p: jnp.sum(moe_apply(x, p, bias, k=2)[0])))(x, params))
+    assert "cond[" not in text
+    x, share, bias = _moe_share()
+    text = str(jax.make_jaxpr(
+        lambda x, p: _share_apply(x, p, bias)[0])(x, share))
+    assert text.count("cond[") == 1
+
+
+def test_moe_sized_branch_holds_no_row_for_every_pair():
+    """At the shapes of ``glm47flash-seq4096-1chip`` (8192 tokens, k 4,
+    8 of 64 experts, hidden 2048, expert width 1536) nothing in the
+    sized branch, forward or backward, has 32768 rows of either width."""
+    tokens, k, d, f, held, experts = 8192, 4, 2048, 1536, 8, 64
+    rows = moe_lib.sized_rows(tokens * k, held, experts)
+    assert rows == 8192
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    shapes = (jax.ShapeDtypeStruct((tokens, d), bf16),
+              jax.ShapeDtypeStruct((held, d, f), f32),
+              jax.ShapeDtypeStruct((held, d, f), f32),
+              jax.ShapeDtypeStruct((held, f, d), f32),
+              jax.ShapeDtypeStruct((tokens, k), jnp.int32),
+              jax.ShapeDtypeStruct((tokens, k), f32),
+              jax.ShapeDtypeStruct((experts,), f32))
+
+    def loss(x, w_gate, w_up, w_down, chosen, weights, drawn):
+        return jnp.sum(moe_lib._sized(rows, x, w_gate, w_up, w_down, chosen,
+                                      weights, drawn, 0).astype(f32))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 5)))(*shapes)
+    seen = set(_all_shapes(jaxpr.jaxpr))
+    assert (rows, d) in seen and (rows, f) in seen
+    assert not {(tokens * k, d), (tokens * k, f)} & seen
 
 
 # -- GSPMD sharding rules --------------------------------------------------

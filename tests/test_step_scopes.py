@@ -146,6 +146,55 @@ def test_compiled_step_names_latent_attention_experts_and_mtp():
         assert any(scope in n and "transpose(" not in n for n in names)
 
 
+def test_each_expert_layers_two_sizes_carry_that_layers_names():
+    # Where a share of the experts is held the routed part is a
+    # conditional over two buffer sizes, traced and lowered once for
+    # all the layers (``jax.jit``); every layer's copy, forward and
+    # backward, still carries the names of the layer it runs in, so
+    # ``mtp_ms`` and the scope table file it where it belongs.
+    from horovod_tpu.models import TransformerConfig, TransformerLM
+    from horovod_tpu.models.transformer import MLAConfig
+    from horovod_tpu.parallel.moe import MoEConfig, sized_rows
+    mesh = Mesh(np.array(jax.devices()[:CHIPS]), ("hvd",))
+    model = TransformerLM(TransformerConfig(
+        vocab_size=64, hidden=64, layers=3, heads=2, max_len=512,
+        norm="rmsnorm", bias=False, mlp="swiglu", mlp_width=128,
+        mla=MLAConfig(24, 16, 24, 8, 32), mtp_layers=1,
+        moe=MoEConfig(experts=8, per_token=2, width=48, held=(0, 2))))
+    assert sized_rows(512 * 2, 2, 8) == 512
+    tokens = jnp.zeros((CHIPS, 512), jnp.int32)
+    variables = model.init(jax.random.PRNGKey(0), tokens,
+                           next_tokens=tokens)
+    params = {"params": variables["params"]}
+    aux = {"moe_state": variables["moe_state"]}
+
+    def loss_fn(p, aux, batch):
+        (main, mtp), aux = model.apply({**p, **aux}, batch[0],
+                                       next_tokens=batch[1],
+                                       mutable=list(aux))
+        return main.mean() + mtp.mean(), aux
+
+    opt = hvd_jax.DistributedOptimizer(optax.adam(1e-2))
+    step = hvd_jax.make_train_step(loss_fn, opt, mesh=mesh, has_aux=True,
+                                   donate=False)
+    text = step.lower(params, aux, opt.init(params),
+                      (tokens, tokens)).compile().as_text()
+    sites = {}
+    for name in re.findall(r'op_name="([^"]+)"', text):
+        site = re.search(r"block_\d/moe|hvd_mtp/mtp_0", name)
+        # (A reduction's own small computation is named without a site.)
+        if site and "hvd_moe/cond/" in name and "route" in name:
+            way = "bwd" if "transpose(" in name else "fwd"
+            sites.setdefault((site.group(0), way), set()).add(
+                re.search(r"branch_\d", name).group(0))
+    assert set(sites) == {
+        (site, way) for site in ("block_1/moe", "block_2/moe",
+                                 "hvd_mtp/mtp_0")
+        for way in ("fwd", "bwd")}
+    assert set(map(frozenset, sites.values())) == {
+        frozenset({"branch_0", "branch_1"})}
+
+
 def test_scope_names_are_the_documented_constants():
     from horovod_tpu.models import transformer
     from horovod_tpu.parallel import moe
