@@ -17,16 +17,21 @@ the blocks run ``passes`` times with the same weights, with norms on the
 sub-layers' outputs too (``sandwich_norm``) and an exit gate whose loss
 is ``looped_lm_loss``; or a stack with a mixer a layer (``mixers``: Mamba and
 gated memory units of ``models/ssm.py``, windowed, full and cross
-differential attention with grouped K/V heads), in which a layer may
+differential attention with grouped K/V heads, and plain softmax
+attention, full or under a window, each with rope or without
+positions: ``PLAIN``), in which a layer may
 read what an earlier layer made, and a head tied to the embedding.
 Hidden sizes are multiples of 128 for MXU tiling; the head
-dimension is ``hidden // heads``, 64 at BERT-large's widths (half of the
+dimension is ``head_dim`` where the configuration states one (28 heads
+of 128 on a hidden size of 2560) and else ``hidden // heads``, 64 at
+BERT-large's widths (half of the
 128 lanes, which the kernel and XLA's layouts pay for), and has to be
 even for rope. Sequence/tensor sharding is applied externally via
 horovod_tpu.parallel (logical axis annotations would over-couple the model
 to one partitioning).
 """
 
+import contextlib
 import dataclasses
 import functools
 from typing import Optional
@@ -43,6 +48,10 @@ from .ssm import GatedMemoryUnit, MambaMixer, SSMConfig
 # Names in a device trace (docs/tracing.md); readers match the literals.
 SCOPE_MLA = "hvd_mla"
 SCOPE_DIFF = "hvd_diff"     # what differential attention adds to the kernels
+# A plain attention layer of a mixed stack (``PLAIN``), by what its keys
+# are: every one before the query, or the window's.
+SCOPE_FULL = "hvd_attn_full"
+SCOPE_WINDOW = "hvd_attn_window"
 SCOPE_MTP = "hvd_mtp"
 SCOPE_LOOP = "hvd_loop"     # the stack of a looped model, all its passes
 SCOPE_EXIT = "hvd_exit"     # its exit gates, heads and the loss's mix
@@ -66,6 +75,7 @@ class TransformerConfig:
     hidden: int = 1024
     layers: int = 24
     heads: int = 16
+    head_dim: Optional[int] = None   # None: hidden // heads
     mlp_ratio: int = 4
     max_len: int = 512
     dtype: jnp.dtype = jnp.bfloat16
@@ -108,22 +118,33 @@ class TransformerConfig:
     # is plain attention. A layer may read what an earlier layer made: a
     # "gmu" layer the scan output of the last "mamba" layer before it, a
     # "cross" layer the K and V of the last "attention" layer before it.
-    # The attention kinds of such a stack are differential attention
-    # (Ye et al., arXiv:2410.05258; ``DiffAttention``).
+    # "attention", "window" and "cross" are differential attention (Ye et
+    # al., arXiv:2410.05258; ``DiffAttention``); the kinds of ``PLAIN``
+    # are plain softmax attention, which hands nothing on and takes its
+    # positions from its kind (``use_rope`` is for a stack without
+    # ``mixers``).
     mixers: Optional[tuple] = None
     # The layers' indices in the published model where the stack is a
     # cut of it (differential attention's lambda_init depends on depth).
     layer_indices: Optional[tuple] = None
-    window: Optional[int] = None     # of the "window" layers: keys seen
+    window: Optional[int] = None     # keys a "window" / "sliding" layer sees
     kv_heads: Optional[int] = None   # K/V heads (None: as many as heads)
     ssm: Optional[SSMConfig] = None  # the "mamba" layers' sizes
     mlp_bias: Optional[bool] = None  # None: as ``bias``
     positions: bool = True           # False: neither rope nor a table
     tie_embeddings: bool = False     # the head is the embedding's transpose
 
+    @property
+    def head_width(self):
+        return self.head_dim or self.hidden // self.heads
 
+
+# Plain softmax attention as a layer kind: (sees ``cfg.window`` keys
+# only, rotates q and k). Without rope such a layer has no positions.
+PLAIN = {"full": (False, False), "full_rope": (False, True),
+         "sliding": (True, False), "sliding_rope": (True, True)}
 # "window": attention that sees ``cfg.window`` keys and hands nothing on.
-MIXERS = ("attention", "window", "mamba", "gmu", "cross")
+MIXERS = ("attention", "window", "mamba", "gmu", "cross", *PLAIN)
 
 
 # BERT-large hyperparameters (the reference benchmark target).
@@ -238,7 +259,7 @@ def _qkv(cfg, x, name="qkv"):
     """q, k, v of ``x`` from one product: ``[.., 3, heads, head_dim]``
     features, or with fewer K/V heads ``[.., heads + 2 kv_heads,
     head_dim]`` (q's heads, then k's, then v's)."""
-    head_dim = cfg.hidden // cfg.heads
+    head_dim = cfg.head_width
     kv = cfg.kv_heads or cfg.heads
     if kv == cfg.heads:
         qkv = nn.DenseGeneral((3, cfg.heads, head_dim), dtype=cfg.dtype,
@@ -251,16 +272,25 @@ def _qkv(cfg, x, name="qkv"):
 
 
 class Attention(nn.Module):
+    """Softmax attention over ``cfg.heads`` query heads and
+    ``cfg.kv_heads`` K/V heads. ``kind``, one of ``PLAIN``, is for a
+    layer of a mixed stack: its window and its positions are its
+    kind's, and it runs under a scope that says which keys it sees."""
     cfg: TransformerConfig
+    kind: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, mask=None):
         cfg = self.cfg
+        sliding, rope = PLAIN.get(self.kind, (False, cfg.use_rope))
         q, k, v = _qkv(cfg, x)
         # (batch, seq, heads, head_dim) -> attention in einsum form.
-        if cfg.use_rope:
+        if rope:
             q, k = _rope(q, k, cfg.rope_theta)
-        out = _attend(cfg, q, k, v, mask)
+        scope = (contextlib.nullcontext() if self.kind is None else
+                 jax.named_scope(SCOPE_WINDOW if sliding else SCOPE_FULL))
+        with scope:
+            out = _attend(cfg, q, k, v, mask, cfg.window if sliding else None)
         return nn.DenseGeneral(cfg.hidden, axis=(-2, -1), dtype=cfg.dtype,
                                use_bias=cfg.bias, name="proj")(out)
 
@@ -290,7 +320,7 @@ class DiffAttention(nn.Module):
     @nn.compact
     def __call__(self, x, mask=None, shared=None):
         cfg = self.cfg
-        head_dim = cfg.hidden // cfg.heads
+        head_dim = cfg.head_width
         if self.cross:
             q = nn.DenseGeneral((cfg.heads, head_dim), dtype=cfg.dtype,
                                 use_bias=cfg.bias, name="q")(x)
@@ -372,6 +402,8 @@ class Block(nn.Module):
             a, made = MambaMixer(cfg, name="mamba")(h)
         elif self.mixer == "gmu":
             a = GatedMemoryUnit(cfg, name="gmu")(h, memory)
+        elif self.mixer in PLAIN:
+            a = Attention(cfg, kind=self.mixer, name="attn")(h, mask)
         elif self.mixer is not None:
             a, kv = DiffAttention(
                 cfg, depth=self.depth, cross=self.mixer == "cross",
@@ -382,9 +414,13 @@ class Block(nn.Module):
             attention = LatentAttention if cfg.mla else Attention
             a = attention(cfg, name="attn")(h, mask)
         x = x + out("ln1_out", a)
+        # A router placed before attention scores what attention read.
+        scores_from = h if (self.expert and cfg.moe.router_reads
+                            == "attention") else None
         h = _norm(cfg, "ln2")(x)
         if self.expert:
-            ffn = MoELayer(cfg.moe, dtype=cfg.dtype, name="moe")(h)
+            ffn = MoELayer(cfg.moe, dtype=cfg.dtype, name="moe")(
+                h, scores_from)
         else:
             width = cfg.mlp_width or cfg.hidden * cfg.mlp_ratio
             dense = functools.partial(

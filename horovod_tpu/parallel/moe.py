@@ -1,5 +1,5 @@
-"""Sparse experts: a dropless, sigmoid-routed expert layer that is told
-which experts it holds.
+"""Sparse experts: a dropless expert layer, routed by a sigmoid or a
+softmax, that is told which experts it holds.
 
 The layer routes every token over all the experts the model has (the
 router keeps its published width and its experts per token) and computes
@@ -16,11 +16,17 @@ How it computes, and why (PERF.md section 3, "expert layer"):
 
 - Routing (scope ``hvd_moe/route``, which also holds the sort, the
   gathers, the weighing and the way back into token order below):
-  scores ``sigmoid(x W_r)`` in float32 at ``highest`` precision, the
-  chosen set the top-k of
-  ``scores + bias`` (the bias selects and takes no part in the weights
-  nor any gradient), weights ``scale * s_i / (sum of the chosen s +
-  1e-20)`` over all k chosen experts, held here or not.
+  scores in float32 at ``highest`` precision, ``sigmoid(r W_r)`` or the
+  logits ``r W_r`` themselves (``MoEConfig.scoring``), the chosen set
+  the top-k of ``scores + bias`` (the bias selects and takes no part in
+  the weights nor any gradient). Weights over all k chosen experts,
+  held here or not: ``scale * s_i / (sum of the chosen s + 1e-20)``
+  under ``"sigmoid"``, ``scale * softmax over the chosen logits`` under
+  ``"softmax"`` (a softmax over all the experts renormalised over the
+  chosen is the same numbers). ``r`` is the experts' input ``x``, or
+  another tensor of the same tokens that the caller hands in
+  (``scores_from``: a router placed before attention reads the
+  attention's input).
 - Dispatch is dropless: the ``(token, choice)`` pairs are sorted by
   expert, pairs of experts held elsewhere last; no capacity, no token
   dropped. A chip that holds ``held`` of ``experts`` experts draws about
@@ -70,38 +76,54 @@ SCOPE_EXPERTS = "experts"
 STATE = "moe_state"     # flax collection: selection bias, tokens drawn
 
 
+SCORINGS = ("sigmoid", "softmax")
+# What the router reads: the experts' own input, or the block's normed
+# input before attention, which the block hands in (``scores_from``).
+ROUTER_READS = ("ffn", "attention")
+# An expert is ``W_down(gate(W_gate x) * W_up x)``: SwiGLU or ReGLU.
+GATES = {"silu": nn.silu, "relu": nn.relu}
+
+
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
     experts: int                    # routed experts of the model
     per_token: int                  # chosen per token
     width: int                      # an expert's hidden width
     held: Tuple[int, int] = None    # [first, end) held here; None: all
-    shared: int = 1                 # shared experts (one SwiGLU, wider)
+    shared: int = 1                 # shared experts (one gated FFN, wider)
     scale: float = 1.0              # routed_scaling_factor
     first_dense: int = 1            # leading layers with a dense FFN
+    scoring: str = "sigmoid"        # of SCORINGS: the weights' form
+    gate: str = "silu"              # of GATES: every expert's activation
+    router_reads: str = "ffn"       # of ROUTER_READS
 
     @property
     def span(self):
         return self.held or (0, self.experts)
 
 
-def swiglu(x, w_gate, w_up, w_down):
-    """``W_down(silu(W_gate x) * W_up x)``: a dense gated FFN, the shared
+def swiglu(x, w_gate, w_up, w_down, gate="silu"):
+    """``W_down(gate(W_gate x) * W_up x)``: a dense gated FFN, the shared
     expert's form and every routed expert's."""
-    h = nn.silu(jnp.dot(x, w_gate.astype(x.dtype))) * jnp.dot(
+    h = GATES[gate](jnp.dot(x, w_gate.astype(x.dtype))) * jnp.dot(
         x, w_up.astype(x.dtype))
     return jnp.dot(h, w_down.astype(x.dtype))
 
 
-def route(x, w_router, bias, *, k, scale):
+def route(x, w_router, bias, *, k, scale, scoring="sigmoid"):
     """(chosen (T, k) int32, weights (T, k) float32, drawn (E,) float32:
     the tokens each expert drew)."""
-    scores = jax.nn.sigmoid(jnp.dot(
-        x.astype(jnp.float32), w_router.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST))
+    scores = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(scores)
     _, chosen = lax.top_k(scores + lax.stop_gradient(bias), k)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
-    weights = scale * picked / (jnp.sum(picked, -1, keepdims=True) + 1e-20)
+    if scoring == "sigmoid":
+        weights = scale * picked / (jnp.sum(picked, -1, keepdims=True)
+                                    + 1e-20)
+    else:
+        weights = scale * jax.nn.softmax(picked, axis=-1)
     drawn = jnp.sum(jax.nn.one_hot(chosen, scores.shape[-1],
                                    dtype=jnp.float32), axis=(0, 1))
     return chosen, weights, drawn
@@ -144,7 +166,8 @@ def _vary_like(x, like):
     return x
 
 
-def _routed(x, w_gate, w_up, w_down, chosen, weights, drawn, first_held):
+def _routed(x, w_gate, w_up, w_down, chosen, weights, drawn, first_held,
+            gate="silu"):
     """The held experts' part of the layer's output for tokens ``x``
     (T, d): sort, grouped products, un-sort, weigh."""
     tokens, k = chosen.shape
@@ -168,7 +191,7 @@ def _routed(x, w_gate, w_up, w_down, chosen, weights, drawn, first_held):
     with jax.named_scope(SCOPE_EXPERTS):
         def product(a, w):
             return lax.ragged_dot(a, w.astype(a.dtype), sizes)
-        ys = product(nn.silu(product(xs, w_gate)) * product(xs, w_up),
+        ys = product(GATES[gate](product(xs, w_gate)) * product(xs, w_up),
                      w_down)
     with jax.named_scope(SCOPE_ROUTE):
         ys = _ungather(jnp.where(rows, ys, 0), order, inverse)
@@ -207,7 +230,7 @@ def took_sized_path(drawn, first, end):
 
 
 def _sized(rows, x, w_gate, w_up, w_down, chosen, weights, drawn,
-           first_held):
+           first_held, gate="silu"):
     """``_routed`` for a draw of at most ``rows`` pairs: the first
     ``rows`` pairs of the sorted order hold every pair of the experts
     held, so only they are gathered, multiplied and added back."""
@@ -225,7 +248,7 @@ def _sized(rows, x, w_gate, w_up, w_down, chosen, weights, drawn,
     with jax.named_scope(SCOPE_EXPERTS):
         def product(a, w):
             return lax.ragged_dot(a, w.astype(a.dtype), sizes)
-        ys = product(nn.silu(product(xs, w_gate)) * product(xs, w_up),
+        ys = product(GATES[gate](product(xs, w_gate)) * product(xs, w_up),
                      w_down)
     with jax.named_scope(SCOPE_ROUTE):
         # Every live row is a pair of an expert held here: weigh it in
@@ -257,24 +280,28 @@ def _pull(path):
     return pull
 
 
-@functools.partial(jax.jit, static_argnums=0)
-def _either(rows, *routed):
+def _paths(rows, gate):
+    """(the sized path, the full-size one), each of ``*routed``."""
+    return (functools.partial(_sized, rows, gate=gate),
+            functools.partial(_routed, gate=gate))
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _either(rows, gate, *routed):
+    with jax.named_scope(SCOPE):
+        return lax.cond(_fits(rows, routed), *_paths(rows, gate), *routed)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _either_back(rows, gate, g, *routed):
     with jax.named_scope(SCOPE):
         return lax.cond(_fits(rows, routed),
-                        functools.partial(_sized, rows), _routed, *routed)
+                        *map(_pull, _paths(rows, gate)), g, *routed)
 
 
-@functools.partial(jax.jit, static_argnums=0)
-def _either_back(rows, g, *routed):
-    with jax.named_scope(SCOPE):
-        return lax.cond(_fits(rows, routed),
-                        _pull(functools.partial(_sized, rows)),
-                        _pull(_routed), g, *routed)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _sized_or_routed(rows, x, w_gate, w_up, w_down, chosen, weights, drawn,
-                     first_held):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _sized_or_routed(rows, gate, x, w_gate, w_up, w_down, chosen, weights,
+                     drawn, first_held):
     """``_sized`` where the held experts' draw fits in ``rows`` rows,
     else ``_routed``, and nothing kept for the way back: the branch
     taken is made again there and pulled back inside a conditional of
@@ -287,17 +314,17 @@ def _sized_or_routed(rows, x, w_gate, w_up, w_down, chosen, weights, drawn,
     alone, and a reader of a trace (``benchmark/scope_sum.py``) files a
     grouped kernel under ``hvd_moe/experts`` when its name holds no
     scope of this module, as it did before there was a ``jax.jit``."""
-    return _either(rows, x, w_gate, w_up, w_down, chosen, weights, drawn,
-                   first_held)
+    return _either(rows, gate, x, w_gate, w_up, w_down, chosen, weights,
+                   drawn, first_held)
 
 
-def _sized_or_routed_bwd(rows, routed, g):
-    pulled = dict(zip(_TRAINED, _either_back(rows, g, *routed)))
+def _sized_or_routed_bwd(rows, gate, routed, g):
+    pulled = dict(zip(_TRAINED, _either_back(rows, gate, g, *routed)))
     return tuple(pulled.get(i) for i in range(len(routed)))
 
 
 _sized_or_routed.defvjp(
-    lambda rows, *routed: (_either(rows, *routed), routed),
+    lambda rows, gate, *routed: (_either(rows, gate, *routed), routed),
     _sized_or_routed_bwd)
 
 
@@ -310,10 +337,13 @@ def _vary_together(*xs):
             for x in xs]
 
 
-def moe_apply(x, params, bias, *, k, scale=1.0, first_held=0):
+def moe_apply(x, params, bias, *, k, scale=1.0, first_held=0,
+              scoring="sigmoid", gate="silu", scores_from=None):
     """The expert layer on tokens ``x`` (T, d). Returns ``(y, drawn)``:
     this share of the layer's output and the tokens each of the model's
-    experts drew (float32, (E,)).
+    experts drew (float32, (E,)). ``scoring`` and ``gate`` as
+    ``MoEConfig``'s; ``scores_from`` (T, d): what the router reads where
+    that is not ``x``.
 
     ``params``: ``router`` (d, E) over all E experts; ``w_gate``,
     ``w_up`` (held, d, f) and ``w_down`` (held, f, d) of the experts
@@ -325,19 +355,21 @@ def moe_apply(x, params, bias, *, k, scale=1.0, first_held=0):
     rows = sized_rows(pairs, params["w_gate"].shape[0],
                       params["router"].shape[1])
     with jax.named_scope(SCOPE), jax.named_scope(SCOPE_ROUTE):
-        chosen, weights, drawn = route(x, params["router"], bias, k=k,
-                                       scale=scale)
+        chosen, weights, drawn = route(
+            x if scores_from is None else scores_from, params["router"],
+            bias, k=k, scale=scale, scoring=scoring)
     routed = (x, params["w_gate"], params["w_up"], params["w_down"],
               chosen, weights, drawn, first_held)
     if rows == pairs:
         with jax.named_scope(SCOPE):
-            y = jax.checkpoint(_routed)(*routed)
+            y = jax.checkpoint(functools.partial(_routed, gate=gate))(
+                *routed)
     else:
-        y = _sized_or_routed(rows, *_vary_together(*routed))
+        y = _sized_or_routed(rows, gate, *_vary_together(*routed))
     if "shared_gate" in params:
         with jax.named_scope(SCOPE), jax.named_scope(SCOPE_EXPERTS):
             y = y + swiglu(x, params["shared_gate"], params["shared_up"],
-                           params["shared_down"])
+                           params["shared_down"], gate)
     return y, drawn
 
 
@@ -350,8 +382,17 @@ class MoELayer(nn.Module):
     dtype: jnp.dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, scores_from=None):
+        """``scores_from``: what the router reads in place of ``x``
+        (``cfg.router_reads`` says which tensor of the block that is;
+        the block hands it in)."""
         cfg = self.cfg
+        if (cfg.scoring not in SCORINGS or cfg.gate not in GATES
+                or cfg.router_reads not in ROUTER_READS):
+            raise ValueError(
+                f"MoEConfig: scoring {cfg.scoring!r} of {SCORINGS}, gate "
+                f"{cfg.gate!r} of {tuple(GATES)}, router_reads "
+                f"{cfg.router_reads!r} of {ROUTER_READS}")
         d = x.shape[-1]
         first, end = cfg.span
         init = nn.initializers.lecun_normal()
@@ -376,7 +417,10 @@ class MoELayer(nn.Module):
                                (cfg.experts,))
         y, drawn = moe_apply(
             x.reshape(-1, d).astype(self.dtype), params, bias.value,
-            k=cfg.per_token, scale=cfg.scale, first_held=first)
+            k=cfg.per_token, scale=cfg.scale, first_held=first,
+            scoring=cfg.scoring, gate=cfg.gate,
+            scores_from=None if scores_from is None
+            else scores_from.reshape(-1, d))
         if self.is_mutable_collection(STATE):
             tokens.value = drawn
         return y.reshape(x.shape)

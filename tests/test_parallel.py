@@ -408,7 +408,8 @@ def poison(monkeypatch):
     def make(path):
         moe_lib._either.clear_cache()
         monkeypatch.setattr(moe_lib, path,
-                            lambda *args: jnp.full_like(args[-8], jnp.nan))
+                            lambda *args, **kwargs: jnp.full_like(
+                                args[-8], jnp.nan))
     yield make
     moe_lib._either.clear_cache()
 
