@@ -34,22 +34,41 @@ How it computes, and why (PERF.md section 3, "expert layer"):
   of ``sized_rows`` rows, twice that expectation (a static shape, from
   shapes alone), whenever the step's own draw fits in them: only the
   first rows of the sorted order are gathered, multiplied, weighed and
-  added back into their tokens (``_sized``). A draw that does not fit
-  takes ``_routed``, the same computation on a row for every pair,
-  chosen on the device by ``lax.cond`` on the count the router already
-  makes; with every expert held there is one path and no conditional.
-  Rows past the held pairs are zero and the grouped product skips their
-  tiles.
+  added back into their tokens (``_sized_rows``, ``_sized``). A draw
+  that does not fit takes ``_routed``, the same computation on a row
+  for every pair, chosen on the device by ``lax.cond`` on the count the
+  router already makes; with every expert held there is one path and no
+  conditional. The grouped product skips the tiles of rows past the
+  held pairs; what it leaves in such rows is not specified, so they are
+  zeroed wherever they could reach a result.
 - The experts' products (scope ``hvd_moe/experts``) are
   ``jax.lax.ragged_dot`` over the groups: XLA's own grouped Mosaic
-  kernel on the TPU, with both gradients. The routed part keeps
-  nothing for the way back and is made again there: where there are
-  two sizes, by a backward pass of its own (``_sized_or_routed``) that
-  chooses again and differentiates only the branch taken, since the
-  derivative of one conditional, or one ``jax.checkpoint`` round it,
-  keeps the union of its branches' residuals, the full-size buffers
-  among them. Forward and backward each go through ``jax.jit``, so a
-  model's layers share one trace and one lowering of the two sizes.
+  kernel on the TPU, with both gradients.
+- What is kept for the way back. Where there are two sizes
+  (``_sized_or_routed``) the sized path keeps its gate and up products
+  before the activation and its rows' places in pair order
+  (``kept_bytes``: from ``sized_rows`` and the experts' width alone),
+  and its backward pass is written by hand over them (``_sized_back``):
+  the sort and those two products are made once a step, the down
+  product's result is neither kept nor made again, and the tokens' rows,
+  the widest of what the forward pass made, are gathered again. The
+  kept arrays are made before the conditional (``_sized_rows``), not
+  inside its branch: what a conditional returns XLA holds twice. The
+  full-size path keeps nothing of its own: differentiating the
+  conditional, or one ``jax.checkpoint`` round it, would keep the union
+  of its branches' residuals, the buffers with a row for every pair
+  among them, so the backward pass is a conditional of its own on the
+  same test, whose full-size branch makes ``_routed`` again and pulls
+  the gradient back through it. A draw over the rows costs that step
+  its time, never the memory. With every expert held there is one path,
+  ``jax.checkpoint(_routed)``: buffers with a row for every pair are
+  what recomputation is for. A model that has to save more says so with
+  ``TransformerConfig.remat``: under ``nn.remat`` the block's forward
+  pass, this layer's included, runs again on the way back, and what is
+  kept here (named ``hvd_moe_kept`` for ``jax.checkpoint`` policies;
+  ``"dots"`` and ``"flash"`` do not keep it) lives only there. Forward
+  and backward each go through ``jax.jit``, so a model's layers share
+  one trace and one lowering of the two sizes.
 - ``_sized`` adds its rows into the tokens by a scatter-add of
   ``sized_rows`` rows, whose transpose is a gather (and the gather's a
   scatter-add): on the chip faster than un-gathering by token through a
@@ -66,6 +85,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..utils.jax_compat import pvary
 
@@ -229,85 +249,190 @@ def took_sized_path(drawn, first, end):
     return rows < pairs and float(drawn[first:end].sum()) <= rows
 
 
-def _sized(rows, x, w_gate, w_up, w_down, chosen, weights, drawn,
-           first_held, gate="silu"):
-    """``_routed`` for a draw of at most ``rows`` pairs: the first
+def _held_sizes(w_gate, drawn, first_held):
+    """The held experts' draws, (held,) int32."""
+    return lax.dynamic_slice(drawn, (first_held,), (w_gate.shape[0],)
+                             ).astype(jnp.int32)
+
+
+def _fits(rows, routed):
+    _, w_gate, _, _, _, _, drawn, first_held = routed
+    return jnp.sum(_held_sizes(w_gate, drawn, first_held)) <= rows
+
+
+def _held_draw(rows, w_gate, drawn, first_held):
+    """(the grouped products' group sizes: the held experts' draws;
+    which of ``rows`` sorted pairs are pairs of theirs, (rows, 1)).
+    A draw over ``rows`` reads as no draw at all: that step's result
+    comes from ``_routed``."""
+    sizes = _held_sizes(w_gate, drawn, first_held)
+    sizes = jnp.where(jnp.sum(sizes) <= rows, sizes, 0)
+    return sizes, (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+
+
+def _sized_rows(rows, x, w_gate, w_up, chosen, drawn, first_held):
+    """The sized path as far as the experts' activation: the first
     ``rows`` pairs of the sorted order hold every pair of the experts
-    held, so only they are gathered, multiplied and added back."""
-    k = chosen.shape[1]
+    held, so only their tokens' rows are gathered and multiplied.
+    Returns the gate and up products and the rows' places in pair
+    order: what ``_sized`` goes on from and ``_sized_back`` reads in
+    place of making it again (``kept_bytes``)."""
     held = w_gate.shape[0]
     with jax.named_scope(SCOPE_ROUTE):
         local = chosen.reshape(-1) - first_held
         key = jnp.where((local >= 0) & (local < held), local, held)
         order = _vary_like(jnp.argsort(key, stable=True), key)[:rows]
-        sizes = lax.dynamic_slice(drawn, (first_held,), (held,)).astype(
-            jnp.int32)
-        live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
-        token = order // k
-        xs = jnp.where(live, x[token], 0)
+        sizes, _ = _held_draw(rows, w_gate, drawn, first_held)
+        # Rows past the held pairs are other tokens' own, left as they
+        # are: a grouped product reads no row past its groups, and the
+        # way back is ``_sized_back``, which zeroes what it must.
+        xs = x[order // chosen.shape[1]]
     with jax.named_scope(SCOPE_EXPERTS):
-        def product(a, w):
-            return lax.ragged_dot(a, w.astype(a.dtype), sizes)
-        ys = product(GATES[gate](product(xs, w_gate)) * product(xs, w_up),
-                     w_down)
+        gated, up = (lax.ragged_dot(xs, w.astype(xs.dtype), sizes)
+                     for w in (w_gate, w_up))
+    return gated, up, order
+
+
+def _sized(rows, kept, x, w_gate, w_up, w_down, chosen, weights, drawn,
+           first_held, gate="silu"):
+    """``_routed`` for a draw of at most ``rows`` pairs, from
+    ``_sized_rows``: the activation, the down product, and the rows
+    weighed and added back into their tokens."""
+    gated, up, order = kept
+    sizes, live = _held_draw(rows, w_gate, drawn, first_held)
+    with jax.named_scope(SCOPE_EXPERTS):
+        ys = lax.ragged_dot(GATES[gate](gated) * up,
+                            w_down.astype(up.dtype), sizes)
     with jax.named_scope(SCOPE_ROUTE):
         # Every live row is a pair of an expert held here: weigh it in
         # sorted order and add it into its token's row.
         weight = weights.reshape(-1)[order].astype(ys.dtype)[:, None]
-        return jnp.zeros_like(x).at[token].add(
+        return jnp.zeros_like(x).at[order // chosen.shape[1]].add(
             jnp.where(live, ys, 0) * weight)
 
 
-def _fits(rows, routed):
-    _, w_gate, _, _, _, _, drawn, first_held = routed
-    return jnp.sum(lax.dynamic_slice(
-        drawn, (first_held,), (w_gate.shape[0],))) <= rows
+# A grouped product's gradient to its weights: each group's rows of the
+# left operand against the same rows of the cotangent (what JAX's own
+# transpose of ``lax.ragged_dot`` makes).
+_BY_GROUP = lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+def _sized_back(rows, gate, g, kept, x, w_gate, w_up, w_down, chosen,
+                weights, drawn, first_held):
+    """``g`` (T, d) pulled back through ``_sized_rows`` and ``_sized``
+    to the routed part's trained arguments, from what the first kept:
+    no sort and no forward product is made again; the tokens' rows are
+    gathered again (``rows x d`` a layer: PERF.md section 6, PR 39).
+    The down product's result ``ys`` is neither kept nor made again:
+    with ``u = g_rows W_d^T``, which the gradient of the gated rows
+    ``h`` needs anyway, ``<h W_d, g_rows> = <h, u>`` is the weights'
+    gradient and ``u * weight`` is ``h``'s. What a grouped product
+    leaves in rows past its groups is not specified: such rows are
+    selected away wherever they reach a result (the tokens' gradient,
+    the weights'), never multiplied by 0."""
+    gated, up, order = kept
+    with jax.named_scope(SCOPE_ROUTE):
+        sizes, live = _held_draw(rows, w_gate, drawn, first_held)
+        token = order // chosen.shape[1]
+        # Not zeroed past the held pairs: those rows are other tokens'
+        # own, and a grouped product reads no row past its groups.
+        xs, gs = x[token], g[token]
+        weight = weights.reshape(-1)[order].astype(g.dtype)[:, None]
+    with jax.named_scope(SCOPE_EXPERTS):
+        def to_rows(ct, w):
+            return lax.ragged_dot(
+                ct, jnp.swapaxes(w.astype(ct.dtype), 1, 2), sizes)
+
+        def to_weights(a, ct, w):
+            return lax.ragged_dot_general(a, ct, sizes, _BY_GROUP).astype(
+                w.dtype)
+        h, back = jax.vjp(lambda a, b: GATES[gate](a) * b, gated, up)
+        u = to_rows(gs, w_down)
+        d_gated, d_up = back(u * weight)
+        d_xs = to_rows(d_gated, w_gate) + to_rows(d_up, w_up)
+        d_w = (to_weights(xs, d_gated, w_gate), to_weights(xs, d_up, w_up),
+               to_weights(h * weight, gs, w_down))
+        d_weight = jnp.sum(h.astype(weights.dtype) * u.astype(weights.dtype),
+                           -1, keepdims=True)
+    with jax.named_scope(SCOPE_ROUTE):
+        d_weights = jnp.zeros_like(weights.reshape(-1)).at[order].add(
+            jnp.where(live, d_weight, 0)[:, 0])
+        return (jnp.zeros_like(x).at[token].add(jnp.where(live, d_xs, 0)),
+                *d_w, d_weights.reshape(weights.shape))
 
 
 _TRAINED = (0, 1, 2, 3, 5)      # of ``routed``: x, the three w, weights
 
 
-def _pull(path):
-    """``path``'s backward pass with nothing kept: ``path`` made again
-    and ``g`` pulled back to its trained arguments."""
-    def pull(g, *routed):
-        def of(*trained):
-            args = list(routed)
-            for i, a in zip(_TRAINED, trained):
-                args[i] = a
-            return path(*args)
-        return jax.vjp(of, *(routed[i] for i in _TRAINED))[1](g)
-    return pull
+def _routed_back(gate, g, kept, *routed):
+    """``g`` pulled back through ``_routed`` to its trained arguments
+    with nothing of its own kept: ``_routed`` is made again here.
+    ``kept`` is ``_sized_rows``'s, of a draw that did not fit: unread."""
+    def of(*trained):
+        args = list(routed)
+        for i, a in zip(_TRAINED, trained):
+            args[i] = a
+        return _routed(*args, gate=gate)
+    return jax.vjp(of, *(routed[i] for i in _TRAINED))[1](g)
 
 
-def _paths(rows, gate):
-    """(the sized path, the full-size one), each of ``*routed``."""
-    return (functools.partial(_sized, rows, gate=gate),
-            functools.partial(_routed, gate=gate))
+def kept_bytes(rows, width):
+    """Bytes an expert layer on ``rows`` sized rows of experts ``width``
+    wide keeps from its forward pass for its backward pass
+    (``_sized_rows``): the gate and up products in bfloat16, the rows'
+    places in int32."""
+    return rows * (2 * width * 2 + 4)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
 def _either(rows, gate, *routed):
+    x, w_gate, w_up, _, chosen, _, drawn, first_held = routed
     with jax.named_scope(SCOPE):
-        return lax.cond(_fits(rows, routed), *_paths(rows, gate), *routed)
+        kept = _sized_rows(rows, x, w_gate, w_up, chosen, drawn, first_held)
+        return lax.cond(
+            _fits(rows, routed), functools.partial(_sized, rows, gate=gate),
+            lambda kept, *routed: _routed(*routed, gate=gate),
+            kept, *routed), kept
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
-def _either_back(rows, gate, g, *routed):
+def _either_back(rows, gate, g, kept, *routed):
     with jax.named_scope(SCOPE):
         return lax.cond(_fits(rows, routed),
-                        *map(_pull, _paths(rows, gate)), g, *routed)
+                        functools.partial(_sized_back, rows, gate),
+                        functools.partial(_routed_back, gate),
+                        g, kept, *routed)
+
+
+# What the sized path keeps for the way back, as ``jax.checkpoint``
+# policies may name it: ``"dots"`` and ``"flash"`` do not, so under
+# them (and under whole-block ``remat``) the layer is made again.
+KEPT_NAME = "hvd_moe_kept"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
 def _sized_or_routed(rows, gate, x, w_gate, w_up, w_down, chosen, weights,
                      drawn, first_held):
     """``_sized`` where the held experts' draw fits in ``rows`` rows,
-    else ``_routed``, and nothing kept for the way back: the branch
-    taken is made again there and pulled back inside a conditional of
-    its own (differentiating one conditional would keep the union of
-    its branches' residuals, the full-size buffers among them). Both
-    ways go through ``jax.jit``, so the layers of a model share one
+    else ``_routed``, with a backward pass of its own that chooses
+    again by the same test. What ``_sized_rows`` made for the sized
+    branch (the gate and up products, the rows' places: ``kept_bytes``,
+    shapes from ``rows`` alone) is kept, and ``_sized_back`` pulls the
+    gradient back from it: the sort and those products are made once a
+    step. It is made before the conditional, whichever branch then
+    runs (XLA holds what a conditional returns twice), so a draw over
+    the rows pays for a gather it does not use. The full-size branch
+    keeps nothing of its own: differentiating the conditional itself
+    would keep the union of its branches' residuals, the buffers with a
+    row for every pair among them, so that step makes ``_routed`` again
+    on the way back and pays in time, never in memory. A model that has
+    to save more says so with ``TransformerConfig.remat``: under
+    ``nn.remat`` the block's forward pass, this layer's included, runs
+    again on the way back and what is kept here lives only there.
+
+    Both ways go through ``jax.jit``, so the layers of a model share one
     trace and one lowering of each, under each layer's own names.
     They open ``hvd_moe`` themselves and are called outside it: XLA
     names the grouped kernels of a jitted function for its call site
@@ -315,17 +440,21 @@ def _sized_or_routed(rows, gate, x, w_gate, w_up, w_down, chosen, weights,
     grouped kernel under ``hvd_moe/experts`` when its name holds no
     scope of this module, as it did before there was a ``jax.jit``."""
     return _either(rows, gate, x, w_gate, w_up, w_down, chosen, weights,
-                   drawn, first_held)
+                   drawn, first_held)[0]
 
 
-def _sized_or_routed_bwd(rows, gate, routed, g):
-    pulled = dict(zip(_TRAINED, _either_back(rows, gate, g, *routed)))
+def _sized_or_routed_fwd(rows, gate, *routed):
+    y, kept = _either(rows, gate, *routed)
+    return y, (tuple(checkpoint_name(r, KEPT_NAME) for r in kept), routed)
+
+
+def _sized_or_routed_bwd(rows, gate, res, g):
+    kept, routed = res
+    pulled = dict(zip(_TRAINED, _either_back(rows, gate, g, kept, *routed)))
     return tuple(pulled.get(i) for i in range(len(routed)))
 
 
-_sized_or_routed.defvjp(
-    lambda rows, gate, *routed: (_either(rows, gate, *routed), routed),
-    _sized_or_routed_bwd)
+_sized_or_routed.defvjp(_sized_or_routed_fwd, _sized_or_routed_bwd)
 
 
 def _vary_together(*xs):
@@ -426,12 +555,13 @@ class MoELayer(nn.Module):
         return y.reshape(x.shape)
 
 
-def publish_expert_tokens(state, held=None):
+def publish_expert_tokens(state, held=None, width=None):
     """Set ``hvd_moe_expert_tokens{layer,expert}``,
-    ``hvd_moe_held_share``, ``hvd_moe_buffer_rows{layer}`` and
-    ``hvd_moe_sized_layers`` from the ``moe_state`` collection a train
-    step returned. Call it outside the step; it fetches the arrays. A
-    no-op when ``HOROVOD_TPU_METRICS`` is off."""
+    ``hvd_moe_held_share``, ``hvd_moe_buffer_rows{layer}``,
+    ``hvd_moe_sized_layers`` and, given the experts' ``width``,
+    ``hvd_moe_kept_bytes{layer}`` from the ``moe_state`` collection a
+    train step returned. Call it outside the step; it fetches the
+    arrays. A no-op when ``HOROVOD_TPU_METRICS`` is off."""
     from ..telemetry import core as telemetry
     from .sharding import _path_str
     if not telemetry.enabled():
@@ -453,6 +583,12 @@ def publish_expert_tokens(state, held=None):
         "Expert layers whose held draw of the last step fitted in "
         "hvd_moe_buffer_rows rows, fewer than a row for every pair: "
         "they ran on buffers of that size")
+    kept = telemetry.gauge(
+        "hvd_moe_kept_bytes",
+        "Bytes the layer's forward pass keeps for its backward pass on "
+        "hvd_moe_buffer_rows rows (kept_bytes: from shapes alone, "
+        "bfloat16 activations); 0 where every expert is held and the "
+        "routed part is made again", ("layer",))
     mine = total = 0.0
     fitted = 0
     for path, drawn in jax.tree_util.tree_leaves_with_path(state):
@@ -463,8 +599,12 @@ def publish_expert_tokens(state, held=None):
         for expert, n in enumerate(drawn):
             tokens.labels(layer=layer, expert=expert).set(float(n))
         first, end = held or (0, len(drawn))
-        buffer_rows.labels(layer=layer).set(sized_rows(
-            round(float(drawn.sum())), end - first, len(drawn)))
+        pairs = round(float(drawn.sum()))
+        rows = sized_rows(pairs, end - first, len(drawn))
+        buffer_rows.labels(layer=layer).set(rows)
+        if width:
+            kept.labels(layer=layer).set(
+                kept_bytes(rows, width) if rows < pairs else 0)
         fitted += took_sized_path(drawn, first, end)
         mine += float(drawn[first:end].sum())
         total += float(drawn.sum())

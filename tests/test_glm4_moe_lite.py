@@ -273,18 +273,27 @@ def test_buffer_rows_and_sized_layers_reach_the_telemetry_plane(monkeypatch):
         "block_1": {"moe": {"bias": jnp.zeros((8,)), "expert_tokens": fits}},
         "block_2": {"moe": {"bias": jnp.zeros((8,)),
                             "expert_tokens": over}}}}
-    moe.publish_expert_tokens(state, held=(0, 2))
+    moe.publish_expert_tokens(state, held=(0, 2), width=32)
     families = telemetry.snapshot()["families"]
     assert {s["labels"]["layer"]: s["value"]
             for s in families["hvd_moe_buffer_rows"]["samples"]} == {
         "moe_state/block_1/moe": 512.0, "moe_state/block_2/moe": 512.0}
     assert families["hvd_moe_sized_layers"]["samples"][0]["value"] == 1.0
-    # Every expert held: one path, buffers of a row for every pair.
-    moe.publish_expert_tokens(state)
+    # Both layers keep 512 rows of 2 x 32 two-byte numbers and an index
+    # for the way back, the second unread.
+    assert moe.kept_bytes(512, 32) == 512 * (64 * 2 + 4)
+    assert {s["labels"]["layer"]: s["value"]
+            for s in families["hvd_moe_kept_bytes"]["samples"]} == {
+        "moe_state/block_1/moe": 67584.0, "moe_state/block_2/moe": 67584.0}
+    # Every expert held: one path, buffers of a row for every pair,
+    # nothing kept.
+    moe.publish_expert_tokens(state, width=32)
     families = telemetry.snapshot()["families"]
     assert {s["value"] for s in
             families["hvd_moe_buffer_rows"]["samples"]} == {1024.0}
     assert families["hvd_moe_sized_layers"]["samples"][0]["value"] == 0.0
+    assert {s["value"] for s in
+            families["hvd_moe_kept_bytes"]["samples"]} == {0.0}
 
 
 def test_publishing_is_a_no_op_with_metrics_off(monkeypatch):

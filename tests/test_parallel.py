@@ -6,6 +6,8 @@ application, the dropless expert layer vs a dense-mask oracle, GSPMD sharding vs
 replicated execution.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,6 +21,7 @@ from horovod_tpu.parallel import (
     ulysses_attention)
 from horovod_tpu.parallel import moe as moe_lib
 from horovod_tpu.parallel.pipeline import stack_stage_params
+from moe_fixtures import poison  # noqa: F401 (a fixture)
 
 
 def _rand(shape, seed, dtype=jnp.float32):
@@ -290,15 +293,19 @@ def _moe_layer(tokens=64, d=16, f=32, e=8):
     return _rand((tokens, d), 0), params, bias
 
 
-def _moe_dense_oracle(x, params, bias, k, scale, first_held=0):
+def _moe_dense_oracle(x, params, bias, k, scale, first_held=0,
+                      scoring="sigmoid", gate="silu"):
     """Every expert held on every token, weighted by the token's weight
     for it or by 0: no sort, no grouped product."""
-    scores = jax.nn.sigmoid(x @ params["router"])
+    scores = x @ params["router"]
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(scores)
     _, chosen = jax.lax.top_k(scores + bias, k)
-    picked = scores * jax.nn.one_hot(chosen, scores.shape[-1]).sum(-2)
+    mask = jax.nn.one_hot(chosen, scores.shape[-1]).sum(-2)
+    picked = (scores if scoring == "sigmoid" else jnp.exp(scores)) * mask
     weights = scale * picked / picked.sum(-1, keepdims=True)
     weights = weights[:, first_held:first_held + params["w_gate"].shape[0]]
-    h = jax.nn.silu(jnp.einsum("td,edf->etf", x, params["w_gate"]))
+    h = moe_lib.GATES[gate](jnp.einsum("td,edf->etf", x, params["w_gate"]))
     h = h * jnp.einsum("td,edf->etf", x, params["w_up"])
     return jnp.einsum("etf,efd,te->td", h, params["w_down"], weights)
 
@@ -399,23 +406,18 @@ def _moe_share(tokens=512):
     return x, share, bias
 
 
-@pytest.fixture
-def poison(monkeypatch):
-    """``poison(path)`` makes ``path`` (``_sized`` or ``_routed``)
-    return NaN: a result that is finite did not come through it. The
-    two are traced under ``jax.jit``, whose traces are dropped before
-    and after."""
-    def make(path):
-        moe_lib._either.clear_cache()
-        monkeypatch.setattr(moe_lib, path,
-                            lambda *args, **kwargs: jnp.full_like(
-                                args[-8], jnp.nan))
-    yield make
-    moe_lib._either.clear_cache()
+def _whole_sized(rows, *routed, gate="silu"):
+    """The sized path from its two halves, as ``_either`` puts them
+    together when the draw fits."""
+    x, w_gate, w_up, _, chosen, _, drawn, first_held = routed
+    return moe_lib._sized(rows, moe_lib._sized_rows(
+        rows, x, w_gate, w_up, chosen, drawn, first_held), *routed,
+        gate=gate)
 
 
-def _share_apply(x, share, bias):
-    return moe_apply(x, share, bias, k=2, scale=1.8, first_held=_HELD)
+def _share_apply(x, share, bias, **kinds):
+    return moe_apply(x, share, bias, k=2, scale=1.8, first_held=_HELD,
+                     **kinds)
 
 
 def test_moe_sized_path_matches_dense_oracle(poison):
@@ -430,39 +432,87 @@ def test_moe_sized_path_matches_dense_oracle(poison):
         atol=2e-5, rtol=2e-4)
 
 
-@pytest.mark.parametrize("against", ["oracle", "routed"])
-def test_moe_sized_path_gradients(against):
-    """Tokens, router and the three expert matrices through the sized
-    path against the dense oracle and against ``_routed`` on a row for
-    every pair."""
+_KINDS = [("silu", "sigmoid"), ("relu", "softmax"), ("silu", "softmax"),
+          ("relu", "sigmoid")]
+
+
+def _assert_trees_close(got, want, atol=2e-4, rtol=2e-3):
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=atol,
+                                   rtol=rtol)
+
+
+@pytest.mark.parametrize("gate,scoring", _KINDS)
+@pytest.mark.parametrize("against", ["oracle", "routed", "sized"])
+def test_moe_sized_path_gradients(against, gate, scoring):
+    """Tokens, router (through the weights) and the three expert
+    matrices through the sized path, whose backward pass is written by
+    hand over what its forward pass kept: against the dense oracle,
+    against ``_routed`` on a row for every pair, and against
+    ``jax.grad`` through the sized path's own two halves."""
     x, share, bias = _moe_share()
+    kinds = dict(gate=gate, scoring=scoring)
 
-    def full(x, p):
-        chosen, weights, drawn = moe_lib.route(x, p["router"], bias, k=2,
-                                               scale=1.8)
-        return moe_lib._routed(x, p["w_gate"], p["w_up"], p["w_down"],
-                               chosen, weights, drawn, _HELD)
+    def plain(path):
+        def of(x, p):
+            chosen, weights, drawn = moe_lib.route(
+                x, p["router"], bias, k=2, scale=1.8, scoring=scoring)
+            return path(x, p["w_gate"], p["w_up"], p["w_down"], chosen,
+                        weights, drawn, _HELD, gate=gate)
+        return of
 
-    other = full if against == "routed" else (
-        lambda x, p: _moe_dense_oracle(x, p, bias, 2, 1.8, _HELD))
+    other = {
+        "oracle": lambda x, p: _moe_dense_oracle(x, p, bias, 2, 1.8, _HELD,
+                                                 **kinds),
+        "routed": plain(moe_lib._routed),
+        "sized": plain(functools.partial(_whole_sized, 512))}[against]
     np.testing.assert_allclose(
-        np.asarray(_share_apply(x, share, bias)[0]),
+        np.asarray(_share_apply(x, share, bias, **kinds)[0]),
         np.asarray(other(x, share)), atol=2e-5, rtol=2e-4)
-    got = jax.grad(lambda x, p: jnp.sum(_share_apply(x, p, bias)[0] ** 2),
-                   argnums=(0, 1))(x, share)
+    got = jax.grad(
+        lambda x, p: jnp.sum(_share_apply(x, p, bias, **kinds)[0] ** 2),
+        argnums=(0, 1))(x, share)
     want = jax.grad(lambda x, p: jnp.sum(other(x, p) ** 2),
                     argnums=(0, 1))(x, share)
-    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=2e-4, rtol=2e-3)
+    assert all(float(jnp.abs(g).max()) > 0 for g in jax.tree.leaves(want))
+    _assert_trees_close(got, want)
 
 
-def test_moe_draw_over_the_sized_rows_takes_the_fallback(poison):
+def _oracle_gradients(x, share, bias):
+    return jax.grad(lambda x, p: jnp.sum(
+        _moe_dense_oracle(x, p, bias, 2, 1.8, _HELD) ** 2),
+        argnums=(0, 1))(x, share)
+
+
+def _share_gradients(x, share, bias):
+    return jax.grad(lambda x, p: jnp.sum(_share_apply(x, p, bias)[0] ** 2),
+                    argnums=(0, 1))(x, share)
+
+
+@pytest.mark.parametrize("way", ["forward", "backward"])
+def test_moe_draw_over_the_sized_rows_takes_the_fallback(poison, way):
     # A bias that sends every token to the two held experts: 1024 pairs
-    # for 512 rows. The full-size program runs and drops nothing.
+    # for 512 rows. The full-size program runs, forward and backward,
+    # drops nothing, and keeps nothing of its own: what its backward
+    # pass is handed has the sized shapes, no row for every pair.
     x, share, bias = _moe_share()
     bias = bias.at[_HELD:_HELD + 2].add(10.0)
     poison("_sized")
+    if way == "backward":
+        _assert_trees_close(_share_gradients(x, share, bias),
+                            _oracle_gradients(x, share, bias))
+        chosen, weights, drawn = moe_lib.route(x, share["router"], bias,
+                                               k=2, scale=1.8)
+        routed = (x, share["w_gate"], share["w_up"], share["w_down"],
+                  chosen, weights, drawn, _HELD)
+        _, pull = jax.vjp(
+            lambda *trained: moe_lib._sized_or_routed(
+                512, "silu", *trained[:4], chosen, trained[4], drawn,
+                _HELD), *routed[:4], weights)
+        kept_shapes = {leaf.shape for leaf in jax.tree.leaves(pull)}
+        assert (512, 32) in kept_shapes
+        assert not {(1024, 16), (1024, 32)} & kept_shapes
+        return
     y, drawn = _share_apply(x, share, bias)
     np.testing.assert_array_equal(np.asarray(drawn),
                                   [0, 0, 512, 512, 0, 0, 0, 0])
@@ -496,14 +546,23 @@ def test_moe_sized_path_boundary(poison, extra, path):
         np.asarray(y),
         np.asarray(_moe_dense_oracle(x, share, bias, 2, 1.8, _HELD)),
         atol=2e-5, rtol=2e-4)
+    # The backward pass chooses again by the same test: the other way
+    # back is poisoned too.
+    _assert_trees_close(_share_gradients(x, share, bias),
+                        _oracle_gradients(x, share, bias))
+
+
+def _all_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _all_eqns(sub)
 
 
 def _all_shapes(jaxpr):
-    for eqn in jaxpr.eqns:
+    for eqn in _all_eqns(jaxpr):
         for var in eqn.outvars:
             yield tuple(getattr(var.aval, "shape", ()))
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _all_shapes(sub)
 
 
 def test_moe_every_expert_held_traces_no_conditional():
@@ -517,30 +576,115 @@ def test_moe_every_expert_held_traces_no_conditional():
     assert text.count("cond[") == 1
 
 
-def test_moe_sized_branch_holds_no_row_for_every_pair():
-    """At the shapes of ``glm47flash-seq4096-1chip`` (8192 tokens, k 4,
-    8 of 64 experts, hidden 2048, expert width 1536) nothing in the
-    sized branch, forward or backward, has 32768 rows of either width."""
-    tokens, k, d, f, held, experts = 8192, 4, 2048, 1536, 8, 64
-    rows = moe_lib.sized_rows(tokens * k, held, experts)
-    assert rows == 8192
+# (tokens, per token, hidden, width, held, experts, gate) of the two
+# expert cells.
+_CELL_SHAPES = {"glm47flash": (8192, 4, 2048, 1536, 8, 64, "silu"),
+                "smallthinker21b": (16384, 6, 2560, 768, 16, 64, "relu")}
+
+
+def _routed_shapes(tokens, k, d, f, held, experts):
     bf16, f32 = jnp.bfloat16, jnp.float32
-    shapes = (jax.ShapeDtypeStruct((tokens, d), bf16),
-              jax.ShapeDtypeStruct((held, d, f), f32),
-              jax.ShapeDtypeStruct((held, d, f), f32),
-              jax.ShapeDtypeStruct((held, f, d), f32),
-              jax.ShapeDtypeStruct((tokens, k), jnp.int32),
-              jax.ShapeDtypeStruct((tokens, k), f32),
-              jax.ShapeDtypeStruct((experts,), f32))
+    return (jax.ShapeDtypeStruct((tokens, d), bf16),
+            jax.ShapeDtypeStruct((held, d, f), f32),
+            jax.ShapeDtypeStruct((held, d, f), f32),
+            jax.ShapeDtypeStruct((held, f, d), f32),
+            jax.ShapeDtypeStruct((tokens, k), jnp.int32),
+            jax.ShapeDtypeStruct((tokens, k), f32),
+            jax.ShapeDtypeStruct((experts,), f32))
+
+
+@pytest.mark.parametrize("cell", list(_CELL_SHAPES))
+def test_moe_sized_branch_holds_no_row_for_every_pair(cell):
+    """At the shapes of ``glm47flash-seq4096-1chip`` (8192 tokens, k 4,
+    8 of 64 experts, hidden 2048, expert width 1536: 8192 of 32768
+    rows) and of ``smallthinker21b-seq16384-1chip`` (49152 of 98304)
+    nothing in the sized branch, forward or backward, has a row for
+    every pair at either width."""
+    tokens, k, d, f, held, experts, gate = _CELL_SHAPES[cell]
+    rows = moe_lib.sized_rows(tokens * k, held, experts)
+    assert rows == {"glm47flash": 8192, "smallthinker21b": 49152}[cell]
 
     def loss(x, w_gate, w_up, w_down, chosen, weights, drawn):
-        return jnp.sum(moe_lib._sized(rows, x, w_gate, w_up, w_down, chosen,
-                                      weights, drawn, 0).astype(f32))
+        return jnp.sum(_whole_sized(
+            rows, x, w_gate, w_up, w_down, chosen, weights, drawn, 0,
+            gate=gate).astype(jnp.float32))
 
-    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 5)))(*shapes)
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 5)))(
+        *_routed_shapes(tokens, k, d, f, held, experts))
     seen = set(_all_shapes(jaxpr.jaxpr))
     assert (rows, d) in seen and (rows, f) in seen
     assert not {(tokens * k, d), (tokens * k, f)} & seen
+
+
+@pytest.mark.parametrize("cell", list(_CELL_SHAPES))
+def test_moe_sized_way_back_makes_no_forward_again(cell):
+    """The backward pass's sized branch at the two cells' shapes: no
+    sort, the tokens' and the cotangent's rows gathered, and six
+    grouped products, three to the rows and three to the weights, where
+    making the forward again had three more; what it reads was kept on
+    ``rows`` rows, ``kept_bytes`` of them, and nothing it makes has a
+    row for every pair."""
+    tokens, k, d, f, held, experts, gate = _CELL_SHAPES[cell]
+    rows = moe_lib.sized_rows(tokens * k, held, experts)
+    routed = (*_routed_shapes(tokens, k, d, f, held, experts), 0)
+    x, w_gate, w_up, _, chosen, _, drawn, first_held = routed
+    kept = jax.eval_shape(
+        lambda *args: moe_lib._sized_rows(rows, *args, first_held),
+        x, w_gate, w_up, chosen, drawn)
+    assert [a.shape for a in kept] == [(rows, f), (rows, f), (rows,)]
+    assert sum(a.size * a.dtype.itemsize for a in kept) == (
+        moe_lib.kept_bytes(rows, f)) == {
+        "glm47flash": 50_364_416, "smallthinker21b": 151_191_552}[cell]
+
+    jaxpr = jax.make_jaxpr(
+        lambda g, kept, *routed: moe_lib._sized_back(
+            rows, gate, g, kept, *routed))(x, kept, *routed)
+    eqns = list(_all_eqns(jaxpr.jaxpr))
+    names = [eqn.primitive.name for eqn in eqns]
+    assert "sort" not in names and "argsort" not in names
+    products = [eqn.outvars[0].aval.shape for eqn in eqns
+                if eqn.primitive.name == "ragged_dot_general"]
+    assert sorted(products) == sorted(
+        [(rows, f), (rows, d), (rows, d),
+         (held, d, f), (held, d, f), (held, f, d)])
+    assert sum(name == "gather" and eqn.outvars[0].aval.shape == (rows, d)
+               for name, eqn in zip(names, eqns)) == 2
+    seen = set(_all_shapes(jaxpr.jaxpr))
+    assert not {(tokens * k, d), (tokens * k, f)} & seen
+
+
+@pytest.mark.parametrize("remat", [False, True, "dots", "flash"])
+def test_moe_gradients_under_remat_are_the_layers_own(remat):
+    """A block whose expert layer holds 2 of 8 experts, under each
+    ``TransformerConfig.remat``: recomputation makes the layer's
+    forward pass again, what it keeps included, and changes no
+    gradient."""
+    from horovod_tpu.models import TransformerConfig, TransformerLM
+    from horovod_tpu.parallel.moe import MoEConfig
+
+    def grads(remat):
+        cfg = TransformerConfig(
+            vocab_size=64, hidden=16, layers=2, heads=2, max_len=256,
+            norm="rmsnorm", bias=False, mlp="swiglu", remat=remat,
+            moe=MoEConfig(experts=8, per_token=2, width=32,
+                          held=(_HELD, _HELD + 2), scale=1.8))
+        model = TransformerLM(cfg)
+        ids = jax.random.randint(jax.random.PRNGKey(3), (2, 256), 0, 64)
+        variables = model.init(jax.random.PRNGKey(4), ids)
+
+        def loss(params):
+            logits, _ = model.apply(
+                {**variables, "params": params}, ids,
+                mutable=[moe_lib.STATE])
+            return jnp.mean(logits.astype(jnp.float32) ** 2)
+        return jax.grad(loss)(variables["params"])
+
+    want = grads(False)
+    assert any("w_gate" in jax.tree_util.keystr(path)
+               and float(jnp.abs(leaf).max()) > 0
+               for path, leaf in jax.tree_util.tree_leaves_with_path(want))
+    if remat:
+        _assert_trees_close(grads(remat), want, atol=1e-6, rtol=1e-5)
 
 
 # -- GSPMD sharding rules --------------------------------------------------

@@ -32,6 +32,7 @@ from benchmark.references import smallthinker as reference  # noqa: E402
 from horovod_tpu.models import TransformerLM  # noqa: E402
 from horovod_tpu.models import transformer  # noqa: E402
 from horovod_tpu.parallel import moe  # noqa: E402
+from moe_fixtures import poison  # noqa: E402, F401 (a fixture)
 
 SEQ = 20        # over two windows of 8
 
@@ -358,23 +359,6 @@ def test_the_four_shares_add_up_to_the_uncut_reference_layer():
     for a, b in zip(got, want):
         assert float(jnp.abs(b).max()) > 0
         np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-4)
-
-
-@pytest.fixture
-def poison(monkeypatch):
-    """``poison(path)`` makes ``path`` (``_sized`` or ``_routed``) return
-    NaN: a finite result did not come through it. Both ways are traced
-    under ``jax.jit``, whose traces are dropped before and after."""
-    def clear():
-        moe._either.clear_cache()
-        moe._either_back.clear_cache()
-
-    def make(path):
-        clear()
-        monkeypatch.setattr(moe, path, lambda *args, **kwargs: jnp.full_like(
-            args[-8], jnp.nan))
-    yield make
-    clear()
 
 
 @pytest.mark.parametrize("path", ["_sized", "_routed"])
