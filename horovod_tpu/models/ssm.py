@@ -1,8 +1,9 @@
-"""State-space mixers of a hybrid decoder: the Mamba layer and the gated
-memory unit that reads a Mamba layer's scan output further up the stack
-(SambaY, arXiv:2507.06607). ``transformer.Block`` chooses them per layer
-(``TransformerConfig.mixers``), as it chooses the expert layer of
-``parallel/moe.py``; the recurrence itself is the kernel pair of
+"""Token mixers of a hybrid decoder that are not attention: the Mamba
+layer and the gated memory unit that reads a Mamba layer's scan output
+further up the stack (SambaY, arXiv:2507.06607), and the gated short
+convolution of LFM2 (``ShortConv``). ``transformer.Block`` chooses them
+per layer (``TransformerConfig.mixers``), as it chooses the expert layer
+of ``parallel/moe.py``; Mamba's recurrence itself is the kernel pair of
 ``ops/selective_scan.py``.
 
 Mamba-1 (Gu & Dao, arXiv:2312.00752), on a normed input ``h``:
@@ -32,6 +33,9 @@ from ..ops import selective_scan as scan_ops
 # Names in a device trace (docs/tracing.md); readers match the literals.
 SCOPE_SSM = scan_ops.SCOPE      # "hvd_ssm": the Mamba mixer
 SCOPE_GMU = "hvd_gmu"           # the gated memory unit
+# The gated short convolution, products and all; inside it ``mix``: the
+# two gates and the taps between the products.
+SCOPE_SHORTCONV = "hvd_shortconv"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -45,15 +49,16 @@ class SSMConfig:
     d_conv: int = 4
 
 
-def causal_conv(x, kernel, bias):
+def causal_conv(x, kernel, bias=None):
     """Depthwise causal convolution over positions: ``x`` ``[batch, seq,
     channels]``, ``kernel`` ``[taps, channels]``; tap ``taps - 1`` meets
-    the position itself. Shifted multiply-adds: four passes that XLA
-    fuses into one."""
+    the position itself, and before the row's start lie zeros. Shifted
+    multiply-adds, a pass a tap, that XLA fuses into one."""
     taps = kernel.shape[0]
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
     seq = x.shape[1]
-    return bias + sum(padded[:, i:i + seq] * kernel[i] for i in range(taps))
+    out = sum(padded[:, i:i + seq] * kernel[i] for i in range(taps))
+    return out if bias is None else bias + out
 
 
 class MambaMixer(nn.Module):
@@ -89,6 +94,28 @@ class MambaMixer(nn.Module):
             gated = (y * nn.silu(z.astype(jnp.float32))).astype(cfg.dtype)
             out = nn.Dense(cfg.hidden, name="out_proj", **dense)(gated)
             return out, y.astype(cfg.dtype)
+
+
+class ShortConv(nn.Module):
+    """``W_out (C * conv(B * z))`` with ``[B, C, z] = W_in h``: a
+    depthwise causal convolution of ``cfg.conv_taps`` taps between two
+    gates made by the product that made its input. It hands nothing
+    on."""
+    cfg: object                 # TransformerConfig
+
+    @nn.compact
+    def __call__(self, h):
+        cfg = self.cfg
+        dense = dict(use_bias=False, dtype=cfg.dtype)
+        with jax.named_scope(SCOPE_SHORTCONV):
+            b, c, z = jnp.split(
+                nn.Dense(3 * cfg.hidden, name="in_proj", **dense)(h), 3,
+                axis=-1)
+            kernel = self.param("conv_kernel", nn.initializers.lecun_normal(),
+                                (cfg.conv_taps, cfg.hidden))
+            with jax.named_scope("mix"):
+                gated = c * causal_conv(b * z, kernel.astype(cfg.dtype))
+            return nn.Dense(cfg.hidden, name="out_proj", **dense)(gated)
 
 
 class GatedMemoryUnit(nn.Module):

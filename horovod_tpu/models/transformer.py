@@ -15,11 +15,12 @@ bias-free SwiGLU, or per layer the expert layer of ``parallel/moe.py``),
 a multi-token-prediction module (``mtp_layers``), and a looped stack:
 the blocks run ``passes`` times with the same weights, with norms on the
 sub-layers' outputs too (``sandwich_norm``) and an exit gate whose loss
-is ``looped_lm_loss``; or a stack with a mixer a layer (``mixers``: Mamba and
-gated memory units of ``models/ssm.py``, windowed, full and cross
-differential attention with grouped K/V heads, and plain softmax
-attention, full or under a window, each with rope or without
-positions: ``PLAIN``), in which a layer may
+is ``looped_lm_loss``; or a stack with a mixer a layer (``mixers``: Mamba,
+gated memory units and gated short convolutions of ``models/ssm.py``,
+windowed, full and cross differential attention with grouped K/V
+heads, and plain softmax attention, full or under a window, each with
+rope or without positions: ``PLAIN``; with a norm on every head's q
+and k where ``qk_norm``), in which a layer may
 read what an earlier layer made, and a head tied to the embedding.
 Hidden sizes are multiples of 128 for MXU tiling; the head
 dimension is ``head_dim`` where the configuration states one (28 heads
@@ -43,7 +44,7 @@ import numpy as np
 import optax
 
 from ..parallel.moe import MoEConfig, MoELayer
-from .ssm import GatedMemoryUnit, MambaMixer, SSMConfig
+from .ssm import GatedMemoryUnit, MambaMixer, ShortConv, SSMConfig
 
 # Names in a device trace (docs/tracing.md); readers match the literals.
 SCOPE_MLA = "hvd_mla"
@@ -122,7 +123,8 @@ class TransformerConfig:
     # al., arXiv:2410.05258; ``DiffAttention``); the kinds of ``PLAIN``
     # are plain softmax attention, which hands nothing on and takes its
     # positions from its kind (``use_rope`` is for a stack without
-    # ``mixers``).
+    # ``mixers``); a "conv" layer is a gated short convolution of
+    # ``conv_taps`` taps (``ssm.ShortConv``), which hands nothing on.
     mixers: Optional[tuple] = None
     # The layers' indices in the published model where the stack is a
     # cut of it (differential attention's lambda_init depends on depth).
@@ -133,6 +135,11 @@ class TransformerConfig:
     mlp_bias: Optional[bool] = None  # None: as ``bias``
     positions: bool = True           # False: neither rope nor a table
     tie_embeddings: bool = False     # the head is the embedding's transpose
+    # A norm of the model's kind over every head's q and k (one gain of
+    # ``head_width`` each, shared by the heads) before rope, in
+    # ``Attention``; v is not normed.
+    qk_norm: bool = False
+    conv_taps: int = 3               # taps of a "conv" layer's filter
 
     @property
     def head_width(self):
@@ -144,7 +151,7 @@ class TransformerConfig:
 PLAIN = {"full": (False, False), "full_rope": (False, True),
          "sliding": (True, False), "sliding_rope": (True, True)}
 # "window": attention that sees ``cfg.window`` keys and hands nothing on.
-MIXERS = ("attention", "window", "mamba", "gmu", "cross", *PLAIN)
+MIXERS = ("attention", "window", "mamba", "gmu", "cross", "conv", *PLAIN)
 
 
 # BERT-large hyperparameters (the reference benchmark target).
@@ -285,6 +292,8 @@ class Attention(nn.Module):
         sliding, rope = PLAIN.get(self.kind, (False, cfg.use_rope))
         q, k, v = _qkv(cfg, x)
         # (batch, seq, heads, head_dim) -> attention in einsum form.
+        if cfg.qk_norm:
+            q, k = _norm(cfg, "q_norm")(q), _norm(cfg, "k_norm")(k)
         if rope:
             q, k = _rope(q, k, cfg.rope_theta)
         scope = (contextlib.nullcontext() if self.kind is None else
@@ -402,6 +411,8 @@ class Block(nn.Module):
             a, made = MambaMixer(cfg, name="mamba")(h)
         elif self.mixer == "gmu":
             a = GatedMemoryUnit(cfg, name="gmu")(h, memory)
+        elif self.mixer == "conv":
+            a = ShortConv(cfg, name="conv")(h)
         elif self.mixer in PLAIN:
             a = Attention(cfg, kind=self.mixer, name="attn")(h, mask)
         elif self.mixer is not None:
@@ -507,6 +518,8 @@ class Backbone(nn.Module):
                 f"mixers {cfg.mixers}: one of {MIXERS} a layer "
                 f"({cfg.layers}), in a stack that runs once with "
                 f"neither latent attention nor MTP modules")
+        if cfg.mixers is not None:
+            publish_stack_layers(cfg.mixers)
         embed = nn.Embed(cfg.vocab_size, cfg.hidden, dtype=cfg.dtype,
                          name="tok_embed")
         x = embed(tokens)
@@ -552,6 +565,23 @@ class Backbone(nn.Module):
                     x, embed(jnp.roll(next_tokens, -i, axis=1)))
                 outs.append(out)
         return tuple(outs)
+
+
+def publish_stack_layers(mixers):
+    """Set ``hvd_stack_layers{kind}`` from the kinds of a mixed stack's
+    layers (``cfg.mixers``), so that a run's metrics say what stack it
+    ran; a kind of ``MIXERS`` that the stack lacks reads 0. ``Backbone``
+    calls it as it is built (under ``jit``: as it is traced). A no-op
+    when ``HOROVOD_TPU_METRICS`` is off."""
+    from ..telemetry import core as telemetry
+    if not telemetry.enabled():
+        return
+    layers = telemetry.gauge(
+        "hvd_stack_layers",
+        "Layers of the mixed stack last built, by the kind of their "
+        "token mixer (TransformerConfig.mixers)", ("kind",))
+    for kind in MIXERS:
+        layers.labels(kind=kind).set(float(mixers.count(kind)))
 
 
 class TransformerLM(nn.Module):

@@ -1,5 +1,6 @@
 """Fixtures shared by the expert layer's tests (``test_parallel.py``,
-``test_smallthinker.py``): import the fixture by name."""
+``test_glm4_moe_lite.py``, ``test_smallthinker.py``,
+``test_lfm2_moe.py``): import the fixture by name."""
 
 import jax.numpy as jnp
 import pytest
@@ -26,3 +27,16 @@ def poison(monkeypatch):
             jnp.full_like(args[-8:][i], jnp.nan) for i in moe._TRAINED))
     yield make
     clear()
+
+
+@pytest.fixture
+def telemetry_plane(monkeypatch):
+    """The telemetry module with metrics on and a registry of the
+    test's own. The process-wide one holds whatever earlier tests of the
+    worker published under the same families (``hvd_moe_*`` has three
+    models' tests as publishers), and a test that compares a family's
+    whole sample set must not see them."""
+    from horovod_tpu.telemetry import core as telemetry
+    monkeypatch.setattr(telemetry, "_ENABLED", True)
+    monkeypatch.setattr(telemetry, "_REGISTRY", telemetry.Registry())
+    return telemetry

@@ -27,6 +27,7 @@ from benchmark.references import common  # noqa: E402
 from benchmark.references import glm4_moe_lite as reference  # noqa: E402
 from horovod_tpu.models import TransformerLM  # noqa: E402
 from horovod_tpu.parallel import moe  # noqa: E402
+from moe_fixtures import telemetry_plane  # noqa: E402, F401 (a fixture)
 
 SEQ = 32
 
@@ -247,9 +248,8 @@ def test_selection_bias_changes_the_choice_and_not_the_weights():
     assert float(jnp.abs(g).max()) == 0.0
 
 
-def test_expert_tokens_reach_the_telemetry_plane(monkeypatch):
-    from horovod_tpu.telemetry import core as telemetry
-    monkeypatch.setattr(telemetry, "_ENABLED", True)
+def test_expert_tokens_reach_the_telemetry_plane(telemetry_plane):
+    telemetry = telemetry_plane
     state = {"moe_state": {"block_1": {"moe": {
         "bias": jnp.zeros((4,)),
         "expert_tokens": jnp.asarray([6.0, 2.0, 0.0, 8.0])}}}}
@@ -261,12 +261,12 @@ def test_expert_tokens_reach_the_telemetry_plane(monkeypatch):
     assert families["hvd_moe_held_share"]["samples"][0]["value"] == 0.5
 
 
-def test_buffer_rows_and_sized_layers_reach_the_telemetry_plane(monkeypatch):
+def test_buffer_rows_and_sized_layers_reach_the_telemetry_plane(
+        telemetry_plane):
     """1024 pairs over 8 experts of which 2 are held: 512 rows. The
     first layer's held draw (400) fits in them, the second's (700) does
     not and ran on a row for every pair."""
-    from horovod_tpu.telemetry import core as telemetry
-    monkeypatch.setattr(telemetry, "_ENABLED", True)
+    telemetry = telemetry_plane
     fits = jnp.asarray([150.0, 250, 104, 104, 104, 104, 104, 104])
     over = jnp.asarray([300.0, 400, 54, 54, 54, 54, 54, 54])
     state = {"moe_state": {

@@ -32,7 +32,7 @@ from benchmark.references import smallthinker as reference  # noqa: E402
 from horovod_tpu.models import TransformerLM  # noqa: E402
 from horovod_tpu.models import transformer  # noqa: E402
 from horovod_tpu.parallel import moe  # noqa: E402
-from moe_fixtures import poison  # noqa: E402, F401 (a fixture)
+from moe_fixtures import poison, telemetry_plane  # noqa: E402, F401 (fixtures)
 
 SEQ = 20        # over two windows of 8
 
@@ -459,10 +459,9 @@ def test_compiled_step_names_each_kind_of_layer_and_the_routers_place():
 
 
 def test_the_models_four_layers_reach_the_telemetry_plane(seeded,
-                                                          monkeypatch):
+                                                          telemetry_plane):
     from horovod_tpu.ops import flash_attention
-    from horovod_tpu.telemetry import core as telemetry
-    monkeypatch.setattr(telemetry, "_ENABLED", True)
+    telemetry = telemetry_plane
     cfg, model, params, aux, batch = seeded
     new_aux = program_loss(model, params, aux, batch)[1]
     moe.publish_expert_tokens(new_aux, held=tuple(cfg["experts_held"]))
