@@ -34,7 +34,7 @@ How it computes, and why (PERF.md section 3, "expert layer"):
   of ``sized_rows`` rows, twice that expectation (a static shape, from
   shapes alone), whenever the step's own draw fits in them: only the
   first rows of the sorted order are gathered, multiplied, weighed and
-  added back into their tokens (``_sized_rows``, ``_sized``). A draw
+  summed into their tokens (``_sized_rows``, ``_sized``). A draw
   that does not fit takes ``_routed``, the same computation on a row
   for every pair, chosen on the device by ``lax.cond`` on the count the
   router already makes; with every expert held there is one path and no
@@ -69,12 +69,27 @@ How it computes, and why (PERF.md section 3, "expert layer"):
   ``"dots"`` and ``"flash"`` do not keep it) lives only there. Forward
   and backward each go through ``jax.jit``, so a model's layers share
   one trace and one lowering of the two sizes.
-- ``_sized`` adds its rows into the tokens by a scatter-add of
-  ``sized_rows`` rows, whose transpose is a gather (and the gather's a
-  scatter-add): on the chip faster than un-gathering by token through a
-  ``[T, k, d]`` buffer (PERF.md section 6, PR 37). In ``_routed`` gather
-  and un-gather are a permutation and its inverse, each the other's
-  transpose, so neither direction scatters a row for every pair.
+- The sized rows come back into token order without a row being
+  scattered, forward (each token's rows weighed and summed) and, by
+  hand, backward (the tokens' gradient): ``_to_tokens``. XLA's
+  scatter-add costs 110-124 ns a row at every shape the benchmark has,
+  live or dead; a gather by token 5.5 ns a row out of a buffer of 34 MB
+  and 40 ns out of one of 252 MB (49,152 rows of 2560). The pairs
+  are sorted by expert with a stable sort, so one expert's rows are in
+  token order and the rows of one block of tokens and one expert are
+  consecutive: on the TPU, for bfloat16 rows in whole tiles
+  (``rows_to_tokens.tiling``, from shapes alone), a Mosaic kernel copies
+  that run for each expert held and sums the block's rows with one
+  product against a 0/1 matrix made from the rows' token ids
+  (``ops/rows_to_tokens.py``). Everywhere else (``_into_tokens``) a
+  gather of T rows for each of a token's k choices, selected where the
+  pair has a row of the buffers (``_places``), summed in float32 and
+  rounded once. Rows past the groups are selected away before either
+  sum, never multiplied by 0. PR 37's reading, that the scatter-add beat
+  the un-gather by token, was of a form that went through a buffer with
+  a row for every pair (PERF.md section 6, PR 37 and 41). In ``_routed``
+  gather and un-gather are a permutation and its inverse, each the
+  other's transpose, so neither direction scatters a row for every pair.
 """
 
 import dataclasses
@@ -87,6 +102,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from ..ops import rows_to_tokens
 from ..utils.jax_compat import pvary
 
 # Names in a device trace (docs/tracing.md); readers match the literals.
@@ -270,6 +286,14 @@ def _held_draw(rows, w_gate, drawn, first_held):
     return sizes, (jnp.arange(rows) < jnp.sum(sizes))[:, None]
 
 
+def _held_key(chosen, first_held, held):
+    """Each (token, choice) pair's expert among the ``held`` held here,
+    or ``held`` for a pair of an expert held elsewhere: what the pairs
+    are sorted by."""
+    local = chosen.reshape(-1) - first_held
+    return jnp.where((local >= 0) & (local < held), local, held)
+
+
 def _sized_rows(rows, x, w_gate, w_up, chosen, drawn, first_held):
     """The sized path as far as the experts' activation: the first
     ``rows`` pairs of the sorted order hold every pair of the experts
@@ -279,8 +303,7 @@ def _sized_rows(rows, x, w_gate, w_up, chosen, drawn, first_held):
     place of making it again (``kept_bytes``)."""
     held = w_gate.shape[0]
     with jax.named_scope(SCOPE_ROUTE):
-        local = chosen.reshape(-1) - first_held
-        key = jnp.where((local >= 0) & (local < held), local, held)
+        key = _held_key(chosen, first_held, held)
         order = _vary_like(jnp.argsort(key, stable=True), key)[:rows]
         sizes, _ = _held_draw(rows, w_gate, drawn, first_held)
         # Rows past the held pairs are other tokens' own, left as they
@@ -293,22 +316,95 @@ def _sized_rows(rows, x, w_gate, w_up, chosen, drawn, first_held):
     return gated, up, order
 
 
+def _places(order, chosen, live):
+    """``(place, has)``, both (T, k): the row of the sized buffers that
+    holds pair ``(t, j)`` and whether there is one. ``order`` (rows,) is
+    the first rows of the sorted order, of which the first ``live`` are
+    pairs of the experts held; every other pair reads ``has`` false and
+    a ``place`` that is some row's all the same, for a gather to read
+    and a select to throw away. A scatter of ``rows`` int32 to places no
+    two of which are alike: the sort is not made again."""
+    rows = order.shape[0]
+    place = _vary_like(jnp.full((chosen.size,), rows, jnp.int32), order).at[
+        order].set(jnp.arange(rows, dtype=jnp.int32), unique_indices=True)
+    place = place.reshape(chosen.shape)
+    return jnp.minimum(place, rows - 1), place < live
+
+
+def _into_tokens(buffer, place, has, weights=None):
+    """Rows of a sized buffer (rows, d) back in token order, (T, d):
+    ``sum_j buffer[place[t, j]] * weights[t, j]`` over the choices of
+    token ``t`` that have a row (``_places``). A gather of T rows a
+    choice, summed in float32 and rounded once; no buffer with a row
+    for every pair, and no row scattered: the transpose of
+    ``x[order // k]`` by hand. Select after the gather, never multiply
+    by 0: what a grouped product leaves in rows past its groups is not
+    specified."""
+    total = 0.0
+    for j in range(place.shape[1]):
+        mine = jnp.where(has[:, j, None], buffer[place[:, j]], 0).astype(
+            jnp.float32)
+        total = total + (mine if weights is None else
+                         mine * weights[:, j, None].astype(jnp.float32))
+    return total.astype(buffer.dtype)
+
+
+def return_rows(tokens, per_token, held, tiles):
+    """Rows the sized path reads to bring its rows back into token
+    order, each way: under the kernel (``tiles``: its block and chunk)
+    a chunk for every token block and expert held, where no run is
+    longer than a chunk; under the gathers a row for every pair."""
+    if tiles is None:
+        return tokens * per_token
+    block, chunk = tiles
+    return tokens // block * held * chunk
+
+
+def _publish_return_rows(tokens, per_token, held, tiles):
+    from ..telemetry import core as telemetry
+    if telemetry.enabled():
+        telemetry.gauge(
+            "hvd_moe_return_rows",
+            "Rows the expert layer last traced reads to bring its sized "
+            "rows back into token order, each way (return_rows: from "
+            "shapes alone; the kernel's chunks, or a row for every pair "
+            "under the gathers)").set(
+                float(return_rows(tokens, per_token, held, tiles)))
+
+
+def _to_tokens(buffer, order, sizes, chosen, first_held, experts,
+               weights=None):
+    """A sized buffer's rows (rows, d), each weighed where there are
+    ``weights`` (T, k), summed into their tokens, (T, d): the way back
+    from sorted order, forward and, by hand, backward. On the TPU, for
+    bfloat16 rows in whole tiles, through the MXU
+    (``ops/rows_to_tokens.py``); else by gathers (``_into_tokens``).
+    Neither scatters a row, and each sums in float32 and rounds once."""
+    tokens, k = chosen.shape
+    tiles = None if rows_to_tokens._interpret() else rows_to_tokens.tiling(
+        tokens, k, experts, sizes.shape[0], *buffer.shape, buffer.dtype)
+    _publish_return_rows(tokens, k, sizes.shape[0], tiles)
+    if tiles is None:
+        place, has = _places(order, chosen, jnp.sum(sizes))
+        return _into_tokens(buffer, place, has, weights)
+    return rows_to_tokens.rows_to_tokens(
+        buffer, order, _held_key(chosen, first_held, sizes.shape[0]), sizes,
+        k, tiles, None if weights is None else weights.reshape(-1))
+
+
 def _sized(rows, kept, x, w_gate, w_up, w_down, chosen, weights, drawn,
            first_held, gate="silu"):
     """``_routed`` for a draw of at most ``rows`` pairs, from
-    ``_sized_rows``: the activation, the down product, and the rows
-    weighed and added back into their tokens."""
+    ``_sized_rows``: the activation, the down product, and each token's
+    rows weighed and summed (``_into_tokens``)."""
     gated, up, order = kept
-    sizes, live = _held_draw(rows, w_gate, drawn, first_held)
+    sizes, _ = _held_draw(rows, w_gate, drawn, first_held)
     with jax.named_scope(SCOPE_EXPERTS):
         ys = lax.ragged_dot(GATES[gate](gated) * up,
                             w_down.astype(up.dtype), sizes)
     with jax.named_scope(SCOPE_ROUTE):
-        # Every live row is a pair of an expert held here: weigh it in
-        # sorted order and add it into its token's row.
-        weight = weights.reshape(-1)[order].astype(ys.dtype)[:, None]
-        return jnp.zeros_like(x).at[order // chosen.shape[1]].add(
-            jnp.where(live, ys, 0) * weight)
+        return _to_tokens(ys, order, sizes, chosen, first_held,
+                          drawn.shape[0], weights)
 
 
 # A grouped product's gradient to its weights: each group's rows of the
@@ -351,7 +447,7 @@ def _sized_back(rows, gate, g, kept, x, w_gate, w_up, w_down, chosen,
         h, back = jax.vjp(lambda a, b: GATES[gate](a) * b, gated, up)
         u = to_rows(gs, w_down)
         d_gated, d_up = back(u * weight)
-        d_xs = to_rows(d_gated, w_gate) + to_rows(d_up, w_up)
+        d_xs = to_rows(d_gated, w_gate), to_rows(d_up, w_up)
         d_w = (to_weights(xs, d_gated, w_gate), to_weights(xs, d_up, w_up),
                to_weights(h * weight, gs, w_down))
         d_weight = jnp.sum(h.astype(weights.dtype) * u.astype(weights.dtype),
@@ -359,8 +455,11 @@ def _sized_back(rows, gate, g, kept, x, w_gate, w_up, w_down, chosen,
     with jax.named_scope(SCOPE_ROUTE):
         d_weights = jnp.zeros_like(weights.reshape(-1)).at[order].add(
             jnp.where(live, d_weight, 0)[:, 0])
-        return (jnp.zeros_like(x).at[token].add(jnp.where(live, d_xs, 0)),
-                *d_w, d_weights.reshape(weights.shape))
+        # The sum of the two products is the way back's first pass, not
+        # a product's: a reader of ``hvd_moe/experts`` does not meet it.
+        return (_to_tokens(d_xs[0] + d_xs[1], order, sizes, chosen,
+                           first_held, drawn.shape[0]), *d_w,
+                d_weights.reshape(weights.shape))
 
 
 _TRAINED = (0, 1, 2, 3, 5)      # of ``routed``: x, the three w, weights

@@ -8,25 +8,28 @@ import pytest
 from horovod_tpu.parallel import moe
 
 
+def clear_traces():
+    """Drop what ``jax.jit`` keeps of the layer's two ways: a test that
+    patches what they trace calls this before and after."""
+    moe._either.clear_cache()
+    moe._either_back.clear_cache()
+
+
 @pytest.fixture
 def poison(monkeypatch):
     """``poison(path)`` makes ``path`` (``_sized`` or ``_routed``) and
     its backward pass return NaN: a result or a gradient that is finite
     did not come through it. They are traced under ``jax.jit``, whose
     traces are dropped before and after."""
-    def clear():
-        moe._either.clear_cache()
-        moe._either_back.clear_cache()
-
     def make(path):
-        clear()
+        clear_traces()
         # Both end in the routed part's eight arguments, tokens first.
         monkeypatch.setattr(moe, path, lambda *args, **kwargs: (
             jnp.full_like(args[-8], jnp.nan)))
         monkeypatch.setattr(moe, path + "_back", lambda *args: tuple(
             jnp.full_like(args[-8:][i], jnp.nan) for i in moe._TRAINED))
     yield make
-    clear()
+    clear_traces()
 
 
 @pytest.fixture

@@ -619,9 +619,10 @@ def test_moe_sized_branch_holds_no_row_for_every_pair(cell):
 @pytest.mark.parametrize("cell", list(_CELL_SHAPES))
 def test_moe_sized_way_back_makes_no_forward_again(cell):
     """The backward pass's sized branch at the two cells' shapes: no
-    sort, the tokens' and the cotangent's rows gathered, and six
-    grouped products, three to the rows and three to the weights, where
-    making the forward again had three more; what it reads was kept on
+    sort, the tokens' and the cotangent's rows gathered, the tokens'
+    gradient gathered back a choice at a time, and six grouped
+    products, three to the rows and three to the weights, where making
+    the forward again had three more; what it reads was kept on
     ``rows`` rows, ``kept_bytes`` of them, and nothing it makes has a
     row for every pair."""
     tokens, k, d, f, held, experts, gate = _CELL_SHAPES[cell]
@@ -647,8 +648,19 @@ def test_moe_sized_way_back_makes_no_forward_again(cell):
     assert sorted(products) == sorted(
         [(rows, f), (rows, d), (rows, d),
          (held, d, f), (held, d, f), (held, f, d)])
-    assert sum(name == "gather" and eqn.outvars[0].aval.shape == (rows, d)
-               for name, eqn in zip(names, eqns)) == 2
+    # Rows move by gathers alone, both ways: the tokens' and the
+    # cotangent's into the buffers, and the buffers' back into token
+    # order a choice at a time (``glm47flash`` has as many rows as
+    # tokens: tell them apart by what is read). Nothing scatters a row.
+    moved = [(eqn.invars[0].aval.shape, eqn.outvars[0].aval.shape)
+             for name, eqn in zip(names, eqns) if name == "gather"
+             and eqn.outvars[0].aval.shape[-1:] == (d,)]
+    assert moved.count(((tokens, d), (rows, d))) == 2 + k * (tokens == rows)
+    assert moved.count(((rows, d), (tokens, d))) == k + 2 * (tokens == rows)
+    assert len(moved) == 2 + k
+    assert not [eqn for name, eqn in zip(names, eqns)
+                if name.startswith("scatter")
+                and eqn.invars[2].aval.shape[-1:] == (d,)]
     seen = set(_all_shapes(jaxpr.jaxpr))
     assert not {(tokens * k, d), (tokens * k, f)} & seen
 
