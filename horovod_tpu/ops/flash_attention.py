@@ -7,9 +7,14 @@ has no attention kernels at all (it is a communication layer; SURVEY.md §2.6)
 transformers and attention is where HBM bandwidth goes.
 
 Design (MXU/VMEM-first):
-- Online-softmax tiling: grid (batch*heads, q_blocks, k_blocks); the k axis
-  is the innermost (sequential) grid dimension, with fp32 running max /
-  denominator / accumulator in VMEM scratch that persists across k steps.
+- Online-softmax tiling: grid (batch*heads, steps). A step is one (query
+  block, key block) tile, named by a table that is handed in as scalar
+  prefetch (``_step_table``): a query block's key blocks one after the
+  other, with fp32 running max / denominator / accumulator in VMEM
+  scratch that persists across them. Where the call's offsets are known
+  while tracing (every call of a model) the table lists the tiles that
+  do something and no other, so no grid step is idle; where they are
+  traced (a ring step) it lists every tile and the body guards them.
 - Two sizes. A **block** (the callers' ``block_q``, ``block_k``; 1024 in
   the model zoo) is what one grid step's DMA brings: a step's fixed cost
   and the rescaling of the running statistics are paid once a block, so
@@ -18,8 +23,9 @@ Design (MXU/VMEM-first):
   kernels walk the block the diagonal crosses in strips of query rows,
   each against the keys it can see, build the mask for the one sub-tile
   on the diagonal and run nothing for those beyond it. Blocks past a
-  query block's last visible one are neither computed nor fetched (their
-  index maps name a block already held).
+  query block's last visible one are no steps of the grid (with traced
+  offsets: neither computed nor fetched, the table names a block
+  already held).
 - The forward is key-major, like the backward: s^T = k q^T is (keys,
   queries), so max and sum over keys add vregs to each other and the
   running statistics are rows along lanes. Query-major, every 8 rows of
@@ -33,8 +39,8 @@ Design (MXU/VMEM-first):
   (scalar-prefetch), so the same compiled kernel serves local attention and
   every step of a ring schedule (offsets are device-varying under shard_map).
 - A causal ``window`` is a second edge of the same mask: the blocks
-  whose keys lie a window or more before their queries are skipped and
-  not fetched like those past the diagonal, and the one or two block
+  whose keys lie a window or more before their queries are left out
+  like those past the diagonal, and the one or two block
   offsets the window's edge passes through are walked in the same
   sub-tiles, each strip from its first visible key (``_aligned_offsets``,
   ``_strip_span``). ``k`` and ``v`` may hold fewer heads than ``q`` (the
@@ -47,7 +53,8 @@ Design (MXU/VMEM-first):
   ``hvd_flash_fwd`` writes the output and the log-sum-exp;
   ``hvd_flash_bwd_dkdv`` writes dq, dk and dv from one pass over the
   tiles (s, one exp, dp and ds once a tile: five matrix products). Its
-  grid is (batch*heads, k_blocks, q_blocks): dk and dv accumulate in
+  grid is (batch*heads, steps) too, a key block's query blocks one after
+  the other: dk and dv accumulate in
   per-key-block scratch, dq in a float32 VMEM accumulator over the whole
   query range of the (batch, head). It keeps the name of the dk/dv
   kernel it grew from, which the readers of a trace match.
@@ -395,8 +402,136 @@ def _kv_block(i, j, lens, n_k, block_q, block_k, causal, window=None,
     return lax.select(j < first, first, lax.select(j <= last, j, after))
 
 
-def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, *rest, sm_scale, causal,
-                block_q, block_k, n_k, sub, dropout_rate=0.0,
+def _first_query_block(j, lens, n_q, block_q, block_k, qb0):
+    # Truncating division: where it differs from the floor the first
+    # block is negative and ``i`` wins either way.
+    first = lax.div(lens[1] + j * block_k - lens[0],
+                    jnp.int32(block_q)) - qb0
+    return lax.min(first, jnp.int32(n_q - 1))
+
+
+def _q_block(j, i, lens, n_q, block_q, block_k, causal, qb0, window=None,
+             n_k=1):
+    """The query block grid step (j, i) of the backward holds, of a call
+    whose tiles are ``qb0`` onward of the sequence. Under a causal mask
+    the steps before a key block's first visible query block are skipped
+    (_block_skip): they name that first block, so they fetch nothing and
+    the block is there when its step comes. Under a window the steps
+    after its last visible query block name the first of the next key
+    block (of ``n_k``)."""
+    if not causal or n_q == 1:
+        return i
+    grid_of = (lens, n_q, block_q, block_k, qb0)
+    first = _first_query_block(j, *grid_of)
+    if window is None:
+        return lax.max(i, first)
+    last = lax.div(lens[1] + j * block_k + (block_k - 1) + (window - 1)
+                   - lens[0], jnp.int32(block_q)) - qb0
+    after = lax.max(_first_query_block(lax.rem(j + 1, jnp.int32(n_k)),
+                                       *grid_of), jnp.int32(0))
+    return lax.select(i <= last, lax.max(i, first), after)
+
+
+# ---------------------------------------------------------------------------
+# The grid: one axis over the tiles that do something
+# ---------------------------------------------------------------------------
+
+# Columns of a step table (_step_table), and the bits of its flags.
+_ROW, _INNER, _FETCH, _FLAGS = range(4)
+_ROW_FIRST, _ROW_LAST, _DQ_FIRST, _DQ_LAST = 1, 2, 4, 8
+
+
+def _column(steps, column, step):
+    """Entry ``step`` of a column of a step table (flat, a column after
+    the other: a one-dimensional array is what scalar memory holds
+    without padding)."""
+    return steps[column * (steps.shape[0] // 4) + step]
+
+
+def _named(column, one_tile):
+    """For an index map: ``(step, steps) -> `` the block that ``column``
+    of the step table names at a grid step. A call of one tile a
+    (batch, head) has block 0 alone and looks nothing up."""
+    if one_tile:
+        return lambda step, steps: 0
+    return lambda step, steps: _column(steps, column, step)
+
+
+def _this_step(steps_ref, one_tile):
+    """``(row, inner, flags)`` of the grid step that is running. A call
+    of one tile a (batch, head) looks nothing up: its one step is tile
+    (0, 0), every row's first and last, and the tests of its flags are
+    Python's, so the body is one straight line (at seq 512, one block a
+    head, the table's loads and branches cost 4% of either kernel:
+    PERF.md section 6, PR 43)."""
+    if one_tile:
+        return (jnp.int32(0), jnp.int32(0),
+                _ROW_FIRST | _ROW_LAST | _DQ_FIRST | _DQ_LAST)
+    return tuple(_column(steps_ref, column, pl.program_id(1))
+                 for column in (_ROW, _INNER, _FLAGS))
+
+
+def _step_table(backward, where, n_q, n_k, block_q, block_k, causal,
+                window=None, qb0=0):
+    """The steps of one (batch, head) of a kernel's grid ``(bh, steps)``,
+    handed to the kernel as scalar prefetch beside ``lens``: int32,
+    four columns of ``steps`` entries each (_column). A step is one
+    (query block, key block) tile. ``_ROW`` is the block whose
+    accumulators the step adds to (the forward's query block, the
+    backward's key block) and ``_INNER`` the other, ascending inside a
+    row, rows ascending: the order of the rectangular grid this one
+    replaced, so every running sum adds the same terms in the same
+    order. ``_FETCH`` is the inner block the step's index maps name and
+    ``_FLAGS`` says whether the step is its row's first
+    (``_ROW_FIRST``: the accumulators start) and last (``_ROW_LAST``:
+    they are written out) and, in the backward, whether its query block
+    is met for the first time (``_DQ_FIRST``: its rows of dq's
+    accumulator start) and for the last (``_DQ_LAST``: they are
+    written).
+
+    ``where`` is the call's ``(q_offset, k_offset, kv_len)``. Python
+    integers (a tuple; every call of a model): the table is made here,
+    in numpy, and lists the tiles that ``_block_skip`` does not skip and
+    nothing else, so no grid step is idle; a block of an output that no
+    tile is left of keeps one step, skipped in the body, which writes
+    its zeros. Traced (the kernels' ``lens``; a ring step, one compiled
+    kernel for every position): the live tiles are not known while
+    tracing, so the table lists every tile, ``_block_skip`` guards the
+    body, and a skipped step names a block already held (_kv_block,
+    _q_block) and fetches nothing. One grid, one kernel body; what
+    differs is the table. ``qb0``: the backward's query blocks are
+    ``qb0`` onward of the sequence (_bwd_call)."""
+    qb, kb = np.meshgrid(np.arange(n_q), np.arange(n_k), indexing="ij")
+    traced = not isinstance(where, tuple)
+    run = np.ones(qb.shape, bool)
+    if not traced:
+        run &= ~_block_skip(causal, *where, qb0 + qb, kb, block_q, block_k,
+                            window)
+        if backward:
+            run[-1, ~run.any(axis=0)] = True
+        run[~run.any(axis=1), 0] = True
+    row, inner = np.nonzero(run.T if backward else run)
+    turns = row[1:] != row[:-1]
+    flags = (_ROW_FIRST * np.r_[True, turns] + _ROW_LAST * np.r_[turns, True])
+    if backward:
+        flags[np.unique(inner, return_index=True)[1]] |= _DQ_FIRST
+        flags[len(inner) - 1
+              - np.unique(inner[::-1], return_index=True)[1]] |= _DQ_LAST
+    columns = [x.astype(np.int32) for x in (row, inner, inner, flags)]
+    if not traced:
+        return np.concatenate(columns)
+    row, inner = jnp.asarray(columns[_ROW]), jnp.asarray(columns[_INNER])
+    if backward:
+        columns[_FETCH] = _q_block(row, inner, where, n_q, block_q, block_k,
+                                   causal, qb0, window, n_k)
+    else:
+        columns[_FETCH] = _kv_block(row, inner, where, n_k, block_q,
+                                    block_k, causal, window, n_q)
+    return jnp.concatenate(columns)
+
+
+def _fwd_kernel(lens_ref, steps_ref, q_ref, k_ref, v_ref, *rest, sm_scale,
+                causal, block_q, block_k, sub, one_tile, dropout_rate=0.0,
                 seeded=False, window=None):
     # rest = [dm_ref?], o_ref, lse_ref, m_scr, l_scr, acc_scr
     if dropout_rate > 0.0 and not seeded:
@@ -404,8 +539,9 @@ def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, *rest, sm_scale, causal,
     else:
         dm_ref = None
         o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
-    kb = pl.program_id(2)
-    qb = pl.program_id(1)
+    # This grid step's tile, and whether it is its query block's first
+    # and last (_step_table).
+    qb, kb, flags = _this_step(steps_ref, one_tile)
     q_start = lens_ref[0]
     k_start = lens_ref[1]
     kv_len = lens_ref[2]
@@ -413,7 +549,7 @@ def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, *rest, sm_scale, causal,
     # so s needs no multiply of its own.
     fold = math.frexp(sm_scale)[0] == 0.5
 
-    @pl.when(kb == 0)
+    @pl.when((flags & _ROW_FIRST) != 0)
     def _():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -512,7 +648,7 @@ def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, *rest, sm_scale, causal,
     def _():
         update(draw(), 0, block_q, 0, block_k, 0, block_k)
 
-    @pl.when(kb == n_k - 1)
+    @pl.when((flags & _ROW_LAST) != 0)
     def _():
         l = l_scr[:1, :]                           # (1, block_q)
         safe_l = jnp.where(l == 0.0, 1.0, l)
@@ -524,18 +660,26 @@ def _fwd_kernel(lens_ref, q_ref, k_ref, v_ref, *rest, sm_scale, causal,
 
 
 def _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
-              dm=None, dropout_rate=0.0, seeded=False, window=None):
+              dm=None, dropout_rate=0.0, seeded=False, window=None,
+              where=None):
     """One forward kernel call. The layers of a model make the same
     call, so it goes through ``jax.jit``: the kernel is traced and
     lowered once a program, not once a layer, and XLA inlines the calls
     (each keeps its own layer's ``op_name``). What the trace reads of
-    the module's state is an argument, so no cached trace outlives it.
+    the module's state is an argument, so no cached trace outlives it;
+    so are the grid's steps (_step_table: a grid step is a tile that
+    does something wherever ``where``, the call's offsets and
+    ``kv_len`` as Python integers, is given, and any tile where they
+    are traced), which the layers' calls hand in alike.
 
     ``k`` and ``v`` may hold fewer heads than ``q`` (``q``'s rows are
     ``group`` to a row of theirs, adjacent), and ``v`` another width
     than ``q`` and ``k``."""
-    return _fwd_jit(q, k, v, lens, dm, sm_scale, causal, block_q, block_k,
-                    _sub_tile(causal, block_q, block_k, q.shape[2]),
+    steps = _step_table(False, lens if where is None else where,
+                        q.shape[1] // block_q, k.shape[1] // block_k,
+                        block_q, block_k, causal, window)
+    return _fwd_jit(q, k, v, lens, dm, steps, sm_scale, causal, block_q,
+                    block_k, _sub_tile(causal, block_q, block_k, q.shape[2]),
                     dropout_rate, seeded, _interpret(), window)
 
 
@@ -544,47 +688,45 @@ def _kv_row(b, group):
     return b if group == 1 else lax.div(b, jnp.int32(group))
 
 
-@functools.partial(jax.jit, static_argnums=tuple(range(5, 14)))
-def _fwd_jit(q, k, v, lens, dm, sm_scale, causal, block_q, block_k, sub,
-             dropout_rate, seeded, interpret, window):
+@functools.partial(jax.jit, static_argnums=tuple(range(6, 15)))
+def _fwd_jit(q, k, v, lens, dm, steps, sm_scale, causal, block_q, block_k,
+             sub, dropout_rate, seeded, interpret, window):
     bh, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]
     group = bh // k.shape[0]
-    n_q = sq // block_q
-    n_k = sk // block_k
+    one_tile = sq == block_q and sk == block_k
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k, n_k=n_k, sub=sub,
+        block_q=block_q, block_k=block_k, sub=sub, one_tile=one_tile,
         dropout_rate=dropout_rate, seeded=seeded, window=window)
+    qb, kb = _named(_ROW, one_tile), _named(_FETCH, one_tile)
 
-    grid_of = (n_k, block_q, block_k, causal)
-
-    def kv_at(b, i, j, lens):
-        return (_kv_row(b, group),
-                _kv_block(i, j, lens, *grid_of, window, n_q), 0)
+    def kv_at(b, s, lens, steps):
+        return _kv_row(b, group), kb(s, steps), 0
 
     in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, i, j, lens: (b, i, 0)),
+        pl.BlockSpec((1, block_q, d),
+                     lambda b, s, lens, steps: (b, qb(s, steps), 0)),
         pl.BlockSpec((1, block_k, d), kv_at),
         pl.BlockSpec((1, block_k, dv), kv_at),
     ]
     operands = [q, k, v]
     if dropout_rate > 0.0 and not seeded:
-        def dm_block(b, i, j, lens):
-            # The next query block's first mask block is another one:
-            # the skipped steps keep the last visible one.
-            last = _last_key_block(i, lens, *grid_of)
-            return b, i, j if last is None else lax.min(j, last)
-
-        in_specs.append(pl.BlockSpec((1, block_q, block_k), dm_block))
+        # Beside the K/V block the step names: the step's own wherever
+        # the tile is visible.
+        in_specs.append(pl.BlockSpec(
+            (1, block_q, block_k), lambda b, s, lens, steps: (
+                b, qb(s, steps), kb(s, steps))))
         operands.append(dm)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(bh, n_q, n_k),
+        num_scalar_prefetch=2,
+        grid=(bh, steps.shape[0] // 4),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, dv), lambda b, i, j, lens: (b, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, i, j, lens: (b, 0, i)),
+            pl.BlockSpec((1, block_q, dv),
+                         lambda b, s, lens, steps: (b, qb(s, steps), 0)),
+            pl.BlockSpec((1, 1, block_q),
+                         lambda b, s, lens, steps: (b, 0, qb(s, steps))),
         ],
         scratch_shapes=[
             pltpu.VMEM((8, block_q), jnp.float32),
@@ -597,7 +739,7 @@ def _fwd_jit(q, k, v, lens, dm, sm_scale, causal, block_q, block_k, sub,
         _struct((bh, 1, sq), jnp.float32, q, k, v, lens),
     ]
     compiler_params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+        dimension_semantics=("parallel", "arbitrary"))
     o, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -605,7 +747,7 @@ def _fwd_jit(q, k, v, lens, dm, sm_scale, causal, block_q, block_k, sub,
         compiler_params=compiler_params,
         interpret=interpret,
         name=KERNEL_FWD,
-    )(lens, *operands)
+    )(lens, steps, *operands)
     return o, lse[:, 0, :]
 
 
@@ -613,47 +755,18 @@ def _fwd_jit(q, k, v, lens, dm, sm_scale, causal, block_q, block_k, sub,
 # Backward kernel
 # ---------------------------------------------------------------------------
 
-def _first_query_block(j, lens, n_q, block_q, block_k, qb0):
-    # Truncating division: where it differs from the floor the first
-    # block is negative and ``i`` wins either way.
-    first = lax.div(lens[1] + j * block_k - lens[0],
-                    jnp.int32(block_q)) - qb0
-    return lax.min(first, jnp.int32(n_q - 1))
-
-
-def _q_block(j, i, lens, n_q, block_q, block_k, causal, qb0, window=None,
-             n_k=1):
-    """The query block grid step (j, i) of the backward holds, of a call
-    whose tiles are ``qb0`` onward of the sequence. Under a causal mask
-    the steps before a key block's first visible query block are skipped
-    (_block_skip): they name that first block, so they fetch nothing and
-    the block is there when its step comes. Under a window the steps
-    after its last visible query block name the first of the next key
-    block (of ``n_k``)."""
-    if not causal or n_q == 1:
-        return i
-    grid_of = (lens, n_q, block_q, block_k, qb0)
-    first = _first_query_block(j, *grid_of)
-    if window is None:
-        return lax.max(i, first)
-    last = lax.div(lens[1] + j * block_k + (block_k - 1) + (window - 1)
-                   - lens[0], jnp.int32(block_q)) - qb0
-    after = lax.max(_first_query_block(lax.rem(j + 1, jnp.int32(n_k)),
-                                       *grid_of), jnp.int32(0))
-    return lax.select(i <= last, lax.max(i, first), after)
-
-
-def _bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                *rest, sm_scale, causal, block_q, block_k, qb0, sub,
-                dropout_rate=0.0, seeded=False, window=None):
+def _bwd_kernel(lens_ref, steps_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                delta_ref, *rest, sm_scale, causal, block_q, block_k, qb0,
+                sub, one_tile, dropout_rate=0.0, seeded=False, window=None):
     # rest = [dm_ref?], dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr
     if dropout_rate > 0.0 and not seeded:
         dm_ref, *rest = rest
     else:
         dm_ref = None
     dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr = rest
-    kb, n_k = pl.program_id(1), pl.num_programs(1)
-    qb, n_q = pl.program_id(2), pl.num_programs(2)
+    # This grid step's tile, whether it is its key block's first and
+    # last, and its query block's in the whole call (_step_table).
+    kb, qb, flags = _this_step(steps_ref, one_tile)
     # This call's query rows are a chunk of the sequence (see _bwd_call):
     # tile ``qb`` here is tile ``qg`` of the whole, which is what the mask
     # and the dropout seed are drawn from.
@@ -669,12 +782,12 @@ def _bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def dq_rows(row0, n_rows):
         return pl.ds(pl.multiple_of(qb * block_q + row0, n_rows), n_rows)
 
-    @pl.when(qb == 0)
+    @pl.when((flags & _ROW_FIRST) != 0)
     def _():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    @pl.when(kb == 0)
+    @pl.when((flags & _DQ_FIRST) != 0)
     def _():
         dq_scr[dq_rows(0, block_q), :] = jnp.zeros(
             (block_q, dq_scr.shape[1]), jnp.float32)
@@ -788,12 +901,12 @@ def _bwd_kernel(lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _():
         tile_update(draw(), 0, block_q, 0, block_k, 0, block_k)
 
-    @pl.when(qb == n_q - 1)
+    @pl.when((flags & _ROW_LAST) != 0)
     def _():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
-    @pl.when(kb == n_k - 1)
+    @pl.when((flags & _DQ_LAST) != 0)
     def _():
         at = dq_rows(0, block_q)
         dq = dq_scr[at, :]
@@ -822,13 +935,16 @@ def _dq_resident_bytes(rows, d, dtype):
 @jax.named_scope(SCOPE)
 def _bwd_call(q, k, v, o, do, lse, lens, sm_scale, causal, block_q, block_k,
               g_lse=None, dm=None, dropout_rate=0.0, seeded=False,
-              window=None):
+              window=None, where=None):
     """dq, dk and dv from one kernel: one pass over the (key, query)
     tiles computes s, exp, dp and ds once and feeds all three gradients.
-    Grid (batch*heads, k_blocks, q_blocks), query innermost: dk and dv
-    accumulate in per-key-block scratch, dq in a float32 VMEM accumulator
-    over the whole query range of the (batch, head), its output block
-    resident until the last key block has been added. Where ``k`` and
+    Grid (batch*heads, steps), a step a tile (_step_table: the tiles
+    that do something wherever ``where`` is given, every tile where the
+    offsets are traced), a key block's query blocks one after the other:
+    dk and dv accumulate in per-key-block scratch, dq in a float32 VMEM
+    accumulator over the whole query range of the (batch, head), its
+    output block resident until the (batch, head) is done. A chunk's
+    table is made for its own query blocks. Where ``k`` and
     ``v`` hold fewer heads than ``q``, the kernel writes every query
     head's share of dk and dv in float32 and the group's are summed
     here."""
@@ -850,21 +966,26 @@ def _bwd_call(q, k, v, o, do, lse, lens, sm_scale, causal, block_q, block_k,
                     // _dq_resident_bytes(block_q, d, q.dtype))
     # What the trace reads of the module's state is an argument, as in
     # _fwd_call.
-    chunk = functools.partial(
-        _bwd_chunk, k=k, v=v, lens=lens, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k,
-        sub=_sub_tile(causal, block_q, block_k, d, backward=True),
-        dropout_rate=dropout_rate, seeded=seeded, interpret=_interpret(),
-        window=window)
+    def chunk(qb0, n_q, *rows, **kv_dtype):
+        return _bwd_chunk(
+            *rows, k=k, v=v, lens=lens, qb0=qb0, steps=_step_table(
+                True, lens if where is None else where, n_q,
+                k.shape[1] // block_k, block_q, block_k, causal, window, qb0),
+            sm_scale=sm_scale, causal=causal, block_q=block_q,
+            block_k=block_k,
+            sub=_sub_tile(causal, block_q, block_k, d, backward=True),
+            dropout_rate=dropout_rate, seeded=seeded,
+            interpret=_interpret(), window=window, **kv_dtype)
+
     if n_q <= per_chunk and group == 1:
-        return chunk(q, do, lse3, delta3, dm, qb0=0)
+        return chunk(0, n_q, q, do, lse3, delta3, dm)
     dqs, dk, dv = [], 0.0, 0.0
     for qb0 in range(0, n_q, per_chunk):
         rows = slice(qb0 * block_q, (qb0 + per_chunk) * block_q)
         dq_c, dk_c, dv_c = chunk(
+            qb0, min(per_chunk, n_q - qb0),
             q[:, rows], do[:, rows], lse3[:, :, rows], delta3[:, :, rows],
-            None if dm is None else dm[:, rows], qb0=qb0,
-            kv_dtype=jnp.float32)
+            None if dm is None else dm[:, rows], kv_dtype=jnp.float32)
         dqs.append(dq_c)
         dk, dv = dk + dk_c, dv + dv_c
     if group > 1:
@@ -877,11 +998,12 @@ def _bwd_call(q, k, v, o, do, lse, lens, sm_scale, causal, block_q, block_k,
 @functools.partial(jax.jit, static_argnames=(
     "qb0", "sm_scale", "causal", "block_q", "block_k", "sub",
     "dropout_rate", "seeded", "interpret", "kv_dtype", "window"))
-def _bwd_chunk(q, do, lse3, delta3, dm, *, k, v, lens, qb0, sm_scale,
-               causal, block_q, block_k, sub, dropout_rate, seeded,
+def _bwd_chunk(q, do, lse3, delta3, dm, *, k, v, lens, steps, qb0,
+               sm_scale, causal, block_q, block_k, sub, dropout_rate, seeded,
                interpret, kv_dtype=None, window=None):
     """The backward kernel over the query rows it is given: tiles ``qb0``
-    onward of the sequence. dk and dv are this chunk's share, a row to
+    onward of the sequence, in the order ``steps`` gives (_step_table,
+    of these rows' query blocks). dk and dv are this chunk's share, a row to
     each of ``q``'s, in
     ``kv_dtype`` (k's and v's own unless the caller sums shares). The
     layers of a model make the same call, so it goes through ``jax.jit``
@@ -889,39 +1011,38 @@ def _bwd_chunk(q, do, lse3, delta3, dm, *, k, v, lens, qb0, sm_scale,
     bh, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]
     group = bh // k.shape[0]
-    n_q = sq // block_q
-    n_k = sk // block_k
-    def qi(j, i, lens):
-        return _q_block(j, i, lens, n_q, block_q, block_k, causal, qb0,
-                        window, n_k)
+    one_tile = sq == block_q and sk == block_k
+    qi, kb = _named(_FETCH, one_tile), _named(_ROW, one_tile)
 
     def q_tile(width):
-        return pl.BlockSpec((1, block_q, width),
-                            lambda b, j, i, lens: (b, qi(j, i, lens), 0))
+        return pl.BlockSpec(
+            (1, block_q, width),
+            lambda b, s, lens, steps: (b, qi(s, steps), 0))
 
     def k_tile(width, row=lambda b: b):
-        return pl.BlockSpec((1, block_k, width),
-                            lambda b, j, i, lens: (row(b), j, 0))
+        return pl.BlockSpec(
+            (1, block_k, width),
+            lambda b, s, lens, steps: (row(b), kb(s, steps), 0))
 
     def kv_row(b):
         return _kv_row(b, group)
 
     q_row = pl.BlockSpec((1, 1, block_q),
-                         lambda b, j, i, lens: (b, 0, qi(j, i, lens)))
+                         lambda b, s, lens, steps: (b, 0, qi(s, steps)))
     in_specs = [q_tile(d), k_tile(d, kv_row), k_tile(dv, kv_row),
                 q_tile(dv), q_row, q_row]
     operands = [q, k, v, do, lse3, delta3]
     if dm is not None:
         in_specs.append(pl.BlockSpec(
             (1, block_q, block_k),
-            lambda b, j, i, lens: (b, qi(j, i, lens), j)))
+            lambda b, s, lens, steps: (b, qi(s, steps), kb(s, steps))))
         operands.append(dm)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(bh, n_k, n_q),
+        num_scalar_prefetch=2,
+        grid=(bh, steps.shape[0] // 4),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, sq, d), lambda b, j, i, lens: (b, 0, 0)),
+            pl.BlockSpec((1, sq, d), lambda b, s, lens, steps: (b, 0, 0)),
             k_tile(d), k_tile(dv),
         ],
         scratch_shapes=[
@@ -933,8 +1054,8 @@ def _bwd_chunk(q, do, lse3, delta3, dm, *, k, v, lens, qb0, sm_scale,
     dq, dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
-            block_k=block_k, qb0=qb0, sub=sub, dropout_rate=dropout_rate,
-            seeded=seeded, window=window),
+            block_k=block_k, qb0=qb0, sub=sub, one_tile=one_tile,
+            dropout_rate=dropout_rate, seeded=seeded, window=window),
         grid_spec=grid_spec,
         out_shape=[
             _struct((bh, sq, d), q.dtype, q, k, v, do, lens),
@@ -942,7 +1063,7 @@ def _bwd_chunk(q, do, lse3, delta3, dm, *, k, v, lens, qb0, sm_scale,
             _struct((bh, sk, dv), kv_dtype or v.dtype, q, k, v, do, lens),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
             # A value wider than the key, and a group's shares of dk
             # and dv written in float32, make the tiles' blocks as large
             # as a head of twice the width does.
@@ -951,7 +1072,7 @@ def _bwd_chunk(q, do, lse3, delta3, dm, *, k, v, lens, qb0, sm_scale,
             + _dq_resident_bytes(sq, d, q.dtype)),
         interpret=interpret,
         name=KERNEL_BWD_DKDV,
-    )(lens, *operands)
+    )(lens, steps, *operands)
     return dq, dk, dv
 
 
@@ -965,24 +1086,30 @@ def _bwd_chunk(q, do, lse3, delta3, dm, *, k, v, lens, qb0, sm_scale,
 SAVED_NAMES = ("hvd_flash_o", "hvd_flash_lse")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash(q, k, v, lens, sm_scale, causal, block_q, block_k, window=None):
+# ``where`` is the call's (q_offset, k_offset, kv_len) as Python integers,
+# or None where any of them is traced: what the grids' steps are made from
+# (_step_table).
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, lens, sm_scale, causal, block_q, block_k, window=None,
+           where=None):
     o, _ = _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
-                     window=window)
+                     window=window, where=where)
     return o
 
 
-def _flash_fwd(q, k, v, lens, sm_scale, causal, block_q, block_k, window):
+def _flash_fwd(q, k, v, lens, sm_scale, causal, block_q, block_k, window,
+               where):
     o, lse = map(ad_checkpoint.checkpoint_name, _fwd_call(
-        q, k, v, lens, sm_scale, causal, block_q, block_k, window=window),
-        SAVED_NAMES)
+        q, k, v, lens, sm_scale, causal, block_q, block_k, window=window,
+        where=where), SAVED_NAMES)
     return o, (q, k, v, o, lse, lens)
 
 
-def _flash_bwd(sm_scale, causal, block_q, block_k, window, res, g):
+def _flash_bwd(sm_scale, causal, block_q, block_k, window, where, res, g):
     q, k, v, o, lse, lens = res
     dq, dk, dv = _bwd_call(q, k, v, o, g, lse, lens, sm_scale, causal,
-                           block_q, block_k, window=window)
+                           block_q, block_k, window=window, where=where)
     dlens = np.zeros((3,), jax.dtypes.float0)
     return dq, dk, dv, dlens
 
@@ -990,21 +1117,25 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, window, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash_with_lse(q, k, v, lens, sm_scale, causal, block_q, block_k):
-    return _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_with_lse(q, k, v, lens, sm_scale, causal, block_q, block_k,
+                    where=None):
+    return _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
+                     where=where)
 
 
-def _flash_with_lse_fwd(q, k, v, lens, sm_scale, causal, block_q, block_k):
-    o, lse = _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k)
+def _flash_with_lse_fwd(q, k, v, lens, sm_scale, causal, block_q, block_k,
+                        where):
+    o, lse = _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
+                       where=where)
     return (o, lse), (q, k, v, o, lse, lens)
 
 
-def _flash_with_lse_bwd(sm_scale, causal, block_q, block_k, res, g):
+def _flash_with_lse_bwd(sm_scale, causal, block_q, block_k, where, res, g):
     q, k, v, o, lse, lens = res
     go, g_lse = g
     dq, dk, dv = _bwd_call(q, k, v, o, go, lse, lens, sm_scale, causal,
-                           block_q, block_k, g_lse=g_lse)
+                           block_q, block_k, g_lse=g_lse, where=where)
     dlens = np.zeros((3,), jax.dtypes.float0)
     return dq, dk, dv, dlens
 
@@ -1012,25 +1143,27 @@ def _flash_with_lse_bwd(sm_scale, causal, block_q, block_k, res, g):
 _flash_with_lse.defvjp(_flash_with_lse_fwd, _flash_with_lse_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def _flash_dropout(q, k, v, lens, dm, sm_scale, causal, block_q, block_k,
-                   rate):
+                   rate, where=None):
     o, _ = _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
-                     dm=dm, dropout_rate=rate)
+                     dm=dm, dropout_rate=rate, where=where)
     return o
 
 
 def _flash_dropout_fwd(q, k, v, lens, dm, sm_scale, causal, block_q,
-                       block_k, rate):
+                       block_k, rate, where):
     o, lse = _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
-                       dm=dm, dropout_rate=rate)
+                       dm=dm, dropout_rate=rate, where=where)
     return o, (q, k, v, o, lse, lens, dm)
 
 
-def _flash_dropout_bwd(sm_scale, causal, block_q, block_k, rate, res, g):
+def _flash_dropout_bwd(sm_scale, causal, block_q, block_k, rate, where, res,
+                       g):
     q, k, v, o, lse, lens, dm = res
     dq, dk, dv = _bwd_call(q, k, v, o, g, lse, lens, sm_scale, causal,
-                           block_q, block_k, dm=dm, dropout_rate=rate)
+                           block_q, block_k, dm=dm, dropout_rate=rate,
+                           where=where)
     dlens = np.zeros((3,), jax.dtypes.float0)
     ddm = np.zeros(dm.shape, jax.dtypes.float0)
     return dq, dk, dv, dlens, ddm
@@ -1039,26 +1172,27 @@ def _flash_dropout_bwd(sm_scale, causal, block_q, block_k, rate, res, g):
 _flash_dropout.defvjp(_flash_dropout_fwd, _flash_dropout_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _flash_seeded(q, k, v, lens, sm_scale, causal, block_q, block_k,
-                  rate):
+                  rate, where=None):
     o, _ = _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
-                     dropout_rate=rate, seeded=True)
+                     dropout_rate=rate, seeded=True, where=where)
     return o
 
 
 def _flash_seeded_fwd(q, k, v, lens, sm_scale, causal, block_q, block_k,
-                      rate):
+                      rate, where):
     o, lse = _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
-                       dropout_rate=rate, seeded=True)
+                       dropout_rate=rate, seeded=True, where=where)
     return o, (q, k, v, o, lse, lens)
 
 
-def _flash_seeded_bwd(sm_scale, causal, block_q, block_k, rate, res, g):
+def _flash_seeded_bwd(sm_scale, causal, block_q, block_k, rate, where, res,
+                      g):
     q, k, v, o, lse, lens = res
     dq, dk, dv = _bwd_call(q, k, v, o, g, lse, lens, sm_scale, causal,
                            block_q, block_k, dropout_rate=rate,
-                           seeded=True)
+                           seeded=True, where=where)
     dlens = np.zeros((4,), jax.dtypes.float0)
     return dq, dk, dv, dlens
 
@@ -1081,9 +1215,8 @@ def _effective_window(window, sq, q_offset, k_offset):
     """``window``, or None where it is at least the distance from the
     call's last query to its first key and so hides nothing (offsets
     known at trace time)."""
-    if window is not None and all(
-            isinstance(x, (int, np.integer)) for x in (q_offset, k_offset)
-    ) and window >= q_offset + sq - k_offset:
+    if window is not None and _static(q_offset, k_offset) is not None \
+            and window >= q_offset + sq - k_offset:
         return None
     return window
 
@@ -1093,9 +1226,11 @@ def subtile_counts(kernel, sq, sk, block_q, block_k, causal, q_offset=0,
     """How ``kernel`` (``"fwd"`` or ``"bwd"``) visits one (batch, head)'s
     score matrix: sub-tiles ``interior`` (no mask built), ``masked`` and
     ``skipped`` (no product, no exponential), and ``steps_without_fetch``,
-    the grid steps whose blocks are the ones already held (K and V in the
-    forward; q and do in the backward, its query range taken as one
-    chunk). Under a ``window`` that hides something, ``skipped`` are the
+    of the steps the grid runs (``grid_steps``: the tiles that do
+    something, not the rectangle of blocks) those whose blocks are the
+    ones already held (K and V in the forward; q and do in the backward,
+    its query range taken as one chunk). Under a ``window`` that hides
+    something, ``skipped`` are the
     sub-tiles the causal mask and the padding hide and ``window`` those
     that only the window does. A function of shapes and offsets alone,
     by the functions the kernels themselves class tiles and name blocks
@@ -1140,17 +1275,8 @@ def subtile_counts(kernel, sq, sk, block_q, block_k, causal, q_offset=0,
                                 q_offset, k_offset, kv_len, head_dim)
         counts["window"] = counts["skipped"] - hidden["skipped"]
         counts["skipped"] = hidden["skipped"]
-    with jax.ensure_compile_time_eval():
-        lens = jnp.asarray([q_offset, k_offset, kv_len], jnp.int32)
-        qb, kb = jnp.asarray(qb, jnp.int32), jnp.asarray(kb, jnp.int32)
-        if backward:        # key blocks outer, query blocks inner
-            held = np.asarray(_q_block(
-                kb, qb, lens, n_q, block_q, block_k, causal, 0, window,
-                n_k)).T.reshape(-1)
-        else:
-            held = np.asarray(_kv_block(
-                qb, kb, lens, n_k, block_q, block_k, causal, window,
-                n_q)).reshape(-1)
+    held = _step_table(backward, (q_offset, k_offset, kv_len), n_q, n_k,
+                       block_q, block_k, causal, window).reshape(4, -1)[_FETCH]
     counts["steps_without_fetch"] = int((held[1:] == held[:-1]).sum())
     return counts
 
@@ -1159,24 +1285,71 @@ fwd_subtile_counts = functools.partial(subtile_counts, "fwd")
 bwd_subtile_counts = functools.partial(subtile_counts, "bwd")
 
 
-def _publish_subtiles(*call):
-    """Set ``hvd_flash_fwd_subtiles{kind}`` and
-    ``hvd_flash_bwd_subtiles{kind}`` (docs/metrics.md) from
-    ``subtile_counts`` of the call being traced (kind ``window`` too
-    where the call has a window that hides something). A no-op when
-    ``HOROVOD_TPU_METRICS`` is off."""
+def _static(*where):
+    """``where`` as Python integers, or None where any is traced."""
+    if all(isinstance(x, (int, np.integer)) for x in where):
+        return tuple(int(x) for x in where)
+    return None
+
+
+def grid_steps(kernel, sq, sk, block_q, block_k, causal, q_offset=0,
+               k_offset=0, kv_len=None, window=None):
+    """The steps of one (batch, head) of ``kernel``'s grid (``"fwd"`` or
+    ``"bwd"``, its query range taken as one chunk): ``run``, the steps
+    the grid has, and ``live``, those of them whose tile does something
+    (``_block_skip`` false). A function of shapes and offsets alone, by
+    the table the kernel is handed (_step_table). ``run == live``: the
+    grid has no idle step (``run`` is larger by the blocks of an output
+    that see nothing and are written as zeros). With a traced offset or
+    ``kv_len`` the grid runs every tile, ``run = n_q * n_k``, and which
+    are live is not known here: no ``live``."""
+    window = _effective_window(window, sq, q_offset, k_offset)
+    block_q, block_k = _clamp_blocks(sq, sk, block_q, block_k)
+    n_q, n_k = -(-sq // block_q), -(-sk // block_k)
+    where = _static(q_offset, k_offset, sk if kv_len is None else kv_len)
+    if where is None:
+        return {"run": n_q * n_k}
+    backward = kernel == "bwd"
+    steps = _step_table(backward, where, n_q, n_k, block_q, block_k, causal,
+                        window).reshape(4, -1)
+    kb, qb = steps[_ROW if backward else _INNER], steps[
+        _INNER if backward else _ROW]
+    live = ~_block_skip(causal, *where, qb, kb, block_q, block_k, window)
+    return {"run": steps.shape[1], "live": int(live.sum())}
+
+
+def _publish_subtiles(sq, sk, block_q, block_k, causal, q_offset, k_offset,
+                    kv_len, head_dim, window):
+    """Set ``hvd_flash_grid_steps{kernel,kind}`` from ``grid_steps`` of
+    the call being traced and, where its offsets and ``kv_len`` are
+    known while tracing, ``hvd_flash_fwd_subtiles{kind}`` and
+    ``hvd_flash_bwd_subtiles{kind}`` from ``subtile_counts`` (kind
+    ``window`` too where the call has a window that hides something);
+    docs/metrics.md. A no-op when ``HOROVOD_TPU_METRICS`` is off."""
     from ..telemetry import core as telemetry
     if not telemetry.enabled():
         return
+    call = (sq, sk, block_q, block_k, causal, q_offset, k_offset, kv_len)
+    steps = telemetry.gauge(
+        "hvd_flash_grid_steps",
+        "Grid steps of one (batch, head) of the flash kernels of the call "
+        "last traced: run, and of those the live ones, whose tile does "
+        "something (not set for traced offsets, whose grid runs every "
+        "tile)", ("kernel", "kind"))
     for kernel, name, which, held in (
             ("fwd", "hvd_flash_fwd_subtiles", "forward", "K/V"),
             ("bwd", "hvd_flash_bwd_subtiles", "backward", "q/do")):
+        for kind, n in grid_steps(kernel, *call, window).items():
+            steps.labels(kernel=kernel, kind=kind).set(float(n))
+        if _static(q_offset, k_offset, kv_len) is None:
+            continue
         gauge = telemetry.gauge(
             name,
             f"Sub-tiles of one (batch, head) the flash {which} kernel of "
             f"the call last traced visits, by kind, and its grid steps "
             f"that fetch no {held}", ("kind",))
-        for kind, n in subtile_counts(kernel, *call).items():
+        for kind, n in subtile_counts(kernel, *call, head_dim,
+                                      window).items():
             gauge.labels(kind=kind).set(float(n))
 
 
@@ -1225,14 +1398,18 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None,
     Args:
       window: with ``causal``, a query at position ``t`` sees the keys at
         positions ``t - window < s <= t`` only (a static integer). The
-        kernels run no sub-tile and fetch no block that the window
-        hides, as for the causal mask; a window that reaches the call's
+        kernels run no sub-tile that the window hides and their grids
+        no step for a block of it, as for the causal mask (a grid step
+        is a (query block, key block) tile that does something, wherever
+        the offsets and ``kv_len`` are Python integers: _step_table,
+        ``grid_steps``); a window that reaches the call's
         first key from its last query is no window, and traces the
         program that ``None`` traces. Not with dropout or ``with_lse``.
       causal: apply a causal mask in *global* coordinates:
         position(q) = q_offset + row, position(k) = k_offset + col. Offsets
         may be traced scalars (device-varying under shard_map) — this is what
-        lets one compiled kernel serve every ring-attention step.
+        lets one compiled kernel serve every ring-attention step; its
+        grids then run every tile, and skip inside the body.
       kv_len: number of valid keys in ``k`` (defaults to its length);
         keys at or beyond this index are masked (padding).
       with_lse: also return the per-query log-sum-exp (fp32, (B,H,Sq)).
@@ -1296,32 +1473,33 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None,
             dropout_mask=dropout_mask, dropout_rate=dropout_rate,
             window=window)
     qp, kp, vp, dims, bq, bk = _prepare(q, k, v, block_q, block_k)
-    if all(isinstance(x, (int, np.integer))
-           for x in (q_offset, k_offset, kv_len)):
-        _publish_subtiles(sq, k.shape[2], bq, bk, bool(causal), q_offset,
-                          k_offset, kv_len, qp.shape[2], window)
+    _publish_subtiles(sq, k.shape[2], bq, bk, bool(causal), q_offset,
+                    k_offset, kv_len, qp.shape[2], window)
+    # Known while tracing (every call of a model): the grids run the
+    # tiles that do something and no other (_step_table).
+    where = _static(q_offset, k_offset, kv_len)
     lens = jnp.asarray([q_offset, k_offset, kv_len], jnp.int32)
     if has_dropout and dropout_seed is not None:
         lens4 = jnp.concatenate(
             [lens, jnp.asarray(dropout_seed, jnp.int32).reshape(1)])
         o = _flash_seeded(qp, kp, vp, lens4, float(sm_scale),
-                          bool(causal), bq, bk, float(dropout_rate))
+                          bool(causal), bq, bk, float(dropout_rate), where)
         return o[:, :sq, :d].reshape(b, h, sq, d).astype(orig_dtype)
     if has_dropout:
         # bf16 carries 0/1 exactly at half the HBM traffic of fp32.
         dm = dropout_mask.astype(jnp.bfloat16).reshape(b * h, sq, -1)
         dm = _pad_to(_pad_to(dm, bk, 2), bq, 1)
         o = _flash_dropout(qp, kp, vp, lens, dm, float(sm_scale),
-                           bool(causal), bq, bk, float(dropout_rate))
+                           bool(causal), bq, bk, float(dropout_rate), where)
         return o[:, :sq, :d].reshape(b, h, sq, d).astype(orig_dtype)
     if with_lse:
         o, lse = _flash_with_lse(qp, kp, vp, lens, float(sm_scale),
-                                 bool(causal), bq, bk)
+                                 bool(causal), bq, bk, where)
         o = o[:, :sq, :d].reshape(b, h, sq, d).astype(orig_dtype)
         lse = lse[:, :sq].reshape(b, h, sq)
         return o, lse
     o = _flash(qp, kp, vp, lens, float(sm_scale), bool(causal), bq, bk,
-               window)
+               window, where)
     return o[:, :sq, :dv].reshape(b, h, sq, dv).astype(orig_dtype)
 
 

@@ -672,59 +672,92 @@ def test_forward_kv_index_map_fetches_once_on_skipped_steps(case):
     assert fa._last_key_block(3, lens, 1, block_q, block_k, True) is None
 
 
-# The six cells that run the kernel: (seq, head_dim) at 1024 blocks,
-# and per (batch, head) the forward's sub-tiles by kind and the steps
-# that fetch nothing (the skipped steps, and the second row's first,
-# which finds block 0 held since the first row's skipped steps).
+# The nine cells that run the kernel, a kind of call each: (seq,
+# head_dim, window) at 1024 blocks, and per (batch, head) the forward's
+# sub-tiles by kind (interior, masked, skipped, hidden by the window
+# alone), the live tiles, which are the steps either grid runs, and of
+# those the steps that fetch nothing. Without a window that is one: the
+# forward's second query block starts with key block 0, held since the
+# first's only step, and the backward's last key block sees the last
+# query block alone, held since the row before. Under the 512 window a
+# query block sees its own key block and the one before it, which is
+# held already: 7 of the 15 steps.
 CELL_COUNTS = {
-    "lm365m-seq8192-1chip": (8192, 64, 120, 16, 120, 29),
-    "lm365m-seq2048-1chip": (2048, 64, 6, 4, 6, 2),
-    "lm365m-seq2048-4chip": (2048, 64, 6, 4, 6, 2),
-    "lm365m-seq512-1chip": (512, 64, 0, 1, 0, 0),
-    "glm47flash-seq4096-1chip": (4096, 256, 120, 16, 120, 7),
-    "ouro26b-seq4096-1chip": (4096, 128, 28, 8, 28, 7),
+    "lm365m-seq8192-1chip": (8192, 64, None, (120, 16, 120, 0), 36, 1),
+    "lm365m-seq2048-1chip": (2048, 64, None, (6, 4, 6, 0), 3, 1),
+    "lm365m-seq2048-4chip": (2048, 64, None, (6, 4, 6, 0), 3, 1),
+    "lm365m-seq512-1chip": (512, 64, None, (0, 1, 0, 0), 1, 0),
+    "glm47flash-seq4096-1chip": (4096, 256, None, (120, 16, 120, 0), 10, 1),
+    "ouro26b-seq4096-1chip": (4096, 128, None, (28, 8, 28, 0), 10, 1),
+    "phi4miniflash-seq8192-1chip": (8192, 64, None, (120, 16, 120, 0), 36,
+                                    1),
+    "phi4miniflash-seq8192-1chip-window512": (
+        8192, 64, 512, (0, 31, 120, 105), 15, 7),
+    "smallthinker21b-seq16384-1chip": (
+        16384, 128, None, (496, 32, 496, 0), 136, 1),
+    "smallthinker21b-seq16384-1chip-window4096": (
+        16384, 128, 4096, (196, 56, 496, 276), 70, 1),
+    "lfm2moe24b-seq8192-1chip": (8192, 64, None, (120, 16, 120, 0), 36, 1),
 }
+
+
+def _counts(interior, masked, skipped, window, unfetched):
+    counts = {"interior": interior, "masked": masked, "skipped": skipped,
+              "steps_without_fetch": unfetched}
+    if window:
+        counts["window"] = window
+    return counts
 
 
 @pytest.mark.parametrize("cell", sorted(CELL_COUNTS))
 def test_fwd_subtile_counts_at_the_cells_shapes(cell):
     from horovod_tpu.ops import flash_attention as fa
-    seq, d, interior, masked, skipped, unfetched = CELL_COUNTS[cell]
-    counts = fa.fwd_subtile_counts(seq, seq, 1024, 1024, True, head_dim=d)
-    assert counts == {"interior": interior, "masked": masked,
-                      "skipped": skipped, "steps_without_fetch": unfetched}
+    seq, d, window, kinds, live, unfetched = CELL_COUNTS[cell]
+    counts = fa.fwd_subtile_counts(seq, seq, 1024, 1024, True, head_dim=d,
+                                   window=window)
+    assert counts == _counts(*kinds, unfetched)
     block = min(seq, 1024)
     sub = fa._sub_tile(True, block, block, d) or block
-    assert interior + masked + skipped == (seq // sub) ** 2
-    # Every sub-tile is on or under the diagonal, over it, or crossed.
+    assert sum(kinds) == (seq // sub) ** 2
+    # Every sub-tile is on or under the diagonal, over it, or crossed;
+    # the window hides some of those on or under it.
     n = seq // sub
-    assert (interior, masked, skipped) == (n * (n - 1) // 2, n,
-                                           n * (n - 1) // 2)
+    interior, masked, skipped, hidden = kinds
+    assert (interior + masked + hidden, skipped) == (n * (n + 1) // 2,
+                                                     n * (n - 1) // 2)
+    assert window or (masked, hidden) == (n, 0)
+    # The grid runs the tiles that do something and no other.
+    for kernel in ("fwd", "bwd"):
+        assert fa.grid_steps(kernel, seq, seq, 1024, 1024, True,
+                             window=window) == {"run": live, "live": live}
 
 
 # The backward at its own side, 128 at every head width: sub-tiles by
 # kind. The steps that fetch no q/do block are the blocks' and not the
 # side's: as many as the forward's that fetch no K/V.
-BWD_CELL_SUBTILES = {8192: (2016, 64, 2016), 4096: (496, 32, 496),
-                     2048: (120, 16, 120), 512: (6, 4, 6)}
+BWD_CELL_SUBTILES = {
+    (8192, None): (2016, 64, 2016, 0), (4096, None): (496, 32, 496, 0),
+    (2048, None): (120, 16, 120, 0), (512, None): (6, 4, 6, 0),
+    (16384, None): (8128, 128, 8128, 0),
+    (8192, 512): (186, 124, 2016, 1770),
+    (16384, 4096): (3472, 224, 8128, 4560)}
 
 
 @pytest.mark.parametrize("cell", sorted(CELL_COUNTS))
 def test_bwd_subtile_counts_at_the_cells_shapes(cell, monkeypatch):
     from horovod_tpu.ops import flash_attention as fa
-    seq, d, *forwards, unfetched = CELL_COUNTS[cell]
-    kinds = ("interior", "masked", "skipped", "steps_without_fetch")
+    seq, d, window, forwards, _, unfetched = CELL_COUNTS[cell]
     assert fa.bwd_subtile_counts(
-        seq, seq, 1024, 1024, True, head_dim=d) == dict(zip(
-            kinds, (*BWD_CELL_SUBTILES[seq], unfetched)))
+        seq, seq, 1024, 1024, True, head_dim=d, window=window) == _counts(
+            *BWD_CELL_SUBTILES[seq, window], unfetched)
     # At the forward's side it counts what the forward counts (seq 2048
     # at 512: interior 6, masked 4, skipped 6 a head).
     block = min(seq, 1024)
     monkeypatch.setattr(fa, "_SUB_TILE_BWD",
                         fa._sub_tile(True, block, block, d))
     assert fa.bwd_subtile_counts(
-        seq, seq, 1024, 1024, True, head_dim=d) == dict(zip(
-            kinds, (*forwards, unfetched)))
+        seq, seq, 1024, 1024, True, head_dim=d, window=window) == _counts(
+            *forwards, unfetched)
 
 
 @pytest.mark.parametrize("kernel", ["fwd", "bwd"])
@@ -740,21 +773,28 @@ def test_subtile_counts_follow_offsets_and_kv_len(kernel, monkeypatch):
     assert counts(2048, 2048, 1024, 1024, False) == {
         "interior": 4, "masked": 0, "skipped": 0, "steps_without_fetch": 0}
     # Key padding: the last key block is cut, the one before is whole.
+    # The block past them all keeps one step of the backward's grid,
+    # which writes its dk and dv, zeros, and names the query block held.
     assert counts(256, 384, 128, 128, False, kv_len=200) == {
-        "interior": 2, "masked": 2, "skipped": 2, "steps_without_fetch": 0}
+        "interior": 2, "masked": 2, "skipped": 2,
+        "steps_without_fetch": int(kernel == "bwd")}
+    assert fa.grid_steps(kernel, 256, 384, 128, 128, False, kv_len=200) == {
+        "run": 4 + (kernel == "bwd"), "live": 4}
 
 
 def test_bwd_subtile_counts_name_the_blocks_the_index_map_names():
     """Two key blocks by two query blocks on the diagonal: the second
-    key block's first step is skipped and names the query block that is
-    held since the first key block's last step and that its second step
-    needs, so neither fetches. With ``kv_len`` short of the second key
-    block, the block on the diagonal there goes through the general
-    mask whole."""
+    key block's only step names the query block that is held since the
+    first key block's last step, and fetches nothing (the tile over the
+    diagonal is no step of the grid). With ``kv_len`` short of the
+    second key block, the block on the diagonal there goes through the
+    general mask whole."""
     from horovod_tpu.ops import flash_attention as fa
     at = dict(q_offset=128, k_offset=128)
     assert fa.bwd_subtile_counts(256, 256, 128, 128, True, **at)[
-        "steps_without_fetch"] == 2
+        "steps_without_fetch"] == 1
+    assert fa.grid_steps("bwd", 256, 256, 128, 128, True, **at) == {
+        "run": 3, "live": 3}
     cut = fa.bwd_subtile_counts(256, 256, 128, 128, True, kv_len=250, **at)
     n = 128 // fa._sub_tile(True, 128, 128, 64, backward=True)
     assert cut["masked"] == n * n + n     # block (1, 1) whole, (0, 0) walked
@@ -777,17 +817,228 @@ def test_subtile_gauges_are_set_when_metrics_are_on(monkeypatch):
                     telemetry.registry().families()[name].samples()}
         assert values("hvd_flash_fwd_subtiles") == {
             "interior": 1.0, "masked": 2.0, "skipped": 1.0,
-            "steps_without_fetch": 2.0}
+            "steps_without_fetch": 1.0}
         # The backward at a side of 64: 4 x 4 sub-tiles a block.
         assert values("hvd_flash_bwd_subtiles") == {
             "interior": 6.0, "masked": 4.0, "skipped": 6.0,
-            "steps_without_fetch": 2.0}
-        # Traced offsets: the schedule is not known here, nothing is set.
+            "steps_without_fetch": 1.0}
+
+        def steps():
+            return {(s["labels"]["kernel"], s["labels"]["kind"]): s["value"]
+                    for s in telemetry.registry().families()[
+                        "hvd_flash_grid_steps"].samples()}
+        # Three of the four tiles do something, and the grids run those.
+        assert steps() == {(kernel, kind): 3.0 for kernel in ("fwd", "bwd")
+                           for kind in ("run", "live")}
+        # Traced offsets: the schedule is not known here. The grids run
+        # every tile, and no other count is set.
         telemetry.reset()
         jax.jit(lambda o: flash_attention(q, k, v, causal=True,
                                           q_offset=o))(jnp.int32(0))
         assert not {"hvd_flash_fwd_subtiles", "hvd_flash_bwd_subtiles"} \
             & set(telemetry.registry().families())
+        assert steps() == {("fwd", "run"): 4.0, ("bwd", "run"): 4.0}
     finally:
         monkeypatch.delenv("HOROVOD_TPU_METRICS", raising=False)
         telemetry.reset()
+
+
+# ---------------------------------------------------------------------------
+# The grid's steps: one axis over the tiles that do something, from a
+# table made while tracing (every tile, from one made on the device,
+# where the offsets are traced)
+# ---------------------------------------------------------------------------
+
+# (sq, sk, block_q, block_k, causal, q_offset, k_offset, kv_len, window,
+# the backward's qb0): the cells' calls, and offsets off the corners, a
+# short ``kv_len``, outputs no tile is left of, a chunk of a query range.
+STEP_TABLE_CASES = {
+    **{cell: (seq, seq, min(seq, 1024), min(seq, 1024), True, 0, 0, seq,
+              window, 0)
+       for cell, (seq, _, window, *_) in CELL_COUNTS.items()},
+    "off_the_corners": (320, 448, 64, 64, True, 7, 3, 448, None, 0),
+    "off_the_corners_window": (512, 512, 64, 64, True, 70, 5, 512, 100, 0),
+    "window_and_short_kv_len": (512, 512, 64, 64, True, 0, 0, 130, 96, 0),
+    "short_kv_len": (256, 384, 64, 64, False, 0, 0, 130, None, 0),
+    "short_kv_len_causal": (256, 384, 64, 64, True, 128, 0, 200, None, 0),
+    "rows_that_see_nothing": (256, 256, 64, 64, True, 0, 128, 256, None, 0),
+    "nothing_visible": (128, 128, 64, 64, True, 0, 128, 128, None, 0),
+    "no_mask": (192, 128, 64, 64, False, 0, 0, 128, None, 0),
+    "wide_query_blocks": (256, 192, 128, 32, True, 16, 0, 192, None, 0),
+    "second_chunk_of_the_queries": (128, 320, 64, 64, True, 0, 0, 320, None,
+                                    2),
+    "last_chunk_under_a_window": (64, 320, 64, 64, True, 0, 0, 320, 100, 4),
+}
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+@pytest.mark.parametrize("case", sorted(STEP_TABLE_CASES))
+def test_step_table_lists_the_live_tiles_once_in_the_grids_order(case,
+                                                                 kernel):
+    from horovod_tpu.ops import flash_attention as fa
+    (sq, sk, block_q, block_k, causal, q_offset, k_offset, kv_len, window,
+     qb0) = STEP_TABLE_CASES[case]
+    backward = kernel == "bwd"
+    n_q, n_k = sq // block_q, sk // block_k
+    where = (q_offset, k_offset, kv_len)
+    table = fa._step_table(backward, where, n_q, n_k, block_q, block_k,
+                           causal, window, qb0)
+    assert isinstance(table, np.ndarray) and table.dtype == np.int32
+    row, inner, fetch, flags = table.reshape(4, -1)
+    steps = list(zip(*((inner, row) if backward else (row, inner))))
+    live = {(i, j) for i in range(n_q) for j in range(n_k)
+            if not fa._block_skip(causal, *where, qb0 + i, j, block_q,
+                                  block_k, window)}
+    # Every tile not skipped exactly once; a skipped one only as the one
+    # step of an output's block that no tile is left of.
+    assert len(set(steps)) == len(steps) and live <= set(steps)
+    for i, j in set(steps) - live:
+        assert not any(a == i for a, _ in live) or (
+            backward and not any(b == j for _, b in live)), (i, j)
+    assert set(row) == set(range(n_k if backward else n_q))
+    assert not backward or set(inner) == set(range(n_q))
+    # Rows ascend, and the steps inside one; the flags sit on a row's
+    # first and last step, and a query block's of the backward.
+    assert sorted(zip(row, inner)) == list(zip(row, inner))
+    turns = [a != b for a, b in zip(row[1:], row[:-1])]
+    assert [bool(f & fa._ROW_FIRST) for f in flags] == [True] + turns
+    assert [bool(f & fa._ROW_LAST) for f in flags] == turns + [True]
+    for bit, first in ((fa._DQ_FIRST, True), (fa._DQ_LAST, False)):
+        met = list(inner) if first else list(inner)[::-1]
+        want = [met.index(qb) == at for at, qb in enumerate(met)]
+        assert [bool(f & bit) for f in flags] == (
+            (want if first else want[::-1]) if backward
+            else [False] * len(steps))
+    # Every step fetches its own block: none is there to hold one.
+    assert (fetch == inner).all()
+    if not qb0:
+        assert fa.grid_steps(kernel, sq, sk, block_q, block_k, causal,
+                             q_offset, k_offset, kv_len, window) == {
+            "run": len(steps), "live": len(live)}
+        # No idle step wherever every block of an output has a live
+        # tile, as in every cell's call.
+        unseen = n_q - len({i for i, _ in live}) + backward * (
+            n_k - len({j for _, j in live}))
+        assert (len(steps) == len(live)) == (unseen == 0)
+        assert unseen == 0 or case not in CELL_COUNTS
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+@pytest.mark.parametrize("case", ["off_the_corners", "off_the_corners_window",
+                                  "short_kv_len_causal",
+                                  "rows_that_see_nothing", "no_mask",
+                                  "wide_query_blocks",
+                                  "second_chunk_of_the_queries"])
+def test_step_table_of_traced_offsets_lists_every_tile(case, kernel):
+    """The offsets as the traced scalars a ring step hands in: every
+    tile in the rectangular grid's order, the flags on a row's ends, and
+    in the fetched block's column what ``_kv_block`` / ``_q_block``
+    name, so a skipped step holds a block and fetches nothing."""
+    from horovod_tpu.ops import flash_attention as fa
+    (sq, sk, block_q, block_k, causal, q_offset, k_offset, kv_len, window,
+     qb0) = STEP_TABLE_CASES[case]
+    backward = kernel == "bwd"
+    n_q, n_k = sq // block_q, sk // block_k
+    lens = jnp.asarray([q_offset, k_offset, kv_len], jnp.int32)
+    qb0 = qb0 if backward else 0
+    table = jax.jit(lambda lens: fa._step_table(
+        backward, lens, n_q, n_k, block_q, block_k, causal, window, qb0))(
+            lens)
+    row, inner, fetch, flags = np.asarray(table).reshape(4, -1)
+    n_rows, n_inner = (n_k, n_q) if backward else (n_q, n_k)
+    assert list(zip(row, inner)) == [(a, b) for a in range(n_rows)
+                                     for b in range(n_inner)]
+    for a, b, named, flag in zip(row, inner, fetch, flags):
+        if backward:
+            want = fa._q_block(jnp.int32(a), jnp.int32(b), lens, n_q,
+                               block_q, block_k, causal, qb0, window, n_k)
+        else:
+            want = fa._kv_block(jnp.int32(a), jnp.int32(b), lens, n_k,
+                                block_q, block_k, causal, window, n_q)
+        assert named == int(want), (a, b)
+        skipped = fa._block_skip(
+            causal, q_offset, k_offset, kv_len,
+            *((qb0 + b, a) if backward else (a, b)), block_q, block_k,
+            window)
+        assert skipped or named == b, (a, b)
+        assert flag == (
+            fa._ROW_FIRST * (b == 0) + fa._ROW_LAST * (b == n_inner - 1)
+            + backward * (fa._DQ_FIRST * (a == 0)
+                          + fa._DQ_LAST * (a == n_rows - 1)))
+    assert fa.grid_steps(kernel, sq, sk, block_q, block_k, causal,
+                         lens[0], k_offset, kv_len, window) == {
+        "run": n_q * n_k}
+
+
+# (q's shape, K/V heads, blocks, kwargs): the same call with its offsets
+# known while tracing (the grids run the live tiles) and traced (every
+# tile, the skipped ones guarded in the body).
+LIVE_TABLE_CASES = {
+    "causal": ((1, 2, 256, 32), 2, 64, dict(causal=True)),
+    "causal_offsets_short_kv_len": (
+        (1, 2, 256, 32), 2, 64, dict(causal=True, q_offset=70, k_offset=5,
+                                     kv_len=200)),
+    "window_512_of_8192_in_miniature": (
+        (1, 2, 512, 32), 2, 64, dict(causal=True, window=32)),
+    "window_four_blocks_long": (
+        (1, 2, 512, 32), 2, 64, dict(causal=True, window=256)),
+    "grouped_heads": ((1, 4, 256, 32), 2, 64, dict(causal=True)),
+    "grouped_heads_window": (
+        (1, 6, 320, 32), 2, 64, dict(causal=True, window=100)),
+    "query_chunks": ((1, 2, 320, 32), 2, 64, dict(causal=True)),
+    "query_chunks_rows_that_see_nothing": (
+        (1, 2, 320, 32), 2, 64, dict(causal=True, k_offset=100)),
+    "lse_cotangent": ((1, 2, 256, 32), 2, 64,
+                      dict(causal=True, with_lse=True)),
+    "dropout_mask": ((1, 2, 256, 32), 2, 64,
+                     dict(causal=True, dropout_rate=0.25)),
+    "wide_value_bfloat16": ((1, 2, 256, 64), 2, 64, dict(causal=True)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LIVE_TABLE_CASES))
+def test_live_table_is_bit_equal_to_every_tile(monkeypatch, case):
+    """Outputs and all three gradients: the live steps run in the order
+    they had in the rectangular grid, so every running sum adds the same
+    terms in the same order."""
+    from horovod_tpu.ops import flash_attention as fa
+    shape, kv_heads, block, kwargs = LIVE_TABLE_CASES[case]
+    kwargs = dict(kwargs)
+    dtype = jnp.bfloat16 if "bfloat16" in case else jnp.float32
+    kv_shape = (shape[0], kv_heads) + shape[2:]
+    q, k = _rand(shape, 0, dtype), _rand(kv_shape, 1, dtype)
+    v = _rand(kv_shape[:3] + (128,) if "wide_value" in case else kv_shape,
+              2, dtype)
+    if case.startswith("query_chunks"):
+        monkeypatch.setattr(
+            fa, "_DQ_RESIDENT_BYTES",
+            2 * fa._dq_resident_bytes(block, shape[3], dtype))
+    if "dropout_rate" in kwargs:
+        kwargs["dropout_mask"] = jax.random.bernoulli(
+            jax.random.PRNGKey(3), 1 - kwargs["dropout_rate"],
+            shape[:3] + shape[2:3])
+    offsets = {name: kwargs.pop(name, 0) for name in ("q_offset", "k_offset")}
+    grids, table = [], fa._step_table
+    monkeypatch.setattr(fa, "_step_table", lambda backward, where, *a: (
+        grids.append((backward, isinstance(where, tuple))),
+        table(backward, where, *a))[1])
+
+    @jax.jit
+    def run(traced):
+        attend = functools.partial(
+            flash_attention, block_q=block, block_k=block, **kwargs,
+            **{**offsets, **traced})
+        return attend(q, k, v), _grads(attend, q, k, v)
+
+    live = run({})
+    assert grids and all(static for _, static in grids)
+    chunks = 3 if case.startswith("query_chunks") else 1
+    assert sum(backward for backward, _ in grids) == chunks
+    del grids[:]
+    every = run({name: jnp.int32(at) for name, at in offsets.items()})
+    assert grids and not any(static for _, static in grids)
+    for a, b in zip(jax.tree.leaves(live), jax.tree.leaves(every)):
+        assert a.dtype == b.dtype and np.isfinite(
+            np.asarray(a, np.float32)).all()
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))

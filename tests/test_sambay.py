@@ -207,9 +207,13 @@ def test_window_counts_at_the_cells_shape():
     assert bwd["masked"] == n + (n - 4)
     assert bwd["skipped"] == n * (n - 1) // 2
     assert bwd["window"] + bwd["interior"] + bwd["masked"] == n * (n + 1) // 2
-    # Neither kernel fetches more blocks than its visible steps need.
-    assert fwd["steps_without_fetch"] >= 64 - 15 - 8
-    assert bwd["steps_without_fetch"] >= 64 - 15 - 8
+    # Either grid runs the 15 live tiles of its 64: a query block sees its
+    # own key block and the one before it. Of those steps, the 7 that
+    # start a row past the first name the block the step before held.
+    for kernel, counts in (("fwd", fwd), ("bwd", bwd)):
+        assert fa.grid_steps(kernel, 8192, 8192, 1024, 1024, True,
+                             window=512) == {"run": 15, "live": 15}
+        assert counts["steps_without_fetch"] == 7
 
 
 # (batch, heads, seq, head_dim) of the three lm365m shapes.
