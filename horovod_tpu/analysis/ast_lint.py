@@ -37,8 +37,9 @@ a static finding. Three rules:
   collective per tensor): each call pays full dispatch + negotiation
   latency serially. The bucketed API reduces the whole set in fused
   buckets — ``grouped_allreduce(list)`` for explicit reductions, or
-  ``DistributedOptimizer`` (whose dispatch plane buckets and, under
-  ``HVDTPU_OVERLAP=1``, overlaps them with backprop) for gradients.
+  ``DistributedOptimizer`` (whose eager dispatch plane buckets them
+  and, under ``HVDTPU_OVERLAP=1``, issues the buckets asynchronously)
+  for gradients.
 - **HVD207** (warning) — a raw ``t0 = time.time()/perf_counter()``
   begin read whose elapsed (``clock() - t0``) feeds a metric
   ``observe()``: the ``telemetry.spans.span`` context is the single
@@ -520,7 +521,8 @@ class _Analyzer(ast.NodeVisitor):
             hint="collect the tensors and make one grouped_allreduce() "
                  "call, or reduce gradients through "
                  "DistributedOptimizer (bucketed dispatch; "
-                 "HVDTPU_OVERLAP=1 overlaps buckets with backprop); "
+                 "HVDTPU_OVERLAP=1 issues the eager plane's buckets "
+                 "asynchronously); "
                  + _DOC_HINT))
 
     @staticmethod

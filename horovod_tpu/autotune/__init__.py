@@ -16,8 +16,8 @@ Modules:
   (warmup -> warm-start decision -> confirm windows or per-arm sweep);
 - :mod:`score`   — the bytes/sec and trace-derived steps/sec sources;
 - :mod:`store`   — the persistent warm-start JSON store;
-- :mod:`overlay` — tuned values for construction-time knobs
-  (``HVDTPU_BUCKET_BYTES`` / ``HVDTPU_ZERO_BUCKET_BYTES``);
+- :mod:`overlay` — tuned values for the one knob read at
+  construction (``HVDTPU_ZERO_BUCKET_BYTES``);
 - :mod:`cli`     — the ``hvd-autotune`` console entry
   (show/history/diff/clear).
 

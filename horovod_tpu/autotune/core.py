@@ -17,8 +17,8 @@ one small grid per perf plane, tuned in sequence (coordinate descent):
 - ``host`` — fusion threshold x cycle time x delegated-plane min
   bucket (the original joint grid; the knobs interact, so they stay
   joint);
-- ``overlap`` — ``HVDTPU_BUCKET_BYTES`` (eager overlap plane, and the
-  overlay consumed by in-jit optimizer construction);
+- ``overlap`` — ``HVDTPU_BUCKET_BYTES`` (the eager overlap plane: a
+  plain attribute of the coordinator);
 - ``compression`` — codec x threshold applied as the live plane's
   catch-all policy (only when the user already opted into a pure
   catch-all policy — per-glob rules are never overwritten);
@@ -265,7 +265,7 @@ class ParameterManager:
         self._arms.append(Arm("host", grid, self._apply_host,
                               fmt=lambda v: f"{v[0]}/{v[1]}/{v[2]}"))
 
-        # overlap: eager-plane bucket bytes (+ construction overlay).
+        # overlap: eager-plane bucket bytes.
         if coord is not None and getattr(coord, "_overlap", False):
             cands = [int(m * 1024 * 1024) for m in _env_list(
                 envparse.AUTOTUNE_BUCKET_BYTES_CANDIDATES_MIB,
@@ -866,9 +866,6 @@ class ParameterManager:
         v = int(v)
         coord = self.runtime.coordinator
         coord._bucket_bytes = v
-        # Construction-time readers (in-jit optimizer bucketing) pick
-        # the tuned value up through the overlay on their next build.
-        overlay.set_int(envparse.BUCKET_BYTES, v)
         self._current["bucket_bytes"] = v
         self._m_switches.inc()
         self._m_bucket_bytes.set(v)

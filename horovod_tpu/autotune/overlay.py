@@ -1,14 +1,14 @@
 """Autotune knob overlay: tuned values for construction-time knobs.
 
-Most tuned knobs apply instantly (the coordinator's fusion threshold
-and cycle time are plain attributes the cycle thread re-reads). Two do
-not: ``HVDTPU_BUCKET_BYTES`` and ``HVDTPU_ZERO_BUCKET_BYTES`` are read
-once when a ``DistributedOptimizer`` is constructed and baked into the
-traced train step. The overlay is the indirection that closes that
-gap: the tuner (a warm-started cache hit at init, or a zero-arm
-candidate mid-sweep) writes here, and the constructors read through
-:func:`get_int` so a tuned value wins over the raw environment. The
-ZeRO step wrapper additionally polls :func:`generation` (one int
+Most tuned knobs apply instantly (the coordinator's fusion threshold,
+cycle time and ``HVDTPU_BUCKET_BYTES`` are plain attributes the cycle
+thread re-reads). One does not: ``HVDTPU_ZERO_BUCKET_BYTES`` is read
+once when a ``DistributedOptimizer(zero=True)`` is constructed and
+baked into the traced train step. The overlay is the indirection that
+closes that gap: the tuner (a warm-started cache hit at init, or a
+zero-arm candidate mid-sweep) writes here, and the constructor reads
+through :func:`resolve_int` so a tuned value wins over the raw
+environment. The ZeRO step wrapper additionally polls :func:`generation` (one int
 compare per step) so a mid-run change triggers a deterministic
 re-plan + reshard at the next step boundary.
 

@@ -10,9 +10,15 @@ Three reduction flavors, matching how JAX programs are actually written on
 TPU:
 
 1. **axis** (compiled, primary): the train step runs under shard_map over
-   the replica mesh; gradients reduce with lax.pmean/psum/Adasum over the
-   axis — pure XLA collectives on ICI. ``make_train_step`` builds the whole
-   step: batch sharded over 'hvd', params replicated, loss pmean'd.
+   the replica mesh; gradients reduce with lax.pmean/psum/Adasum (or a
+   wire codec's quantized pipeline) over the axis, a collective a leaf —
+   pure XLA collectives on ICI, all emitted by ``_reduce_in_axis``.
+   ``make_train_step`` builds the whole step: batch sharded over 'hvd',
+   params replicated, loss pmean'd; on a TPU mesh of more than one chip
+   it alone decides whether the step is compiled to run its exchange
+   under the backward pass (``_OVERLAP_OPTIONS``, small leaves packed).
+   ``HVDTPU_OVERLAP`` / ``HVDTPU_BUCKET_BYTES`` are the eager plane's
+   (coordinator.py) and change nothing in a compiled step.
 2. **auto** (compiled, implicit): under plain jit with replicated params and
    a batch sharded over the mesh, XLA's SPMD partitioner already inserts the
    gradient reduction — the wrapper is a no-op reduce and only contributes
@@ -101,17 +107,32 @@ _PACK_BELOW_BYTES = 1 << 18
 
 
 def _reduce_in_axis(grads, op, axis_name, prescale=None, postscale=None,
-                    pack=False):
-    """Reduce a tree over ``axis_name``, an all-reduce a leaf. With
-    ``pack`` (Average and Sum; a step compiled under
+                    pack=False, codec=None, block=None):
+    """Reduce a tree over ``axis_name``, a collective a leaf: the one
+    in-axis reducer of the compiled steps. A leaf is prescaled, reduced
+    (``pmean`` / ``psum`` / ``adasum_axis``, or with a wire ``codec``
+    the quantized pipeline of ``quantized_allreduce_axis``, stateless:
+    error feedback lives on the eager plane) and postscaled. With
+    ``pack`` (Average and Sum without a codec; a step compiled under
     ``_OVERLAP_OPTIONS``) the small leaves of a dtype are concatenated
     into one all-reduce (kilobytes: the copy is free) and every large
     leaf stays an all-reduce of its own, an operand XLA can make
     asynchronous: elementwise the same sums, bit for bit."""
+    plain = op in (reduce_ops.Average, reduce_ops.Sum)
+    if codec is not None and not plain:
+        raise ValueError(
+            f"codec {codec!r} reduces by Average or Sum only, not "
+            f"{reduce_ops.op_name(op)}")
+
     def red(g):
         if prescale is not None:
             g = g * jnp.asarray(prescale).astype(g.dtype)
-        if op == reduce_ops.Average:
+        if codec is not None:
+            from ..compression.codecs import quantized_allreduce_axis
+            g = quantized_allreduce_axis(
+                g, axis_name, codec=codec, block=block,
+                average=op == reduce_ops.Average)
+        elif op == reduce_ops.Average:
             g = lax.pmean(g, axis_name)
         elif op == reduce_ops.Sum:
             g = lax.psum(g, axis_name)
@@ -129,7 +150,7 @@ def _reduce_in_axis(grads, op, axis_name, prescale=None, postscale=None,
             g = g * jnp.asarray(postscale).astype(g.dtype)
         return g
 
-    if not pack or op not in (reduce_ops.Average, reduce_ops.Sum):
+    if not pack or codec is not None or not plain:
         return jax.tree.map(red, grads)
     from ..ops.bucketing import Bucket, _pack, _unpack
     leaves, treedef = jax.tree.flatten(grads)
@@ -190,30 +211,14 @@ class DistributedOptimizer:
         self.postscale = postscale_factor
         self.average_aggregated = average_aggregated_gradients
         self.process_set = process_set
-        # Set by make_train_step on its own copy, where the step is
-        # compiled under _OVERLAP_OPTIONS (see _reduce_in_axis).
-        self._pack_exchange = False
         # Wire codecs (Compression.int8/fp8) run the quantized pipeline
         # INSIDE the reduction (docs/compression.md): in-jit via
         # quantized_allreduce_axis on the axis path, via the entry codec
         # marker on the eager plane. Adasum needs exact per-rank
         # gradients — reject loudly instead of quantizing them.
-        # Bucketed comm/compute overlap (HVDTPU_OVERLAP;
-        # docs/performance.md): the in-jit axis reduction is emitted as
-        # one collective per ~HVDTPU_BUCKET_BYTES bucket instead of one
-        # per leaf, giving XLA's scheduler per-bucket dependencies it
-        # can overlap with the remaining backward pass. Read once at
-        # construction — the train step bakes the plan at trace time.
         from ..utils import envparse as _ep
-        from ..ops import bucketing as _bucketing
-        from ..autotune import overlay as _overlay
-        self._overlap = _ep.get_bool(_ep.OVERLAP)
-        # Overlay first: a warm-started (or converged) autotune value
-        # for the construction-time bucket knobs wins over the raw env
-        # (horovod_tpu/autotune/overlay.py).
-        self._bucket_bytes = _overlay.resolve_int(
-            _ep.BUCKET_BYTES, _bucketing.DEFAULT_BUCKET_BYTES)
         self._wire_codec = getattr(compression, "wire_codec", None)
+        self._wire_block = None
         if self._wire_codec is not None:
             from ..compression import codecs as _codecs
             _codecs.get_codec(self._wire_codec)  # loud on fp8-less jax
@@ -223,14 +228,13 @@ class DistributedOptimizer:
                     "Average/Sum gradient reductions only (Adasum's "
                     "scale-invariant combination needs exact per-rank "
                     "gradients; docs/compression.md)")
-            from ..utils import envparse as _envparse
-            self._wire_block = _envparse.get_int(
-                _envparse.COMPRESSION_BLOCK, _codecs.DEFAULT_BLOCK)
+            self._wire_block = _ep.get_int(
+                _ep.COMPRESSION_BLOCK, _codecs.DEFAULT_BLOCK)
         # ZeRO-1 sharded weight update (HVDTPU_ZERO; ops/zero.py,
-        # docs/performance.md). Resolved at construction like the
-        # overlap knobs; the incompatible combinations are rejected
-        # HERE — loudly, not at the first traced step (hvd-lint HVD208
-        # flags the same combinations statically).
+        # docs/performance.md). Resolved at construction; the
+        # incompatible combinations are rejected HERE — loudly, not at
+        # the first traced step (hvd-lint HVD208 flags the same
+        # combinations statically).
         self.zero = _ep.get_bool(_ep.ZERO) if zero is None else bool(zero)
         self._zero_rt = None
         if self.zero:
@@ -251,8 +255,13 @@ class DistributedOptimizer:
                     "zero=True (HVDTPU_ZERO) does not compose with "
                     "backward_passes_per_step > 1 (accumulate micro-"
                     "batch gradients before the step instead)")
+            # Overlay first: a warm-started (or converged) autotune
+            # value for the bucket knob wins over the raw env
+            # (horovod_tpu/autotune/overlay.py).
+            from ..autotune import overlay as _overlay
+            from ..ops.bucketing import DEFAULT_BUCKET_BYTES
             self._zero_bucket_bytes = _overlay.resolve_int(
-                _ep.ZERO_BUCKET_BYTES, _bucketing.DEFAULT_BUCKET_BYTES)
+                _ep.ZERO_BUCKET_BYTES, DEFAULT_BUCKET_BYTES)
             self._zero_overlay_gen = _overlay.generation()
             self._zero_overlay_pin = False
 
@@ -343,66 +352,49 @@ class DistributedOptimizer:
         acc = jax.tree.map(jnp.zeros_like, params)
         return (inner, acc, jnp.zeros((), jnp.int32))
 
-    def _reduce(self, grads):
+    def _reduce(self, grads, pack=False):
+        """Reduce a gradient tree across the replicas: sparse leaves
+        split off to their own plane; the rest cast (fp16 / bf16; the
+        wire compressors' ``compress`` is an identity), reduced one of
+        three ways and cast back. ``pack``: see ``update``."""
         from ..ops import sparse as sparse_ops
         if any(sparse_ops.is_sparse(leaf) for leaf in jax.tree.leaves(
                 grads, is_leaf=sparse_ops.is_sparse)):
-            return self._reduce_with_sparse(grads)
-        if self._wire_codec is not None:
-            return self._reduce_quantized(grads)
-        ctxs = None
-        comp_grads = grads
-        if self.compression is not Compression.none:
-            leaves, treedef = jax.tree.flatten(grads)
-            pairs = [self.compression.compress(g) for g in leaves]
-            comp_grads = jax.tree.unflatten(treedef, [p[0] for p in pairs])
-            ctxs = [p[1] for p in pairs]
+            return self._reduce_with_sparse(grads, pack)
+        leaves, treedef = jax.tree.flatten(grads)
+        pairs = [self.compression.compress(g) for g in leaves]
+        leaves, ctxs = [p[0] for p in pairs], [p[1] for p in pairs]
 
         if self.axis_name is not None:
-            if self._overlap and self.op in (reduce_ops.Average,
-                                             reduce_ops.Sum):
-                from ..ops.bucketing import bucketed_reduce_axis
-                leaves, treedef = jax.tree.flatten(comp_grads)
-                out = jax.tree.unflatten(treedef, bucketed_reduce_axis(
-                    leaves, self.op, self.axis_name,
-                    bucket_bytes=self._bucket_bytes,
-                    prescale=self.prescale, postscale=self.postscale))
-            else:
-                # Adasum (or OVERLAP=0): per-leaf reduction — Adasum's
-                # per-tensor combination cannot be bucketed.
-                out = _reduce_in_axis(comp_grads, self.op, self.axis_name,
-                                      self.prescale, self.postscale,
-                                      pack=self._pack_exchange)
-        else:
-            rt = basics.runtime()
-            if rt.mode == basics.MODE_SPMD:
-                from ..ops.collectives import grouped_allreduce
-                leaves, treedef = jax.tree.flatten(comp_grads)
-                reduced = grouped_allreduce(
-                    leaves, op=self.op,
-                    prescale_factor=self.prescale or 1.0,
-                    postscale_factor=self.postscale or 1.0,
-                    process_set=self.process_set)
-                out = jax.tree.unflatten(treedef, reduced)
-            else:
-                # Single-controller jit path: XLA's partitioner already
-                # reduced the gradients of replicated params — identity.
-                out = comp_grads
+            # Compiled, under shard_map: a collective a leaf (a wire
+            # codec: both legs of its pipeline carry the quantized
+            # format).
+            leaves = _reduce_in_axis(
+                leaves, self.op, self.axis_name, self.prescale,
+                self.postscale, pack=pack, codec=self._wire_codec,
+                block=self._wire_block)
+        elif basics.runtime().mode == basics.MODE_SPMD:
+            # The eager plane; a wire codec rides the entry's marker.
+            from ..ops.collectives import grouped_allreduce
+            leaves = grouped_allreduce(
+                leaves, op=self.op, compression=self.compression,
+                prescale_factor=self.prescale or 1.0,
+                postscale_factor=self.postscale or 1.0,
+                process_set=self.process_set)
+        # else the single-controller jit path: XLA's partitioner already
+        # reduced the gradients of replicated params — identity.
 
-        if ctxs is not None:
-            leaves, treedef = jax.tree.flatten(out)
-            out = jax.tree.unflatten(
-                treedef, [self.compression.decompress(g, c)
-                          for g, c in zip(leaves, ctxs)])
-        return out
+        return jax.tree.unflatten(
+            treedef, [self.compression.decompress(g, ctx)
+                      for g, ctx in zip(leaves, ctxs)])
 
-    def _reduce_with_sparse(self, grads):
+    def _reduce_with_sparse(self, grads, pack):
         """Gradient trees carrying :class:`ops.sparse.SparseGradient`
         leaves (embedding gradients): sparse leaves ride the sparse
         plane — ``HVDTPU_SPARSE`` picks allgather-of-slices vs
         densify-then-allreduce per tensor (docs/sparse.md) — and come
         back DENSE; dense leaves ride the normal reduction unchanged
-        (overlap/compression intact). Cast compression skips sparse
+        (packing and compression intact). Cast compression skips sparse
         leaves (the plane's row-wise int8 wire codec covers their
         values via the HVDTPU_COMPRESSION name policy instead)."""
         from ..ops import sparse as sparse_ops
@@ -440,7 +432,7 @@ class DistributedOptimizer:
                 handles[i] = sparse_ops.sparse_allreduce_async(
                     prescaled(leaves[i]), op=self.op, name=f"grad.sp{i}",
                     process_set=self.process_set)
-        reduced_dense = iter(self._reduce(dense_leaves)
+        reduced_dense = iter(self._reduce(dense_leaves, pack)
                              if dense_leaves else [])
 
         def red_sparse(sg, i):
@@ -465,56 +457,11 @@ class DistributedOptimizer:
                   for i, leaf in enumerate(leaves)]
         return jax.tree.unflatten(treedef, merged)
 
-    def _reduce_quantized(self, grads):
-        """Wire-codec reduction: both collective legs carry the
-        quantized format. Axis path = in-jit EQuARX pipeline per leaf
-        (stateless — error feedback needs cross-step state and lives on
-        the eager plane); eager SPMD path = the entry codec marker
-        through grouped_allreduce; single-controller jit path =
-        identity (the partitioner already reduced replicated params and
-        there is no wire to compress)."""
-        from ..compression.codecs import quantized_allreduce_axis
-
-        if self.axis_name is not None:
-            average = self.op == reduce_ops.Average
-            if self._overlap:
-                # One quantized pipeline per bucket: both collective
-                # legs of every bucket ride the wire format, and the
-                # per-bucket dependencies overlap with backprop exactly
-                # like the plain bucketed path (docs/performance.md).
-                from ..ops.bucketing import bucketed_reduce_axis
-                leaves, treedef = jax.tree.flatten(grads)
-                return jax.tree.unflatten(treedef, bucketed_reduce_axis(
-                    leaves, self.op, self.axis_name,
-                    bucket_bytes=self._bucket_bytes,
-                    prescale=self.prescale, postscale=self.postscale,
-                    wire_codec=self._wire_codec,
-                    block=self._wire_block))
-
-            def red(g):
-                if self.prescale is not None:
-                    g = g * jnp.asarray(self.prescale).astype(g.dtype)
-                g = quantized_allreduce_axis(
-                    g, self.axis_name, codec=self._wire_codec,
-                    block=self._wire_block, average=average)
-                if self.postscale is not None:
-                    g = g * jnp.asarray(self.postscale).astype(g.dtype)
-                return g
-            return jax.tree.map(red, grads)
-
-        rt = basics.runtime()
-        if rt.mode == basics.MODE_SPMD:
-            from ..ops.collectives import grouped_allreduce
-            leaves, treedef = jax.tree.flatten(grads)
-            reduced = grouped_allreduce(
-                leaves, op=self.op, compression=self.compression,
-                prescale_factor=self.prescale or 1.0,
-                postscale_factor=self.postscale or 1.0,
-                process_set=self.process_set)
-            return jax.tree.unflatten(treedef, reduced)
-        return grads
-
-    def update(self, grads, state, params=None):
+    def update(self, grads, state, params=None, pack=False):
+        """optax's ``update``. ``pack`` is ``make_train_step``'s, for a
+        step it compiles under ``_OVERLAP_OPTIONS``: the small leaves
+        share an all-reduce (``_reduce_in_axis``); everyone else leaves
+        it false."""
         if self.zero:
             from ..ops import sparse as sparse_ops
             if any(sparse_ops.is_sparse(leaf) for leaf in
@@ -540,7 +487,7 @@ class DistributedOptimizer:
         inner_state, acc, count = state
         if self.k == 1:
             with jax.named_scope(SCOPE_EXCHANGE):
-                reduced = self._reduce(grads)
+                reduced = self._reduce(grads, pack)
             with jax.named_scope(SCOPE_OPTIMIZER):
                 updates, new_inner = self.inner.update(
                     reduced, inner_state, params)
@@ -632,7 +579,8 @@ def _step_body(loss_fn, axis_name, has_aux, apply, pack=False):
     ``has_aux`` and ZeRO), so that the tracing contract above holds for
     all three: ``apply(grads, opt_state, params) -> (new_params,
     new_opt_state)`` owns the gradient exchange and the update, and
-    scopes them itself."""
+    scopes them itself. ``pack`` is ``make_train_step``'s decision to
+    overlap, here for the mean of the loss and of the aux state."""
 
     def mean(tree):
         with jax.named_scope(SCOPE_EXCHANGE):
@@ -724,25 +672,26 @@ def make_train_step(loss_fn, dist_opt, mesh=None, axis_name=HVD_AXIS,
         raise ValueError(
             f"DistributedOptimizer was built for axis "
             f"{dist_opt.axis_name!r} but the train step uses {axis_name!r}")
-    # One device has no exchange to schedule, only the TPU compiler
-    # knows the options' names, and what they were read on is the plain
-    # exchange (an Average or Sum a leaf): everywhere else, Adasum, wire
-    # codecs, HVDTPU_OVERLAP's buckets and the aggregated path
-    # included, the step is emitted and compiled as it always was.
+    # Whether this step is compiled to overlap its exchange (the small
+    # leaves packed, _OVERLAP_OPTIONS on the jit) is decided here, once,
+    # from what the step can see. One device has no exchange to
+    # schedule, only the TPU compiler knows the options' names, and what
+    # they were read on is the plain exchange (an Average or Sum a
+    # leaf): everywhere else, Adasum, wire codecs and the aggregated
+    # path included, the step is emitted and compiled as it always was.
     overlap = (mesh.shape[axis_name] > 1
                and mesh.devices.flat[0].platform == "tpu"
                and dist_opt.op in (reduce_ops.Average, reduce_ops.Sum)
-               and dist_opt.k == 1 and not dist_opt._overlap
-               and dist_opt._wire_codec is None)
+               and dist_opt.k == 1 and dist_opt._wire_codec is None)
     # Clone rather than mutate: the caller's optimizer object keeps its
     # eager behavior outside this train step.
     import copy
     dist_opt = copy.copy(dist_opt)
     dist_opt.axis_name = axis_name
-    dist_opt._pack_exchange = overlap
 
     def apply(grads, opt_state, params):
-        updates, new_opt_state = dist_opt.update(grads, opt_state, params)
+        updates, new_opt_state = dist_opt.update(grads, opt_state, params,
+                                                 pack=overlap)
         with jax.named_scope(SCOPE_OPTIMIZER):
             return optax.apply_updates(params, updates), new_opt_state
 

@@ -101,7 +101,10 @@ class ResNet(nn.Module):
     axis_name: Optional[str] = None   # set to 'hvd' for SyncBatchNorm
     # False | True/"full" | "dots" (save conv outputs, recompute
     # elementwise BN/ReLU) — trades recompute for backward-pass HBM,
-    # pushing the batch-size spill cliff out (docs/PERF.md).
+    # pushing the batch-size spill cliff out (round 2 on a v5e, by
+    # bench.py's clock: without it throughput halves between batch
+    # 448 and 512; with it 512 fits, but the recomputed convolutions
+    # cost more than the batch buys, so the benchmark runs without).
     remat: Any = False
     # "conv" (classic 7x7/s2) | "space_to_depth": reorganize the input
     # to (H/2, W/2, 4C) and run an equivalent 4x4/s1 conv — the 7x7

@@ -1,58 +1,36 @@
-"""Gradient bucketing for comm/compute overlap (``HVDTPU_OVERLAP``).
+"""Bucket plans and the packing of leaves into one buffer.
 
-Horovod's core performance idea is to overlap gradient communication
-with the remaining backward pass: gradients are packed into fixed-size
-buckets and each bucket's collective is dispatched as soon as its
-members are ready, so the reduction of layer N runs under the gradient
-compute of layer N-1 (reference: horovod/common/controller.cc
-FuseResponses; *Densifying Assumed-sparse Tensors*, arXiv:1905.04035,
-on why dense bucketed accumulation beats per-tensor dispatch).
+What is left of Horovod's fusion-buffer idea (reference:
+horovod/common/controller.cc FuseResponses) once XLA schedules the
+compiled step's exchange by itself:
 
-The in-jit realization here is dependency-driven rather than
-hook-driven: :func:`bucketed_reduce_axis` emits ONE collective per
-bucket whose operands are only that bucket's gradient leaves. Because
-backprop produces gradients in reverse layer order, a bucket holding
-late-layer gradients is ready while early layers are still
-differentiating — XLA's latency-hiding scheduler is then free to run
-its collective under the remaining backward compute, which a single
-fused all-gradient barrier (or a reduction depending on the full tree)
-structurally forbids. Buckets are planned over the REVERSED leaf order
-for exactly that reason: leaf trees flatten roughly first-layer-first,
-so reversing approximates gradient-availability order and the first
-bucket issued is the first one ready.
+- :func:`plan_buckets` groups leaves by dtype into buckets of a byte
+  budget. The ZeRO legs (``ops/zero.py``, ``parallel/twod.py``) shard
+  their state by that plan, and the redistribution planner
+  (``resharding/spec.py``) reads its offsets; it walks the leaves last
+  to first, so a plan's first bucket holds the gradients backprop
+  produces first.
+- ``_pack`` / ``_unpack`` concatenate a bucket's leaves into one flat
+  buffer and slice it apart again: the ZeRO legs' wire format, and how
+  ``horovod_tpu.jax._reduce_in_axis`` lets the small leaves of a dtype
+  share one all-reduce in a step compiled under ``_OVERLAP_OPTIONS``.
+  An elementwise collective (psum / pmean) of the concatenation is the
+  per-leaf collective element for element, bit for bit
+  (tests/test_exchange_schedule.py). Adasum and the wire codecs are
+  never packed: both are defined per tensor.
+- ``DEFAULT_BUCKET_BYTES`` is the default of ``HVDTPU_BUCKET_BYTES``,
+  the eager plane's bucket under ``HVDTPU_OVERLAP`` (coordinator.py),
+  and of ``HVDTPU_ZERO_BUCKET_BYTES``.
 
-Read from the compiled schedule for a TPU v5e 2x2 (PERF.md section 6,
-PR 35): by itself this hides nothing there. XLA's all-reduce combiner
-merges the buckets again into tuple all-reduces, synchronous and after
-the last backward kernel. What keeps all-reduces apart, and
-asynchronous, is ``horovod_tpu.jax._OVERLAP_OPTIONS``, which
-``make_train_step`` passes on a TPU mesh of more than one chip for the
-per-leaf exchange, which then overlaps without this module's two
-copies; with ``HVDTPU_OVERLAP`` on, the step keeps the program it had
-(docs/performance.md).
-
-Numerics: splitting an elementwise collective (psum/pmean) into
-per-bucket concatenated calls performs the identical per-element
-cross-replica reduction, so the bucketed path is bit-identical to the
-per-leaf path for Sum/Average — pinned by
-tests/test_overlap.py::test_overlap_bit_exact_vs_barrier. Wire-codec
-buckets (int8/fp8) quantize the CONCATENATED bucket, so quantization
-blocks may span tensor boundaries; that changes rounding relative to
-per-tensor quantization (never relative to OVERLAP=0 plain fp32, which
-stays exact) and is documented in docs/performance.md.
-
-Adasum is excluded: its scale-invariant combination is defined per
-tensor, and concatenating tensors into one vector would change the dot
-products it is built from. Callers keep Adasum on the per-leaf path.
+The compiled step has no bucketed exchange of its own: on a TPU XLA's
+combiner merges per-bucket collectives again, so they hide nothing
+(docs/performance.md section 2).
 """
 
 import numpy as np
 
-import jax
 import jax.numpy as jnp
 from jax import lax
-
-from . import reduce_ops
 
 DEFAULT_BUCKET_BYTES = 16 * 1024 * 1024
 
@@ -118,45 +96,3 @@ def _unpack(buf, leaves, bucket, out):
         out[i] = lax.slice(buf, (offset,), (offset + size,)).reshape(
             leaves[i].shape)
         offset += size
-
-
-def bucketed_reduce_axis(leaves, op, axis_name, *,
-                         bucket_bytes=DEFAULT_BUCKET_BYTES,
-                         prescale=None, postscale=None,
-                         wire_codec=None, block=256):
-    """Per-bucket gradient reduction over a shard_map axis.
-
-    Plain path (``wire_codec=None``): one ``psum``/``pmean`` per bucket
-    — bit-identical to the per-leaf reduction, but with per-bucket data
-    dependencies the XLA scheduler can overlap with remaining backprop.
-    Wire path: one EQuARX quantized pipeline per bucket
-    (``quantized_allreduce_axis`` on the concatenated buffer), so both
-    collective legs of every bucket ride the narrow format.
-
-    Returns the reduced leaves in the original order.
-    """
-    if op not in (reduce_ops.Average, reduce_ops.Sum):
-        raise ValueError(
-            "bucketed_reduce_axis supports Average/Sum only (Adasum's "
-            f"per-tensor combination cannot be bucketed); got "
-            f"{reduce_ops.op_name(op)}")
-    if not leaves:
-        return []
-    out = [None] * len(leaves)
-    for bucket in plan_buckets(leaves, bucket_bytes):
-        buf = _pack(leaves, bucket)
-        if prescale is not None:
-            buf = buf * jnp.asarray(prescale).astype(buf.dtype)
-        if wire_codec is not None:
-            from ..compression.codecs import quantized_allreduce_axis
-            buf = quantized_allreduce_axis(
-                buf, axis_name, codec=wire_codec, block=block,
-                average=(op == reduce_ops.Average))
-        elif op == reduce_ops.Average:
-            buf = lax.pmean(buf, axis_name)
-        else:
-            buf = lax.psum(buf, axis_name)
-        if postscale is not None:
-            buf = buf * jnp.asarray(postscale).astype(buf.dtype)
-        _unpack(buf, leaves, bucket, out)
-    return out

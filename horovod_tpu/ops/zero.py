@@ -23,9 +23,8 @@ are absorbed by per-bucket padding (never by per-leaf remainders), and
 a world-size change is a plan-to-plan redistribution
 (:func:`reshard_state`) rather than an ad-hoc gather/scatter.
 
-Buckets come from :func:`ops.bucketing.plan_buckets` — the same
-reversed-leaf-order plans the overlap path uses — so under
-``HVDTPU_OVERLAP`` semantics the first bucket emitted holds the last
+Buckets come from :func:`ops.bucketing.plan_buckets`, planned over the
+reversed leaf order, so the first bucket emitted holds the last
 (= earliest-available) gradients and XLA's latency-hiding scheduler can
 run bucket k's reduce-scatter under the remaining backward pass and
 bucket k's allgather under other buckets' updates.

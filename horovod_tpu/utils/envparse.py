@@ -192,8 +192,8 @@ AUTOTUNE_CONFIRM_CYCLES = register(
     "elastic-version bump (baseline window + warm window)")
 AUTOTUNE_BUCKET_BYTES_CANDIDATES_MIB = register(
     "AUTOTUNE_BUCKET_BYTES_CANDIDATES_MIB", "1,4,16,64",
-    "Overlap-plane bucket-bytes grid (the overlap arm; only when "
-    "HVDTPU_OVERLAP is on)")
+    "Bucket-bytes grid of the eager plane's overlap (the overlap arm; "
+    "only when HVDTPU_OVERLAP is on)")
 AUTOTUNE_COMPRESSION_CANDIDATES = register(
     "AUTOTUNE_COMPRESSION_CANDIDATES", "",
     "Compression-codec grid for the compression arm (default: the "
@@ -328,12 +328,13 @@ SPARSE_EMA = register(
 # -- comm/compute overlap (docs/performance.md) ----------------------------
 OVERLAP = register(
     "OVERLAP", "0",
-    "Bucketed comm/compute overlap: per-bucket gradient collectives "
-    "the scheduler can run under remaining backprop (in-jit axis "
-    "path), priority-ordered async bucket dispatch (eager plane)")
+    "Bucketed comm/compute overlap on the eager plane: "
+    "priority-ordered async dispatch of HVDTPU_BUCKET_BYTES buckets "
+    "(a compiled step does not read it)")
 BUCKET_BYTES = register(
     "BUCKET_BYTES", "16 MiB",
-    "Payload bytes per gradient bucket on the overlap path")
+    "Payload bytes per allreduce bucket of the eager plane under "
+    "HVDTPU_OVERLAP")
 
 # -- ZeRO-1 sharded weight update (docs/performance.md) ---------------------
 ZERO = register(
@@ -344,7 +345,7 @@ ZERO = register(
 ZERO_BUCKET_BYTES = register(
     "ZERO_BUCKET_BYTES", "16 MiB",
     "Payload bytes per ZeRO fusion bucket (reduce-scatter/allgather "
-    "legs); defaults to the overlap plane's bucket budget")
+    "legs); defaults to the eager overlap plane's bucket budget")
 RESHARD_BUCKET_BYTES = register(
     "RESHARD_BUCKET_BYTES", "4 MiB",
     "Window budget of redistribution-planner collective steps "

@@ -427,14 +427,14 @@ def test_overlay_set_get_generation():
     from horovod_tpu.autotune import overlay
     from horovod_tpu.utils import envparse
     g0 = overlay.generation()
-    assert overlay.get_int(envparse.BUCKET_BYTES) is None
-    assert overlay.get_int(envparse.BUCKET_BYTES, 7) == 7
-    overlay.set_int(envparse.BUCKET_BYTES, 4 * MIB)
-    assert overlay.get_int(envparse.BUCKET_BYTES, 7) == 4 * MIB
+    assert overlay.get_int(envparse.ZERO_BUCKET_BYTES) is None
+    assert overlay.get_int(envparse.ZERO_BUCKET_BYTES, 7) == 7
+    overlay.set_int(envparse.ZERO_BUCKET_BYTES, 4 * MIB)
+    assert overlay.get_int(envparse.ZERO_BUCKET_BYTES, 7) == 4 * MIB
     assert overlay.generation() == g0 + 1
-    assert overlay.snapshot() == {envparse.BUCKET_BYTES: 4 * MIB}
+    assert overlay.snapshot() == {envparse.ZERO_BUCKET_BYTES: 4 * MIB}
     overlay.clear()
-    assert overlay.get_int(envparse.BUCKET_BYTES) is None
+    assert overlay.get_int(envparse.ZERO_BUCKET_BYTES) is None
     assert overlay.generation() == g0 + 2
 
 
@@ -716,7 +716,6 @@ def test_multi_arm_sweep_tunes_every_plane(monkeypatch, tmp_path):
     _drive_fn(pm, rt, rate)
     assert set(pm._winners) == {"host", "overlap", "compression", "zero"}
     assert rt.coordinator._bucket_bytes == 4 * MIB
-    assert overlay.get_int(envparse.BUCKET_BYTES) == 4 * MIB
     assert overlay.get_int(envparse.ZERO_BUCKET_BYTES) == 4 * MIB
     assert pm._winners["compression"] == ("int8", 1024)
     assert rt.coordinator._compression.policy.rules == [("*", "int8")]
@@ -825,11 +824,11 @@ def test_overlay_resolve_int_precedence(monkeypatch):
     every construction-time reader goes through."""
     from horovod_tpu.autotune import overlay
     from horovod_tpu.utils import envparse
-    assert overlay.resolve_int(envparse.BUCKET_BYTES, 7) == 7
-    monkeypatch.setenv("HVDTPU_BUCKET_BYTES", str(2 * MIB))
-    assert overlay.resolve_int(envparse.BUCKET_BYTES, 7) == 2 * MIB
-    overlay.set_int(envparse.BUCKET_BYTES, 4 * MIB)
-    assert overlay.resolve_int(envparse.BUCKET_BYTES, 7) == 4 * MIB
+    assert overlay.resolve_int(envparse.ZERO_BUCKET_BYTES, 7) == 7
+    monkeypatch.setenv("HVDTPU_ZERO_BUCKET_BYTES", str(2 * MIB))
+    assert overlay.resolve_int(envparse.ZERO_BUCKET_BYTES, 7) == 2 * MIB
+    overlay.set_int(envparse.ZERO_BUCKET_BYTES, 4 * MIB)
+    assert overlay.resolve_int(envparse.ZERO_BUCKET_BYTES, 7) == 4 * MIB
 
 
 def test_compression_arm_dedupes_none_thresholds(monkeypatch):

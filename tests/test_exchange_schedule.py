@@ -81,12 +81,16 @@ def replica_tree(n):
             "scalar": leaf(())}
 
 
+@pytest.mark.parametrize("n", [1, 2, 4, 8])
 @pytest.mark.parametrize("scales", [(None, None), (0.5, 3.0)],
                          ids=["plain", "scaled"])
 @pytest.mark.parametrize("op", [reduce_ops.Average, reduce_ops.Sum],
                          ids=["average", "sum"])
-def test_packed_exchange_is_per_leaf_bit_for_bit(cpu_mesh, op, scales):
-    tree = replica_tree(8)
+def test_packed_exchange_is_per_leaf_bit_for_bit(cpu_mesh, op, scales, n):
+    """An elementwise collective of a concatenation is the collective
+    of its parts, at every mesh size."""
+    mesh = Mesh(cpu_mesh.devices[:n], (AXIS,))
+    tree = replica_tree(n)
     assert any(x[0].nbytes >= hvd_jax._PACK_BELOW_BYTES
                for x in jax.tree.leaves(tree))
 
@@ -95,7 +99,7 @@ def test_packed_exchange_is_per_leaf_bit_for_bit(cpu_mesh, op, scales):
         return (hvd_jax._reduce_in_axis(t, op, AXIS, *scales, pack=True),
                 per_leaf(t, op, *scales))
     ours, theirs = jax.jit(jax.shard_map(
-        both, mesh=cpu_mesh, in_specs=P(AXIS), out_specs=P()))(tree)
+        both, mesh=mesh, in_specs=P(AXIS), out_specs=P()))(tree)
     for a, b in zip(jax.tree.leaves(ours), jax.tree.leaves(theirs)):
         assert a.shape == b.shape and a.dtype == b.dtype
         np.testing.assert_array_equal(np.asarray(a, np.float32),
@@ -113,16 +117,62 @@ def test_packed_exchange_is_one_all_reduce_a_dtype_for_the_small(cpu_mesh):
     assert len(re.findall(r"stablehlo\.all_reduce", text)) == 5
 
 
-def test_without_pack_the_exchange_is_the_parents(cpu_mesh):
+def adasum_per_leaf(tree):
+    """The parent's Adasum: the tree reduction a leaf, then a psum of
+    ``g / n`` that makes the value replica-invariant again."""
+    from horovod_tpu.ops.adasum import adasum_axis
+
+    def red(g):
+        g = adasum_axis(g, AXIS)
+        return lax.psum(g / lax.axis_size(AXIS), AXIS)
+    return jax.tree.map(red, tree)
+
+
+def int8_per_leaf(tree, prescale, postscale):
+    """The parent's wire-codec reduction on the axis: one quantized
+    pipeline a leaf, between the scales."""
+    from horovod_tpu.compression.codecs import quantized_allreduce_axis
+
+    def red(g):
+        g = g * jnp.asarray(prescale).astype(g.dtype)
+        g = quantized_allreduce_axis(g, AXIS, codec="int8", block=128,
+                                     average=True)
+        return g * jnp.asarray(postscale).astype(g.dtype)
+    return jax.tree.map(red, tree)
+
+
+@pytest.mark.parametrize("ours, theirs", [
+    (lambda t: hvd_jax._reduce_in_axis(t, reduce_ops.Average, AXIS),
+     lambda t: per_leaf(t, reduce_ops.Average)),
+    (lambda t: hvd_jax._reduce_in_axis(t, reduce_ops.Sum, AXIS),
+     lambda t: per_leaf(t, reduce_ops.Sum)),
+    (lambda t: hvd_jax._reduce_in_axis(t, reduce_ops.Sum, AXIS, 0.5, 3.0),
+     lambda t: per_leaf(t, reduce_ops.Sum, 0.5, 3.0)),
+    # Adasum and a codec are defined a tensor: ``pack`` packs nothing.
+    (lambda t: hvd_jax._reduce_in_axis(t, reduce_ops.Adasum, AXIS,
+                                       pack=True),
+     lambda t: adasum_per_leaf(t)),
+    (lambda t: hvd_jax._reduce_in_axis(t, reduce_ops.Average, AXIS, 0.5,
+                                       3.0, pack=True, codec="int8",
+                                       block=128),
+     lambda t: int8_per_leaf(t, 0.5, 3.0)),
+], ids=["average", "sum", "scaled", "adasum", "int8"])
+def test_without_pack_the_exchange_is_the_parents(cpu_mesh, ours, theirs):
+    """The one reducer's contract: for every op and codec the program
+    of the parent's recipe, written out here, text for text."""
     tree = jax.tree.map(lambda x: x[0], replica_tree(1))
 
     def lowered(fn):
         return jax.jit(jax.shard_map(
-            fn, mesh=cpu_mesh, in_specs=P(), out_specs=P())).lower(
-                tree).as_text()
-    assert lowered(lambda t: hvd_jax._reduce_in_axis(
-        t, reduce_ops.Average, AXIS)) == lowered(
-            lambda t: per_leaf(t, reduce_ops.Average))
+            fn, mesh=cpu_mesh, in_specs=P(), out_specs=P(),
+            check_vma=False)).lower(tree).as_text()
+    assert lowered(ours) == lowered(theirs)
+
+
+def test_a_codec_reduces_by_average_or_sum_only():
+    with pytest.raises(ValueError, match="Average or Sum"):
+        hvd_jax._reduce_in_axis({"w": jnp.ones((4,))}, reduce_ops.Adasum,
+                                AXIS, codec="int8", block=128)
 
 
 def toy_step(mesh, has_aux=False):
@@ -172,6 +222,12 @@ def topo():
     compilation_cache.reset_cache()
 
 
+def placed(mesh, tree, spec=P()):
+    """``tree``'s shapes and dtypes, laid out on ``mesh`` by ``spec``."""
+    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=NamedSharding(mesh, spec)), tree)
+
+
 def lm_step(devices, layers, monkeypatch, seq=2048, rows=6):
     """``lm365m``'s widths at ``layers`` layers through
     ``make_train_step`` over ``devices``: (step, abstract arguments)."""
@@ -193,14 +249,10 @@ def lm_step(devices, layers, monkeypatch, seq=2048, rows=6):
     step = hvd_jax.make_train_step(loss_fn, opt, mesh=mesh)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
                             jnp.zeros((1, seq), jnp.int32))
-
-    def placed(tree, spec):
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=NamedSharding(mesh, spec)), tree)
-    batch = placed((jax.ShapeDtypeStruct((rows * len(devices), seq),
-                                         jnp.int32),) * 2, P(AXIS))
-    return step, (placed(params, P()),
-                  placed(jax.eval_shape(opt.init, params), P()), batch)
+    batch = placed(mesh, (jax.ShapeDtypeStruct((rows * len(devices), seq),
+                                               jnp.int32),) * 2, P(AXIS))
+    return step, (placed(mesh, params),
+                  placed(mesh, jax.eval_shape(opt.init, params)), batch)
 
 
 def test_four_chip_step_schedules_its_exchange_under_the_backward(
@@ -232,18 +284,14 @@ def test_four_chip_step_schedules_its_exchange_under_the_backward(
             <= TEMP_FACTOR * plain.memory_analysis().temp_size_in_bytes)
 
 
-@pytest.mark.parametrize("how", ["aggregated", "buckets", "adasum",
-                                 "wire_codec"])
+@pytest.mark.parametrize("how", ["aggregated", "adasum", "wire_codec"])
 def test_only_the_plain_exchange_is_compiled_to_overlap(
         topo, monkeypatch, how):
-    """``backward_passes_per_step > 1``, ``HVDTPU_OVERLAP``'s buckets,
-    Adasum and the wire codecs keep the parent's program on the TPU
-    mesh too: no option on the jit, nothing packed."""
+    """``backward_passes_per_step > 1``, Adasum and the wire codecs
+    keep the parent's program on the TPU mesh too: no option on the
+    jit, nothing packed."""
     from horovod_tpu.ops.compression import Compression
-    if how == "buckets":
-        monkeypatch.setenv("HVDTPU_OVERLAP", "1")
     kwargs = {"aggregated": {"backward_passes_per_step": 2},
-              "buckets": {},
               "adasum": {"op": reduce_ops.Adasum},
               "wire_codec": {"compression": Compression.int8}}[how]
     opt = hvd_jax.DistributedOptimizer(optax.sgd(0.1), **kwargs)
@@ -258,16 +306,31 @@ def test_only_the_plain_exchange_is_compiled_to_overlap(
     step = hvd_jax.make_train_step(
         lambda p, batch: jnp.mean((batch @ p["w"]) ** 2), opt, mesh=mesh)
     assert seen == [None]
-    params = {"w": jax.ShapeDtypeStruct(
-        (16, 4), jnp.float32, sharding=NamedSharding(mesh, P()))}
-    state = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=NamedSharding(mesh, P())),
-        jax.eval_shape(opt.init, params))
-    batch = jax.ShapeDtypeStruct((8, 16), jnp.float32,
-                                 sharding=NamedSharding(mesh, P(AXIS)))
-    step.lower(params, state, batch)
+    params = placed(mesh, {"w": jax.ShapeDtypeStruct((16, 4), jnp.float32)})
+    step.lower(params, placed(mesh, jax.eval_shape(opt.init, params)),
+               placed(mesh, jax.ShapeDtypeStruct((8, 16), jnp.float32),
+                      P(AXIS)))
     assert not any(packed)
+
+
+def test_the_eager_planes_knobs_leave_the_compiled_step_alone(
+        topo, monkeypatch):
+    """``HVDTPU_OVERLAP`` and ``HVDTPU_BUCKET_BYTES`` are the eager
+    plane's: in the environment they change neither the options on the
+    step's jit nor a character of what it lowers to."""
+    seen = spy_on_jit(monkeypatch)
+    mesh = Mesh(np.array(topo.devices), (AXIS,))
+
+    def lowered():
+        step, args = toy_step(mesh)
+        return step.lower(*placed(mesh, args[:-1]),
+                          placed(mesh, args[-1], P(AXIS))).as_text()
+    without = lowered()
+    monkeypatch.setenv("HVDTPU_OVERLAP", "1")
+    monkeypatch.setenv("HVDTPU_BUCKET_BYTES", "128")
+    assert lowered() == without
+    assert seen == [hvd_jax._OVERLAP_OPTIONS] * 2
+    assert "stablehlo.concatenate" in without       # w and b: one pack
 
 
 def test_one_chip_step_is_the_parents(topo, monkeypatch):
@@ -278,7 +341,7 @@ def test_one_chip_step_is_the_parents(topo, monkeypatch):
     # The parent's recipe: one pmean a leaf, a jit without options.
     monkeypatch.setattr(
         hvd_jax, "_reduce_in_axis",
-        lambda tree, op, axis, prescale=None, postscale=None, pack=False:
+        lambda tree, op, axis, prescale=None, postscale=None, **kw:
         per_leaf(tree, op, prescale, postscale))
     theirs, args = lm_step(topo.devices[:1], 1, monkeypatch)
     assert ours.as_text() == theirs.lower(*args).as_text()

@@ -548,8 +548,28 @@ def test_e2e_drain_via_migration_and_direct_client_transparency():
     kv = KVStoreServer(job_token=token, addr="127.0.0.1")
     kv_port = kv.start()
     m = ToyLM()
-    w0 = ServingWorker(_SlowLM(0.01), cohort="c0", wid=0,
-                       num_pages=64, page_size=4).start()
+    w0 = ServingWorker(ToyLM(), cohort="c0", wid=0,
+                       num_pages=64, page_size=4)
+    # The drain has to land on a decoding sequence. The hand-off runs
+    # on its own thread and needs the scheduler's lock, which the loop
+    # thread holds through every decode and takes again at once, so a
+    # delay in the model only made a window to race for (and under
+    # load to lose). Park w0's loop between steps instead, outside the
+    # lock, from the stream's first token until the hand-off has moved
+    # it: what is waited on is events, and the timeouts only bound a
+    # failure.
+    stepped, resume = threading.Event(), threading.Event()
+    real_step = w0.scheduler.step
+
+    def held_step():
+        composition = real_step()
+        if composition:
+            stepped.set()
+            resume.wait(timeout=60)
+        return composition
+
+    w0.scheduler.step = held_step
+    w0.start()
     w1 = ServingWorker(m, cohort="c0", wid=1, num_pages=128,
                        page_size=4).start()
     try:
@@ -567,14 +587,15 @@ def test_e2e_drain_via_migration_and_direct_client_transparency():
 
         t = threading.Thread(target=gen)
         t.start()
-        # Let the stream reach decode, then drain the host under it.
-        for _ in range(200):
-            if w0.scheduler.stats()["running"] >= 1:
-                break
-            time.sleep(0.01)
+        assert stepped.wait(timeout=60), "the stream never reached decode"
         status, body = _post(ports[0], "/v1/serving/drain", {},
                              token=token)
         assert status == 200 and body["draining"]
+        deadline = time.monotonic() + 60
+        while (w0.scheduler.migrated_out < 1
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
+        resume.set()
         t.join(timeout=60)
         status, body = out["r"]
         assert status == 200, out["r"]
@@ -585,6 +606,7 @@ def test_e2e_drain_via_migration_and_direct_client_transparency():
         assert w1.scheduler.migrated_in >= 1
         assert body["worker"] == "c0.1"
     finally:
+        resume.set()
         w0.stop()
         w1.stop()
         kv.stop()

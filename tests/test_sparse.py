@@ -691,7 +691,7 @@ def test_jax_spmd_sparse_leaves_submit_async_before_sync(
                         raising=False)
     orig_reduce = opt._reduce
 
-    def spy_reduce(grads):
+    def spy_reduce(grads, pack=False):
         # The inner dense-leaf reduction arrives as a LIST; the test's
         # own entry call is a dict tree. The dense reduction
         # synchronizes internally, so it must come AFTER every sparse
