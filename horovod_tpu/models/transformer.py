@@ -20,7 +20,9 @@ gated memory units and gated short convolutions of ``models/ssm.py``,
 windowed, full and cross differential attention with grouped K/V
 heads, and plain softmax attention, full or under a window, each with
 rope or without positions: ``PLAIN``; with a norm on every head's q
-and k where ``qk_norm``), in which a layer may
+and k where ``qk_norm``; and attention over the keys a learned indexer
+picks for every query, ``SPARSE``, with rope whose lanes take their
+angle from three position streams), in which a layer may
 read what an earlier layer made, and a head tied to the embedding.
 Hidden sizes are multiples of 128 for MXU tiling; the head
 dimension is ``head_dim`` where the configuration states one (28 heads
@@ -56,6 +58,9 @@ SCOPE_WINDOW = "hvd_attn_window"
 SCOPE_MTP = "hvd_mtp"
 SCOPE_LOOP = "hvd_loop"     # the stack of a looped model, all its passes
 SCOPE_EXIT = "hvd_exit"     # its exit gates, heads and the loss's mix
+# A ``SPARSE`` layer's indexer, selection, kernels and alignment pass run
+# under ``ops.sparse_attention.SCOPE`` ("hvd_dsa") and its four parts.
+DSA_STATE = "dsa_state"     # flax collection: alignment loss, keys selected
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,6 +73,17 @@ class MLAConfig:
     nope_dim: int
     rope_dim: int
     v_dim: int
+
+
+@dataclasses.dataclass(frozen=True)
+class IndexerConfig:
+    """The indexer of a ``SPARSE`` layer (DeepSeek-V3.2-Exp's sparse
+    attention): ``heads`` query heads of ``head_dim`` over one key head
+    score every causal pair, and a query attends over the ``topk`` keys
+    of largest score."""
+    heads: int
+    head_dim: int
+    topk: int
 
 
 @dataclasses.dataclass(frozen=True)
@@ -124,7 +140,10 @@ class TransformerConfig:
     # are plain softmax attention, which hands nothing on and takes its
     # positions from its kind (``use_rope`` is for a stack without
     # ``mixers``); a "conv" layer is a gated short convolution of
-    # ``conv_taps`` taps (``ssm.ShortConv``), which hands nothing on.
+    # ``conv_taps`` taps (``ssm.ShortConv``), which hands nothing on; a
+    # ``SPARSE`` layer is plain attention with rope over the keys its
+    # indexer (``indexer``) selects for each query, and hands out its
+    # alignment loss through the collection ``DSA_STATE``.
     mixers: Optional[tuple] = None
     # The layers' indices in the published model where the stack is a
     # cut of it (differential attention's lambda_init depends on depth).
@@ -140,6 +159,17 @@ class TransformerConfig:
     # ``Attention``; v is not normed.
     qk_norm: bool = False
     conv_taps: int = 3               # taps of a "conv" layer's filter
+    indexer: Optional[IndexerConfig] = None     # of the ``SPARSE`` layers
+    # Rope over several position streams (M-RoPE, Qwen2-VL,
+    # arXiv:2409.12191) in the ``SPARSE`` layers: frequency pair ``i`` of
+    # a head turns by stream ``s``'s position where ``i`` lies in the
+    # ``s``-th run of ``rope_sections`` (consecutive pairs, summing to
+    # half the head's width). ``rope_layout`` says what a row holds, from
+    # which its table of positions is made (``mrope_positions``):
+    # ``("text", n)`` and ``("image", rows, columns)`` spans, a constant
+    # of the configuration. None: every stream is the token's index.
+    rope_sections: Optional[tuple] = None
+    rope_layout: Optional[tuple] = None
 
     @property
     def head_width(self):
@@ -150,8 +180,11 @@ class TransformerConfig:
 # only, rotates q and k). Without rope such a layer has no positions.
 PLAIN = {"full": (False, False), "full_rope": (False, True),
          "sliding": (True, False), "sliding_rope": (True, True)}
+# Attention over the keys the layer's indexer selects, with rope.
+SPARSE = "sparse_rope"
 # "window": attention that sees ``cfg.window`` keys and hands nothing on.
-MIXERS = ("attention", "window", "mamba", "gmu", "cross", "conv", *PLAIN)
+MIXERS = ("attention", "window", "mamba", "gmu", "cross", "conv", *PLAIN,
+          SPARSE)
 
 
 # BERT-large hyperparameters (the reference benchmark target).
@@ -189,15 +222,52 @@ _rotary.defvjp(lambda x, cos, sin: (_rotate(x, cos, sin), (cos, sin)),
                                   None, None))
 
 
+def mrope_positions(layout):
+    """The table of positions ``[tokens, 3]`` (time, height, width) of a
+    row that holds the spans of ``layout`` in order (Qwen2-VL's rule): a
+    text token's three are the running position ``p``; an image of
+    ``rows x columns`` merged patches that starts at ``p0`` gives the
+    patch in row ``r``, column ``c`` ``(p0, p0 + r, p0 + c)``, and the
+    text after it resumes at ``p0 + max(rows, columns)``."""
+    out, p = [], 0
+    for kind, *size in layout:
+        if kind == "text":
+            at = p + np.arange(size[0])
+            out.append(np.stack([at, at, at], axis=1))
+            p += size[0]
+        elif kind == "image":
+            r, c = np.divmod(np.arange(size[0] * size[1]), size[1])
+            out.append(np.stack([np.full_like(r, p), p + r, p + c], axis=1))
+            p += max(size)
+        else:
+            raise ValueError(f"a span is text or image, not {kind!r}")
+    return np.concatenate(out)
+
+
 @jax.named_scope("rope")
-def _rope(q, k, theta=10000.0):
+def _rope(q, k, theta=10000.0, positions=None, sections=None):
     """Rotary position embeddings over the head dimension of ``q`` and
     ``k`` (``[..., seq, heads, head_dim]``, head_dim even): base
-    ``theta``, lane ``i`` paired with lane ``i + head_dim // 2``."""
+    ``theta``, lane ``i`` paired with lane ``i + head_dim // 2``.
+    ``positions`` (numpy, ``[seq]`` or ``[seq, streams]``; None: the
+    token's index) are what the angles are taken at; with ``sections``,
+    frequency pair ``i`` reads the stream whose run of ``sections`` it
+    lies in, and without, the first."""
     seq, half = q.shape[-3], q.shape[-1] // 2
     freqs = 1.0 / (theta ** (np.arange(half) / half))
-    angles = jnp.asarray(np.einsum("s,d->sd", np.arange(seq), freqs),
-                         jnp.float32)
+    positions = np.arange(seq) if positions is None else np.asarray(positions)
+    if positions.ndim == 2:
+        stream = (np.repeat(np.arange(len(sections)), sections)
+                  if sections else np.zeros(half, int))
+        if len(stream) != half or len(positions) != seq:
+            raise ValueError(
+                f"rope: sections {sections} over {half} pairs, "
+                f"{len(positions)} positions for {seq} tokens")
+        positions = positions[:, stream]
+        products = positions * freqs[None, :]
+    else:
+        products = np.einsum("s,d->sd", positions, freqs)
+    angles = jnp.asarray(products, jnp.float32)
     cos, sin = (jnp.concatenate([t, t], axis=-1)[:, None]
                 for t in (jnp.cos(angles), jnp.sin(angles)))
     return _rotary(q, cos, sin), _rotary(k, cos, sin)
@@ -221,7 +291,12 @@ def _attend(cfg, q, k, v, mask=None, window=None):
     configuration's implementation; the scale is that of q's width.
     ``k`` and ``v`` may hold fewer heads than ``q`` (query head ``h``
     reads head ``h // group``) and ``v`` another width; with ``window``
-    a query sees that many keys, itself the last."""
+    a query sees that many keys, itself the last. ``mask`` is a padding
+    mask of ``[batch, keys]`` and takes the einsum path; the flash path
+    takes the causal mask, a window and grouped heads, and, not from
+    here, a mask of (key, query) pairs shared by the heads: a
+    ``SPARSE`` layer's selected set goes to the kernels through
+    ``ops.sparse_attention`` (``flash_attention(mask=)``)."""
     if cfg.attention_impl == "flash":
         # Pallas kernel path (ops/flash_attention.py): BHSD layout, the
         # causal mask and the window handled in-kernel (tiles they hide
@@ -278,11 +353,49 @@ def _qkv(cfg, x, name="qkv"):
             qkv[..., cfg.heads + kv:, :])
 
 
+class Indexer(nn.Module):
+    """The indexer of a ``SPARSE`` layer on the layer's normed input,
+    detached: ``(q_i, k_i, w)``, ``cfg.indexer.heads`` query heads of
+    ``head_dim`` and one key head (a LayerNorm on it), both turned by
+    plain rope at the first position stream, and a weight a head a
+    token in float32, scaled by ``heads ** -0.5 * head_dim ** -0.5``.
+    The index score of a pair is ``sum_j w[t, j] relu(q_i[t, j] .
+    k_i[s])`` (``ops.sparse_attention``). Nothing upstream of the layer
+    feels the indexer: its input carries no gradient."""
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, h, positions=None):
+        cfg, ix = self.cfg, self.cfg.indexer
+        h = jax.lax.stop_gradient(h)
+        q_i = nn.DenseGeneral((ix.heads, ix.head_dim), dtype=cfg.dtype,
+                              use_bias=False, name="q")(h)
+        k_i = nn.LayerNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype,
+                           name="k_norm")(
+            nn.Dense(ix.head_dim, dtype=cfg.dtype, use_bias=False,
+                     name="k")(h))
+        q_i, k_i = _rope(q_i, k_i[..., None, :], cfg.rope_theta,
+                         None if positions is None else positions[:, 0])
+        w = nn.Dense(ix.heads, dtype=jnp.float32, use_bias=False,
+                     precision=jax.lax.Precision.HIGHEST, name="w")(h)
+        return q_i, k_i[..., 0, :], w * (ix.heads * ix.head_dim) ** -0.5
+
+
 class Attention(nn.Module):
     """Softmax attention over ``cfg.heads`` query heads and
-    ``cfg.kv_heads`` K/V heads. ``kind``, one of ``PLAIN``, is for a
-    layer of a mixed stack: its window and its positions are its
-    kind's, and it runs under a scope that says which keys it sees."""
+    ``cfg.kv_heads`` K/V heads. ``kind``, one of ``PLAIN`` or
+    ``SPARSE``, is for a layer of a mixed stack: its window and its
+    positions are its kind's, and it runs under a scope that says which
+    keys it sees. A ``SPARSE`` layer sees, of the keys before a query,
+    the ``cfg.indexer.topk`` its ``Indexer`` scores highest
+    (``ops.sparse_attention``, whatever ``cfg.attention_impl``: the
+    flash kernels under the selected set as a mask), rotates q and k by
+    ``cfg.rope_sections`` over the positions of ``cfg.rope_layout``,
+    and leaves in collection ``DSA_STATE`` its alignment loss, the mean
+    over rows and queries of ``KL(head-mean attention || softmax of the
+    index scores)`` on the selected sets, which trains the indexer alone
+    (``dsa_align_loss`` sums the layers'), and the mean number of keys a
+    query selected."""
     cfg: TransformerConfig
     kind: Optional[str] = None
 
@@ -294,6 +407,8 @@ class Attention(nn.Module):
         # (batch, seq, heads, head_dim) -> attention in einsum form.
         if cfg.qk_norm:
             q, k = _norm(cfg, "q_norm")(q), _norm(cfg, "k_norm")(k)
+        if self.kind == SPARSE:
+            return self._sparse(x, q, k, v, mask)
         if rope:
             q, k = _rope(q, k, cfg.rope_theta)
         scope = (contextlib.nullcontext() if self.kind is None else
@@ -302,6 +417,81 @@ class Attention(nn.Module):
             out = _attend(cfg, q, k, v, mask, cfg.window if sliding else None)
         return nn.DenseGeneral(cfg.hidden, axis=(-2, -1), dtype=cfg.dtype,
                                use_bias=cfg.bias, name="proj")(out)
+
+    def _sparse(self, x, q, k, v, mask):
+        from ..ops import sparse_attention as dsa
+        cfg = self.cfg
+        if mask is not None or not cfg.causal or cfg.indexer is None:
+            raise ValueError(
+                f"a {SPARSE!r} layer is causal, takes no padding mask and "
+                f"needs TransformerConfig.indexer")
+        positions = (None if cfg.rope_layout is None
+                     else mrope_positions(cfg.rope_layout))
+        q, k = _rope(q, k, cfg.rope_theta, positions, cfg.rope_sections)
+        with jax.named_scope(dsa.SCOPE), jax.named_scope(dsa.SCOPE_INDEX):
+            q_i, k_i, w = Indexer(cfg, name="indexer")(x, positions)
+        align = self.variable(DSA_STATE, "align_loss", jnp.zeros, ())
+        selected = self.variable(DSA_STATE, "selected_keys", jnp.zeros, ())
+        keeps = self.is_mutable_collection(DSA_STATE)
+        out, loss, count = dsa.sparse_attention(
+            q, k, v, q_i, k_i, w, cfg.indexer.topk, with_align=keeps)
+        if keeps:
+            align.value, selected.value = loss, count
+        return nn.DenseGeneral(cfg.hidden, axis=(-2, -1), dtype=cfg.dtype,
+                               use_bias=cfg.bias, name="proj")(out)
+
+
+def dsa_align_loss(state):
+    """``sum_l L_I(l)``: the alignment losses that the ``SPARSE`` layers
+    of a call left in collection ``DSA_STATE`` (``state``: that
+    collection, or the tree of collections ``apply(..., mutable=)``
+    returned). Differentiable: add it to the language model's loss
+    inside the loss function; its gradient reaches the indexers' leaves
+    and nothing else."""
+    found = [leaf for path, leaf in
+             jax.tree_util.tree_leaves_with_path(state)
+             if getattr(path[-1], "key", None) == "align_loss"]
+    return sum(found) if found else 0.0
+
+
+def publish_dsa(state, seq=None, topk=None):
+    """Set ``hvd_dsa_align_loss{layer}`` and ``hvd_dsa_selected_keys
+    {layer}`` from the ``DSA_STATE`` collection a train step returned
+    and, given the row's length and the indexer's ``topk``,
+    ``hvd_dsa_kept_share`` (selected over causal pairs) and
+    ``hvd_dsa_mask_bytes`` (a layer's kept mask, a row), both from
+    shapes. Call it outside the step; it fetches the arrays. A no-op
+    when ``HOROVOD_TPU_METRICS`` is off."""
+    from ..ops import sparse_attention as dsa
+    from ..parallel.sharding import _path_str
+    from ..telemetry import core as telemetry
+    if not telemetry.enabled():
+        return
+    gauges = {
+        "align_loss": telemetry.gauge(
+            "hvd_dsa_align_loss",
+            "The layer's alignment loss in the last step: the mean over "
+            "queries of KL(head-mean attention || softmax of the index "
+            "scores) on the selected keys", ("layer",)),
+        "selected_keys": telemetry.gauge(
+            "hvd_dsa_selected_keys",
+            "Mean number of keys a query of the layer selected in the last "
+            "step, counted from the mask", ("layer",))}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(state):
+        gauge = gauges.get(getattr(path[-1], "key", None))
+        if gauge is not None:
+            gauge.labels(layer=_path_str(path[:-1])).set(
+                float(jax.device_get(leaf)))
+    if seq and topk:
+        telemetry.gauge(
+            "hvd_dsa_kept_share",
+            "Selected pairs over causal pairs of a row (from shapes)").set(
+                dsa.kept_share(seq, topk))
+        telemetry.gauge(
+            "hvd_dsa_mask_bytes",
+            "Bytes of the selected set a sparse layer keeps for its "
+            "backward pass, a row: int8 [keys, queries]").set(
+                float(seq * seq))
 
 
 def diff_lambda_init(depth):
@@ -413,7 +603,7 @@ class Block(nn.Module):
             a = GatedMemoryUnit(cfg, name="gmu")(h, memory)
         elif self.mixer == "conv":
             a = ShortConv(cfg, name="conv")(h)
-        elif self.mixer in PLAIN:
+        elif self.mixer in PLAIN or self.mixer == SPARSE:
             a = Attention(cfg, kind=self.mixer, name="attn")(h, mask)
         elif self.mixer is not None:
             a, kv = DiffAttention(

@@ -47,6 +47,12 @@ Design (MXU/VMEM-first):
   index maps name the shared row; nothing is repeated in HBM) and ``v``
   may be wider than ``q`` and ``k`` (differential attention's pair of
   values).
+- A ``mask`` of data (int8, (batch, keys, queries), one for a batch
+  element's heads: the keys a learned indexer selected for each query,
+  ``ops/sparse_attention.py``) is one more operand of both kernels, a
+  block of it fetched with every tile; the grids stay the causal
+  mask's, since no table made from shapes knows which tiles such a
+  mask empties. A call without it traces what it always did.
 - Returns (out, lse); lse makes partial results mergeable (ring attention)
   and feeds the backward pass.
 - Custom VJP with one backward kernel. Two Mosaic calls a layer:
@@ -532,13 +538,14 @@ def _step_table(backward, where, n_q, n_k, block_q, block_k, causal,
 
 def _fwd_kernel(lens_ref, steps_ref, q_ref, k_ref, v_ref, *rest, sm_scale,
                 causal, block_q, block_k, sub, one_tile, dropout_rate=0.0,
-                seeded=False, window=None):
-    # rest = [dm_ref?], o_ref, lse_ref, m_scr, l_scr, acc_scr
+                seeded=False, window=None, has_mask=False):
+    # rest = [dm_ref?], [mask_ref?], o_ref, lse_ref, m_scr, l_scr, acc_scr
+    dm_ref = mask_ref = None
     if dropout_rate > 0.0 and not seeded:
-        dm_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        dm_ref = None
-        o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
+        dm_ref, *rest = rest
+    if has_mask:
+        mask_ref, *rest = rest
+    o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     # This grid step's tile, and whether it is its query block's first
     # and last (_step_table).
     qb, kb, flags = _this_step(steps_ref, one_tile)
@@ -587,11 +594,20 @@ def _fwd_kernel(lens_ref, steps_ref, q_ref, k_ref, v_ref, *rest, sm_scale,
                 window))
 
         st = masked(st, _NEG_INF)
+        if mask_ref is not None:
+            st = jnp.where(_mask_tile(mask_ref, keys, rows), st, _NEG_INF)
         m_prev = m_scr[:1, rows]                   # (1, n_rows)
         m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        pt = jnp.exp(st - m_new)                   # (width, n_rows) fp32
-        if rows_after != 0:
+        if mask_ref is not None:
+            # A row that has seen no key yet keeps m at _NEG_INF, where
+            # exp(st - m) would be 1 for its hidden keys: against a
+            # maximum held just above it they are exp(-9e29) = 0, at the
+            # cost of a row of maxima and not of a pass over the tile.
+            pt = jnp.exp(st - jnp.maximum(m_new, 0.1 * _NEG_INF))
+        else:
+            pt = jnp.exp(st - m_new)               # (width, n_rows) fp32
+        if rows_after != 0 and mask_ref is None:
             # Fully-masked rows: m_new stays _NEG_INF and p would be
             # exp(0)=1 — zero those contributions so l stays 0 for
             # them. (On the diagonal every row sees its own key, so a
@@ -661,7 +677,7 @@ def _fwd_kernel(lens_ref, steps_ref, q_ref, k_ref, v_ref, *rest, sm_scale,
 
 def _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
               dm=None, dropout_rate=0.0, seeded=False, window=None,
-              where=None):
+              where=None, mask=None):
     """One forward kernel call. The layers of a model make the same
     call, so it goes through ``jax.jit``: the kernel is traced and
     lowered once a program, not once a layer, and XLA inlines the calls
@@ -674,13 +690,19 @@ def _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
 
     ``k`` and ``v`` may hold fewer heads than ``q`` (``q``'s rows are
     ``group`` to a row of theirs, adjacent), and ``v`` another width
-    than ``q`` and ``k``."""
+    than ``q`` and ``k``. ``mask`` (``_mask_tile``) is one more operand
+    of the same grid: the table is made from shapes and offsets, which
+    a mask of data does not change, so every tile under the diagonal
+    runs."""
     steps = _step_table(False, lens if where is None else where,
                         q.shape[1] // block_q, k.shape[1] // block_k,
                         block_q, block_k, causal, window)
-    return _fwd_jit(q, k, v, lens, dm, steps, sm_scale, causal, block_q,
-                    block_k, _sub_tile(causal, block_q, block_k, q.shape[2]),
-                    dropout_rate, seeded, _interpret(), window)
+    args = (q, k, v, lens, dm, steps, sm_scale, causal, block_q, block_k,
+            _sub_tile(causal, block_q, block_k, q.shape[2]), dropout_rate,
+            seeded, _interpret(), window)
+    if mask is None:
+        return _fwd_jit(*args)
+    return _fwd_masked_jit(mask, *args)
 
 
 def _kv_row(b, group):
@@ -688,9 +710,44 @@ def _kv_row(b, group):
     return b if group == 1 else lax.div(b, jnp.int32(group))
 
 
+def _mask_tile(mask_ref, keys, rows):
+    """Which (key, query) pairs of a part of a tile the call's ``mask``
+    keeps. The mask is int8 ``[batch, keys, queries]``, key-major as
+    the kernels' tiles are and shared by a batch element's heads; a
+    block of it comes with every tile, beside K and V."""
+    return mask_ref[0, keys, rows].astype(jnp.int32) != 0
+
+
+def _mask_spec(mask, bh, block_q, block_k, qb, kb):
+    """The mask's block of a grid step whose query and key blocks
+    ``qb(step, steps)`` and ``kb(step, steps)`` name."""
+    heads = bh // mask.shape[0]
+    return pl.BlockSpec(
+        (1, block_k, block_q), lambda b, s, lens, steps: (
+            lax.div(b, jnp.int32(heads)), kb(s, steps), qb(s, steps)))
+
+
+def _mask_vmem_bytes(block_q, block_k):
+    """Scoped VMEM a mask adds to a kernel: its int8 block, double-
+    buffered, and the 32 bits a tile of it is widened to."""
+    return 6 * block_q * block_k
+
+
 @functools.partial(jax.jit, static_argnums=tuple(range(6, 15)))
 def _fwd_jit(q, k, v, lens, dm, steps, sm_scale, causal, block_q, block_k,
              sub, dropout_rate, seeded, interpret, window):
+    return _fwd_pallas(None, q, k, v, lens, dm, steps, sm_scale, causal,
+                       block_q, block_k, sub, dropout_rate, seeded, interpret,
+                       window)
+
+
+@functools.partial(jax.jit, static_argnums=tuple(range(7, 16)))
+def _fwd_masked_jit(mask, *args):
+    return _fwd_pallas(mask, *args)
+
+
+def _fwd_pallas(mask, q, k, v, lens, dm, steps, sm_scale, causal, block_q,
+                block_k, sub, dropout_rate, seeded, interpret, window):
     bh, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]
     group = bh // k.shape[0]
@@ -698,7 +755,8 @@ def _fwd_jit(q, k, v, lens, dm, steps, sm_scale, causal, block_q, block_k,
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, sub=sub, one_tile=one_tile,
-        dropout_rate=dropout_rate, seeded=seeded, window=window)
+        dropout_rate=dropout_rate, seeded=seeded, window=window,
+        has_mask=mask is not None)
     qb, kb = _named(_ROW, one_tile), _named(_FETCH, one_tile)
 
     def kv_at(b, s, lens, steps):
@@ -718,6 +776,12 @@ def _fwd_jit(q, k, v, lens, dm, steps, sm_scale, causal, block_q, block_k,
             (1, block_q, block_k), lambda b, s, lens, steps: (
                 b, qb(s, steps), kb(s, steps))))
         operands.append(dm)
+    limits = {}
+    if mask is not None:
+        in_specs.append(_mask_spec(mask, bh, block_q, block_k, qb, kb))
+        operands.append(mask)
+        limits["vmem_limit_bytes"] = _TILE_VMEM_BYTES + _mask_vmem_bytes(
+            block_q, block_k)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(bh, steps.shape[0] // 4),
@@ -739,7 +803,7 @@ def _fwd_jit(q, k, v, lens, dm, steps, sm_scale, causal, block_q, block_k,
         _struct((bh, 1, sq), jnp.float32, q, k, v, lens),
     ]
     compiler_params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"))
+        dimension_semantics=("parallel", "arbitrary"), **limits)
     o, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -757,12 +821,15 @@ def _fwd_jit(q, k, v, lens, dm, steps, sm_scale, causal, block_q, block_k,
 
 def _bwd_kernel(lens_ref, steps_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                 delta_ref, *rest, sm_scale, causal, block_q, block_k, qb0,
-                sub, one_tile, dropout_rate=0.0, seeded=False, window=None):
-    # rest = [dm_ref?], dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr
+                sub, one_tile, dropout_rate=0.0, seeded=False, window=None,
+                has_mask=False):
+    # rest = [dm_ref?], [mask_ref?], dq_ref, dk_ref, dv_ref, dq_scr,
+    # dk_scr, dv_scr
+    dm_ref = mask_ref = None
     if dropout_rate > 0.0 and not seeded:
         dm_ref, *rest = rest
-    else:
-        dm_ref = None
+    if has_mask:
+        mask_ref, *rest = rest
     dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr = rest
     # This grid step's tile, whether it is its key block's first and
     # last, and its query block's in the whole call (_step_table).
@@ -830,6 +897,8 @@ def _bwd_kernel(lens_ref, steps_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                 (n, n_rows), row0, key0 + first, rows_after, causal,
                 q_start + qg * block_q, k_start, kb * block_k, kv_len,
                 window))(pt, 0.0)
+        if mask_ref is not None:
+            pt = jnp.where(_mask_tile(mask_ref, keys, rows), pt, 0.0)
 
         # Dropout backward: o = (P∘M̃)V with M̃ = mask/(1-rate), so
         # dV = (P∘M̃)ᵀdO and dP = (dO Vᵀ)∘M̃; the delta trick survives
@@ -935,7 +1004,7 @@ def _dq_resident_bytes(rows, d, dtype):
 @jax.named_scope(SCOPE)
 def _bwd_call(q, k, v, o, do, lse, lens, sm_scale, causal, block_q, block_k,
               g_lse=None, dm=None, dropout_rate=0.0, seeded=False,
-              window=None, where=None):
+              window=None, where=None, mask=None):
     """dq, dk and dv from one kernel: one pass over the (key, query)
     tiles computes s, exp, dp and ds once and feeds all three gradients.
     Grid (batch*heads, steps), a step a tile (_step_table: the tiles
@@ -947,7 +1016,8 @@ def _bwd_call(q, k, v, o, do, lse, lens, sm_scale, causal, block_q, block_k,
     table is made for its own query blocks. Where ``k`` and
     ``v`` hold fewer heads than ``q``, the kernel writes every query
     head's share of dk and dv in float32 and the group's are summed
-    here."""
+    here. ``mask`` as in the forward (``_mask_tile``): a chunk takes
+    its own queries' columns of it."""
     bh, sq, d = q.shape
     group = bh // k.shape[0]
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
@@ -967,6 +1037,9 @@ def _bwd_call(q, k, v, o, do, lse, lens, sm_scale, causal, block_q, block_k,
     # What the trace reads of the module's state is an argument, as in
     # _fwd_call.
     def chunk(qb0, n_q, *rows, **kv_dtype):
+        if mask is not None:
+            kv_dtype = dict(kv_dtype, mask=mask[
+                :, :, qb0 * block_q:(qb0 + n_q) * block_q])
         return _bwd_chunk(
             *rows, k=k, v=v, lens=lens, qb0=qb0, steps=_step_table(
                 True, lens if where is None else where, n_q,
@@ -1000,7 +1073,7 @@ def _bwd_call(q, k, v, o, do, lse, lens, sm_scale, causal, block_q, block_k,
     "dropout_rate", "seeded", "interpret", "kv_dtype", "window"))
 def _bwd_chunk(q, do, lse3, delta3, dm, *, k, v, lens, steps, qb0,
                sm_scale, causal, block_q, block_k, sub, dropout_rate, seeded,
-               interpret, kv_dtype=None, window=None):
+               interpret, kv_dtype=None, window=None, mask=None):
     """The backward kernel over the query rows it is given: tiles ``qb0``
     onward of the sequence, in the order ``steps`` gives (_step_table,
     of these rows' query blocks). dk and dv are this chunk's share, a row to
@@ -1037,6 +1110,9 @@ def _bwd_chunk(q, do, lse3, delta3, dm, *, k, v, lens, steps, qb0,
             (1, block_q, block_k),
             lambda b, s, lens, steps: (b, qi(s, steps), kb(s, steps))))
         operands.append(dm)
+    if mask is not None:
+        in_specs.append(_mask_spec(mask, bh, block_q, block_k, qi, kb))
+        operands.append(mask)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(bh, steps.shape[0] // 4),
@@ -1055,7 +1131,8 @@ def _bwd_chunk(q, do, lse3, delta3, dm, *, k, v, lens, steps, qb0,
         functools.partial(
             _bwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
             block_k=block_k, qb0=qb0, sub=sub, one_tile=one_tile,
-            dropout_rate=dropout_rate, seeded=seeded, window=window),
+            dropout_rate=dropout_rate, seeded=seeded, window=window,
+            has_mask=mask is not None),
         grid_spec=grid_spec,
         out_shape=[
             _struct((bh, sq, d), q.dtype, q, k, v, do, lens),
@@ -1069,7 +1146,9 @@ def _bwd_chunk(q, do, lse3, delta3, dm, *, k, v, lens, steps, qb0,
             # as a head of twice the width does.
             vmem_limit_bytes=_TILE_VMEM_BYTES * _lane_tiles(d) * (
                 2 if dv > d or group > 1 else 1)
-            + _dq_resident_bytes(sq, d, q.dtype)),
+            + _dq_resident_bytes(sq, d, q.dtype)
+            + (_mask_vmem_bytes(block_q, block_k) if mask is not None
+               else 0)),
         interpret=interpret,
         name=KERNEL_BWD_DKDV,
     )(lens, steps, *operands)
@@ -1141,6 +1220,35 @@ def _flash_with_lse_bwd(sm_scale, causal, block_q, block_k, where, res, g):
 
 
 _flash_with_lse.defvjp(_flash_with_lse_fwd, _flash_with_lse_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash_masked(q, k, v, lens, mask, sm_scale, causal, block_q, block_k,
+                  where=None):
+    """``(o, lse)`` under a mask of data (``_mask_tile``)."""
+    return _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
+                     where=where, mask=mask)
+
+
+def _flash_masked_fwd(q, k, v, lens, mask, sm_scale, causal, block_q,
+                      block_k, where):
+    o, lse = map(ad_checkpoint.checkpoint_name, _fwd_call(
+        q, k, v, lens, sm_scale, causal, block_q, block_k, where=where,
+        mask=mask), SAVED_NAMES)
+    return (o, lse), (q, k, v, o, lse, lens, mask)
+
+
+def _flash_masked_bwd(sm_scale, causal, block_q, block_k, where, res, g):
+    q, k, v, o, lse, lens, mask = res
+    go, g_lse = g
+    dq, dk, dv = _bwd_call(q, k, v, o, go, lse, lens, sm_scale, causal,
+                           block_q, block_k, g_lse=g_lse, where=where,
+                           mask=mask)
+    return (dq, dk, dv, np.zeros((3,), jax.dtypes.float0),
+            np.zeros(mask.shape, jax.dtypes.float0))
+
+
+_flash_masked.defvjp(_flash_masked_fwd, _flash_masked_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
@@ -1386,7 +1494,7 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None,
                     q_offset=0, k_offset=0, kv_len=None,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
                     with_lse=False, dropout_mask=None, dropout_rate=0.0,
-                    dropout_seed=None, window=None):
+                    dropout_seed=None, window=None, mask=None):
     """Flash attention over (batch, heads, seq, head_dim) tensors.
 
     ``k`` and ``v`` may hold fewer heads than ``q``, a whole number of
@@ -1397,6 +1505,16 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None,
     the output is of ``v``'s.
 
     Args:
+      mask: (batch, keys, queries), integers (int8 is what the kernels
+        fetch): a query sees a key only where its entry is not 0,
+        besides what ``causal`` and ``kv_len`` hide; one mask for all
+        the heads of a batch element, key-major as the kernels' tiles
+        are. Every query has to keep a key. The grids are those of the
+        call without it (a table made from shapes cannot know which
+        tiles a mask of data empties), each tile fetching its block of
+        the mask beside K and V. Goes with grouped K/V heads and
+        ``with_lse``; not with dropout or a window. Without it the call
+        traces the program it always did.
       window: with ``causal``, a query at position ``t`` sees the keys at
         positions ``t - window < s <= t`` only (a static integer). The
         kernels run no sub-tile that the window hides and their grids
@@ -1453,7 +1571,13 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None,
         raise NotImplementedError(
             "flash_attention: dropout with with_lse is unsupported "
             "(ring/merged attention never uses attention dropout)")
-    if (has_dropout or with_lse) and (
+    if mask is not None and (has_dropout or window is not None
+                             or mask.shape != (b, k.shape[2], sq)):
+        raise NotImplementedError(
+            f"flash_attention: a mask is (batch, keys, queries), here "
+            f"{(b, k.shape[2], sq)}, not {mask.shape}, and goes with "
+            f"neither dropout nor a window")
+    if (has_dropout or (with_lse and mask is None)) and (
             window is not None or k.shape[1] != h or dv != d):
         raise NotImplementedError(
             "flash_attention: a window, grouped K/V heads and a value "
@@ -1472,7 +1596,7 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None,
             q, k, v, causal=causal, sm_scale=sm_scale, q_offset=q_offset,
             k_offset=k_offset, kv_len=kv_len, with_lse=with_lse,
             dropout_mask=dropout_mask, dropout_rate=dropout_rate,
-            window=window)
+            window=window, mask=mask)
     qp, kp, vp, dims, bq, bk = _prepare(q, k, v, block_q, block_k)
     _publish_subtiles(sq, k.shape[2], bq, bk, bool(causal), q_offset,
                     k_offset, kv_len, qp.shape[2], window)
@@ -1480,6 +1604,12 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None,
     # tiles that do something and no other (_step_table).
     where = _static(q_offset, k_offset, kv_len)
     lens = jnp.asarray([q_offset, k_offset, kv_len], jnp.int32)
+    if mask is not None:
+        maskp = _pad_to(_pad_to(mask.astype(jnp.int8), bk, 1), bq, 2)
+        o, lse = _flash_masked(qp, kp, vp, lens, maskp, float(sm_scale),
+                               bool(causal), bq, bk, where)
+        o = o[:, :sq, :dv].reshape(b, h, sq, dv).astype(orig_dtype)
+        return (o, lse[:, :sq].reshape(b, h, sq)) if with_lse else o
     if has_dropout and dropout_seed is not None:
         lens4 = jnp.concatenate(
             [lens, jnp.asarray(dropout_seed, jnp.int32).reshape(1)])
@@ -1507,11 +1637,12 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None,
 def reference_attention(q, k, v, *, causal=False, sm_scale=None,
                         q_offset=0, k_offset=0, kv_len=None,
                         with_lse=False, dropout_mask=None,
-                        dropout_rate=0.0, window=None):
+                        dropout_rate=0.0, window=None, mask=None):
     """Plain einsum attention with the same masking semantics — the
     correctness oracle for the kernel tests and the shard_map-on-CPU
     fallback. Offsets may be traced scalars. Grouped K/V heads are
-    repeated, the window is a mask."""
+    repeated, the window is a mask, and ``mask`` (batch, keys, queries)
+    one more."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
     if k.shape[1] != h:
@@ -1523,7 +1654,7 @@ def reference_attention(q, k, v, *, causal=False, sm_scale=None,
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * sm_scale
     cols = jnp.arange(sk)
-    mask = (cols < kv_len)[None, None, None, :]
+    keep, mask = mask, (cols < kv_len)[None, None, None, :]
     if causal:
         rows = q_offset + jnp.arange(sq)
         cmask = rows[:, None] >= (k_offset + cols)[None, :]
@@ -1531,6 +1662,8 @@ def reference_attention(q, k, v, *, causal=False, sm_scale=None,
             cmask = jnp.logical_and(
                 cmask, rows[:, None] - (k_offset + cols)[None, :] < window)
         mask = jnp.logical_and(mask, cmask[None, None])
+    if keep is not None:
+        mask = jnp.logical_and(mask, (keep != 0).swapaxes(1, 2)[:, None])
     mask = jnp.broadcast_to(mask, s.shape)
     s = jnp.where(mask, s, _NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
