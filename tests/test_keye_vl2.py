@@ -14,6 +14,7 @@ benchmark's ``correct``, at the published widths.)
 """
 
 import dataclasses
+import functools
 import json
 import os
 import re
@@ -71,7 +72,8 @@ def small_blocks(monkeypatch):
     """Blocks a row of 48 is several of, so that every pass crosses a
     block's and a group's edge."""
     monkeypatch.setattr(dsa, "SELECT_BLOCK", 16)
-    monkeypatch.setattr(dsa, "ALIGN_BLOCK", 8)
+    monkeypatch.setattr(dsa, "ALIGN_TILE_Q", 16)
+    monkeypatch.setattr(dsa, "ALIGN_TILE_K", 8)
     monkeypatch.setattr(dsa, "FLASH_BLOCK", 32)
     monkeypatch.setattr(reference, "QUERY_BLOCK", 16)
 
@@ -352,7 +354,7 @@ def test_the_models_selected_sets_are_the_references(seeded):
     scores = reference.index_scores(q_i, k_i, w)
     select = jax.jit(lambda *a: dsa.index_select(*a, TOPK))
     for b in range(2):
-        got = np.asarray(select(q_i[b], k_i[b], w[b])).T
+        got = np.asarray(select(q_i[b], k_i[b], w[b])[0]).T
         np.testing.assert_array_equal(got.astype(bool),
                                       reference_set(scores[b], TOPK))
     # The module computes what the reference's indexer computes.
@@ -479,7 +481,7 @@ def dense_sparse_attention(q, k, v, q_i, k_i, w, topk):
         jnp.einsum("tjd,sd->tjs", q_i, k_i)))
     causal = jnp.tril(jnp.ones((seq, seq), bool))
     _, chosen = lax.top_k(lax.stop_gradient(
-        jnp.where(causal, scores, -jnp.inf)), topk)
+        jnp.where(causal, scores, -jnp.inf)), min(topk, seq))
     taken = jnp.zeros((seq, seq), bool).at[
         jnp.arange(seq)[:, None], chosen].set(True) & causal
     group = heads // k.shape[1]
@@ -531,6 +533,183 @@ def test_sparse_attention_and_its_alignment_pass_match_autodiff():
         *a, TOPK, with_align=False))(*args)
     assert none is None
     np.testing.assert_allclose(out2, out, atol=1e-6)
+
+
+ALIGN_CASES = {
+    # Rows of 64 in tiles of 16 queries by 8 keys: four query blocks,
+    # each with the tiles the diagonal crosses.
+    "several_blocks": {},
+    "kv_groups_of_1": {"heads": 4, "groups": 4},
+    "kv_groups_of_8": {"heads": 8, "groups": 1},
+    "row_shorter_than_topk": {"seq": 8, "topk": 16},
+    "tied_index_scores": {"levels": 2.0},
+    "p_underflows_on_a_kept_key": {"sharp": 300.0},
+    "without_grads": {},
+    "two_rows": {"rows": 2},
+}
+
+
+def align_inputs(rows=1, seq=64, heads=4, groups=2, topk=TOPK, levels=None,
+                 sharp=None):
+    d, j, di = 16, 2, 8
+    keys = jax.random.split(jax.random.PRNGKey(11), 6)
+    q = jax.random.normal(keys[0], (rows, seq, heads, d))
+    k = jax.random.normal(keys[1], (rows, seq, groups, d))
+    v = jax.random.normal(keys[2], (rows, seq, groups, d))
+    q_i = jax.random.normal(keys[3], (rows, seq, j, di))
+    k_i = jax.random.normal(keys[4], (rows, seq, di))
+    w = 0.3 * jax.random.normal(keys[5], (rows, seq, j))
+    if levels:
+        # A few levels an entry: products, and so scores, that tie.
+        q_i, k_i = jnp.round(q_i * levels) / levels, jnp.round(k_i)
+        w = jnp.round(w * 4) / 4
+    if sharp:
+        # Every head of query 40 sees one key and underflows on the
+        # rest, the kept ones too: p is 0 where the set is not.
+        q = q.at[:, 40].multiply(sharp)
+    return (q, k, v, q_i, k_i, w), topk
+
+
+def align_operands(q, k, v, q_i, k_i, w, topk, attend=fa.flash_attention):
+    """What ``sparse_attention`` hands ``align_loss`` beside the
+    indexer's operands, of one row: q and k head-major, the attention's
+    log-sum-exp under the selected set, the set and the log-sum-exp of
+    the index scores over it."""
+    mask_t, lse_i = dsa.index_select(q_i, k_i, w, topk)
+    qh, kh = q.swapaxes(0, 1), k.swapaxes(0, 1)
+    _, lse = attend(qh[None], kh[None], v.swapaxes(0, 1)[None], causal=True,
+                    sm_scale=q.shape[-1] ** -0.5, mask=mask_t[None],
+                    with_lse=True)
+    return qh, kh, lse[0], mask_t, lse_i
+
+
+@pytest.mark.parametrize("case", sorted(ALIGN_CASES))
+def test_the_alignment_kernel_matches_autodiff_of_the_dense_loss(case):
+    """``align_loss`` in Pallas's interpreter, its loss and its three
+    gradients in closed form, against autodiff of
+    ``dense_sparse_attention``'s KL at float32."""
+    (q, k, v, q_i, k_i, w), topk = align_inputs(**ALIGN_CASES[case])
+    rows = q.shape[0]
+    sm_scale = q.shape[-1] ** -0.5
+
+    def parts(b):
+        return align_operands(q[b], k[b], v[b], q_i[b], k_i[b], w[b], topk)
+
+    def ours(q_i, k_i, w):
+        return sum(dsa.align_loss(*parts(b)[:4], q_i[b], k_i[b], w[b],
+                                  parts(b)[4], sm_scale)
+                   for b in range(rows)) / rows
+
+    def dense(q_i, k_i, w):
+        return sum(dense_sparse_attention(
+            q[b], k[b], v[b], q_i[b], k_i[b], w[b], topk)[1]
+            for b in range(rows)) / rows
+
+    want, want_grads = jax.jit(jax.value_and_grad(dense, argnums=(0, 1, 2)))(
+        q_i, k_i, w)
+    if case == "without_grads":
+        # The primal alone: the same kernel body, the gradient terms
+        # left out; the same loss to the last bit.
+        alone = jax.jit(ours)(q_i, k_i, w)
+        assert float(alone) == pytest.approx(float(want), rel=1e-5)
+        assert float(alone) == float(jax.jit(jax.value_and_grad(ours))(
+            q_i, k_i, w)[0])
+        return
+    got, grads = jax.jit(jax.value_and_grad(ours, argnums=(0, 1, 2)))(
+        q_i, k_i, w)
+    assert float(want) > 1e-3
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    for name, a, c in zip(("q_i", "k_i", "w"), grads, want_grads):
+        assert a.shape == c.shape and a.dtype == c.dtype
+        assert float(jnp.abs(c).max()) > 0, name
+        np.testing.assert_allclose(a, c, atol=2e-6, rtol=2e-5, err_msg=name)
+    if case == "p_underflows_on_a_kept_key":
+        mask_t = parts(0)[3]
+        logits = jnp.einsum("hd,shd->hs", q[0, 40], jnp.repeat(
+            k[0], q.shape[2] // k.shape[2], 1)) * sm_scale
+        p = jax.nn.softmax(jnp.where(mask_t[:, 40] != 0, logits, -jnp.inf),
+                           axis=-1).mean(0)
+        assert int(jnp.sum((mask_t[:, 40] != 0) & (p == 0))) > 0
+    if case == "tied_index_scores":
+        scores = jnp.einsum("tj,tjs->ts", w[0], jax.nn.relu(
+            jnp.einsum("tjd,sd->tjs", q_i[0], k_i[0])))
+        assert len(np.unique(np.asarray(scores[40, :41]))) < 30
+
+
+def test_inside_shard_map_off_the_tpu_the_row_goes_through_every_pair():
+    """Pallas's interpreter refuses device-varying operands, so there,
+    as ``flash_attention`` does, the pass steps aside: the same
+    numbers from every pair at once, and the same through a
+    ``shard_map`` as outside it."""
+    from jax.sharding import Mesh, PartitionSpec as P
+    (q, k, v, q_i, k_i, w), topk = align_inputs(rows=2, seq=32)
+    sm_scale = q.shape[-1] ** -0.5
+
+    def operands(b, q_i=q_i, k_i=k_i, w=w):
+        qh, kh, lse, mask_t, lse_i = align_operands(
+            q[b], k[b], v[b], q_i[b], k_i[b], w[b], topk,
+            fa.reference_attention)
+        return qh, kh, lse, mask_t, q_i[b], k_i[b], w[b], lse_i
+
+    tiles = jax.jit(lambda: dsa._by_tiles(*operands(0), sm_scale, True))()
+    pairs = jax.jit(lambda: dsa._every_pair(*operands(0), sm_scale))()
+    for a, b in zip(tiles, pairs):
+        np.testing.assert_allclose(a, b, atol=1e-6, rtol=2e-5)
+
+    def loss(q_i, k_i, w, b):
+        return dsa.align_loss(*operands(b, q_i, k_i, w), sm_scale)
+
+    mesh = Mesh(np.array(jax.devices()[:2]), ("hvd",))
+
+    @jax.jit
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("hvd"),
+                       out_specs=P("hvd"))
+    def sharded(q, k, v, q_i, k_i, w):
+        # A row a device; what varies over the mesh reaches the pass.
+        def one(q_i, k_i, w):
+            qh, kh, lse, mask_t, lse_i = align_operands(
+                q[0], k[0], v[0], q_i[0], k_i[0], w[0], topk,
+                fa.reference_attention)
+            return dsa.align_loss(qh, kh, lse, mask_t, q_i[0], k_i[0], w[0],
+                                  lse_i, sm_scale)
+        value, grads = jax.value_and_grad(one, argnums=(0, 1, 2))(q_i, k_i, w)
+        return value[None], grads
+
+    values, grads = sharded(q, k, v, q_i, k_i, w)
+    for b in range(2):
+        want, want_grads = jax.jit(jax.value_and_grad(
+            lambda *a: loss(*a, b), argnums=(0, 1, 2)))(q_i, k_i, w)
+        assert float(values[b]) == pytest.approx(float(want), rel=1e-5)
+        for a, c in zip(grads, want_grads):
+            np.testing.assert_allclose(a[b], c[b], atol=1e-6, rtol=2e-5)
+
+
+@pytest.mark.parametrize("seq,block_q,block_k,run", [
+    (16384, 1024, 1024, 136), (16384, 512, 256, 1056), (64, 16, 8, 20),
+    (48, 16, 8, 12), (8, 8, 8, 1)])
+def test_the_alignment_kernels_steps_are_the_tiles_holding_a_causal_pair(
+        seq, block_q, block_k, run, telemetry_plane):
+    """From shapes: a step a (query block, key block) tile with a key
+    not after one of its queries, and no other; a query block's one
+    after the other, first and last flagged."""
+    n_q, n_k = seq // block_q, seq // block_k
+    want = [(qb, kb) for qb in range(n_q) for kb in range(n_k)
+            if kb * block_k <= qb * block_q + block_q - 1]
+    steps = dsa._align_steps(seq, block_q, block_k).reshape(4, -1)
+    assert list(zip(steps[fa._ROW], steps[fa._INNER])) == want
+    assert (steps[fa._FETCH] == steps[fa._INNER]).all()
+    assert len(want) == run
+    firsts = [i for i, (qb, kb) in enumerate(want) if kb == 0]
+    lasts = [i - 1 for i in firsts[1:]] + [len(want) - 1]
+    assert list(np.flatnonzero(steps[fa._FLAGS] & fa._ROW_FIRST)) == firsts
+    assert list(np.flatnonzero(steps[fa._FLAGS] & fa._ROW_LAST)) == lasts
+    assert dsa.align_tiles(seq, block_q, block_k) == {
+        "run": run, "rectangle": n_q * n_k}
+    # The gauge, set while tracing (docs/metrics.md).
+    dsa._publish_tiles(seq, block_q, block_k)
+    tiles = {s["labels"]["kind"]: s["value"] for s in telemetry_plane.snapshot()[
+        "families"]["hvd_dsa_align_tiles"]["samples"]}
+    assert tiles == {"run": float(run), "rectangle": float(n_q * n_k)}
 
 
 def test_a_call_that_keeps_no_state_runs_no_alignment_pass(seeded):
