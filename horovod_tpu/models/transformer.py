@@ -298,7 +298,10 @@ def _attend(cfg, q, k, v, mask=None, window=None):
     ``SPARSE`` layer's selected set goes to the kernels through
     ``ops.sparse_attention`` (``flash_attention(mask=)``)."""
     if cfg.attention_impl == "flash":
-        # Pallas kernel path (ops/flash_attention.py): BHSD layout, the
+        # Pallas kernel path (ops/flash_attention.py): q, k, v handed
+        # over as they are held (``layout="bshd"``: at a head of 64 the
+        # kernels read the order XLA keeps them in, and nothing is
+        # copied; ``flash_attention.addressing``), the
         # causal mask and the window handled in-kernel (tiles they hide
         # are neither run nor fetched), grouped K/V heads read through
         # the kernels' index maps. Per-sample padding masks need the
@@ -316,9 +319,8 @@ def _attend(cfg, q, k, v, mask=None, window=None):
         # the mask leaves of a block the forward resolves finer, in its
         # own sub-tiles (ops/flash_attention.py: _sub_tile).
         return flash_attention(
-            q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),
-            causal=cfg.causal, block_q=1024,
-            block_k=1024, window=window).swapaxes(1, 2)
+            q, k, v, causal=cfg.causal, block_q=1024, block_k=1024,
+            window=window, layout="bshd")
     if k.shape[2] != q.shape[2]:
         k, v = (jnp.repeat(x, q.shape[2] // x.shape[2], axis=2)
                 for x in (k, v))

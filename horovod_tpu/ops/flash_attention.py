@@ -53,6 +53,18 @@ Design (MXU/VMEM-first):
   block of it fetched with every tile; the grids stay the causal
   mask's, since no table made from shapes knows which tiles such a
   mask empties. A call without it traces what it always did.
+- Two ways a head's blocks lie (``addressing``), chosen from the call's
+  layout and widths. Head-major, ``[batch x heads, seq, width]``: what
+  ``layout="bhsd"`` operands are, and what XLA makes of a projection's
+  output at no cost where the width fills lane tiles. Sequence-minor,
+  ``[batch x heads, width, seq]``: how XLA holds a 64-wide q, k, v,
+  output and gradients, which head-major kernels had it copy in and out
+  (eight copies a layer, into arrays half padding); a ``layout="bshd"``
+  call at that width reads and writes them where they lie. A tile of
+  scores is (keys, queries) either way: the forward's two products
+  change their dimension numbers (its accumulator was ``[dv, block_q]``
+  already), the backward turns its blocks into scratch as they arrive
+  and its gradients back as they leave, and runs the body it had.
 - Returns (out, lse); lse makes partial results mergeable (ring attention)
   and feeds the backward pass.
 - Custom VJP with one backward kernel. Two Mosaic calls a layer:
@@ -150,6 +162,68 @@ def _pad_to(x, multiple, axis):
 
 def _lane_tiles(d):
     return -(-d // _LANE)
+
+
+# ---------------------------------------------------------------------------
+# Which way a head's blocks lie
+# ---------------------------------------------------------------------------
+
+# How the kernels of a call address a head (``addressing``). Either way
+# a grid row is one (batch, head) and a tile of scores is (keys,
+# queries); what differs is which axis of q's, k's, v's, o's and the
+# gradients' blocks is the head's width.
+# ``HEAD_MAJOR``: ``[batch x heads, seq, width]``, positions along
+# sublanes and the width along lanes. Where the width fills whole lane
+# tiles that is how XLA holds q, k, v around the kernels anyway (a
+# product writes ``[batch, heads, seq, width]`` as readily as any other
+# order), and nothing is copied.
+# ``SEQ_MINOR``: ``[batch x heads, width, seq]``, the width along
+# sublanes and the positions along lanes. A 64-wide array XLA never
+# holds width-minor, where every (8, 128) tile would be half padding:
+# the projections, rope and the output product read and write
+# ``[batch, heads, width, seq]``, and a kernel that wants the head-major
+# form has XLA copy q, k, v in, the output out, and the four back again
+# in the gradient, into arrays that are half padding. Read this way the
+# blocks are whole tiles and nothing is copied; the 64-wide blocks are
+# turned inside the kernels, where that is a few vector registers a
+# tile and not a pass over HBM.
+HEAD_MAJOR, SEQ_MINOR = "head_major", "seq_minor"
+_SEQ_MINOR_WIDTH = 64
+
+
+def addressing(head_dim, v_dim=None, layout="bshd", dropout=False):
+    """How the kernels of a call address a head, ``"seq_minor"`` or
+    ``"head_major"`` (above): a function of the call's layout and widths
+    alone. ``"bhsd"`` operands are head-major as they come. Of ``"bshd"``
+    ones, q and k 64 wide and v a multiple of that (64, or differential
+    attention's 128) are read sequence-minor: the widths measured on
+    the chip (PERF.md section 6, PR 47). Every other call is
+    head-major, and so is one with dropout, whose mask is laid out by
+    query rows."""
+    v_dim = head_dim if v_dim is None else v_dim
+    if (layout == "bshd" and not dropout and head_dim == _SEQ_MINOR_WIDTH
+            and v_dim % _SEQ_MINOR_WIDTH == 0):
+        return SEQ_MINOR
+    return HEAD_MAJOR
+
+
+def _laid(seq, width, seq_minor):
+    """``(seq, width)`` as a head's block or array has them, the width
+    first where the call is sequence-minor; its own inverse."""
+    return (width, seq) if seq_minor else (seq, width)
+
+
+def _block(ref, positions, seq_minor):
+    """``positions`` of the block a q, k or v ref of the forward holds:
+    ``[positions, width]``, or with ``seq_minor`` ``[width,
+    positions]``."""
+    return ref[(0, *_laid(positions, slice(None), seq_minor))]
+
+
+def _over(lhs, rhs):
+    """``dot_general`` dimension numbers contracting axis ``lhs`` of the
+    left operand with axis ``rhs`` of the right one."""
+    return (((lhs,), (rhs,)), ((), ()))
 
 
 # ---------------------------------------------------------------------------
@@ -538,8 +612,10 @@ def _step_table(backward, where, n_q, n_k, block_q, block_k, causal,
 
 def _fwd_kernel(lens_ref, steps_ref, q_ref, k_ref, v_ref, *rest, sm_scale,
                 causal, block_q, block_k, sub, one_tile, dropout_rate=0.0,
-                seeded=False, window=None, has_mask=False):
+                seeded=False, window=None, has_mask=False, seq_minor=False):
     # rest = [dm_ref?], [mask_ref?], o_ref, lse_ref, m_scr, l_scr, acc_scr
+    # The axis of a q, k or v block that is the head's width (SEQ_MINOR).
+    w = 0 if seq_minor else 1
     dm_ref = mask_ref = None
     if dropout_rate > 0.0 and not seeded:
         dm_ref, *rest = rest
@@ -578,11 +654,11 @@ def _fwd_kernel(lens_ref, steps_ref, q_ref, k_ref, v_ref, *rest, sm_scale,
         lanes."""
         rows = pl.ds(row0, n_rows)
         keys = slice(key0, key0 + width)
-        q = q_ref[0, rows, :]
+        q = _block(q_ref, rows, seq_minor)
         if fold:
             q = (q.astype(jnp.float32) * sm_scale).astype(q.dtype)
         st = jax.lax.dot_general(
-            k_ref[0, keys, :], q, (((1,), (1,)), ((), ())),
+            _block(k_ref, keys, seq_minor), q, _over(w, w),
             preferred_element_type=jnp.float32)      # (width, n_rows)
         if not fold:
             st = st * sm_scale
@@ -623,8 +699,8 @@ def _fwd_kernel(lens_ref, steps_ref, q_ref, k_ref, v_ref, *rest, sm_scale,
         elif dm_ref is not None:
             pvt = pt * _keep_scale(dm_ref[0, rows, keys], dropout_rate).T
         acc_scr[:, rows] = acc_scr[:, rows] * alpha + jax.lax.dot_general(
-            v_ref[0, keys, :], pvt.astype(v_ref.dtype),
-            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            _block(v_ref, keys, seq_minor), pvt.astype(v_ref.dtype),
+            _over(1 - w, 0), preferred_element_type=jnp.float32)
         m_scr[:, rows] = jnp.broadcast_to(m_new, (m_scr.shape[0], n_rows))
         l_scr[:, rows] = jnp.broadcast_to(l_new, (l_scr.shape[0], n_rows))
 
@@ -668,7 +744,8 @@ def _fwd_kernel(lens_ref, steps_ref, q_ref, k_ref, v_ref, *rest, sm_scale,
     def _():
         l = l_scr[:1, :]                           # (1, block_q)
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / safe_l).T.astype(o_ref.dtype)
+        out = acc_scr[:] / safe_l                  # (dv, block_q)
+        o_ref[0] = (out if seq_minor else out.T).astype(o_ref.dtype)
         # lse is laid out (bh, 1, sq): TPU requires the last two block dims
         # to divide (8, 128) or equal the array dims — (1, 1, block_q) does.
         lse_ref[0] = jnp.where(l == 0.0, _NEG_INF,
@@ -677,7 +754,7 @@ def _fwd_kernel(lens_ref, steps_ref, q_ref, k_ref, v_ref, *rest, sm_scale,
 
 def _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
               dm=None, dropout_rate=0.0, seeded=False, window=None,
-              where=None, mask=None):
+              where=None, mask=None, seq_minor=False):
     """One forward kernel call. The layers of a model make the same
     call, so it goes through ``jax.jit``: the kernel is traced and
     lowered once a program, not once a layer, and XLA inlines the calls
@@ -693,16 +770,28 @@ def _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
     than ``q`` and ``k``. ``mask`` (``_mask_tile``) is one more operand
     of the same grid: the table is made from shapes and offsets, which
     a mask of data does not change, so every tile under the diagonal
-    runs."""
+    runs.
+
+    ``seq_minor``: 0, or the batch size of a call whose operands are
+    ``[batch x heads, width, seq]`` (SEQ_MINOR), not ``[batch x heads,
+    seq, width]``. Its output is ``[batch, heads, width, seq]``, a
+    transpose of what the kernel writes (_o_row) that XLA makes a
+    layout and not a copy: the transpose is on this side of the
+    ``custom_vjp``, so the output's cotangent need not lie as the
+    output does. The log-sum-exp is ``[batch x heads, seq]`` either
+    way."""
+    seq, width = _laid(1, 2, seq_minor)      # the operands' axes
     steps = _step_table(False, lens if where is None else where,
-                        q.shape[1] // block_q, k.shape[1] // block_k,
+                        q.shape[seq] // block_q, k.shape[seq] // block_k,
                         block_q, block_k, causal, window)
     args = (q, k, v, lens, dm, steps, sm_scale, causal, block_q, block_k,
-            _sub_tile(causal, block_q, block_k, q.shape[2]), dropout_rate,
-            seeded, _interpret(), window)
-    if mask is None:
-        return _fwd_jit(*args)
-    return _fwd_masked_jit(mask, *args)
+            _sub_tile(causal, block_q, block_k, q.shape[width]),
+            dropout_rate, seeded, _interpret(), window, seq_minor)
+    o, lse = (_fwd_jit(*args) if mask is None
+              else _fwd_masked_jit(mask, *args))
+    if seq_minor:
+        o = o.reshape(-1, seq_minor, *o.shape[1:]).swapaxes(0, 1)
+    return o, lse
 
 
 def _kv_row(b, group):
@@ -733,40 +822,64 @@ def _mask_vmem_bytes(block_q, block_k):
     return 6 * block_q * block_k
 
 
-@functools.partial(jax.jit, static_argnums=tuple(range(6, 15)))
+@functools.partial(jax.jit, static_argnums=tuple(range(6, 16)))
 def _fwd_jit(q, k, v, lens, dm, steps, sm_scale, causal, block_q, block_k,
-             sub, dropout_rate, seeded, interpret, window):
+             sub, dropout_rate, seeded, interpret, window, seq_minor):
     return _fwd_pallas(None, q, k, v, lens, dm, steps, sm_scale, causal,
                        block_q, block_k, sub, dropout_rate, seeded, interpret,
-                       window)
+                       window, seq_minor)
 
 
-@functools.partial(jax.jit, static_argnums=tuple(range(7, 16)))
+@functools.partial(jax.jit, static_argnums=tuple(range(7, 17)))
 def _fwd_masked_jit(mask, *args):
     return _fwd_pallas(mask, *args)
 
 
+def _tile_spec(block, width, row, named, seq_minor):
+    """The ``BlockSpec`` of ``block`` positions by ``width`` of grid row
+    ``row(b)``'s head: the block of positions ``named(step, steps)``
+    names, laid out as the call's operands are (SEQ_MINOR)."""
+    return pl.BlockSpec(
+        (1, *_laid(block, width, seq_minor)),
+        lambda b, s, lens, steps: (
+            row(b), *_laid(named(s, steps), 0, seq_minor)))
+
+
+def _o_row(bh, seq_minor):
+    """For an index map: the row of the forward kernel's output that
+    grid row ``b`` of ``bh`` writes. Head-major, and of a batch of one:
+    its own. Sequence-minor (``seq_minor`` is the batch size) the
+    kernel writes ``[heads x batch, width, seq]``, the heads outermost,
+    which is how the output product reads it; the cotangent comes back
+    from that product batch outermost, as q, k and v are (_fwd_call)."""
+    if seq_minor <= 1:
+        return lambda b: b
+    heads = bh // seq_minor
+    return lambda b: (lax.rem(b, jnp.int32(heads)) * jnp.int32(seq_minor)
+                      + lax.div(b, jnp.int32(heads)))
+
+
 def _fwd_pallas(mask, q, k, v, lens, dm, steps, sm_scale, causal, block_q,
-                block_k, sub, dropout_rate, seeded, interpret, window):
-    bh, sq, d = q.shape
-    sk, dv = k.shape[1], v.shape[2]
+                block_k, sub, dropout_rate, seeded, interpret, window,
+                seq_minor):
+    bh, (sq, d), (sk, dv) = q.shape[0], *(
+        _laid(*x.shape[1:], seq_minor) for x in (q, v))
     group = bh // k.shape[0]
     one_tile = sq == block_q and sk == block_k
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, sub=sub, one_tile=one_tile,
         dropout_rate=dropout_rate, seeded=seeded, window=window,
-        has_mask=mask is not None)
+        has_mask=mask is not None, seq_minor=seq_minor)
     qb, kb = _named(_ROW, one_tile), _named(_FETCH, one_tile)
 
-    def kv_at(b, s, lens, steps):
-        return _kv_row(b, group), kb(s, steps), 0
+    def kv_row(b):
+        return _kv_row(b, group)
 
     in_specs = [
-        pl.BlockSpec((1, block_q, d),
-                     lambda b, s, lens, steps: (b, qb(s, steps), 0)),
-        pl.BlockSpec((1, block_k, d), kv_at),
-        pl.BlockSpec((1, block_k, dv), kv_at),
+        _tile_spec(block_q, d, lambda b: b, qb, seq_minor),
+        _tile_spec(block_k, d, kv_row, kb, seq_minor),
+        _tile_spec(block_k, dv, kv_row, kb, seq_minor),
     ]
     operands = [q, k, v]
     if dropout_rate > 0.0 and not seeded:
@@ -787,8 +900,7 @@ def _fwd_pallas(mask, q, k, v, lens, dm, steps, sm_scale, causal, block_q,
         grid=(bh, steps.shape[0] // 4),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, dv),
-                         lambda b, s, lens, steps: (b, qb(s, steps), 0)),
+            _tile_spec(block_q, dv, _o_row(bh, seq_minor), qb, seq_minor),
             pl.BlockSpec((1, 1, block_q),
                          lambda b, s, lens, steps: (b, 0, qb(s, steps))),
         ],
@@ -799,7 +911,7 @@ def _fwd_pallas(mask, q, k, v, lens, dm, steps, sm_scale, causal, block_q,
         ],
     )
     out_shapes = [
-        _struct((bh, sq, dv), q.dtype, q, k, v, lens),
+        _struct((bh, *_laid(sq, dv, seq_minor)), q.dtype, q, k, v, lens),
         _struct((bh, 1, sq), jnp.float32, q, k, v, lens),
     ]
     compiler_params = pltpu.CompilerParams(
@@ -822,15 +934,32 @@ def _fwd_pallas(mask, q, k, v, lens, dm, steps, sm_scale, causal, block_q,
 def _bwd_kernel(lens_ref, steps_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                 delta_ref, *rest, sm_scale, causal, block_q, block_k, qb0,
                 sub, one_tile, dropout_rate=0.0, seeded=False, window=None,
-                has_mask=False):
+                has_mask=False, seq_minor=False):
     # rest = [dm_ref?], [mask_ref?], dq_ref, dk_ref, dv_ref, dq_scr,
-    # dk_scr, dv_scr
+    # dk_scr, dv_scr, [q_scr, k_scr, v_scr, do_scr]
     dm_ref = mask_ref = None
     if dropout_rate > 0.0 and not seeded:
         dm_ref, *rest = rest
     if has_mask:
         mask_ref, *rest = rest
-    dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr = rest
+    dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *turned = rest
+    # Every product below takes its blocks as [positions, width]: p and
+    # ds, a tile's 1024 x 1024, then enter dv, dk and dq as the operand
+    # that streams, and the 64-wide blocks as the one that stays. A
+    # sequence-minor call (SEQ_MINOR) holds [width, positions]: its
+    # blocks are turned into scratch as they arrive, K and V once a key
+    # block, q and do once a tile, and dq, dk, dv turned back as they
+    # are written, so the body is the head-major one. (With the blocks
+    # as they lie and the products' dimension numbers changed instead,
+    # the tile is the operand that stays: 28% slower at seq 2048 and
+    # 55% at seq 512 in the step; PERF.md section 6, PR 47.)
+    if seq_minor:
+        q_of, k_of, v_of, do_of = (
+            lambda at, scr=scr: scr[at, :] for scr in turned)
+    else:
+        q_of, k_of, v_of, do_of = (
+            lambda at, ref=ref: ref[0, at, :]
+            for ref in (q_ref, k_ref, v_ref, do_ref))
     # This grid step's tile, whether it is its key block's first and
     # last, and its query block's in the whole call (_step_table).
     kb, qb, flags = _this_step(steps_ref, one_tile)
@@ -853,6 +982,9 @@ def _bwd_kernel(lens_ref, steps_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     def _():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
+        if seq_minor:
+            turned[1][:] = k_ref[0].T
+            turned[2][:] = v_ref[0].T
 
     @pl.when((flags & _DQ_FIRST) != 0)
     def _():
@@ -877,10 +1009,10 @@ def _bwd_kernel(lens_ref, steps_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         operand's rows."""
         rows = slice(row0, row0 + n_rows)
         keys = slice(key0, key0 + width)
-        q = q_ref[0, rows, :]             # (n_rows, d)
-        do = do_ref[0, rows, :]
-        k = k_ref[0, keys, :]             # (width, d)
-        v = v_ref[0, keys, :]
+        q = q_of(rows)                    # (n_rows, d)
+        do = do_of(rows)
+        k = k_of(keys)                    # (width, d)
+        v = v_of(keys)
         lse = lse_ref[0, :, rows]         # (1, n_rows)
         delta = delta_ref[0, :, rows]
         if fold:
@@ -941,6 +1073,12 @@ def _bwd_kernel(lens_ref, steps_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     visible = jnp.logical_not(skip)
 
+    if seq_minor:
+        @pl.when(visible)
+        def _():
+            turned[0][:] = q_ref[0].T
+            turned[3][:] = do_ref[0].T
+
     @pl.when(jnp.logical_and(visible, interior))
     def _():
         tile_update(draw(), 0, block_q, 0, block_k, 0, 0)
@@ -972,15 +1110,18 @@ def _bwd_kernel(lens_ref, steps_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     @pl.when((flags & _ROW_LAST) != 0)
     def _():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+        for ref, scr in ((dk_ref, dk_scr), (dv_ref, dv_scr)):
+            ref[0] = (scr[:].T if seq_minor else scr[:]).astype(ref.dtype)
 
     @pl.when((flags & _DQ_LAST) != 0)
     def _():
         at = dq_rows(0, block_q)
         dq = dq_scr[at, :]
-        dq_ref[0, at, :] = (dq * sm_scale if fold else dq).astype(
-            dq_ref.dtype)
+        dq = (dq * sm_scale if fold else dq).astype(dq_ref.dtype)
+        if seq_minor:
+            dq_ref[0, :, at] = dq.T
+        else:
+            dq_ref[0, at, :] = dq
 
 
 # Scoped VMEM of the backward kernel. Its tiles and their temporaries get
@@ -997,14 +1138,20 @@ _TILE_VMEM_BYTES = 16 * 2 ** 20
 _DQ_RESIDENT_BYTES = 32 * 2 ** 20
 
 
-def _dq_resident_bytes(rows, d, dtype):
-    return rows * _lane_tiles(d) * _LANE * (4 + 2 * jnp.dtype(dtype).itemsize)
+def _dq_resident_bytes(rows, d, dtype, seq_minor=False):
+    """Of dq's float32 accumulator and its double-buffered output block
+    over ``rows`` queries. A width along lanes is padded to whole lane
+    tiles; the output block of a sequence-minor call has it along
+    sublanes, and is not."""
+    padded = _lane_tiles(d) * _LANE
+    return rows * (4 * padded + 2 * jnp.dtype(dtype).itemsize * (
+        d if seq_minor else padded))
 
 
 @jax.named_scope(SCOPE)
 def _bwd_call(q, k, v, o, do, lse, lens, sm_scale, causal, block_q, block_k,
               g_lse=None, dm=None, dropout_rate=0.0, seeded=False,
-              window=None, where=None, mask=None):
+              window=None, where=None, mask=None, seq_minor=False):
     """dq, dk and dv from one kernel: one pass over the (key, query)
     tiles computes s, exp, dp and ds once and feeds all three gradients.
     Grid (batch*heads, steps), a step a tile (_step_table: the tiles
@@ -1017,11 +1164,16 @@ def _bwd_call(q, k, v, o, do, lse, lens, sm_scale, causal, block_q, block_k,
     ``v`` hold fewer heads than ``q``, the kernel writes every query
     head's share of dk and dv in float32 and the group's are summed
     here. ``mask`` as in the forward (``_mask_tile``): a chunk takes
-    its own queries' columns of it."""
-    bh, sq, d = q.shape
+    its own queries' columns of it. ``seq_minor`` as in ``_fwd_call``:
+    the three gradients are laid out as the operands are; ``o`` and
+    ``do`` are ``[batch, heads, width, seq]``."""
+    seq, width = _laid(1, 2, seq_minor)      # the operands' axes
+    bh, sq, d = q.shape[0], q.shape[seq], q.shape[width]
     group = bh // k.shape[0]
+    if seq_minor:
+        o, do = (x.reshape(bh, *x.shape[2:]) for x in (o, do))
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)                        # (bh, sq)
+                    axis=width)                     # (bh, sq)
     if g_lse is not None:
         # dlse_i/ds_ij = p_ij, so the lse cotangent enters the shared
         # ds = p*(dp - delta')*scale term as delta' = delta - g_lse.
@@ -1033,7 +1185,7 @@ def _bwd_call(q, k, v, o, do, lse, lens, sm_scale, causal, block_q, block_k,
     # the same kernel in chunks and dk, dv are summed in float32.
     n_q = sq // block_q
     per_chunk = max(1, _DQ_RESIDENT_BYTES
-                    // _dq_resident_bytes(block_q, d, q.dtype))
+                    // _dq_resident_bytes(block_q, d, q.dtype, seq_minor))
     # What the trace reads of the module's state is an argument, as in
     # _fwd_call.
     def chunk(qb0, n_q, *rows, **kv_dtype):
@@ -1043,37 +1195,43 @@ def _bwd_call(q, k, v, o, do, lse, lens, sm_scale, causal, block_q, block_k,
         return _bwd_chunk(
             *rows, k=k, v=v, lens=lens, qb0=qb0, steps=_step_table(
                 True, lens if where is None else where, n_q,
-                k.shape[1] // block_k, block_q, block_k, causal, window, qb0),
+                k.shape[seq] // block_k, block_q, block_k, causal, window,
+                qb0),
             sm_scale=sm_scale, causal=causal, block_q=block_q,
             block_k=block_k,
             sub=_sub_tile(causal, block_q, block_k, d, backward=True),
             dropout_rate=dropout_rate, seeded=seeded,
-            interpret=_interpret(), window=window, **kv_dtype)
+            interpret=_interpret(), window=window, seq_minor=seq_minor,
+            **kv_dtype)
 
     if n_q <= per_chunk and group == 1:
         return chunk(0, n_q, q, do, lse3, delta3, dm)
     dqs, dk, dv = [], 0.0, 0.0
     for qb0 in range(0, n_q, per_chunk):
         rows = slice(qb0 * block_q, (qb0 + per_chunk) * block_q)
+        tile = (slice(None), slice(None), rows) if seq_minor else (
+            slice(None), rows)
         dq_c, dk_c, dv_c = chunk(
             qb0, min(per_chunk, n_q - qb0),
-            q[:, rows], do[:, rows], lse3[:, :, rows], delta3[:, :, rows],
+            q[tile], do[tile], lse3[:, :, rows], delta3[:, :, rows],
             None if dm is None else dm[:, rows], kv_dtype=jnp.float32)
         dqs.append(dq_c)
         dk, dv = dk + dk_c, dv + dv_c
     if group > 1:
         dk, dv = (x.reshape(-1, group, *x.shape[1:]).sum(axis=1)
                   for x in (dk, dv))
-    return (jnp.concatenate(dqs, axis=1), dk.astype(k.dtype),
+    return (jnp.concatenate(dqs, axis=seq), dk.astype(k.dtype),
             dv.astype(v.dtype))
 
 
 @functools.partial(jax.jit, static_argnames=(
     "qb0", "sm_scale", "causal", "block_q", "block_k", "sub",
-    "dropout_rate", "seeded", "interpret", "kv_dtype", "window"))
+    "dropout_rate", "seeded", "interpret", "kv_dtype", "window",
+    "seq_minor"))
 def _bwd_chunk(q, do, lse3, delta3, dm, *, k, v, lens, steps, qb0,
                sm_scale, causal, block_q, block_k, sub, dropout_rate, seeded,
-               interpret, kv_dtype=None, window=None, mask=None):
+               interpret, kv_dtype=None, window=None, mask=None,
+               seq_minor=False):
     """The backward kernel over the query rows it is given: tiles ``qb0``
     onward of the sequence, in the order ``steps`` gives (_step_table,
     of these rows' query blocks). dk and dv are this chunk's share, a row to
@@ -1081,21 +1239,17 @@ def _bwd_chunk(q, do, lse3, delta3, dm, *, k, v, lens, steps, qb0,
     ``kv_dtype`` (k's and v's own unless the caller sums shares). The
     layers of a model make the same call, so it goes through ``jax.jit``
     like the forward's: traced and lowered once a program."""
-    bh, sq, d = q.shape
-    sk, dv = k.shape[1], v.shape[2]
+    bh, (sq, d), (sk, dv) = q.shape[0], *(
+        _laid(*x.shape[1:], seq_minor) for x in (q, v))
     group = bh // k.shape[0]
     one_tile = sq == block_q and sk == block_k
     qi, kb = _named(_FETCH, one_tile), _named(_ROW, one_tile)
 
     def q_tile(width):
-        return pl.BlockSpec(
-            (1, block_q, width),
-            lambda b, s, lens, steps: (b, qi(s, steps), 0))
+        return _tile_spec(block_q, width, lambda b: b, qi, seq_minor)
 
     def k_tile(width, row=lambda b: b):
-        return pl.BlockSpec(
-            (1, block_k, width),
-            lambda b, s, lens, steps: (row(b), kb(s, steps), 0))
+        return _tile_spec(block_k, width, row, kb, seq_minor)
 
     def kv_row(b):
         return _kv_row(b, group)
@@ -1118,26 +1272,29 @@ def _bwd_chunk(q, do, lse3, delta3, dm, *, k, v, lens, steps, qb0,
         grid=(bh, steps.shape[0] // 4),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, sq, d), lambda b, s, lens, steps: (b, 0, 0)),
+            _tile_spec(sq, d, lambda b: b, lambda s, steps: 0, seq_minor),
             k_tile(d), k_tile(dv),
         ],
         scratch_shapes=[
             pltpu.VMEM((sq, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, dv), jnp.float32),
-        ],
+        ] + ([pltpu.VMEM(shape, q.dtype) for shape in (
+            (block_q, d), (block_k, d), (block_k, dv), (block_q, dv))]
+             if seq_minor else []),
     )
     dq, dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
             block_k=block_k, qb0=qb0, sub=sub, one_tile=one_tile,
             dropout_rate=dropout_rate, seeded=seeded, window=window,
-            has_mask=mask is not None),
+            has_mask=mask is not None, seq_minor=seq_minor),
         grid_spec=grid_spec,
         out_shape=[
-            _struct((bh, sq, d), q.dtype, q, k, v, do, lens),
-            _struct((bh, sk, d), kv_dtype or k.dtype, q, k, v, do, lens),
-            _struct((bh, sk, dv), kv_dtype or v.dtype, q, k, v, do, lens),
+            _struct((bh, *_laid(*shape, seq_minor)), dtype, q, k, v, do, lens)
+            for shape, dtype in (((sq, d), q.dtype),
+                                 ((sk, d), kv_dtype or k.dtype),
+                                 ((sk, dv), kv_dtype or v.dtype))
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
@@ -1146,7 +1303,7 @@ def _bwd_chunk(q, do, lse3, delta3, dm, *, k, v, lens, steps, qb0,
             # as a head of twice the width does.
             vmem_limit_bytes=_TILE_VMEM_BYTES * _lane_tiles(d) * (
                 2 if dv > d or group > 1 else 1)
-            + _dq_resident_bytes(sq, d, q.dtype)
+            + _dq_resident_bytes(sq, d, q.dtype, seq_minor)
             + (_mask_vmem_bytes(block_q, block_k) if mask is not None
                else 0)),
         interpret=interpret,
@@ -1167,28 +1324,31 @@ SAVED_NAMES = ("hvd_flash_o", "hvd_flash_lse")
 
 # ``where`` is the call's (q_offset, k_offset, kv_len) as Python integers,
 # or None where any of them is traced: what the grids' steps are made from
-# (_step_table).
+# (_step_table). ``seq_minor``: how q, k, v, the output and the gradients
+# are laid out (_fwd_call): 0, or the batch size.
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, lens, sm_scale, causal, block_q, block_k, window=None,
-           where=None):
+           where=None, seq_minor=False):
     o, _ = _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
-                     window=window, where=where)
+                     window=window, where=where, seq_minor=seq_minor)
     return o
 
 
 def _flash_fwd(q, k, v, lens, sm_scale, causal, block_q, block_k, window,
-               where):
+               where, seq_minor):
     o, lse = map(ad_checkpoint.checkpoint_name, _fwd_call(
         q, k, v, lens, sm_scale, causal, block_q, block_k, window=window,
-        where=where), SAVED_NAMES)
+        where=where, seq_minor=seq_minor), SAVED_NAMES)
     return o, (q, k, v, o, lse, lens)
 
 
-def _flash_bwd(sm_scale, causal, block_q, block_k, window, where, res, g):
+def _flash_bwd(sm_scale, causal, block_q, block_k, window, where, seq_minor,
+               res, g):
     q, k, v, o, lse, lens = res
     dq, dk, dv = _bwd_call(q, k, v, o, g, lse, lens, sm_scale, causal,
-                           block_q, block_k, window=window, where=where)
+                           block_q, block_k, window=window, where=where,
+                           seq_minor=seq_minor)
     dlens = np.zeros((3,), jax.dtypes.float0)
     return dq, dk, dv, dlens
 
@@ -1196,25 +1356,27 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, window, where, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _flash_with_lse(q, k, v, lens, sm_scale, causal, block_q, block_k,
-                    where=None):
+                    where=None, seq_minor=False):
     return _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
-                     where=where)
+                     where=where, seq_minor=seq_minor)
 
 
 def _flash_with_lse_fwd(q, k, v, lens, sm_scale, causal, block_q, block_k,
-                        where):
+                        where, seq_minor):
     o, lse = _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
-                       where=where)
+                       where=where, seq_minor=seq_minor)
     return (o, lse), (q, k, v, o, lse, lens)
 
 
-def _flash_with_lse_bwd(sm_scale, causal, block_q, block_k, where, res, g):
+def _flash_with_lse_bwd(sm_scale, causal, block_q, block_k, where, seq_minor,
+                        res, g):
     q, k, v, o, lse, lens = res
     go, g_lse = g
     dq, dk, dv = _bwd_call(q, k, v, o, go, lse, lens, sm_scale, causal,
-                           block_q, block_k, g_lse=g_lse, where=where)
+                           block_q, block_k, g_lse=g_lse, where=where,
+                           seq_minor=seq_minor)
     dlens = np.zeros((3,), jax.dtypes.float0)
     return dq, dk, dv, dlens
 
@@ -1222,28 +1384,29 @@ def _flash_with_lse_bwd(sm_scale, causal, block_q, block_k, where, res, g):
 _flash_with_lse.defvjp(_flash_with_lse_fwd, _flash_with_lse_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def _flash_masked(q, k, v, lens, mask, sm_scale, causal, block_q, block_k,
-                  where=None):
+                  where=None, seq_minor=False):
     """``(o, lse)`` under a mask of data (``_mask_tile``)."""
     return _fwd_call(q, k, v, lens, sm_scale, causal, block_q, block_k,
-                     where=where, mask=mask)
+                     where=where, mask=mask, seq_minor=seq_minor)
 
 
 def _flash_masked_fwd(q, k, v, lens, mask, sm_scale, causal, block_q,
-                      block_k, where):
+                      block_k, where, seq_minor):
     o, lse = map(ad_checkpoint.checkpoint_name, _fwd_call(
         q, k, v, lens, sm_scale, causal, block_q, block_k, where=where,
-        mask=mask), SAVED_NAMES)
+        mask=mask, seq_minor=seq_minor), SAVED_NAMES)
     return (o, lse), (q, k, v, o, lse, lens, mask)
 
 
-def _flash_masked_bwd(sm_scale, causal, block_q, block_k, where, res, g):
+def _flash_masked_bwd(sm_scale, causal, block_q, block_k, where, seq_minor,
+                      res, g):
     q, k, v, o, lse, lens, mask = res
     go, g_lse = g
     dq, dk, dv = _bwd_call(q, k, v, o, go, lse, lens, sm_scale, causal,
                            block_q, block_k, g_lse=g_lse, where=where,
-                           mask=mask)
+                           mask=mask, seq_minor=seq_minor)
     return (dq, dk, dv, np.zeros((3,), jax.dtypes.float0),
             np.zeros(mask.shape, jax.dtypes.float0))
 
@@ -1461,6 +1624,23 @@ def _publish_subtiles(sq, sk, block_q, block_k, causal, q_offset, k_offset,
             gauge.labels(kind=kind).set(float(n))
 
 
+def _publish_layout(kind):
+    """Count the call being traced in ``hvd_flash_layout{kind}``, by how
+    its kernels address a head (``addressing``); docs/metrics.md. A
+    no-op when ``HOROVOD_TPU_METRICS`` is off."""
+    from ..telemetry import core as telemetry
+    if not telemetry.enabled():
+        return
+    telemetry.counter(
+        "hvd_flash_layout",
+        "Flash calls traced, by how their kernels address a head: "
+        "head_major reads [batch x heads, seq, width] and seq_minor "
+        "[batch x heads, width, seq], which is how XLA holds a width "
+        "under a lane tile; a head_major call at such a width has XLA "
+        "copy q, k, v, the output and their gradients into padded "
+        "arrays and back", ("kind",)).labels(kind=kind).inc()
+
+
 def _prepare(q, k, v, block_q, block_k):
     """Reshape (B,H,S,D)→(BH,S,D), pad D to a lane tile (64 when D<=64,
     else 128) and S to block multiples; ``k`` and ``v`` by their own
@@ -1494,8 +1674,29 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None,
                     q_offset=0, k_offset=0, kv_len=None,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
                     with_lse=False, dropout_mask=None, dropout_rate=0.0,
-                    dropout_seed=None, window=None, mask=None):
-    """Flash attention over (batch, heads, seq, head_dim) tensors.
+                    dropout_seed=None, window=None, mask=None, layout="bhsd"):
+    """Flash attention over (batch, heads, seq, head_dim) tensors, or,
+    with ``layout="bshd"``, (batch, seq, heads, head_dim) ones.
+
+    ``layout`` says how ``q``, ``k``, ``v`` are laid out, and the output
+    and the three gradients with them; the log-sum-exp is (batch, heads,
+    seq) either way. It describes data and changes no result. What each
+    costs a caller (``addressing``): the kernels of a ``"bhsd"`` call
+    read a head as ``[seq, width]``, which is that layout as it stands.
+    A caller that holds what a projection writes, ``[batch, seq, heads,
+    width]``, and makes ``"bhsd"`` of it pays nothing where the width
+    fills whole lane tiles (128, 256): XLA has the projections write
+    ``[batch, heads, seq, width]`` to begin with. At a width under a
+    lane tile it pays eight copies a layer: XLA holds such arrays
+    ``[batch, heads, width, seq]``, where no tile is half padding, and
+    copies q, k and v in, the output out, and the four back again in
+    the gradient, each array read and written once more and every
+    kernel-side array padded to twice its bytes (8 x 24 layers x 33.5
+    MB at ``lm365m-seq8192``: 23.6 ms of a 541 ms step). A ``"bshd"``
+    call at such a width has the kernels read and write ``[batch x
+    heads, width, seq]``, which is XLA's own arrangement, so nothing is
+    copied and nothing padded; at any other width, and with dropout,
+    it is the ``"bhsd"`` call of the transposed operands.
 
     ``k`` and ``v`` may hold fewer heads than ``q``, a whole number of
     query heads to each (grouped K/V heads: query head ``h`` reads head
@@ -1548,6 +1749,16 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None,
         mode (pltpu prng has no CPU lowering) — callers on CPU use
         dropout_mask instead.
     """
+    if layout not in ("bhsd", "bshd"):
+        raise ValueError(f"flash_attention: layout {layout!r} is neither "
+                         f"'bhsd' nor 'bshd'")
+    held = None
+    if layout == "bshd":
+        # From here on q, k, v are the head-major views, whose shapes
+        # the checks below read; a call that is read sequence-minor
+        # (``addressing``) runs on the arrays as they are held.
+        held = (q, k, v)
+        q, k, v = (x.swapaxes(1, 2) for x in held)
     orig_dtype = q.dtype
     b, h, sq, d = q.shape
     dv = v.shape[3]
@@ -1587,51 +1798,82 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None,
             "flash_attention: dropout_seed needs the on-chip prng "
             "(pltpu) — unavailable in interpret mode; pass an explicit "
             "dropout_mask on CPU")
+    def as_held(out):
+        """A head-major output, as the caller's operands are laid out."""
+        if held is None:
+            return out
+        if with_lse:
+            return out[0].swapaxes(1, 2), out[1]
+        return out.swapaxes(1, 2)
+
     if _interpret() and _varying(q, k, v, q_offset, k_offset):
         # Pallas's HLO interpreter cannot run with device-varying operands
         # inside shard_map (check_vma dynamic_slice limitation); on non-TPU
         # backends use the einsum oracle there. On TPU the compiled kernel
         # handles shard_map natively.
-        return reference_attention(
+        return as_held(reference_attention(
             q, k, v, causal=causal, sm_scale=sm_scale, q_offset=q_offset,
             k_offset=k_offset, kv_len=kv_len, with_lse=with_lse,
             dropout_mask=dropout_mask, dropout_rate=dropout_rate,
-            window=window, mask=mask)
-    qp, kp, vp, dims, bq, bk = _prepare(q, k, v, block_q, block_k)
-    _publish_subtiles(sq, k.shape[2], bq, bk, bool(causal), q_offset,
-                    k_offset, kv_len, qp.shape[2], window)
+            window=window, mask=mask))
+    kind = addressing(d, dv, layout, has_dropout)
+    _publish_layout(kind)
     # Known while tracing (every call of a model): the grids run the
     # tiles that do something and no other (_step_table).
     where = _static(q_offset, k_offset, kv_len)
     lens = jnp.asarray([q_offset, k_offset, kv_len], jnp.int32)
+    if kind == SEQ_MINOR:
+        # [batch, seq, heads, width] as [batch x heads, width, seq]: the
+        # order XLA holds such arrays in, so the transposes move
+        # nothing. The positions are padded to whole blocks.
+        bq, bk = _clamp_blocks(sq, k.shape[2], block_q, block_k)
+        qp, kp, vp = (
+            _pad_to(x.transpose(0, 2, 3, 1).reshape(-1, x.shape[3],
+                                                    x.shape[1]), block, 2)
+            for x, block in zip(held, (bq, bk, bk)))
+        _publish_subtiles(sq, k.shape[2], bq, bk, bool(causal), q_offset,
+                          k_offset, kv_len, d, window)
+        call = (float(sm_scale), bool(causal), bq, bk)
+        if mask is not None:
+            maskp = _pad_to(_pad_to(mask.astype(jnp.int8), bk, 1), bq, 2)
+            o, lse = _flash_masked(qp, kp, vp, lens, maskp, *call, where, b)
+        elif with_lse:
+            o, lse = _flash_with_lse(qp, kp, vp, lens, *call, where, b)
+        else:
+            o = _flash(qp, kp, vp, lens, *call, window, where, b)
+        o = o[..., :sq].transpose(0, 3, 1, 2).astype(orig_dtype)
+        return (o, lse[:, :sq].reshape(b, h, sq)) if with_lse else o
+    qp, kp, vp, dims, bq, bk = _prepare(q, k, v, block_q, block_k)
+    _publish_subtiles(sq, k.shape[2], bq, bk, bool(causal), q_offset,
+                    k_offset, kv_len, qp.shape[2], window)
     if mask is not None:
         maskp = _pad_to(_pad_to(mask.astype(jnp.int8), bk, 1), bq, 2)
         o, lse = _flash_masked(qp, kp, vp, lens, maskp, float(sm_scale),
                                bool(causal), bq, bk, where)
         o = o[:, :sq, :dv].reshape(b, h, sq, dv).astype(orig_dtype)
-        return (o, lse[:, :sq].reshape(b, h, sq)) if with_lse else o
+        return as_held((o, lse[:, :sq].reshape(b, h, sq)) if with_lse else o)
     if has_dropout and dropout_seed is not None:
         lens4 = jnp.concatenate(
             [lens, jnp.asarray(dropout_seed, jnp.int32).reshape(1)])
         o = _flash_seeded(qp, kp, vp, lens4, float(sm_scale),
                           bool(causal), bq, bk, float(dropout_rate), where)
-        return o[:, :sq, :d].reshape(b, h, sq, d).astype(orig_dtype)
+        return as_held(o[:, :sq, :d].reshape(b, h, sq, d).astype(orig_dtype))
     if has_dropout:
         # bf16 carries 0/1 exactly at half the HBM traffic of fp32.
         dm = dropout_mask.astype(jnp.bfloat16).reshape(b * h, sq, -1)
         dm = _pad_to(_pad_to(dm, bk, 2), bq, 1)
         o = _flash_dropout(qp, kp, vp, lens, dm, float(sm_scale),
                            bool(causal), bq, bk, float(dropout_rate), where)
-        return o[:, :sq, :d].reshape(b, h, sq, d).astype(orig_dtype)
+        return as_held(o[:, :sq, :d].reshape(b, h, sq, d).astype(orig_dtype))
     if with_lse:
         o, lse = _flash_with_lse(qp, kp, vp, lens, float(sm_scale),
                                  bool(causal), bq, bk, where)
         o = o[:, :sq, :d].reshape(b, h, sq, d).astype(orig_dtype)
         lse = lse[:, :sq].reshape(b, h, sq)
-        return o, lse
+        return as_held((o, lse))
     o = _flash(qp, kp, vp, lens, float(sm_scale), bool(causal), bq, bk,
                window, where)
-    return o[:, :sq, :dv].reshape(b, h, sq, dv).astype(orig_dtype)
+    return as_held(o[:, :sq, :dv].reshape(b, h, sq, dv).astype(orig_dtype))
 
 
 def reference_attention(q, k, v, *, causal=False, sm_scale=None,

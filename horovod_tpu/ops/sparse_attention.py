@@ -538,18 +538,20 @@ def sparse_attention(q, k, v, q_i, k_i, w, topk, *, with_align=True):
         with jax.named_scope(SCOPE_SELECT):
             selected = jnp.sum(mask_t, dtype=jnp.float32) / (
                 q.shape[0] * q.shape[1])
-        q, k = q.swapaxes(1, 2), k.swapaxes(1, 2)
         with jax.named_scope(SCOPE_ATTEND):
             out, lse = fa.flash_attention(
-                q, k, v.swapaxes(1, 2), causal=True, sm_scale=sm_scale,
+                q, k, v, causal=True, sm_scale=sm_scale,
                 block_q=FLASH_BLOCK, block_k=FLASH_BLOCK, mask=mask_t,
-                with_lse=True)
+                with_lse=True, layout="bshd")
         align = None
         if with_align:
             with jax.named_scope(SCOPE_ALIGN):
-                qs, ks, lses = map(lax.stop_gradient, (q, k, lse))
+                # The alignment kernel's rows are heads: its copies of q
+                # and k are the pass's own, and carry no gradient.
+                qs, ks, lses = map(lax.stop_gradient, (
+                    q.swapaxes(1, 2), k.swapaxes(1, 2), lse))
                 align = sum(align_loss(qs[b], ks[b], lses[b], mask_t[b],
                                        q_i[b], k_i[b], w[b], lse_i[b],
                                        sm_scale)
                             for b in rows) / q.shape[0]
-        return out.swapaxes(1, 2), align, lax.stop_gradient(selected)
+        return out, align, lax.stop_gradient(selected)

@@ -5,8 +5,12 @@ of 128 over 4 K/V heads at 16,384 positions, the full layer and the
 layer, each on a grid ``(batch x heads, live tiles)`` whose second axis
 is the step table's (``flash_attention._step_table``), and, with traced
 offsets (a ring step), on every tile. What the chip's compiler refuses
-it refuses here, at no chip time. Nothing runs, so this says nothing
-about results or times.
+it refuses here, at no chip time. And attention layers whole, with
+their projections and rope around the kernels (``layout="bshd"``): the
+compiled step holds the two Mosaic calls a layer and no copy of an
+array as large as q, which is what a kernel that reads a layout XLA
+does not hold costs. Nothing runs, so this says nothing about results
+or times.
 
 The topology is described inside a fixture, never at import, and the
 persistent compilation cache is off around the compiles, as in
@@ -131,3 +135,73 @@ def test_traced_offsets_compile_on_every_tile(one_chip, compiled_kernel):
                          q_offset=jnp.int32(0)) == {"run": 1024}
     assert grad.lower(x, x, x, at, at).compile().as_text().count(
         "tpu_custom_call") == 2
+
+
+# An attention layer of the cell: (TransformerConfig's fields, (batch,
+# seq)). At width 64 the kernels read [batch x heads, width, seq], which
+# is how XLA holds q, k and v there (``addressing``: "seq_minor"); at 128
+# the head-major form is XLA's own. Compiled from the parent's kernels,
+# which took [batch x heads, seq, width] at every width, the lm365m
+# layers held eight q-sized copies each.
+LAYERS = {
+    "lm365m-seq8192": (dict(hidden=1024, heads=16), (2, 8192)),
+    "lm365m-seq2048": (dict(hidden=1024, heads=16), (6, 2048)),
+    "smallthinker21b": (dict(hidden=2560, heads=28, head_dim=128,
+                             kv_heads=4, bias=False, norm="rmsnorm"),
+                        (1, 16384)),
+}
+
+
+def _q_sized_copies(text, size):
+    """The ``copy`` and ``transpose`` instructions of a compiled
+    module's text whose result has ``size`` elements or more, outside
+    fusions (inside one, a copy is how the fusion reads an operand, not
+    a pass over HBM)."""
+    import re
+    import numpy as np
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    found, inside = [], None
+    for line in text.split("\n"):
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            inside = head.group(1)
+        if inside in fused:
+            continue
+        made = re.search(
+            r"= \w+\[([\d,]+)\]\{[^ ]*\} (copy|transpose)\(", line)
+        if made and np.prod([int(n) for n in made.group(1).split(",")]) \
+                >= size:
+            found.append(line.strip()[:120])
+    return found
+
+
+@pytest.mark.parametrize("cell", sorted(LAYERS))
+def test_attention_layers_compile_without_a_copy_of_q(one_chip,
+                                                      compiled_kernel, cell):
+    from horovod_tpu.models import transformer
+    fields, (batch, seq) = LAYERS[cell]
+    cfg = transformer.TransformerConfig(
+        max_len=seq, attention_impl="flash", layers=1, **fields)
+    layer = transformer.Attention(cfg)
+    x = jax.ShapeDtypeStruct((batch, seq, cfg.hidden), jnp.bfloat16,
+                             sharding=one_chip)
+    params = jax.tree.map(
+        lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                          sharding=one_chip),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0),
+                       jnp.zeros((1, seq, cfg.hidden), jnp.bfloat16)))
+
+    def loss(params, x):
+        # Two layers: the first's output product and the second's
+        # projections are each other's neighbours, as in a stack.
+        for _ in range(2):
+            x = layer.apply(params, x).astype(x.dtype)
+        return jnp.sum(x.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    assert text.count("tpu_custom_call") == 4
+    assert _q_sized_copies(
+        text, batch * seq * cfg.heads * cfg.head_width) == []
+    assert fa.addressing(cfg.head_width) == (
+        fa.SEQ_MINOR if cfg.head_width == 64 else fa.HEAD_MAJOR)

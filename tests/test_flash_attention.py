@@ -1042,3 +1042,196 @@ def test_live_table_is_bit_equal_to_every_tile(monkeypatch, case):
             np.asarray(a, np.float32)).all()
         np.testing.assert_array_equal(np.asarray(a, np.float32),
                                       np.asarray(b, np.float32))
+
+
+# ---------------------------------------------------------------------------
+# layout="bshd": the operands as the projections leave them. At width 64
+# the kernels read and write [batch x heads, width, seq] (``addressing``:
+# "seq_minor"), which is how XLA holds such arrays; every other width is
+# the "bhsd" call of the transposed operands.
+# ---------------------------------------------------------------------------
+
+# (batch, seq, query heads, K/V heads, width, v's width, tile): width 64
+# with as many K/V heads as query heads, with four and with two query
+# heads to a K/V head, an odd head count, differential attention's wide
+# values; the widths that fill lane tiles and one under them that is not
+# 64, which are head-major; each at one tile a head and at several.
+BSHD_SHAPES = {
+    "w64_16over16": (1, 128, 16, 16, 64, 64),
+    "w64_32over8": (1, 128, 32, 8, 64, 64),
+    "w64_40over20": (1, 128, 40, 20, 64, 64),
+    "w64_3over3_batch2": (2, 192, 3, 3, 64, 64),
+    "w64_v128_4over2": (2, 128, 4, 2, 64, 128),
+    "w128_28over4": (1, 128, 28, 4, 128, 128),
+    "w128_32over4": (1, 128, 32, 4, 128, 128),
+    "w256_v128_2over2": (2, 128, 2, 2, 256, 128),
+    "w32_4over2": (2, 128, 4, 2, 32, 32),
+}
+BSHD_MASKS = {
+    "causal": dict(causal=True),
+    "window": dict(causal=True, window=70),
+    "mask_lse": dict(causal=True, with_lse=True),   # and mask=
+    "lse": dict(causal=True, with_lse=True),
+}
+
+
+def _bshd_operands(shape, dtype, masks):
+    b, s, h, g, d, dv = BSHD_SHAPES[shape]
+    q, k, v = (_rand((b, s, n, width), i, dtype) for i, (n, width)
+               in enumerate(((h, d), (g, d), (g, dv))))
+    kwargs = dict(BSHD_MASKS[masks])
+    if masks == "mask_lse":
+        keep = np.random.RandomState(3).rand(b, s, s) < 0.5
+        kwargs["mask"] = jnp.asarray(keep | np.eye(s, dtype=bool), jnp.int8)
+    return q, k, v, kwargs
+
+
+def _as_bhsd(attend):
+    """``attend`` on "bshd" operands through the "bhsd" call: the
+    transposes are the caller's."""
+    def call(q, k, v, **kwargs):
+        out = attend(q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),
+                     **kwargs)
+        if kwargs.get("with_lse"):
+            return out[0].swapaxes(1, 2), out[1]
+        return out.swapaxes(1, 2)
+    return call
+
+
+def _bshd_cases():
+    """Every shape under the causal mask; under the others the shapes of
+    few heads (the interpreter runs a grid row a head), and ``with_lse``
+    without a mask where the call allows it: as many K/V heads as query
+    heads, and values as wide as the keys."""
+    for shape, (b, s, h, g, d, dv) in sorted(BSHD_SHAPES.items()):
+        for masks in sorted(BSHD_MASKS):
+            if masks != "causal" and h > 16:
+                continue
+            if masks == "lse" and (g != h or dv != d):
+                continue
+            yield shape, masks
+
+
+@pytest.mark.parametrize("tile", [128, 64], ids=["one_tile", "tiles"])
+@pytest.mark.parametrize("shape,masks", list(_bshd_cases()))
+def test_bshd_layout_matches_reference_and_the_bhsd_call(shape, masks, tile):
+    b, s, h, g, d, dv = BSHD_SHAPES[shape]
+    q, k, v, kwargs = _bshd_operands(shape, jnp.float32, masks)
+    blocks = dict(block_q=tile, block_k=tile)
+    held = functools.partial(flash_attention, layout="bshd", **blocks)
+    outs = jax.tree.leaves(held(q, k, v, **kwargs))
+    want = jax.tree.leaves(_as_bhsd(reference_attention)(q, k, v, **kwargs))
+    same = jax.tree.leaves(_as_bhsd(functools.partial(
+        flash_attention, **blocks))(q, k, v, **kwargs))
+    assert outs[0].shape == (b, s, h, dv)
+    for out, ref, bhsd in zip(outs, want, same):
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+        # The same sums in the same order, whichever way the blocks lie:
+        # in float32 the interpreter's products agree bit for bit.
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(bhsd))
+    grads = _grads(held, q, k, v, **kwargs)
+    for got, ref, bhsd in zip(
+            grads, _grads(_as_bhsd(reference_attention), q, k, v, **kwargs),
+            _grads(_as_bhsd(functools.partial(flash_attention, **blocks)),
+                   q, k, v, **kwargs)):
+        assert got.shape == ref.shape
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=5e-4, rtol=5e-4)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(bhsd))
+
+
+@pytest.mark.parametrize("shape", ["w64_16over16", "w64_v128_4over2",
+                                   "w128_28over4"])
+def test_bshd_layout_in_bfloat16(shape):
+    """bfloat16 operands, as the cells run: the interpreter's products of
+    bfloat16 do not round alike for every order of the dimensions, so the
+    two layouts agree to a unit in the last place of bfloat16."""
+    q, k, v, kwargs = _bshd_operands(shape, jnp.bfloat16, "window")
+    blocks = dict(block_q=64, block_k=64)
+    out = flash_attention(q, k, v, layout="bshd", **blocks, **kwargs)
+    ref = _as_bhsd(functools.partial(flash_attention, **blocks))(
+        q, k, v, **kwargs)
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               atol=2 ** -7, rtol=2 ** -7)
+    for got, want in zip(
+            _grads(functools.partial(flash_attention, layout="bshd",
+                                     **blocks), q, k, v, **kwargs),
+            _grads(_as_bhsd(functools.partial(flash_attention, **blocks)),
+                   q, k, v, **kwargs)):
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   atol=2 ** -5, rtol=2 ** -6)
+
+
+def test_bshd_layout_pads_the_positions_and_takes_traced_offsets():
+    q, k, v = (_rand((2, 100, 2, 64), i) for i in range(3))
+    out = flash_attention(q, k, v, causal=True, block_q=64, block_k=64,
+                          kv_len=90, layout="bshd")
+    ref = _as_bhsd(reference_attention)(q, k, v, causal=True, kv_len=90)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+    out = jax.jit(lambda o: flash_attention(
+        q, k, v, causal=True, block_q=64, block_k=64, q_offset=o,
+        layout="bshd"))(jnp.int32(32))
+    ref = _as_bhsd(reference_attention)(q, k, v, causal=True, q_offset=32)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_bshd_layout_with_dropout_is_the_head_major_call():
+    q, k, v = (_rand((1, 128, 2, 64), i) for i in range(3))
+    keep = jnp.asarray(np.random.RandomState(4).rand(1, 2, 128, 128) < 0.9)
+    kwargs = dict(causal=True, dropout_mask=keep, dropout_rate=0.1)
+    out = flash_attention(q, k, v, layout="bshd", **kwargs)
+    np.testing.assert_array_equal(
+        np.asarray(out),
+        np.asarray(_as_bhsd(flash_attention)(q, k, v, **kwargs)))
+
+
+def test_layout_is_one_of_two():
+    q = _rand((1, 2, 128, 64), 0)
+    with pytest.raises(ValueError, match="layout"):
+        flash_attention(q, q, q, layout="sbhd")
+
+
+@pytest.mark.parametrize("shape", sorted(BSHD_SHAPES))
+def test_addressing_is_chosen_from_the_widths(shape):
+    from horovod_tpu.ops.flash_attention import (
+        HEAD_MAJOR, SEQ_MINOR, addressing)
+    b, s, h, g, d, dv = BSHD_SHAPES[shape]
+    assert addressing(d, dv) == (SEQ_MINOR if d == 64 else HEAD_MAJOR)
+    assert addressing(d, dv, layout="bhsd") == HEAD_MAJOR
+    assert addressing(d, dv, dropout=True) == HEAD_MAJOR
+
+
+def test_layout_counter_says_how_each_traced_call_addressed_a_head(
+        monkeypatch):
+    from horovod_tpu import telemetry
+    monkeypatch.setenv("HOROVOD_TPU_METRICS", "1")
+    telemetry.reset()
+    try:
+        def count():
+            families = telemetry.registry().families()
+            return {s["labels"]["kind"]: s["value"]
+                    for s in families["hvd_flash_layout"].samples()}
+        for shape, calls in (("w64_16over16", {"seq_minor": 1.0}),
+                             ("w64_v128_4over2", {"seq_minor": 2.0}),
+                             ("w32_4over2", {"seq_minor": 2.0,
+                                             "head_major": 1.0}),
+                             ("w128_28over4", {"seq_minor": 2.0,
+                                               "head_major": 2.0})):
+            q, k, v, kwargs = _bshd_operands(shape, jnp.float32, "causal")
+            jax.jit(functools.partial(flash_attention, layout="bshd",
+                                      **kwargs)).lower(q, k, v)
+            assert count() == calls
+        # Head-major operands are head-major calls, whatever their width.
+        q = _rand((1, 2, 128, 64), 0)
+        jax.jit(functools.partial(flash_attention, causal=True)).lower(
+            q, q, q)
+        assert count() == {"seq_minor": 2.0, "head_major": 3.0}
+    finally:
+        monkeypatch.delenv("HOROVOD_TPU_METRICS", raising=False)
+        telemetry.reset()
