@@ -1,10 +1,12 @@
 """Token mixers of a hybrid decoder that are not attention: the Mamba
 layer and the gated memory unit that reads a Mamba layer's scan output
-further up the stack (SambaY, arXiv:2507.06607), and the gated short
-convolution of LFM2 (``ShortConv``). ``transformer.Block`` chooses them
-per layer (``TransformerConfig.mixers``), as it chooses the expert layer
-of ``parallel/moe.py``; Mamba's recurrence itself is the kernel pair of
-``ops/selective_scan.py``.
+further up the stack (SambaY, arXiv:2507.06607), the Mamba-2 layer
+(``Mamba2Mixer``), and the gated short convolution of LFM2
+(``ShortConv``). ``transformer.Block`` chooses them per layer
+(``TransformerConfig.mixers``), as it chooses the expert layer of
+``parallel/moe.py``; Mamba's recurrence itself is the kernel pair of
+``ops/selective_scan.py``, Mamba-2's the chunked products of
+``ops/ssd.py``.
 
 Mamba-1 (Gu & Dao, arXiv:2312.00752), on a normed input ``h``:
 
@@ -20,6 +22,27 @@ Mamba-1 (Gu & Dao, arXiv:2312.00752), on a normed input ``h``:
 ``cfg.dtype`` operands and accumulate in float32. The layer also returns
 ``y`` (with the ``D`` skip, before the gate): the memory that gated
 memory units read.
+
+Mamba-2 (Dao & Gu, arXiv:2405.21060, as Nemotron-H runs it,
+arXiv:2504.03624), on a normed input ``h``; ``heads`` heads of
+``head_dim`` (``d_inner = heads x head_dim``), ``groups`` groups of
+``B`` / ``C`` with ``N = d_state`` numbers each, head ``i`` reading
+group ``i // (heads / groups)``:
+
+    [z, xBC, dt] = W_in h            hidden -> d_inner + (d_inner + 2 groups N) + heads
+    xBC = silu(conv1d_causal(xBC) + b_c)     depthwise, d_conv taps
+    [u, B, C] = xBC
+    dt = softplus(dt + dt_bias)              a number a head a token
+    S_t = exp(dt_t A_i) S_{t-1} + (dt_t u_t) (x) B_t,   A_i = -exp(A_log_i)
+    y_t = S_t C_t + D_i u_t                  S: [head_dim, N] a head
+    g   = RMSNorm_groups(y silu(z))          over each d_inner / groups lanes
+    out = W_out g                            d_inner -> hidden, no bias
+
+``A`` is a scalar a head, so the state is a matrix a head and the
+recurrence a sum of matrix products over chunks (``ops/ssd.py``).
+``dt``, ``A``, the decays, the states and the norm's statistics are
+float32; the products take ``cfg.dtype`` operands and accumulate in
+float32. The layer hands nothing on.
 """
 
 import dataclasses
@@ -29,9 +52,11 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import selective_scan as scan_ops
+from ..ops import ssd as ssd_ops
 
 # Names in a device trace (docs/tracing.md); readers match the literals.
 SCOPE_SSM = scan_ops.SCOPE      # "hvd_ssm": the Mamba mixer
+SCOPE_SSD = ssd_ops.SCOPE        # "hvd_ssd": the Mamba-2 mixer
 SCOPE_GMU = "hvd_gmu"           # the gated memory unit
 # The gated short convolution, products and all; inside it ``mix``: the
 # two gates and the taps between the products.
@@ -42,11 +67,18 @@ SCOPE_SHORTCONV = "hvd_shortconv"
 class SSMConfig:
     """The sizes of a Mamba layer: ``d_inner`` channels, each with a
     state of ``d_state`` numbers, a depthwise convolution of ``d_conv``
-    taps before the scan, the step size made through ``dt_rank``."""
+    taps before the scan, the step size made through ``dt_rank``. A
+    Mamba-2 layer besides has ``heads`` heads of ``head_dim`` channels
+    (``d_inner`` in all) and ``groups`` groups of B and C; its step size
+    is a number a head from the input product, and ``dt_rank`` is not
+    read."""
     d_inner: int
     dt_rank: int
     d_state: int = 16
     d_conv: int = 4
+    heads: int = 0              # Mamba-2 alone, as the two below
+    head_dim: int = 0
+    groups: int = 1
 
 
 def causal_conv(x, kernel, bias=None):
@@ -94,6 +126,51 @@ class MambaMixer(nn.Module):
             gated = (y * nn.silu(z.astype(jnp.float32))).astype(cfg.dtype)
             out = nn.Dense(cfg.hidden, name="out_proj", **dense)(gated)
             return out, y.astype(cfg.dtype)
+
+
+class Mamba2Mixer(nn.Module):
+    """The layer's output; it hands nothing on."""
+    cfg: object                 # TransformerConfig (cfg.ssm set)
+
+    @nn.compact
+    def __call__(self, h):
+        cfg, m = self.cfg, self.cfg.ssm
+        if m.heads * m.head_dim != m.d_inner or m.heads % m.groups:
+            raise ValueError(
+                f"SSMConfig: {m.heads} heads of {m.head_dim} over "
+                f"{m.groups} groups for d_inner {m.d_inner}")
+        bc = m.groups * m.d_state
+        dense = dict(use_bias=False, dtype=cfg.dtype)
+        with jax.named_scope(SCOPE_SSD):
+            z, xbc, dt = jnp.split(
+                nn.Dense(2 * m.d_inner + 2 * bc + m.heads, name="in_proj",
+                         **dense)(h),
+                (m.d_inner, 2 * m.d_inner + 2 * bc), axis=-1)
+            conv_w = self.param("conv_kernel", nn.initializers.lecun_normal(),
+                                (m.d_conv, m.d_inner + 2 * bc))
+            conv_b = self.param("conv_bias", nn.initializers.zeros,
+                                (m.d_inner + 2 * bc,))
+            xbc = nn.silu(causal_conv(xbc, conv_w.astype(cfg.dtype),
+                                      conv_b.astype(cfg.dtype)))
+            u, b, c = jnp.split(xbc, (m.d_inner, m.d_inner + bc), axis=-1)
+            dt_bias = self.param("dt_bias", nn.initializers.zeros,
+                                 (m.heads,))
+            a_log = self.param("A_log", nn.initializers.zeros, (m.heads,))
+            skip = self.param("D", nn.initializers.ones, (m.heads,))
+            dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+            u = u.reshape(*u.shape[:2], m.heads, m.head_dim)
+            by_group = (*b.shape[:2], m.groups, m.d_state)
+            y = ssd_ops.ssd(u, dt, -jnp.exp(a_log.astype(jnp.float32)),
+                            b.reshape(by_group), c.reshape(by_group))
+            y = y.astype(jnp.float32) + skip[:, None] * u.astype(jnp.float32)
+            gated = y.reshape(z.shape) * nn.silu(z.astype(jnp.float32))
+            # One gain of d_inner, the statistics over each group's lanes.
+            gain = self.param("norm", nn.initializers.ones, (m.d_inner,))
+            lanes = gated.reshape(*gated.shape[:2], m.groups, -1)
+            normed = lanes * jax.lax.rsqrt(jnp.mean(
+                jnp.square(lanes), -1, keepdims=True) + cfg.norm_eps)
+            normed = (normed.reshape(gated.shape) * gain).astype(cfg.dtype)
+            return nn.Dense(cfg.hidden, name="out_proj", **dense)(normed)
 
 
 class ShortConv(nn.Module):

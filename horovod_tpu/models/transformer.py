@@ -16,14 +16,17 @@ a multi-token-prediction module (``mtp_layers``), and a looped stack:
 the blocks run ``passes`` times with the same weights, with norms on the
 sub-layers' outputs too (``sandwich_norm``) and an exit gate whose loss
 is ``looped_lm_loss``; or a stack with a mixer a layer (``mixers``: Mamba,
-gated memory units and gated short convolutions of ``models/ssm.py``,
+Mamba-2, gated memory units and gated short convolutions of
+``models/ssm.py``, no token mixer at all,
 windowed, full and cross differential attention with grouped K/V
 heads, and plain softmax attention, full or under a window, each with
 rope or without positions: ``PLAIN``; with a norm on every head's q
 and k where ``qk_norm``; and attention over the keys a learned indexer
 picks for every query, ``SPARSE``, with rope whose lanes take their
 angle from three position streams), in which a layer may
-read what an earlier layer made, and a head tied to the embedding.
+read what an earlier layer made, with an FFN a layer too (``ffns``:
+dense, the expert layer, or none, so that a block may be a mixer or an
+FFN alone), and a head tied to the embedding.
 Hidden sizes are multiples of 128 for MXU tiling; the head
 dimension is ``head_dim`` where the configuration states one (28 heads
 of 128 on a hidden size of 2560) and else ``hidden // heads``, 64 at
@@ -46,7 +49,8 @@ import numpy as np
 import optax
 
 from ..parallel.moe import MoEConfig, MoELayer
-from .ssm import GatedMemoryUnit, MambaMixer, ShortConv, SSMConfig
+from .ssm import (GatedMemoryUnit, Mamba2Mixer, MambaMixer, ShortConv,
+                  SSMConfig)
 
 # Names in a device trace (docs/tracing.md); readers match the literals.
 SCOPE_MLA = "hvd_mla"
@@ -101,8 +105,9 @@ class TransformerConfig:
     # flash kernel are made again). "flash": the block's input and what
     # the Mosaic kernels hand their backward passes: the flash kernel's
     # output and log-sum-exp, the selective scan's output and chunk
-    # states (everything but the kernels is made again). True/"full":
-    # the block's input alone.
+    # states, the Mamba-2 recurrence's output and chunk states
+    # (everything but those is made again). True/"full": the block's
+    # input alone.
     remat: object = False
     causal: bool = True
     use_rope: bool = True          # decoder LM; BERT uses learned positions
@@ -143,14 +148,23 @@ class TransformerConfig:
     # ``conv_taps`` taps (``ssm.ShortConv``), which hands nothing on; a
     # ``SPARSE`` layer is plain attention with rope over the keys its
     # indexer (``indexer``) selects for each query, and hands out its
-    # alignment loss through the collection ``DSA_STATE``.
+    # alignment loss through the collection ``DSA_STATE``; a "mamba2"
+    # layer is ``ssm.Mamba2Mixer`` (sizes in ``ssm``) and hands nothing
+    # on; a ``NO_MIXER`` layer has no token mixer and no ``ln1``: the
+    # block is its FFN under ``ln2`` and one residual add.
     mixers: Optional[tuple] = None
+    # The FFN of every layer, one of FFNS a layer: "dense" (``mlp``),
+    # "expert" (``moe``), or ``NO_FFN``: the block is its mixer under
+    # ``ln1`` and one residual add. None: the expert layer from
+    # ``moe.first_dense`` on where there is ``moe``, else dense. A layer
+    # with neither part is refused.
+    ffns: Optional[tuple] = None
     # The layers' indices in the published model where the stack is a
     # cut of it (differential attention's lambda_init depends on depth).
     layer_indices: Optional[tuple] = None
     window: Optional[int] = None     # keys a "window" / "sliding" layer sees
     kv_heads: Optional[int] = None   # K/V heads (None: as many as heads)
-    ssm: Optional[SSMConfig] = None  # the "mamba" layers' sizes
+    ssm: Optional[SSMConfig] = None  # the "mamba" / "mamba2" layers' sizes
     mlp_bias: Optional[bool] = None  # None: as ``bias``
     positions: bool = True           # False: neither rope nor a table
     tie_embeddings: bool = False     # the head is the embedding's transpose
@@ -182,9 +196,11 @@ PLAIN = {"full": (False, False), "full_rope": (False, True),
          "sliding": (True, False), "sliding_rope": (True, True)}
 # Attention over the keys the layer's indexer selects, with rope.
 SPARSE = "sparse_rope"
+NO_MIXER = NO_FFN = "none"      # a block without that part
 # "window": attention that sees ``cfg.window`` keys and hands nothing on.
 MIXERS = ("attention", "window", "mamba", "gmu", "cross", "conv", *PLAIN,
-          SPARSE)
+          SPARSE, "mamba2", NO_MIXER)
+FFNS = ("dense", "expert", NO_FFN)
 
 
 # BERT-large hyperparameters (the reference benchmark target).
@@ -580,7 +596,7 @@ class LatentAttention(nn.Module):
 
 class Block(nn.Module):
     cfg: TransformerConfig
-    expert: bool = False    # the FFN is the expert layer (cfg.moe)
+    ffn: str = "dense"              # one of FFNS: "expert" is cfg.moe's
     mixer: Optional[str] = None     # one of MIXERS (cfg.mixers[layer])
     depth: int = 0                  # the layer's index in the whole model
 
@@ -593,14 +609,21 @@ class Block(nn.Module):
         and ``shared_kv`` are what earlier layers made: inputs of the
         block, which recomputation keeps and does not make again."""
         cfg = self.cfg
+        if self.mixer == NO_MIXER and self.ffn == NO_FFN:
+            raise ValueError("a block with neither a mixer nor an FFN")
 
         def out(name, y):
             return _norm(cfg, name)(y) if cfg.sandwich_norm else y
 
-        h = _norm(cfg, "ln1")(x)
-        made = None
+        # A part that is not there has no norm and no residual add.
+        h = None if self.mixer == NO_MIXER else _norm(cfg, "ln1")(x)
+        a = made = None
         if self.mixer == "mamba":
             a, made = MambaMixer(cfg, name="mamba")(h)
+        elif self.mixer == "mamba2":
+            a = Mamba2Mixer(cfg, name="mamba2")(h)
+        elif self.mixer == NO_MIXER:
+            pass
         elif self.mixer == "gmu":
             a = GatedMemoryUnit(cfg, name="gmu")(h, memory)
         elif self.mixer == "conv":
@@ -616,12 +639,15 @@ class Block(nn.Module):
         else:
             attention = LatentAttention if cfg.mla else Attention
             a = attention(cfg, name="attn")(h, mask)
-        x = x + out("ln1_out", a)
+        if a is not None:
+            x = x + out("ln1_out", a)
+        if self.ffn == NO_FFN:
+            return x, made
         # A router placed before attention scores what attention read.
-        scores_from = h if (self.expert and cfg.moe.router_reads
+        scores_from = h if (self.ffn == "expert" and cfg.moe.router_reads
                             == "attention") else None
         h = _norm(cfg, "ln2")(x)
-        if self.expert:
+        if self.ffn == "expert":
             ffn = MoELayer(cfg.moe, dtype=cfg.dtype, name="moe")(
                 h, scores_from)
         else:
@@ -645,11 +671,13 @@ def _block(cfg):
         return nn.remat(Block,
                         policy=policies.dots_with_no_batch_dims_saveable)
     if cfg.remat == "flash":
-        # What the Mosaic kernels hand their backward passes: neither
-        # the flash forward nor the selective scan runs again.
-        from ..ops import flash_attention, selective_scan
+        # What the Mosaic kernels and the Mamba-2 recurrence hand their
+        # backward passes: neither the flash forward nor either scan
+        # runs again.
+        from ..ops import flash_attention, selective_scan, ssd
         return nn.remat(Block, policy=policies.save_only_these_names(
-            *flash_attention.SAVED_NAMES, *selective_scan.SAVED_NAMES))
+            *flash_attention.SAVED_NAMES, *selective_scan.SAVED_NAMES,
+            *ssd.SAVED_NAMES))
     return nn.remat(Block) if cfg.remat else Block
 
 
@@ -684,7 +712,8 @@ class MTPModule(nn.Module):
                                   _norm(cfg, "hidden_norm")(h)], axis=-1)
         x = nn.Dense(cfg.hidden, dtype=cfg.dtype, use_bias=False,
                      name="proj")(joined)
-        x = _block(cfg)(cfg, expert=cfg.moe is not None, name="block")(x)
+        x = _block(cfg)(cfg, ffn="expert" if cfg.moe else "dense",
+                        name="block")(x)
         return x, _norm(cfg, "ln_f")(x)
 
 
@@ -710,8 +739,17 @@ class Backbone(nn.Module):
                 f"mixers {cfg.mixers}: one of {MIXERS} a layer "
                 f"({cfg.layers}), in a stack that runs once with "
                 f"neither latent attention nor MTP modules")
+        ffns = cfg.ffns or tuple(
+            "expert" if cfg.moe is not None and i >= cfg.moe.first_dense
+            else "dense" for i in range(cfg.layers))
+        if (len(ffns) != cfg.layers or set(ffns) - set(FFNS)
+                or (NO_FFN in ffns and cfg.mixers is None)
+                or ("expert" in ffns and cfg.moe is None)):
+            raise ValueError(
+                f"ffns {cfg.ffns}: one of {FFNS} a layer ({cfg.layers}); "
+                f"\"expert\" needs moe, and {NO_FFN!r} a stack of mixers")
         if cfg.mixers is not None:
-            publish_stack_layers(cfg.mixers)
+            publish_stack_layers(cfg.mixers, ffns)
         embed = nn.Embed(cfg.vocab_size, cfg.hidden, dtype=cfg.dtype,
                          name="tok_embed")
         x = embed(tokens)
@@ -733,13 +771,12 @@ class Backbone(nn.Module):
         # gradients are the sums over their readers.
         made = {"mamba": None, "attention": None}
         for i in range(cfg.layers):
-            expert = cfg.moe is not None and i >= cfg.moe.first_dense
             if cfg.mixers is None:
-                x = block(cfg, expert=expert, name=f"block_{i}")(x, mask)
+                x = block(cfg, ffn=ffns[i], name=f"block_{i}")(x, mask)
                 continue
             mixer = cfg.mixers[i]
             depth = cfg.layer_indices[i] if cfg.layer_indices else i
-            x, new = block(cfg, expert=expert, mixer=mixer, depth=depth,
+            x, new = block(cfg, ffn=ffns[i], mixer=mixer, depth=depth,
                            name=f"block_{i}")(x, mask, made["mamba"],
                                               made["attention"])
             if mixer in made:
@@ -759,10 +796,11 @@ class Backbone(nn.Module):
         return tuple(outs)
 
 
-def publish_stack_layers(mixers):
+def publish_stack_layers(mixers, ffns=()):
     """Set ``hvd_stack_layers{kind}`` from the kinds of a mixed stack's
-    layers (``cfg.mixers``), so that a run's metrics say what stack it
-    ran; a kind of ``MIXERS`` that the stack lacks reads 0. ``Backbone``
+    layers (``cfg.mixers``) and ``hvd_stack_ffns{kind}`` from their
+    FFNs, so that a run's metrics say what stack it ran; a kind of
+    ``MIXERS`` or ``FFNS`` that the stack lacks reads 0. ``Backbone``
     calls it as it is built (under ``jit``: as it is traced). A no-op
     when ``HOROVOD_TPU_METRICS`` is off."""
     from ..telemetry import core as telemetry
@@ -774,6 +812,12 @@ def publish_stack_layers(mixers):
         "token mixer (TransformerConfig.mixers)", ("kind",))
     for kind in MIXERS:
         layers.labels(kind=kind).set(float(mixers.count(kind)))
+    blocks = telemetry.gauge(
+        "hvd_stack_ffns",
+        "Layers of the mixed stack last built, by their FFN "
+        "(TransformerConfig.ffns: dense, expert, or none)", ("kind",))
+    for kind in FFNS:
+        blocks.labels(kind=kind).set(float(ffns.count(kind)))
 
 
 class TransformerLM(nn.Module):

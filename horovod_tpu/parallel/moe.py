@@ -1,6 +1,17 @@
 """Sparse experts: a dropless expert layer, routed by a sigmoid or a
 softmax, that is told which experts it holds.
 
+An expert, routed or shared, has one of two forms (``MoEConfig.gate``):
+
+    gated     W_down (act(W_gate x) * W_up x)    "silu" (SwiGLU), "relu" (ReGLU)
+    ungated   W_down act(W_up x)                 "relu2": act(v) = relu(v)^2
+
+The gated form has three matrices an expert (``w_gate``, ``w_up``,
+``w_down``; ``shared_gate``, ``shared_up``, ``shared_down``), the
+ungated form two: it has no ``w_gate`` and no ``shared_gate`` leaf,
+keeps one product of the sized rows where the gated form keeps two, and
+pulls a gradient back through four grouped products, not six.
+
 The layer routes every token over all the experts the model has (the
 router keeps its published width and its experts per token) and computes
 the part of the result that the experts held here give, plus the shared
@@ -43,7 +54,13 @@ How it computes, and why (PERF.md section 3, "expert layer"):
   zeroed wherever they could reach a result.
 - The experts' products (scope ``hvd_moe/experts``) are
   ``jax.lax.ragged_dot`` over the groups: XLA's own grouped Mosaic
-  kernel on the TPU, with both gradients.
+  kernel on the TPU, with both gradients. That kernel tiles the two
+  widths by what divides them; where one is not whole lane tiles (an
+  expert 1856 wide) it runs at a ninth of its bound and its time follows
+  the groups' sizes more than their sum, so the sized rows' products
+  (``_grouped``, ``_grouped_weights``) go through the kernels of
+  ``ops/grouped_product.py`` there, on the TPU, from shapes alone; the
+  full-size path, which JAX differentiates, keeps ``ragged_dot``.
 - What is kept for the way back. Where there are two sizes
   (``_sized_or_routed``) the sized path keeps its gate and up products
   before the activation and its rows' places in pair order
@@ -102,7 +119,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from ..ops import rows_to_tokens
+from ..ops import grouped_product, rows_to_tokens
 from ..utils.jax_compat import pvary
 
 # Names in a device trace (docs/tracing.md); readers match the literals.
@@ -116,8 +133,13 @@ SCORINGS = ("sigmoid", "softmax")
 # What the router reads: the experts' own input, or the block's normed
 # input before attention, which the block hands in (``scores_from``).
 ROUTER_READS = ("ffn", "attention")
-# An expert is ``W_down(gate(W_gate x) * W_up x)``: SwiGLU or ReGLU.
-GATES = {"silu": nn.silu, "relu": nn.relu}
+# An expert's activation. "silu" and "relu" act on a gate product of
+# their own, ``W_down(act(W_gate x) * W_up x)``: SwiGLU and ReGLU. The
+# values of UNGATED act on the one product there is, ``W_down act(W_up
+# x)``, and the expert has no gate matrix.
+GATES = {"silu": nn.silu, "relu": nn.relu,
+         "relu2": lambda v: jnp.square(nn.relu(v))}
+UNGATED = ("relu2",)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -130,7 +152,9 @@ class MoEConfig:
     scale: float = 1.0              # routed_scaling_factor
     first_dense: int = 1            # leading layers with a dense FFN
     scoring: str = "sigmoid"        # of SCORINGS: the weights' form
-    gate: str = "silu"              # of GATES: every expert's activation
+    # Of GATES, every expert's activation: "silu" and "relu" with a gate
+    # matrix, "relu2" (squared ReLU) without one.
+    gate: str = "silu"
     router_reads: str = "ffn"       # of ROUTER_READS
 
     @property
@@ -138,11 +162,20 @@ class MoEConfig:
         return self.held or (0, self.experts)
 
 
+def _hidden(gate, product, x, w_gate, w_up):
+    """An expert's hidden rows from ``product(x, w)``: gated, or with no
+    ``w_gate`` the activation of the one product."""
+    if w_gate is None:
+        return GATES[gate](product(x, w_up))
+    return GATES[gate](product(x, w_gate)) * product(x, w_up)
+
+
 def swiglu(x, w_gate, w_up, w_down, gate="silu"):
     """``W_down(gate(W_gate x) * W_up x)``: a dense gated FFN, the shared
-    expert's form and every routed expert's."""
-    h = GATES[gate](jnp.dot(x, w_gate.astype(x.dtype))) * jnp.dot(
-        x, w_up.astype(x.dtype))
+    expert's form and every routed expert's; with ``w_gate`` None the
+    ungated form, ``W_down gate(W_up x)``."""
+    h = _hidden(gate, lambda a, w: jnp.dot(a, w.astype(a.dtype)), x, w_gate,
+                w_up)
     return jnp.dot(h, w_down.astype(x.dtype))
 
 
@@ -207,7 +240,7 @@ def _routed(x, w_gate, w_up, w_down, chosen, weights, drawn, first_held,
     """The held experts' part of the layer's output for tokens ``x``
     (T, d): sort, grouped products, un-sort, weigh."""
     tokens, k = chosen.shape
-    held = w_gate.shape[0]
+    held = w_up.shape[0]
     with jax.named_scope(SCOPE_ROUTE):
         local = chosen.reshape(-1) - first_held
         mine = (local >= 0) & (local < held)
@@ -227,8 +260,7 @@ def _routed(x, w_gate, w_up, w_down, chosen, weights, drawn, first_held,
     with jax.named_scope(SCOPE_EXPERTS):
         def product(a, w):
             return lax.ragged_dot(a, w.astype(a.dtype), sizes)
-        ys = product(GATES[gate](product(xs, w_gate)) * product(xs, w_up),
-                     w_down)
+        ys = product(_hidden(gate, product, xs, w_gate, w_up), w_down)
     with jax.named_scope(SCOPE_ROUTE):
         ys = _ungather(jnp.where(rows, ys, 0), order, inverse)
         weights = jnp.where(mine.reshape(tokens, k), weights, 0.0)
@@ -265,23 +297,23 @@ def took_sized_path(drawn, first, end):
     return rows < pairs and float(drawn[first:end].sum()) <= rows
 
 
-def _held_sizes(w_gate, drawn, first_held):
+def _held_sizes(w_up, drawn, first_held):
     """The held experts' draws, (held,) int32."""
-    return lax.dynamic_slice(drawn, (first_held,), (w_gate.shape[0],)
+    return lax.dynamic_slice(drawn, (first_held,), (w_up.shape[0],)
                              ).astype(jnp.int32)
 
 
 def _fits(rows, routed):
-    _, w_gate, _, _, _, _, drawn, first_held = routed
-    return jnp.sum(_held_sizes(w_gate, drawn, first_held)) <= rows
+    _, _, w_up, _, _, _, drawn, first_held = routed
+    return jnp.sum(_held_sizes(w_up, drawn, first_held)) <= rows
 
 
-def _held_draw(rows, w_gate, drawn, first_held):
+def _held_draw(rows, w_up, drawn, first_held):
     """(the grouped products' group sizes: the held experts' draws;
     which of ``rows`` sorted pairs are pairs of theirs, (rows, 1)).
     A draw over ``rows`` reads as no draw at all: that step's result
     comes from ``_routed``."""
-    sizes = _held_sizes(w_gate, drawn, first_held)
+    sizes = _held_sizes(w_up, drawn, first_held)
     sizes = jnp.where(jnp.sum(sizes) <= rows, sizes, 0)
     return sizes, (jnp.arange(rows) < jnp.sum(sizes))[:, None]
 
@@ -294,24 +326,58 @@ def _held_key(chosen, first_held, held):
     return jnp.where((local >= 0) & (local < held), local, held)
 
 
+def _grouped(a, w, sizes, transposed=False):
+    """The sized rows ``a`` (rows, k), each group's against its expert's
+    matrix of ``w`` (held, k, n), or against its transpose where
+    ``transposed`` (``w`` (held, n, k)): XLA's grouped kernel, or for
+    widths that are not whole lane tiles, which it tiles badly, the
+    Pallas kernels of ``ops/grouped_product.py`` (from shapes alone;
+    never off the TPU). Neither reads a row past the groups, and what
+    either leaves in such rows is not specified."""
+    if grouped_product.takes(a.shape[0], *w.shape[1:], a.dtype):
+        return grouped_product.rows_by_group(a, w, sizes, transposed)
+    return lax.ragged_dot(a, jnp.swapaxes(w, 1, 2) if transposed else w,
+                          sizes)
+
+
+# A grouped product's gradient to its weights: each group's rows of the
+# left operand against the same rows of the cotangent (what JAX's own
+# transpose of ``lax.ragged_dot`` makes).
+_BY_GROUP = lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+def _grouped_weights(a, ct, sizes):
+    """``_grouped``'s gradient to its matrices, (held, k, n): each
+    group's rows of ``a`` (rows, k) against the same rows of ``ct``
+    (rows, n), by the kernels that ``_grouped`` takes at these widths
+    (theirs sums and returns float32)."""
+    if grouped_product.takes(a.shape[0], a.shape[1], ct.shape[1], a.dtype):
+        return grouped_product.weights_by_group(a, ct, sizes)
+    return lax.ragged_dot_general(a, ct, sizes, _BY_GROUP)
+
+
 def _sized_rows(rows, x, w_gate, w_up, chosen, drawn, first_held):
     """The sized path as far as the experts' activation: the first
     ``rows`` pairs of the sorted order hold every pair of the experts
     held, so only their tokens' rows are gathered and multiplied.
-    Returns the gate and up products and the rows' places in pair
-    order: what ``_sized`` goes on from and ``_sized_back`` reads in
-    place of making it again (``kept_bytes``)."""
-    held = w_gate.shape[0]
+    Returns the gate and up products (the gate's None where the experts
+    have no gate matrix) and the rows' places in pair order: what
+    ``_sized`` goes on from and ``_sized_back`` reads in place of making
+    it again (``kept_bytes``)."""
+    held = w_up.shape[0]
     with jax.named_scope(SCOPE_ROUTE):
         key = _held_key(chosen, first_held, held)
         order = _vary_like(jnp.argsort(key, stable=True), key)[:rows]
-        sizes, _ = _held_draw(rows, w_gate, drawn, first_held)
+        sizes, _ = _held_draw(rows, w_up, drawn, first_held)
         # Rows past the held pairs are other tokens' own, left as they
         # are: a grouped product reads no row past its groups, and the
         # way back is ``_sized_back``, which zeroes what it must.
         xs = x[order // chosen.shape[1]]
     with jax.named_scope(SCOPE_EXPERTS):
-        gated, up = (lax.ragged_dot(xs, w.astype(xs.dtype), sizes)
+        gated, up = (None if w is None else
+                     _grouped(xs, w.astype(xs.dtype), sizes)
                      for w in (w_gate, w_up))
     return gated, up, order
 
@@ -392,27 +458,24 @@ def _to_tokens(buffer, order, sizes, chosen, first_held, experts,
         k, tiles, None if weights is None else weights.reshape(-1))
 
 
+def _activated(gate, gated, up):
+    """The hidden rows from the kept products (``_sized_rows``)."""
+    return GATES[gate](up) if gated is None else GATES[gate](gated) * up
+
+
 def _sized(rows, kept, x, w_gate, w_up, w_down, chosen, weights, drawn,
            first_held, gate="silu"):
     """``_routed`` for a draw of at most ``rows`` pairs, from
     ``_sized_rows``: the activation, the down product, and each token's
     rows weighed and summed (``_into_tokens``)."""
     gated, up, order = kept
-    sizes, _ = _held_draw(rows, w_gate, drawn, first_held)
+    sizes, _ = _held_draw(rows, w_up, drawn, first_held)
     with jax.named_scope(SCOPE_EXPERTS):
-        ys = lax.ragged_dot(GATES[gate](gated) * up,
-                            w_down.astype(up.dtype), sizes)
+        ys = _grouped(_activated(gate, gated, up), w_down.astype(up.dtype),
+                      sizes)
     with jax.named_scope(SCOPE_ROUTE):
         return _to_tokens(ys, order, sizes, chosen, first_held,
                           drawn.shape[0], weights)
-
-
-# A grouped product's gradient to its weights: each group's rows of the
-# left operand against the same rows of the cotangent (what JAX's own
-# transpose of ``lax.ragged_dot`` makes).
-_BY_GROUP = lax.RaggedDotDimensionNumbers(
-    dot_dimension_numbers=(((0,), (0,)), ((), ())),
-    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
 
 
 def _sized_back(rows, gate, g, kept, x, w_gate, w_up, w_down, chosen,
@@ -427,10 +490,13 @@ def _sized_back(rows, gate, g, kept, x, w_gate, w_up, w_down, chosen,
     gradient and ``u * weight`` is ``h``'s. What a grouped product
     leaves in rows past its groups is not specified: such rows are
     selected away wherever they reach a result (the tokens' gradient,
-    the weights'), never multiplied by 0."""
+    the weights'), never multiplied by 0. Experts without a gate matrix
+    kept one product and pull back through four grouped products where
+    the gated form's six are; ``w_gate``'s place in what is returned
+    holds None."""
     gated, up, order = kept
     with jax.named_scope(SCOPE_ROUTE):
-        sizes, live = _held_draw(rows, w_gate, drawn, first_held)
+        sizes, live = _held_draw(rows, w_up, drawn, first_held)
         token = order // chosen.shape[1]
         # Not zeroed past the held pairs: those rows are other tokens'
         # own, and a grouped product reads no row past its groups.
@@ -438,18 +504,18 @@ def _sized_back(rows, gate, g, kept, x, w_gate, w_up, w_down, chosen,
         weight = weights.reshape(-1)[order].astype(g.dtype)[:, None]
     with jax.named_scope(SCOPE_EXPERTS):
         def to_rows(ct, w):
-            return lax.ragged_dot(
-                ct, jnp.swapaxes(w.astype(ct.dtype), 1, 2), sizes)
+            return _grouped(ct, w.astype(ct.dtype), sizes, transposed=True)
 
         def to_weights(a, ct, w):
-            return lax.ragged_dot_general(a, ct, sizes, _BY_GROUP).astype(
-                w.dtype)
-        h, back = jax.vjp(lambda a, b: GATES[gate](a) * b, gated, up)
+            return _grouped_weights(a, ct, sizes).astype(w.dtype)
+        h, back = jax.vjp(functools.partial(_activated, gate), gated, up)
         u = to_rows(gs, w_down)
-        d_gated, d_up = back(u * weight)
-        d_xs = to_rows(d_gated, w_gate), to_rows(d_up, w_up)
-        d_w = (to_weights(xs, d_gated, w_gate), to_weights(xs, d_up, w_up),
-               to_weights(h * weight, gs, w_down))
+        # A kept product's cotangent beside the matrix it is of; without
+        # a gate matrix both are None.
+        kept_of = list(zip(back(u * weight), (w_gate, w_up)))
+        d_xs = [to_rows(d, w) for d, w in kept_of if w is not None]
+        d_w = ([None if w is None else to_weights(xs, d, w)
+                for d, w in kept_of] + [to_weights(h * weight, gs, w_down)])
         d_weight = jnp.sum(h.astype(weights.dtype) * u.astype(weights.dtype),
                            -1, keepdims=True)
     with jax.named_scope(SCOPE_ROUTE):
@@ -457,7 +523,7 @@ def _sized_back(rows, gate, g, kept, x, w_gate, w_up, w_down, chosen,
             jnp.where(live, d_weight, 0)[:, 0])
         # The sum of the two products is the way back's first pass, not
         # a product's: a reader of ``hvd_moe/experts`` does not meet it.
-        return (_to_tokens(d_xs[0] + d_xs[1], order, sizes, chosen,
+        return (_to_tokens(sum(d_xs[1:], d_xs[0]), order, sizes, chosen,
                            first_held, drawn.shape[0]), *d_w,
                 d_weights.reshape(weights.shape))
 
@@ -477,12 +543,13 @@ def _routed_back(gate, g, kept, *routed):
     return jax.vjp(of, *(routed[i] for i in _TRAINED))[1](g)
 
 
-def kept_bytes(rows, width):
+def kept_bytes(rows, width, gate="silu"):
     """Bytes an expert layer on ``rows`` sized rows of experts ``width``
     wide keeps from its forward pass for its backward pass
-    (``_sized_rows``): the gate and up products in bfloat16, the rows'
-    places in int32."""
-    return rows * (2 * width * 2 + 4)
+    (``_sized_rows``): the gate and up products in bfloat16 (the one
+    product of experts without a gate matrix), the rows' places in
+    int32."""
+    return rows * ((1 if gate in UNGATED else 2) * width * 2 + 4)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
@@ -544,7 +611,8 @@ def _sized_or_routed(rows, gate, x, w_gate, w_up, w_down, chosen, weights,
 
 def _sized_or_routed_fwd(rows, gate, *routed):
     y, kept = _either(rows, gate, *routed)
-    return y, (tuple(checkpoint_name(r, KEPT_NAME) for r in kept), routed)
+    return y, (tuple(None if r is None else checkpoint_name(r, KEPT_NAME)
+                     for r in kept), routed)
 
 
 def _sized_or_routed_bwd(rows, gate, res, g):
@@ -560,8 +628,10 @@ def _vary_together(*xs):
     """``xs``, each marked varying over every mesh axis that any of
     them varies over (inside ``shard_map``): a hand-written backward
     pass hands each its cotangent as varying as the result."""
-    axes = frozenset().union(*(jax.typeof(x).vma for x in xs))
-    return [functools.reduce(pvary, sorted(axes - jax.typeof(x).vma), x)
+    axes = frozenset().union(*(jax.typeof(x).vma for x in xs
+                               if x is not None))
+    return [x if x is None else
+            functools.reduce(pvary, sorted(axes - jax.typeof(x).vma), x)
             for x in xs]
 
 
@@ -577,16 +647,17 @@ def moe_apply(x, params, bias, *, k, scale=1.0, first_held=0,
     ``w_up`` (held, d, f) and ``w_down`` (held, f, d) of the experts
     held, which are experts ``first_held`` and on (``first_held`` may be
     traced, e.g. from ``lax.axis_index``); optionally ``shared_gate``,
-    ``shared_up`` (d, fs), ``shared_down`` (fs, d). ``bias`` (E,): the
-    selection bias, a buffer."""
+    ``shared_up`` (d, fs), ``shared_down`` (fs, d). Under a ``gate`` of
+    ``UNGATED`` there is neither ``w_gate`` nor ``shared_gate``.
+    ``bias`` (E,): the selection bias, a buffer."""
     pairs = x.shape[0] * k
-    rows = sized_rows(pairs, params["w_gate"].shape[0],
+    rows = sized_rows(pairs, params["w_up"].shape[0],
                       params["router"].shape[1])
     with jax.named_scope(SCOPE), jax.named_scope(SCOPE_ROUTE):
         chosen, weights, drawn = route(
             x if scores_from is None else scores_from, params["router"],
             bias, k=k, scale=scale, scoring=scoring)
-    routed = (x, params["w_gate"], params["w_up"], params["w_down"],
+    routed = (x, params.get("w_gate"), params["w_up"], params["w_down"],
               chosen, weights, drawn, first_held)
     if rows == pairs:
         with jax.named_scope(SCOPE):
@@ -594,9 +665,9 @@ def moe_apply(x, params, bias, *, k, scale=1.0, first_held=0,
                 *routed)
     else:
         y = _sized_or_routed(rows, gate, *_vary_together(*routed))
-    if "shared_gate" in params:
+    if "shared_up" in params:
         with jax.named_scope(SCOPE), jax.named_scope(SCOPE_EXPERTS):
-            y = y + swiglu(x, params["shared_gate"], params["shared_up"],
+            y = y + swiglu(x, params.get("shared_gate"), params["shared_up"],
                            params["shared_down"], gate)
     return y, drawn
 
@@ -625,21 +696,19 @@ class MoELayer(nn.Module):
         first, end = cfg.span
         init = nn.initializers.lecun_normal()
         batched = nn.initializers.lecun_normal(batch_axis=(0,))
-        params = {
-            "router": self.param("router", init, (d, cfg.experts)),
-            "w_gate": self.param("w_gate", batched,
-                                 (end - first, d, cfg.width)),
-            "w_up": self.param("w_up", batched,
-                               (end - first, d, cfg.width)),
-            "w_down": self.param("w_down", batched,
-                                 (end - first, cfg.width, d)),
-        }
-        if cfg.shared:
-            wide = cfg.shared * cfg.width
-            params.update(
-                shared_gate=self.param("shared_gate", init, (d, wide)),
-                shared_up=self.param("shared_up", init, (d, wide)),
-                shared_down=self.param("shared_down", init, (wide, d)))
+        wide = cfg.shared * cfg.width
+        # An ungated expert has no gate leaf, routed or shared.
+        shapes = {"router": (init, (d, cfg.experts)),
+                  "w_gate": (batched, (end - first, d, cfg.width)),
+                  "w_up": (batched, (end - first, d, cfg.width)),
+                  "w_down": (batched, (end - first, cfg.width, d)),
+                  "shared_gate": (init, (d, wide)),
+                  "shared_up": (init, (d, wide)),
+                  "shared_down": (init, (wide, d))}
+        params = {name: self.param(name, *shape)
+                  for name, shape in shapes.items()
+                  if (cfg.shared or not name.startswith("shared"))
+                  and not (cfg.gate in UNGATED and name.endswith("gate"))}
         bias = self.variable(STATE, "bias", jnp.zeros, (cfg.experts,))
         tokens = self.variable(STATE, "expert_tokens", jnp.zeros,
                                (cfg.experts,))
@@ -654,10 +723,11 @@ class MoELayer(nn.Module):
         return y.reshape(x.shape)
 
 
-def publish_expert_tokens(state, held=None, width=None):
+def publish_expert_tokens(state, held=None, width=None, gate="silu"):
     """Set ``hvd_moe_expert_tokens{layer,expert}``,
     ``hvd_moe_held_share``, ``hvd_moe_buffer_rows{layer}``,
-    ``hvd_moe_sized_layers`` and, given the experts' ``width``,
+    ``hvd_moe_sized_layers`` and, given the experts' ``width`` (and
+    their ``gate``, where they have no gate matrix),
     ``hvd_moe_kept_bytes{layer}`` from the ``moe_state`` collection a
     train step returned. Call it outside the step; it fetches the
     arrays. A no-op when ``HOROVOD_TPU_METRICS`` is off."""
@@ -703,7 +773,7 @@ def publish_expert_tokens(state, held=None, width=None):
         buffer_rows.labels(layer=layer).set(rows)
         if width:
             kept.labels(layer=layer).set(
-                kept_bytes(rows, width) if rows < pairs else 0)
+                kept_bytes(rows, width, gate) if rows < pairs else 0)
         fitted += took_sized_path(drawn, first, end)
         mine += float(drawn[first:end].sum())
         total += float(drawn.sum())
