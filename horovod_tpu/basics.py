@@ -226,6 +226,12 @@ def init(comm=None, process_sets=None):
                     envparse.TIMELINE_MARK_CYCLES))
             runtime.timeline.start()
 
+        # The host while the step runs (docs/tracing.md): the pulse and
+        # the collector's hook, after the timeline that follows their
+        # spans. An elastic reset's init finds them running.
+        from .utils import pulse
+        pulse.start()
+
         # Metrics plane (docs/metrics.md): when the job has a launcher
         # rendezvous, push this rank's snapshot to the driver KV store
         # on a timer so its /metrics route can serve the cluster roll-up.
@@ -263,6 +269,9 @@ def shutdown():
             return
         if _runtime.coordinator is not None:
             _runtime.coordinator.stop()
+        # Before the timeline, which writes the spans it hands over last.
+        from .utils import pulse
+        pulse.stop()
         if _runtime.timeline is not None:
             _runtime.timeline.stop()
         if _runtime.tracer is not None:

@@ -164,7 +164,9 @@ def _joined_version():
 def _reset():
     """shutdown(); init() — re-rendezvous with new ranks from the driver
     (reference: horovod/torch/elastic/__init__.py:46-48)."""
-    basics.shutdown()
+    from .utils import pulse
+    with pulse.kept():      # the reset is a pause it should time
+        basics.shutdown()
     basics.init()
 
 

@@ -65,7 +65,7 @@ class Timeline:
 
     def span(self, name, owner, start, end):
         """A span that has ended, in seconds on this file's clock: the
-        start-up log's (``compile_cache.follow``), one row an owner."""
+        program's log's (``compile_cache.follow``), one row an owner."""
         if self._running:
             self._queue.put(("X", (owner,), name, int(start * 1e6),
                              int((end - start) * 1e6)))
@@ -149,7 +149,9 @@ class Timeline:
         for name in names:
             tid = pids.setdefault(name, len(pids) + 1)
             if phase == "X":
-                self._emit(file, {"name": activity, "cat": "hvd_startup",
+                category = ("hvd_host" if activity in compile_cache.STEADY
+                            else "hvd_startup")
+                self._emit(file, {"name": activity, "cat": category,
                                   "ph": "X", "ts": ts_us, "dur": dur_us[0],
                                   "pid": 0, "tid": tid,
                                   "args": {"owner": name}}, first)

@@ -1,15 +1,16 @@
 """JAX's persistent compilation cache at a path that does not move,
-and the process's start-up log: what ran from the process's start to
-the first step, each span under the program or package it belonged to.
+and the program's log: what ran from the process's start to the first
+step, each span under the program or package it belonged to, and after
+it the pauses of the host.
 
 The cache directory is part of the cache key, so it must be the same in
 every process of a checkout: where ``JAX_COMPILATION_CACHE_DIR`` is set
 JAX reads it and this module sets nothing; otherwise the cache lives in
 ``<checkout>/.jax_cache`` (gitignored), derived from this file's path.
 
-The log (docs/tracing.md "From the process's start to the first step")
-is one list of spans ``(name, owner, start, end)`` on
-``time.perf_counter()``, the timeline's clock:
+The log (docs/tracing.md "From the process's start to the first step"
+and "The host while the step runs") is one list of spans ``(name,
+owner, start, end)`` on ``time.perf_counter()``, the timeline's clock:
 
 - ``before_program``: from the process's start (the kernel's record) to
   the first line of ``horovod_tpu/__init__.py``; owner ``horovod_tpu``.
@@ -26,17 +27,26 @@ is one list of spans ``(name, owner, start, end)`` on
   ``cache_miss`` (instants): JAX sends them without a name, inside a
   backend compilation; they take that span's owner when it arrives and
   have owner None until then.
+- ``host_pause`` (owner ``pulse``) and ``gc`` (owner ``gen0`` /
+  ``gen1`` / ``gen2``): ``utils/pulse.py``'s, a wake-up of the pulse
+  that came late and a collection of a millisecond or more. They are the
+  entries that arrive in the steady state, so the log keeps the newest
+  ``STEADY_KEPT`` of them; what it keeps of the rest has no bound
+  (ROADMAP D11).
 
 ``events()`` is the older view of the same list: ``(phase, value,
 perf_counter)`` of the compile pipeline's entries. An entry is one list
 append and arrives only when something is imported, initialised or
-compiled, so the steady state pays nothing. With ``HOROVOD_TPU_METRICS``
-on, the same arrivals feed ``hvd_startup_seconds{stage}``,
+compiled, or when the host stood still: a steady step that nothing
+interrupts leaves none (what the pulse itself costs is in
+``utils/pulse.py``). With ``HOROVOD_TPU_METRICS`` on, the same
+arrivals feed ``hvd_startup_seconds{stage}``,
 ``hvd_compile_seconds{phase}``, ``hvd_compile_cache_hits_total`` and
 ``hvd_compile_cache_misses_total`` (docs/metrics.md): a recompile after
 warm-up is a counter that moves, and the log says which program it was.
 """
 
+import collections
 import os
 import threading
 import time
@@ -79,6 +89,10 @@ _STEP_STAGES = {"trace": "step_trace", "lower": "step_lower",
 STAGES = ("before_program", "import", "init", *_STEP_STAGES.values(),
           "other_programs")
 
+# The spans that arrive in the steady state, and how many are kept.
+STEADY = ("host_pause", "gc")
+STEADY_KEPT = 1024
+
 
 class _Union:
     """Seconds under spans that arrive in the order they end, a span
@@ -114,6 +128,9 @@ class _Union:
 # for a span that is not the compile pipeline's.
 _lock = threading.Lock()
 _log = []
+# The steady state's entries, each with the length the log had when it
+# arrived: its place among entries that are never taken out.
+_steady = collections.deque(maxlen=STEADY_KEPT)
 _stages = {stage: _Union() for stage in STAGES}
 _followers = []
 _listening = False
@@ -158,7 +175,10 @@ def record(name, owner, start, end, value=None):
                 if waiting[1] is None:
                     waiting[1] = owner
                     _publish(waiting)
-        _log.append(entry)
+        if name in STEADY:
+            _steady.append((len(_log), entry))
+        else:
+            _log.append(entry)
         if owner is not None:
             _publish(entry)
 
@@ -225,11 +245,23 @@ def listen():
     jax.monitoring.register_event_listener(_on_event)
 
 
+def _entries():
+    """The log with the steady state's entries in their places, in
+    arrival order. Called under the lock."""
+    entries, at = [], 0
+    for place, entry in _steady:
+        entries += _log[at:place]
+        entries.append(entry)
+        at = place
+    return entries + _log[at:]
+
+
 def spans():
     """A copy of the log: ``(name, owner, start, end)`` tuples in
-    arrival order, which is the order they ended in."""
+    arrival order, which is the order they ended in (a ``gc`` span
+    arrives with the pulse's next wake-up)."""
     with _lock:
-        return [tuple(entry[:4]) for entry in _log]
+        return [tuple(entry[:4]) for entry in _entries()]
 
 
 def events():
@@ -253,7 +285,7 @@ def follow(follower):
     """Call ``follower(name, owner, start, end)`` for every span in the
     log that has its owner, and for later ones as they get it."""
     with _lock:
-        for name, owner, start, end, _ in _log:
+        for name, owner, start, end, _ in _entries():
             if owner is not None:
                 follower(name, owner, start, end)
         _followers.append(follower)

@@ -95,3 +95,17 @@ def hvd():
 @pytest.fixture(scope="session")
 def n_devices():
     return len(jax.devices())
+
+
+@pytest.fixture
+def fresh_log(monkeypatch):
+    """The test writes to a program's log (``utils/compile_cache.py``)
+    of its own."""
+    import collections
+    from horovod_tpu.utils import compile_cache
+    monkeypatch.setattr(compile_cache, "_log", [])
+    monkeypatch.setattr(compile_cache, "_steady", collections.deque(
+        maxlen=compile_cache.STEADY_KEPT))
+    monkeypatch.setattr(compile_cache, "_followers", [])
+    monkeypatch.setattr(compile_cache, "_stages", {
+        stage: compile_cache._Union() for stage in compile_cache.STAGES})

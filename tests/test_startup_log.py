@@ -1,4 +1,4 @@
-"""The process's start-up log (``utils/compile_cache.py``;
+"""The program's log from the process's start (``utils/compile_cache.py``;
 docs/tracing.md "From the process's start to the first step"): spans
 with owners, the older ``events()`` view of the same list, the gauge
 and the timeline that read it."""
@@ -18,16 +18,15 @@ from horovod_tpu.timeline import Timeline
 from horovod_tpu.utils import compile_cache
 
 NAMES = {"before_program", "import", "init", "trace", "lower",
-         "backend_compile", "cache_load", "cache_hit", "cache_miss"}
+         "backend_compile", "cache_load", "cache_hit", "cache_miss",
+         *compile_cache.STEADY}
 
 
-@pytest.fixture
-def fresh_log(monkeypatch):
-    """The test writes to a log of its own."""
-    monkeypatch.setattr(compile_cache, "_log", [])
-    monkeypatch.setattr(compile_cache, "_followers", [])
-    monkeypatch.setattr(compile_cache, "_stages", {
-        stage: compile_cache._Union() for stage in compile_cache.STAGES})
+def startup_spans():
+    """The log without what the steady state leaves (``host_pause``,
+    ``gc``: a loaded test worker does pause; tests/test_pulse.py)."""
+    return [s for s in compile_cache.spans()
+            if s[0] not in compile_cache.STEADY]
 
 
 def _step(offset):
@@ -41,10 +40,10 @@ def _step(offset):
 def test_a_program_owns_the_spans_of_its_compilation():
     compile_cache.listen()
     step, x = _step(0.375), jnp.arange(6.0)
-    n, before = len(compile_cache.spans()), time.perf_counter()
+    n, before = len(startup_spans()), time.perf_counter()
     step(x).block_until_ready()
     after = time.perf_counter()
-    new = compile_cache.spans()[n:]
+    new = startup_spans()[n:]
     mine = {name: (start, end) for name, owner, start, end in new
             if owner == compile_cache.STEP_NAME}
     assert sorted(mine) == ["backend_compile", "lower", "trace"]
@@ -64,19 +63,19 @@ def test_a_program_owns_the_spans_of_its_compilation():
     assert ends == sorted(ends)
     assert {name for name, _, _, _ in compile_cache.spans()} <= NAMES
     # A second call compiles nothing and leaves nothing.
-    n = len(compile_cache.spans())
+    n = len(startup_spans())
     step(x).block_until_ready()
-    assert compile_cache.spans()[n:] == []
+    assert startup_spans()[n:] == []
     # A copy: the caller cannot edit the log.
     compile_cache.spans().clear()
-    assert len(compile_cache.spans()) == n
+    assert len(startup_spans()) == n
 
 
 def test_events_is_a_view_of_the_same_log():
     compile_cache.listen()
-    n, m = len(compile_cache.spans()), len(compile_cache.events())
+    n, m = len(startup_spans()), len(compile_cache.events())
     _step(0.625)(jnp.arange(3.0)).block_until_ready()
-    spans, events = compile_cache.spans()[n:], compile_cache.events()[m:]
+    spans, events = startup_spans()[n:], compile_cache.events()[m:]
     # What it gave: (phase, seconds, arrival), the arrival the span's
     # end and the seconds what JAX sent, the span's length.
     assert [(name, end) for name, _, _, end in spans] == [
@@ -91,13 +90,13 @@ def test_the_cache_entries_take_the_owner_of_the_compilation_around_them(
     compile_cache._on_event(compile_cache._HIT)
     compile_cache._on_seconds(
         "/jax/compilation_cache/cache_retrieval_time_sec", 0.25)
-    assert [owner for _, owner, _, _ in compile_cache.spans()] == [
+    assert [owner for _, owner, _, _ in startup_spans()] == [
         "first", None, None]
     at = time.perf_counter()
     compile_cache._on_seconds(
         "/jax/core/compile/backend_compile_duration", at - 2.5,
         fun_name="jit(hvd_train_step)")
-    assert [(name, owner) for name, owner, _, _ in compile_cache.spans()] == [
+    assert [(name, owner) for name, owner, _, _ in startup_spans()] == [
         ("backend_compile", "first"), ("cache_hit", "hvd_train_step"),
         ("cache_load", "hvd_train_step"),
         ("backend_compile", "hvd_train_step")]
@@ -222,16 +221,17 @@ def test_the_log_is_silent_with_metrics_off():
 def test_a_timeline_started_later_holds_the_spans(tmp_path):
     compile_cache.listen()
     _step(1.375)(jnp.arange(7.0)).block_until_ready()
-    n = len(compile_cache.spans())
+    n = len(startup_spans())
     timeline = Timeline(str(tmp_path / "trace.json"))
     timeline.start()
     _step(1.625)(jnp.arange(7.0)).block_until_ready()
     timeline.stop()
-    after_stop = len(compile_cache.spans())
+    after_stop = len(startup_spans())
     _step(1.875)(jnp.arange(7.0)).block_until_ready()   # not followed
     with open(timeline.shard_path) as f:
-        written = [e for e in json.load(f) if e["ph"] == "X"]
-    spans = [s for s in compile_cache.spans()[:after_stop]
+        written = [e for e in json.load(f)
+                   if e["ph"] == "X" and e["cat"] == "hvd_startup"]
+    spans = [s for s in startup_spans()[:after_stop]
              if s[1] is not None]
     assert len(written) == len(spans) > n
     for event, (name, owner, start, end) in zip(written, spans):

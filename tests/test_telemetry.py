@@ -407,11 +407,12 @@ def test_cli_usage_errors():
 # Timeline satellites: flush once per drain, race-free stop
 # ==========================================================================
 def _own_events(path):
-    """The events the test wrote: the start-up log's spans, which every
-    timeline begins with (category ``hvd_startup``, docs/tracing.md),
-    are ``tests/test_startup_log.py``'s."""
+    """The events the test wrote: the program's log's spans, which every
+    timeline begins with and follows (categories ``hvd_startup`` and
+    ``hvd_host``, docs/tracing.md), are ``tests/test_startup_log.py``'s
+    and ``tests/test_pulse.py``'s."""
     return [e for e in json.loads(path.read_text())
-            if e.get("cat") != "hvd_startup"]
+            if e.get("cat") not in ("hvd_startup", "hvd_host")]
 
 
 def test_timeline_flushes_once_per_drain(tmp_path):
